@@ -71,12 +71,12 @@ def main(argv=None):
 
     import jax
 
-    from gaussiank_sgd_tpu import virtual_cpu
     from gaussiank_sgd_tpu.benchlib import (bench_model, mfu,
                                             noise_floored_delta_ms)
+    from gaussiank_sgd_tpu.compile_cache import enable_compile_cache
 
     # persistent compile cache across matrix runs/windows (TPU backend too)
-    virtual_cpu.enable_compile_cache("/tmp/gksgd_tpu_cache")
+    enable_compile_cache()
 
     if args.densities:
         densities = tuple(float(d) for d in args.densities.split(","))

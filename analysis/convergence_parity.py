@@ -32,7 +32,7 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from gaussiank_sgd_tpu import virtual_cpu  # noqa: E402
+from gaussiank_sgd_tpu import compile_cache, virtual_cpu  # noqa: E402
 
 ARTIFACTS = os.path.join(REPO, "analysis", "artifacts")
 
@@ -144,7 +144,7 @@ def main(argv=None):
     args = p.parse_args(argv)
 
     virtual_cpu.provision(args.devices)
-    virtual_cpu.enable_compile_cache()
+    compile_cache.enable_compile_cache()
     os.makedirs(ARTIFACTS, exist_ok=True)
 
     dataset_kwargs = dict(args.dataset_kwargs)

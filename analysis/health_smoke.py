@@ -61,9 +61,9 @@ def _run(cfg, nan_steps=None) -> str:
 
 
 def main(argv: Optional[List[str]] = None) -> int:
-    from gaussiank_sgd_tpu import virtual_cpu
+    from gaussiank_sgd_tpu import compile_cache, virtual_cpu
     virtual_cpu.provision(8)
-    virtual_cpu.enable_compile_cache()
+    compile_cache.enable_compile_cache()
     failures: List[str] = []
     with tempfile.TemporaryDirectory(prefix="health_smoke_") as tmp:
         # -- scenario 1: clean run gates green --------------------------

@@ -340,9 +340,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     if args.smoke:
-        from gaussiank_sgd_tpu import virtual_cpu
+        from gaussiank_sgd_tpu import compile_cache, virtual_cpu
         virtual_cpu.provision(8)
-        virtual_cpu.enable_compile_cache()
+        compile_cache.enable_compile_cache()
         import tempfile
         with tempfile.TemporaryDirectory() as td:
             result = run_smoke(td)

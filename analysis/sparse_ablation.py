@@ -37,13 +37,13 @@ def main(argv=None):
     # the ef_only/sel_nores prefix probes live in benchlib.ablation_specs
     # (shared with analysis/bench_matrix.py's per-cell phase columns);
     # bench_model resolves their names directly
-    from gaussiank_sgd_tpu import virtual_cpu
     from gaussiank_sgd_tpu.benchlib import bench_model
+    from gaussiank_sgd_tpu.compile_cache import enable_compile_cache
 
     # persistent compile cache (works for the TPU backend too): a re-run
     # in a better drift window must not pay the ~20-min 57M-param compile
     # bill again
-    virtual_cpu.enable_compile_cache("/tmp/gksgd_tpu_cache")
+    enable_compile_cache()
 
     from gaussiank_sgd_tpu.benchlib import paired_delta_ms
 

@@ -122,8 +122,8 @@ def _load_roofline(artifacts: str):
 
 
 def main(argv: Optional[List[str]] = None):
-    from gaussiank_sgd_tpu import virtual_cpu
     from gaussiank_sgd_tpu.benchlib import bench_model, bench_overlap, mfu
+    from gaussiank_sgd_tpu.compile_cache import enable_compile_cache
 
     # default [] (not sys.argv): the test harness calls main() inside a
     # pytest process whose argv is pytest's, not ours
@@ -150,7 +150,7 @@ def main(argv: Optional[List[str]] = None):
 
     # persistent compile cache: repeated driver runs skip the multi-minute
     # 20-60M-param compiles (drift windows change, programs don't)
-    virtual_cpu.enable_compile_cache("/tmp/gksgd_tpu_cache")
+    enable_compile_cache()
 
     artifacts = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                              "analysis", "artifacts")

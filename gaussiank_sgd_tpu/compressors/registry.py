@@ -57,6 +57,25 @@ class CompressorSpec(NamedTuple):
     # (chunk, k) -> padded chunk size the fused EF kernel needs, or None
     # when the fused path can't serve that geometry (density/capacity).
     ef_pad: Optional[Callable[[int, int], Optional[int]]] = None
+    # True when fn/batched_fn/fused_ef_fn are Pallas kernels taking an
+    # ``interpret=`` keyword. The train step binds it from its mesh's
+    # platform (:meth:`with_interpret`); unbound, a direct call runs on
+    # the process's default backend.
+    pallas: bool = False
+
+    def with_interpret(self, interpret: bool) -> "CompressorSpec":
+        """This spec with the Pallas execution mode bound into every kernel
+        entry point (identity for XLA-only specs)."""
+        if not self.pallas:
+            return self
+
+        def bind(f):
+            return (None if f is None
+                    else functools.partial(f, interpret=interpret))
+
+        return self._replace(fn=bind(self.fn),
+                             batched_fn=bind(self.batched_fn),
+                             fused_ef_fn=bind(self.fused_ef_fn))
 
 
 def get_compressor(name: str, *, density: float = 0.001,
@@ -119,9 +138,8 @@ def get_compressor(name: str, *, density: float = 0.001,
             # the kernel's candidate buffer can't hold k above density
             # S/R = 0.03125 (pallas_pack.supports_density); the warm
             # XLA pack is the right tool there. The spec NAME says so —
-            # a benchmark labeling this cell 'gaussian_fused' would
-            # otherwise time the identical program under two labels
-            # (code-review r4)
+            # it is the only route from this selector to another, and the
+            # built step, its log line and every benchmark cell carry it
             fn = functools.partial(gaussian_warm_compress, density=density,
                                    sigma_scale=sigma_scale)
             return CompressorSpec("gaussian_fused(warm-fallback)", fn,
@@ -139,14 +157,14 @@ def get_compressor(name: str, *, density: float = 0.001,
         epad = functools.partial(ef_padded_chunk, density=density)
         return CompressorSpec("gaussian_fused", fn, False, True,
                               lambda k: k, stateful=True, batched_fn=bfn,
-                              fused_ef_fn=effn, ef_pad=epad)
+                              fused_ef_fn=effn, ef_pad=epad, pallas=True)
     if name in ("gaussian_pallas", "gaussianp"):
         # same selection contract as 'gaussian', threshold found by the
         # 3-pass Pallas kernel estimator (ops/pallas_select.py, SURVEY §7
         # stage 6) instead of the ~13-pass XLA mean/std+bisection composite
         from ..ops.pallas_select import pallas_gaussian_compress
         return CompressorSpec("gaussian_pallas", pallas_gaussian_compress,
-                              False, True, lambda k: k)
+                              False, True, lambda k: k, pallas=True)
     if name == "randomk":
         return CompressorSpec("randomk", randomk_compress, True, False,
                               lambda k: k)

@@ -33,7 +33,10 @@ def _build() -> bool:
     if not os.path.exists(src):
         return False
     os.makedirs(os.path.dirname(_LIB_PATH), exist_ok=True)
-    cmd = ["g++", "-O3", "-march=native", "-fPIC", "-std=c++17", "-shared",
+    # no -march=native: native/build/ is git-ignored but travels with a
+    # copy of the tree, and a library tuned to the build host's CPU dies
+    # with SIGILL (no traceback) in the loader of any other
+    cmd = ["g++", "-O3", "-fPIC", "-std=c++17", "-shared",
            "-pthread", "-o", _LIB_PATH, src]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)

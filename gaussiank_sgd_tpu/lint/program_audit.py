@@ -61,7 +61,8 @@ PROGRAMS_VERSION = 1
 PAYLOAD_COLLECTIVES = ("all_gather", "ppermute")
 
 #: primitive-name fragments that mean "host round-trip inside the program"
-CALLBACK_MARKERS = ("callback", "infeed", "outfeed")
+#: (``jax.debug.print`` is its own primitive, ``debug_print``, since jax 0.5)
+CALLBACK_MARKERS = ("callback", "debug_print", "infeed", "outfeed")
 
 _HEX_RE = re.compile(r"0x[0-9a-fA-F]+")
 

@@ -64,7 +64,11 @@ it only defers.
 ``num_selected`` is the exact above-threshold count, accumulated in SMEM
 across the (sequential) grid — the same observability the reference logs.
 
-Off-TPU the kernel runs in interpret mode (tests/conftest.py CPU mesh).
+Execution mode: a train step binds ``interpret`` explicitly from the
+platform of the mesh it is built for (parallel/trainstep.py — a TPU mesh
+always gets the Mosaic-compiled kernel, a CPU mesh the interpreter, and
+``DPTrainStep.kernel_mode`` says which). ``interpret=None`` is for direct
+library calls outside a step, which run on the process's default backend.
 """
 
 from __future__ import annotations
@@ -77,12 +81,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # pltpu imports cleanly only where libtpu/mosaic is available
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 from ..compressors.base import (CompressedGrad, CompressResult,
                                 finish_pack)
@@ -91,6 +90,17 @@ _LANES = 128
 _MAX_SEG = 64     # largest segment span (contract-density geometry, n/64
                   # candidates); shrinks with density — see segment_span
 _DENSITY_CEIL = 1.0 / 32   # capacity ceiling (unchanged from r4's S/R)
+_SUBLANES = 8     # Mosaic tiles f32/i32 as (8, 128): every block's
+                  # second-to-last dim must be a multiple of 8
+
+
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """Pallas execution mode for a DIRECT kernel call: ``None`` means "the
+    process's default backend" (arrays created outside a mesh live there).
+    Train steps never pass None — see the module docstring."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
 def segment_span(density: float) -> int:
@@ -124,10 +134,24 @@ def supports_density(density: float) -> bool:
     """True iff the kernel geometry can emit k = density*n pairs.
 
     At the 1/32 ceiling the SEG=16 geometry holds 1/16 of n candidate
-    slots >= k. Beyond it ``gaussian_fused_compress`` would route every
-    call to the XLA warm path, so the registry must rename the spec
-    instead (one label, one program)."""
+    slots >= k. Beyond it the registry builds the XLA warm selector under
+    its own name (``gaussian_fused(warm-fallback)``); the kernel entry
+    points here refuse such a call (:func:`_require_capacity`)."""
     return density <= _DENSITY_CEIL
+
+
+def _require_capacity(chunk: int, k: int, density: float) -> None:
+    """The geometry gate of the select+pack entry points: raise unless the
+    kernel can emit ``k`` pairs from a ``chunk``-element buffer. There is
+    no quiet route to another selector under this one's name — the
+    registry picks (and names) the selector from the density before a step
+    is built, and k = ceil(density*chunk) always fits below the ceiling
+    (capacity is >= 2*density*chunk). Above the density ceiling
+    ``rows_per_block`` raises from inside ``_chunk_geometry``."""
+    nc = _chunk_geometry(chunk, density)[3]
+    if k > nc:
+        raise ValueError(f"k={k} exceeds candidate capacity {nc} "
+                         f"(chunk={chunk}, density={density})")
 
 
 def _chunk_geometry(chunk: int,
@@ -136,15 +160,25 @@ def _chunk_geometry(chunk: int,
     ``chunk`` elements at ``density`` — the single source of the geometry
     rules so capacity checks agree with what the kernel actually runs.
 
-    R is capped at the chunk's own rows (rounded up to a SEG multiple):
-    without the cap a uniform plan's small chunks would zero-pad to a full
-    1024-row block and the kernel's HBM pass would read up to 4x zeros
-    (code-review r5)."""
+    Every geometry returned here is one Mosaic accepts: the candidate
+    tile is ``[R // SEG, 128]``, so ``R`` is a multiple of ``8 * SEG``
+    (tests/test_kernel_lowering.py lowers the grid for TPU).
+
+    A chunk smaller than one 1024-row block gets ONE block of its own
+    rows, rounded up to that ``8 * SEG`` granule — without the cap a
+    uniform plan's small chunks would zero-pad to a full block and the
+    kernel's HBM pass would read mostly zeros. SEG halves (more candidate
+    cells, so never less capacity or more cap overflow than the density
+    rule asked for) until the granule fits the chunk, which bounds the pad
+    below 2x down to 8192-element chunks."""
     R = rows_per_block(density)
     seg = segment_span(density)
     rows_total = -(-chunk // _LANES)
     if rows_total < R:
-        R = max(seg, -(-rows_total // seg) * seg)
+        while seg > 8 and _SUBLANES * seg > rows_total:
+            seg //= 2
+        granule = _SUBLANES * seg
+        R = -(-rows_total // granule) * granule
     bpc = -(-chunk // (R * _LANES))
     return R, seg, bpc, (R // seg) * bpc * _LANES
 
@@ -257,8 +291,7 @@ def fused_select_candidates_chunked(
     (zeros never cross a positive threshold; the pad region is beyond every
     valid chunk-local index, so residual stripping is unaffected).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n_chunks, chunk = x2d.shape
     R, seg, bpc, nc = _chunk_geometry(chunk, density)
     nseg = R // seg
@@ -267,8 +300,8 @@ def fused_select_candidates_chunked(
     x = jnp.pad(x2d.astype(jnp.float32),
                 ((0, 0), (0, chunk_pad - chunk))).reshape(-1, _LANES)
 
-    space = pltpu.VMEM if (_HAS_PLTPU and not interpret) else None
-    smem = pltpu.SMEM if (_HAS_PLTPU and not interpret) else None
+    space = None if interpret else pltpu.VMEM
+    smem = None if interpret else pltpu.SMEM
     vals, idxs, counts = pl.pallas_call(
         functools.partial(_select_kernel, rows=R, seg=seg),
         grid=(n_chunks, bpc),
@@ -316,10 +349,9 @@ def ef_padded_chunk(chunk: int, k: int, *,
     == chunk) — otherwise the in-chunk pad would shift every following
     chunk's global offsets and the caller must keep the unfused path.
 
-    Returns None (caller falls back to the unfused path) when the density
-    is above the geometry ceiling or k exceeds the candidate capacity —
-    the same conditions under which ``gaussian_fused_compress_batched``
-    would route to the XLA warm path."""
+    Returns None (the step keeps the unfused EF accumulate) when the
+    density is above the geometry ceiling or k exceeds the candidate
+    capacity — the conditions :func:`_require_capacity` refuses."""
     if not supports_density(density):
         return None
     R, _, bpc, nc = _chunk_geometry(chunk, density)
@@ -344,8 +376,7 @@ def fused_ef_select_candidates_chunked(
     ``jnp.pad`` here, which is the point: the pad copy the unfused path
     pays every step is exactly what fusion removes.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n_chunks, chunk_pad = res2d.shape
     R, seg, bpc, nc = _chunk_geometry(chunk_pad, density)
     nseg = R // seg
@@ -358,8 +389,8 @@ def fused_ef_select_candidates_chunked(
     g = g2d.astype(jnp.float32).reshape(-1, _LANES)
     scale2d = jnp.asarray(scale, jnp.float32).reshape(1, 1)
 
-    space = pltpu.VMEM if (_HAS_PLTPU and not interpret) else None
-    smem = pltpu.SMEM if (_HAS_PLTPU and not interpret) else None
+    space = None if interpret else pltpu.VMEM
+    smem = None if interpret else pltpu.SMEM
     acc, vals, idxs, counts = pl.pallas_call(
         functools.partial(_ef_select_kernel, rows=R, seg=seg),
         grid=(n_chunks, bpc),
@@ -495,15 +526,9 @@ def fused_select_pack(acc: jax.Array, k: int, threshold: jax.Array,
     Truncation beyond k drops smallest-magnitude candidates — the
     ``pack_by_mask(priority="magnitude")`` contract.
     """
-    n = acc.shape[0]
+    _require_capacity(acc.shape[0], k, density)
     vals, idxs, count = fused_select_candidates(acc, threshold, density,
                                                 interpret)
-    nc = vals.shape[0]
-    if k > nc:  # geometry guarantees nc >= k at supported densities
-        # (nc = n/SEG >= 2k everywhere below the 1/32 ceiling);
-        # unreachable for k = ceil(density*n), but fail loud for direct calls
-        raise ValueError(f"k={k} exceeds candidate capacity {nc} "
-                         f"(n={n}, density={density})")
     comp, residual = _pack_candidates(vals, idxs, acc, k)
     return CompressResult(comp, residual, count)
 
@@ -540,23 +565,9 @@ def gaussian_fused_compress(acc: jax.Array, k: int, state: jax.Array,
         buffer; count >> k defers overflow to the residual). Exactness of
         EF bookkeeping never depends on the threshold's quality.
     """
-    from ..compressors.gaussian import gaussian_warm_compress
-
+    del rng, sigma_scale  # registry-signature parity; see the EF form
     n = acc.shape[0]
-    if not supports_density(density):
-        # direct call above the geometry's capacity ceiling (the registry
-        # renames the spec instead of reaching here): route to the XLA warm
-        # path rather than raising from rows_per_block
-        return gaussian_warm_compress(acc, k, state, rng, density=density,
-                                      sigma_scale=sigma_scale, gain=gain)
-    _, _, _, nc = _chunk_geometry(n, density)
-    if k > nc:
-        # trace-time geometry check: only reachable for direct calls with a
-        # k far above ceil(density*n) — route to the XLA warm path instead
-        # of producing a truncated-below-k pack
-        return gaussian_warm_compress(acc, k, state, rng, density=density,
-                                      sigma_scale=sigma_scale, gain=gain)
-
+    _require_capacity(n, k, density)
     vals, idxs, count = fused_select_candidates(acc, state, density,
                                                 interpret)
     sent_idx, val = _select_candidates_topk(vals, idxs, k, n)
@@ -589,24 +600,9 @@ def gaussian_fused_compress_batched(
     coupling — a persistently-cold lane can never drag warm lanes into a
     recovery path, because no recovery path exists.
     """
-    from ..compressors.gaussian import gaussian_warm_compress_batched
-
+    del rng, sigma_scale  # registry-signature parity; see the EF form
     n_chunks, chunk = x.shape
-    if not supports_density(density):
-        # direct call above the geometry's capacity ceiling — same
-        # documented warm-XLA routing as the flat form (the registry
-        # renames the spec instead of reaching here)
-        return gaussian_warm_compress_batched(x, k, state, rng,
-                                              density=density,
-                                              sigma_scale=sigma_scale,
-                                              gain=gain)
-    _, _, _, nc_chunk = _chunk_geometry(chunk, density)
-    if k > nc_chunk:
-        # trace-time geometry check, as in gaussian_fused_compress
-        return gaussian_warm_compress_batched(x, k, state, rng,
-                                              density=density,
-                                              sigma_scale=sigma_scale,
-                                              gain=gain)
+    _require_capacity(chunk, k, density)
     vals, idxs, counts = fused_select_candidates_chunked(x, state, density,
                                                          interpret)
     sent_idx, val = jax.vmap(
@@ -647,9 +643,8 @@ def gaussian_fused_ef_compress_batched(
     del rng, sigma_scale  # signature parity with the unfused batched form
     n_chunks, chunk_pad = res2d.shape
     if ef_padded_chunk(chunk_pad, k, density=density) != chunk_pad:
-        # unlike gaussian_fused_compress_batched there is no silent warm-XLA
-        # fallback here: reaching this path with unpadded chunks means the
-        # caller's build-time eligibility gate is broken — fail loud
+        # reaching this path with unpadded chunks means the caller's
+        # build-time eligibility gate is broken — fail loud
         raise ValueError(
             f"fused EF path needs pre-padded block-aligned chunks with "
             f"k <= capacity: got chunk={chunk_pad}, k={k}, "

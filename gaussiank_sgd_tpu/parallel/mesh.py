@@ -27,9 +27,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 # Env vars whose presence means "this process was launched as part of a
-# multi-process job" — if any is set, a failed jax.distributed.initialize()
-# is a hard error: swallowing it would let each host silently train its own
-# unsynchronized replica.
+# multi-process job". jax.distributed.initialize() is called only then:
+# called unconditionally it probes for a cluster first (on a TPU VM, the
+# GCE metadata server — minutes of retries on a machine without network).
 _MULTIHOST_ENV_VARS = (
     "JAX_COORDINATOR_ADDRESS", "COORDINATOR_ADDRESS",
     "JAX_NUM_PROCESSES", "JAX_PROCESS_ID",
@@ -41,36 +41,22 @@ def _looks_multihost() -> bool:
     if any(os.environ.get(v) for v in _MULTIHOST_ENV_VARS):
         return True
     # TPU slice metadata: multi-host only when several workers are listed
-    # (single-host tunnels set TPU_WORKER_HOSTNAMES=localhost)
     hosts = os.environ.get("TPU_WORKER_HOSTNAMES", "")
     return len([h for h in hosts.split(",") if h.strip()]) > 1
 
 
 def maybe_initialize_distributed() -> None:
-    """Initialize multi-host JAX if launched as part of a multi-process job.
+    """Initialize multi-host JAX iff launched as part of a multi-process
+    job; a single-host run (one chip, one four-chip host, the CPU test
+    mesh) never calls ``jax.distributed.initialize()``. This replaces the
+    reference's ``hvd.init()`` / ``MPI_Init`` (SURVEY.md §3.1 step 1).
 
-    Safe to call unconditionally: a single-process run (including the CPU test
-    mesh and the single-chip bench) is a no-op. This replaces the reference's
-    ``hvd.init()`` / ``MPI_Init`` (SURVEY.md §3.1 step 1).
-
-    If the environment *looks* multi-host (coordinator/process-count env vars
-    set) a failure to initialize is re-raised — a multi-host job falling back
-    to per-host independent training is the worst silent failure mode a
+    A failure to initialize propagates: a multi-host job falling back to
+    per-host independent training is the worst silent failure mode a
     data-parallel framework has.
     """
-    try:
+    if _looks_multihost():
         jax.distributed.initialize()
-    except Exception as e:  # noqa: BLE001 — classified below
-        if _looks_multihost():
-            raise RuntimeError(
-                "multi-host launch detected (coordinator env vars set) but "
-                "jax.distributed.initialize() failed — refusing to continue "
-                "as an unsynchronized single-process job") from e
-        # Single-process run (no cluster autodetected) or already
-        # initialized — both fine; log for debuggability and move on.
-        import logging
-        logging.getLogger(__name__).debug(
-            "jax.distributed.initialize() skipped: %s", e)
 
 
 def data_parallel_mesh(num_devices: Optional[int] = None,

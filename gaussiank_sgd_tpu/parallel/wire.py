@@ -3,7 +3,8 @@
 The sparse exchange used to move each selected entry as an (int32 global
 index, float32 value) pair — 64 bits per entry, and after PR 4 fused the
 EF+select compute on-device, those 64 bits dominate the remaining gap to
-the >=0.90 sparse:dense contract (BENCH_r05: vgg16 at 0.8115). This module
+the >=0.90 sparse:dense contract (last driver chip record, 2026-07-31:
+vgg16 at 0.8115; ROADMAP.md keeps the figures). This module
 halves the payload without changing the algorithm, combining the two
 classic observations from the reference lineage: sparse comms volume is
 the scaling bottleneck (gTop-k, Shi et al.), and low-precision gradient

@@ -38,12 +38,17 @@ if _vcpu.strip():
 
     virtual_cpu.provision(int(_vcpu))
 
+from .compile_cache import enable_compile_cache
 from .parallel.mesh import maybe_initialize_distributed
 from .training.config import add_args, from_args
 from .training.trainer import Trainer
 
 
-def main(argv=None):
+def make_trainer(argv=None) -> Trainer:
+    """Everything ``main`` does before the first step: parse ``argv``, place
+    the compile cache, join the multi-host job if there is one, build the
+    Trainer. Split out so a caller that needs the trainer object afterwards
+    (chip_smoke.py) constructs it exactly as the CLI does."""
     if argv is None:
         argv = sys.argv[1:]     # pin what parse_args sees, so from_args's
                                 # explicit-flag detection re-reads the SAME list
@@ -52,9 +57,13 @@ def main(argv=None):
                     "training (GaussianK-SGD capability surface)")
     add_args(p)
     args = p.parse_args(argv)
+    enable_compile_cache()
     maybe_initialize_distributed()
-    cfg = from_args(args, argv)
-    trainer = Trainer(cfg)
+    return Trainer(from_args(args, argv))
+
+
+def main(argv=None):
+    trainer = make_trainer(argv)
     try:
         result = trainer.fit()
         trainer.logger.info("done: %s", result)

@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from gaussiank_sgd_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from gaussiank_sgd_tpu.compressors import CompressedGrad, get_compressor

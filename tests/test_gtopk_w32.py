@@ -12,14 +12,14 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _CODE = r"""
 import sys
 sys.path.insert(0, %(repo)r)
-from gaussiank_sgd_tpu import virtual_cpu
+from gaussiank_sgd_tpu import compile_cache, virtual_cpu
 virtual_cpu.provision(32)
-virtual_cpu.enable_compile_cache()
+compile_cache.enable_compile_cache()
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from gaussiank_sgd_tpu.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from gaussiank_sgd_tpu.compressors import get_compressor

@@ -1,0 +1,117 @@
+"""The select kernels lower for the TPU — checked without one.
+
+``.trace(...).lower(lowering_platforms=("tpu",))`` runs the Pallas -> Mosaic
+lowering on the CPU host in seconds. It is the stage that rejects an illegal
+block shape ("last two dimensions of your block shape are divisible by 8 and
+128"), which interpret mode never sees: before PR 21 every uniform chunk
+under 65 536 elements was refused there. The contract: every geometry a
+bucket plan can produce either lowers to a ``tpu_custom_call`` or is reported
+ineligible by the geometry gate (``ef_padded_chunk`` / ``_require_capacity``)
+BEFORE lowering — the compiler is never what says no.
+
+Lowering is not compiling: Mosaic's own passes and the run are the chip's to
+prove (chip_smoke.py).
+"""
+
+import functools
+import math
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from gaussiank_sgd_tpu.compressors import get_compressor
+from gaussiank_sgd_tpu.ops.pallas_pack import (
+    ef_padded_chunk, fused_ef_select_candidates_chunked,
+    fused_select_candidates_chunked, gaussian_fused_compress_batched,
+    gaussian_fused_ef_compress_batched)
+
+# parameter counts of the five shipped configs (exp_configs/): each is a
+# single whole-model bucket under the default greedy plan
+MODEL_NUMEL = {"resnet20": 269_722, "vgg16": 14_986_698,
+               "resnet50": 25_557_032, "lstm": 19_775_200,
+               "transformer": 60_524_544}
+UNIFORM_CHUNKS = (8192, 65_536, 100_000, 1 << 22)
+DENSITIES = (0.001, 0.01)
+
+GRID = ([(1, n, d) for n in MODEL_NUMEL.values() for d in DENSITIES]
+        + [(3, c, d) for c in UNIFORM_CHUNKS for d in DENSITIES])
+
+
+def _f32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+
+def _lowers_with_kernel(fn, *avals, **kw_avals) -> bool:
+    text = jax.jit(fn).trace(*avals, **kw_avals).lower(
+        lowering_platforms=("tpu",)).as_text()
+    return "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("n_chunks,chunk,density", GRID)
+def test_select_kernel_lowers_for_every_plan_geometry(n_chunks, chunk,
+                                                      density):
+    """The unfused form pads each chunk to its block itself, so every
+    geometry must lower — raw kernel and the full select+pack wrapper."""
+    k = math.ceil(density * chunk)
+    assert _lowers_with_kernel(
+        functools.partial(fused_select_candidates_chunked, density=density,
+                          interpret=False),
+        _f32(n_chunks, chunk), _f32(n_chunks))
+    assert _lowers_with_kernel(
+        functools.partial(gaussian_fused_compress_batched, k=k,
+                          density=density, interpret=False),
+        _f32(n_chunks, chunk), state=_f32(n_chunks))
+
+
+@pytest.mark.parametrize("n_chunks,chunk,density", GRID)
+def test_fused_ef_kernel_lowers_or_gate_says_no(n_chunks, chunk, density):
+    """The fused EF+select form takes pre-padded buffers: the gate returns
+    the padded chunk (a pure suffix pad for one bucket; multi-chunk plans
+    are eligible only when already aligned) and what it accepts lowers."""
+    k = math.ceil(density * chunk)
+    cp = ef_padded_chunk(chunk, k, density=density)
+    assert cp is not None and cp >= chunk      # below the density ceiling
+    if n_chunks > 1 and cp != chunk:
+        # trainstep._fused_ef_layout keeps the unfused accumulate here
+        with pytest.raises(ValueError, match="pre-padded block-aligned"):
+            jax.eval_shape(
+                functools.partial(gaussian_fused_ef_compress_batched, k=k,
+                                  density=density, interpret=False),
+                _f32(n_chunks, chunk), _f32(n_chunks, chunk), _f32(),
+                state=_f32(n_chunks))
+        return
+    assert _lowers_with_kernel(
+        functools.partial(fused_ef_select_candidates_chunked,
+                          density=density, interpret=False),
+        _f32(n_chunks, cp), _f32(n_chunks, cp), _f32(), _f32(n_chunks))
+    assert _lowers_with_kernel(
+        functools.partial(gaussian_fused_ef_compress_batched, k=k,
+                          density=density, interpret=False),
+        _f32(n_chunks, cp), _f32(n_chunks, cp), _f32(),
+        state=_f32(n_chunks))
+
+
+def test_vgg16_whole_model_bucket_is_the_smoke_geometry():
+    # the figure chip_smoke.py asserts on the chip
+    assert ef_padded_chunk(14_986_698, 14_987, density=0.001) == 15_073_280
+
+
+def test_above_the_density_ceiling_the_gate_refuses_before_lowering():
+    assert ef_padded_chunk(100_000, 6250, density=0.0625) is None
+    with pytest.raises(ValueError, match="supports density"):
+        jax.eval_shape(
+            functools.partial(gaussian_fused_compress_batched, k=6250,
+                              density=0.0625, interpret=False),
+            _f32(3, 100_000), state=_f32(3))
+    # ... and the registry builds (and names) the XLA selector instead
+    spec = get_compressor("gaussian_fused", density=0.0625)
+    assert spec.name == "gaussian_fused(warm-fallback)" and not spec.pallas
+
+
+def test_threshold_estimator_kernels_lower():
+    from gaussiank_sgd_tpu.ops.pallas_select import pallas_gaussian_compress
+
+    assert _lowers_with_kernel(
+        functools.partial(pallas_gaussian_compress, k=270, interpret=False),
+        _f32(269_722))

@@ -646,7 +646,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from gaussiank_sgd_tpu.compat import shard_map
+from jax import shard_map
 from gaussiank_sgd_tpu.compressors import get_compressor
 from gaussiank_sgd_tpu.parallel.bucketing import make_bucket_plan
 from gaussiank_sgd_tpu.parallel.gtopk import gtopk_allreduce

@@ -1,13 +1,18 @@
 """MFU accounting (VERDICT r2 item 2): the FLOPs numerator comes from XLA's
 HLO cost analysis of the compiled program — exact for the conv/matmul terms
 that dominate — and the peak table maps jax device_kind to public bf16
-specs. On CPU there is no peak entry, so MFU is None (never a made-up
-number)."""
+specs. On CPU there is no peak, so MFU is None (never a made-up number);
+on a TPU the table does not know, the lookup raises."""
+
+import json
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from gaussiank_sgd_tpu import benchlib
 from gaussiank_sgd_tpu.benchlib import (device_peak_flops, mfu,
                                         program_flops)
 
@@ -52,13 +57,82 @@ def test_device_peak_flops_cpu_is_none():
     assert device_peak_flops(jax.devices()[0]) is None
 
 
-def test_peak_table_prefix_order():
-    """'TPU v5 lite' (v5e) must resolve before the 'TPU v5' (v5p) prefix."""
-    class FakeDev:
-        device_kind = "TPU v5 lite"
+def test_peak_table_is_exact_and_unknown_tpu_raises():
+    """Exact device_kind keys: v5e is 197e12, and a TPU kind the table
+    does not hold is an error — never a neighbouring generation's peak."""
+    class FakeTpu:
+        platform = "tpu"
 
-    class FakeV5p:
-        device_kind = "TPU v5p"
+        def __init__(self, kind):
+            self.device_kind = kind
 
-    assert device_peak_flops(FakeDev()) == 197e12
-    assert device_peak_flops(FakeV5p()) == 459e12
+    assert device_peak_flops(FakeTpu("TPU v5 lite")) == 197e12
+    assert device_peak_flops(FakeTpu("TPU v5p")) == 459e12
+    for kind in ("TPU v5 litepod", "TPU v9", ""):
+        with pytest.raises(KeyError, match="no peak FLOP/s"):
+            device_peak_flops(FakeTpu(kind))
+
+
+# ------------------------------------------------ the trainer's MFU probe
+# It only runs where a peak is known, i.e. never on the CPU this suite runs
+# on — so these fake the peak to enter it at all.
+
+def _mfu_trainer(tmp_path, **kw):
+    from gaussiank_sgd_tpu.training.config import TrainConfig
+    from gaussiank_sgd_tpu.training.trainer import Trainer
+
+    base = dict(dnn="mnistnet", dataset="mnist", batch_size=8, nworkers=1,
+                lr=0.005, compressor="gaussian", density=0.01,
+                compress_warmup_steps=2, max_steps=4, log_every=2,
+                compute_dtype="float32", output_dir=str(tmp_path),
+                eval_every_epochs=0, save_every_epochs=0)
+    base.update(kw)
+    return Trainer(TrainConfig(**base))
+
+
+def test_trainer_mfu_probe_reuses_the_compiled_step(tmp_path, monkeypatch):
+    """The probe lowers and compiles the step a second time; that must be
+    a cache hit on the program that just ran, never a compile of its own
+    (at the dense->sparse boundary it used to pick the step that had not
+    run yet and paid its whole compile inside the log call)."""
+    monkeypatch.setattr(benchlib, "device_peak_flops",
+                        lambda device=None: 1e12)
+    in_probe, compiled_in_probe = [False], []
+
+    def on_duration(event, duration, **kw):
+        if in_probe[0] and event.endswith("backend_compile_duration"):
+            compiled_in_probe.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    t = _mfu_trainer(tmp_path)
+    real_probe = t._maybe_probe_mfu
+
+    def probe(fn):
+        in_probe[0] = True
+        try:
+            real_probe(fn)
+        finally:
+            in_probe[0] = False
+
+    t._maybe_probe_mfu = probe
+    t.fit()
+    t.close()
+    assert t._flops_per_step and t._peak_flops == 1e12
+    assert compiled_in_probe == []
+    recs = [json.loads(line) for line in
+            open(os.path.join(t.run_dir, "metrics.jsonl"))]
+    assert all("mfu" in r for r in recs if r["event"] == "train")
+
+
+def test_trainer_mfu_probe_failure_is_an_error_where_a_peak_is_known(
+        tmp_path, monkeypatch):
+    def boom(jitted, *args):
+        raise RuntimeError("cost analysis unavailable")
+
+    monkeypatch.setattr(benchlib, "device_peak_flops",
+                        lambda device=None: 1e12)
+    monkeypatch.setattr(benchlib, "program_flops", boom)
+    t = _mfu_trainer(tmp_path)
+    with pytest.raises(RuntimeError, match="cost analysis unavailable"):
+        t.fit()
+    t.close()

@@ -154,17 +154,22 @@ def test_warm_cold_routing_and_controller():
         assert float(t2) == float(t_cold)
 
 
-def test_k_beyond_candidate_capacity_falls_back():
-    # direct call with k >> ceil(density*n): geometry cannot hold k
-    # candidates, so the fn must route to the XLA warm path, not truncate
+def test_k_beyond_candidate_capacity_is_refused():
+    # direct call with k >> ceil(density*n): the geometry cannot hold k
+    # candidates. The gate raises — no quiet route to another selector
+    # under this one's name (the registry renames the spec above the
+    # density ceiling; below it k = ceil(density*n) always fits)
     n = rows_per_block(0.001) * _LANES
     acc = _acc(n, seed=4)
     _, _, _, nc = _chunk_geometry(n, 0.001)
-    k = nc + 1                     # one more than the candidate capacity
-    res, _t = gaussian_fused_compress(acc, k, jnp.float32(0.1),
-                                      density=0.001)
-    assert res.compressed.indices.shape[0] == k
-    _ef_ok(acc, res)
+    with pytest.raises(ValueError, match="exceeds candidate capacity"):
+        gaussian_fused_compress(acc, nc + 1, jnp.float32(0.1),
+                                density=0.001)
+    with pytest.raises(ValueError, match="exceeds candidate capacity"):
+        gaussian_fused_compress_batched(acc[None], nc + 1,
+                                        jnp.full((1,), 0.1), density=0.001)
+    with pytest.raises(ValueError, match="supports density"):
+        gaussian_fused_compress(acc, 8, jnp.float32(0.1), density=0.5)
 
 
 def test_chunked_candidates_match_flat_per_chunk():
@@ -192,12 +197,15 @@ def test_small_chunk_caps_reduction_span():
     """density <= 0.002 nominally picks R=1024, but a chunk smaller than
     1024 rows must cap R at its own row count (code-review r5: otherwise
     every chunk pads to a full 131072-element block and the kernel reads
-    up to 4x zeros). With the cap the geometry still emits every
-    above-threshold entry (lambda tiny), with chunk-local indices."""
+    up to 4x zeros) — in a geometry Mosaic accepts: SEG halves until the
+    candidate tile [R/SEG, 128] has 8 sublanes. With the cap the geometry
+    still emits every above-threshold entry, with chunk-local indices."""
     chunk = 32_768                       # 256 rows < R=1024
     R, seg, bpc, nc = _chunk_geometry(chunk, 0.001)
-    assert R == 256 and seg == 64 and bpc == 1
+    assert R == 256 and seg == 32 and bpc == 1
     assert nc == (R // seg) * _LANES
+    assert _chunk_geometry(8192, 0.001)[:3] == (64, 8, 1)      # tight
+    assert _chunk_geometry(65_536, 0.001)[:3] == (512, 64, 1)  # tight
 
     rng = np.random.default_rng(23)
     x_np = rng.normal(0, 0.5, (2, chunk)).astype(np.float32)  # below t

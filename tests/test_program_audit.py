@@ -120,8 +120,14 @@ def test_callback_primitive_is_detected():
 
     closed = jax.make_jaxpr(noisy)(jnp.zeros(4))
     prims = collect_primitives(closed.jaxpr)
-    cbs = find_callbacks(prims)
-    assert cbs and any("callback" in c for c in cbs)
+    assert find_callbacks(prims) == ["debug_print"]
+
+    def quiet(x):
+        return jax.pure_callback(lambda v: v, x, x) * 2
+
+    closed = jax.make_jaxpr(quiet)(jnp.zeros(4))
+    assert find_callbacks(collect_primitives(closed.jaxpr)) == [
+        "pure_callback"]
 
 
 def test_callback_in_step_program_violates_contract(report):

@@ -13,7 +13,7 @@ import optax
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-from gaussiank_sgd_tpu.compat import shard_map
+from jax import shard_map
 from gaussiank_sgd_tpu.parallel.mesh import data_parallel_mesh, dp_sp_mesh
 from gaussiank_sgd_tpu.parallel.ring_attention import ring_attention
 

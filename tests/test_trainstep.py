@@ -251,7 +251,15 @@ def test_flat_opt_matches_optax_trajectory():
     """The flat sparse-aware SGD+momentum update (parallel/flat_opt.py)
     must produce the SAME parameter trajectory as the optax path — sparse
     steps, dense warm-up steps, and a dense->sparse transition — for both
-    plain momentum and momentum+weight-decay."""
+    plain momentum and momentum+weight-decay.
+
+    Both builds exchange in f32 (``wire="off"``), so the tolerance below is
+    the f32 bound it looks like: the two updates are the same algebra in a
+    different operation order, a few ulp (~1e-8 on these O(0.1) params) per
+    step over 8 steps. Under the packed wire the comparison is not an f32
+    one: a sent value sitting on a bf16 rounding midpoint rounds the other
+    way after a 1-ulp perturbation, which moves that parameter by
+    lr * 2^-8 * |v| / P in one step (EF returns it the next) — 2.4e-5 here."""
     from gaussiank_sgd_tpu.parallel.flat_opt import FlatSGDM
 
     for wd in (0.0, 0.01):
@@ -264,9 +272,9 @@ def test_flat_opt_matches_optax_trajectory():
             chain.append(optax.add_decayed_weights(wd))
         chain.append(optax.sgd(0.05, momentum=0.9))
         ts_ref = build_dp_train_step(loss_fn, optax.chain(*chain), spec,
-                                     plan, mesh)
+                                     plan, mesh, wire="off")
         ts_flat = build_dp_train_step(
-            loss_fn, None, spec, plan, mesh,
+            loss_fn, None, spec, plan, mesh, wire="off",
             flat_opt=FlatSGDM(lr=0.05, momentum=0.9, weight_decay=wd))
         s_ref = ts_ref.init_state(params, jax.random.PRNGKey(42))
         s_flat = ts_flat.init_state(params, jax.random.PRNGKey(42))
@@ -415,3 +423,75 @@ def test_decorrelate_comp_rng_spreads_random_indices():
     shared = run(False)
     spread = run(True)
     assert spread > 2 * shared
+
+
+def test_kernel_mode_comes_from_the_mesh_not_the_default_backend(monkeypatch):
+    """A step's Pallas kernels run the way its MESH's platform needs, decided
+    once at build time and reported on DPTrainStep.kernel_mode. With the
+    process's default backend claiming to be a TPU, a step built over the CPU
+    mesh must still carry the interpreted kernel (a Mosaic call cannot even
+    lower for the CPU devices it would run on)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    ts, state, make_batch, mesh = build("gaussian_fused", density=0.01)
+    assert ts.kernel_mode == "interpret"
+    batch = shard_batch(mesh, make_batch(64))
+    assert "tpu_custom_call" not in ts.sparse_step.lower(state,
+                                                         batch).as_text()
+    state, m = ts.sparse_step(state, batch)
+    assert np.isfinite(float(m.loss))
+    assert build("topk")[0].kernel_mode == "none"      # no kernel at all
+
+
+def test_tpu_mesh_never_gets_an_interpreted_kernel(monkeypatch):
+    """The converse, on a device-less TPU topology (libtpu compiles for a
+    chip this host does not have): default backend CPU, mesh TPU — the step
+    reports ``mosaic`` and lowers to exactly one Mosaic call. Before PR 21
+    the interpreted kernel was inlined into the TPU program, silently."""
+    pytest.importorskip("libtpu")
+    from jax.experimental import topologies
+    from jax.sharding import Mesh
+
+    # no metadata server to ask, and no chip to guard with libtpu's
+    # one-process lockfile: nothing here creates a TPU client
+    monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
+    monkeypatch.setenv("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except jax.errors.JaxRuntimeError as e:
+        pytest.skip(f"no device-less TPU topology on this host: {e}")
+    mesh = Mesh(np.array(topo.devices[:2]), ("dp",))
+    assert jax.default_backend() == "cpu"
+    params, loss_fn, make_batch = make_problem()
+    ts = build_dp_train_step(
+        loss_fn, optax.sgd(0.05), get_compressor("gaussian_fused",
+                                                 density=0.01),
+        plan_for_params(params, 0.01, None), mesh)
+    assert ts.kernel_mode == "mosaic"
+    state = jax.eval_shape(
+        lambda p: ts.init_state(p, jax.random.PRNGKey(0)), params)
+    batch = jax.eval_shape(lambda: make_batch(64))
+    text = ts.sparse_step.lower(state, batch).as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_init_state_is_created_under_the_steps_shardings():
+    """Replicated leaves on every device of the mesh, per-worker leaves one
+    shard per worker — so the first step neither re-lays the state out nor
+    compiles for a layout it will never see again (an unplaced state cost a
+    second compile of the step program: ~30 s for VGG-16 on the chip)."""
+    ts, state, make_batch, mesh = build("gaussian_warm")
+    devs = set(mesh.devices.flat)
+    n = ts.plan.total_numel
+    assert [s.data.shape for s in state.ef_residual.addressable_shards] \
+        == [(n,)] * mesh.size
+    assert {s.device for s in state.ef_residual.addressable_shards} == devs
+    assert [s.data.shape for s in state.comp_state.addressable_shards] \
+        == [(1, 1)] * mesh.size
+    for leaf in jax.tree_util.tree_leaves(
+            (state.step, state.params, state.opt_state, state.rng)):
+        assert leaf.sharding.is_fully_replicated
+        assert set(leaf.sharding.device_set) == devs
+    batch = shard_batch(mesh, make_batch(64))
+    for _ in range(2):
+        state, _ = ts.dense_step(state, batch)
+    assert ts.dense_step._cache_size() == 1
