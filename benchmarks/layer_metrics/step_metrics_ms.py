@@ -1,0 +1,10 @@
+"""Layer: sparse step program. Scope `step_metrics`: the residual's norm and the
+metrics' reductions. Self time of the device operations whose `op_name`
+carries the scope, per step of the profiled sparse block, averaged over the
+chips. Moves `examples_per_s`. Source: device_trace."""
+
+from benchmarks import span_reduce
+
+
+def read(run):
+    return span_reduce.scope_ms(run, "step_metrics")

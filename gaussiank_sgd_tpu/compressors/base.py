@@ -141,9 +141,11 @@ def finish_pack(acc: jax.Array, sent_idx: jax.Array, val: jax.Array,
     exactly the sent entries (invalid slots scatter out-of-range and
     drop); packed indices map the sentinel back to 0."""
     n = acc.shape[0]
-    valid = sent_idx < n
-    idx = jnp.where(valid, sent_idx, 0)
-    residual = acc.at[sent_idx].set(0.0, mode="drop")
+    with jax.named_scope("pack"):
+        valid = sent_idx < n
+        idx = jnp.where(valid, sent_idx, 0)
+    with jax.named_scope("scatter"):
+        residual = acc.at[sent_idx].set(0.0, mode="drop")
     return CompressedGrad(idx, val), residual
 
 

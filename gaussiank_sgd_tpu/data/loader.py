@@ -164,9 +164,31 @@ class EpochStream:
             return batch
 
 
+class Prefetcher:
+    """The iterator :func:`prefetch` returns: the batches of its source,
+    pulled by a daemon thread, and ``ready()``, how many of them wait in
+    its queue right now (0 to ``depth``) — the train loop's one input
+    counter (telemetry span ``data_wait``, field ``ready``)."""
+
+    def __init__(self, batches: Iterator, q: "queue.Queue", depth: int):
+        self._batches = batches
+        self._q = q
+        self.depth = depth
+
+    def __iter__(self) -> "Prefetcher":
+        return self
+
+    def __next__(self):
+        return next(self._batches)
+
+    def ready(self) -> int:
+        return self._q.qsize()
+
+
 def prefetch(it: Iterator, depth: int = 2, max_retries: int = 0,
              backoff_s: float = 0.05, max_backoff_s: float = 2.0,
-             on_event: Optional[Callable[[dict], None]] = None) -> Iterator:
+             on_event: Optional[Callable[[dict], None]] = None,
+             ) -> Prefetcher:
     """Run ``it`` in a daemon thread, keeping ``depth`` batches ready.
 
     Overlaps host batch prep with device compute — the role of the
@@ -188,6 +210,16 @@ def prefetch(it: Iterator, depth: int = 2, max_retries: int = 0,
     the sink must be thread-safe (telemetry.EventBus.publish is).
     """
     q: "queue.Queue" = queue.Queue(maxsize=depth)
+    return Prefetcher(
+        _prefetched(it, q, max_retries, backoff_s, max_backoff_s, on_event),
+        q, depth)
+
+
+def _prefetched(it: Iterator, q: "queue.Queue", max_retries: int,
+                backoff_s: float, max_backoff_s: float,
+                on_event: Optional[Callable[[dict], None]]) -> Iterator:
+    """The generator behind :func:`prefetch`: starts the producer thread
+    at its first pull and hands on what the thread queued."""
     _END = object()
     _ERR = object()
 

@@ -304,6 +304,7 @@ def fused_select_candidates_chunked(
     smem = None if interpret else pltpu.SMEM
     vals, idxs, counts = pl.pallas_call(
         functools.partial(_select_kernel, rows=R, seg=seg),
+        name="select",
         grid=(n_chunks, bpc),
         in_specs=[
             pl.BlockSpec((R, _LANES), lambda c, i: (c * bpc + i, 0),
@@ -393,6 +394,7 @@ def fused_ef_select_candidates_chunked(
     smem = None if interpret else pltpu.SMEM
     acc, vals, idxs, counts = pl.pallas_call(
         functools.partial(_ef_select_kernel, rows=R, seg=seg),
+        name="ef_select",
         grid=(n_chunks, bpc),
         in_specs=[
             pl.BlockSpec((R, _LANES), lambda c, i: (c * bpc + i, 0),
@@ -463,10 +465,11 @@ def _cand_top_k(vals: jax.Array, k: int):
     approx; the 128k ceiling also routes the 15-25M CNN configs' 234-391k
     buffers to the approx path). The ~5% approx misses at the k-boundary
     stay in the EF residual and are re-selected next step."""
-    key = jnp.abs(vals)
-    if vals.shape[0] <= _EXACT_CAND_MAX:
-        return lax.top_k(key, k)
-    return lax.approx_max_k(key, k, recall_target=0.95)
+    with jax.named_scope("cand_topk"):
+        key = jnp.abs(vals)
+        if vals.shape[0] <= _EXACT_CAND_MAX:
+            return lax.top_k(key, k)
+        return lax.approx_max_k(key, k, recall_target=0.95)
 
 
 def _select_candidates_topk(vals: jax.Array, idxs: jax.Array, k: int,
@@ -478,9 +481,10 @@ def _select_candidates_topk(vals: jax.Array, idxs: jax.Array, k: int,
     result through a ``lax.cond`` without paying the big-buffer
     cond-boundary copy (see base.select_by_mask)."""
     kv, kpos = _cand_top_k(vals, k)
-    valid = kv > 0
-    val = jnp.where(valid, vals[kpos], 0.0)
-    sent_idx = jnp.where(valid, idxs[kpos], n).astype(jnp.int32)
+    with jax.named_scope("pack"):
+        valid = kv > 0
+        val = jnp.where(valid, vals[kpos], 0.0)
+        sent_idx = jnp.where(valid, idxs[kpos], n).astype(jnp.int32)
     return sent_idx, val
 
 
@@ -496,12 +500,13 @@ def _controller_update(state: jax.Array, count: jax.Array, val: jax.Array,
     bucket) bootstraps to a tiny positive value so the controller can
     re-raise it multiplicatively when gradients appear.
     """
-    ratio = (count.astype(jnp.float32) + 1.0) / float(k + 1)
-    t_warm = state * jnp.clip(ratio ** gain, 0.25, 4.0)
-    mags = jnp.where(valid, jnp.abs(val.astype(jnp.float32)), jnp.inf)
-    kth = jnp.min(mags, axis=-1)
-    bootstrap = jnp.where(jnp.isfinite(kth), kth, jnp.float32(1e-8))
-    return jnp.where(state > 0, t_warm, bootstrap).astype(state.dtype)
+    with jax.named_scope("ef_select"):
+        ratio = (count.astype(jnp.float32) + 1.0) / float(k + 1)
+        t_warm = state * jnp.clip(ratio ** gain, 0.25, 4.0)
+        mags = jnp.where(valid, jnp.abs(val.astype(jnp.float32)), jnp.inf)
+        kth = jnp.min(mags, axis=-1)
+        bootstrap = jnp.where(jnp.isfinite(kth), kth, jnp.float32(1e-8))
+        return jnp.where(state > 0, t_warm, bootstrap).astype(state.dtype)
 
 
 def _pack_candidates(vals: jax.Array, idxs: jax.Array, buf: jax.Array,
@@ -527,8 +532,9 @@ def fused_select_pack(acc: jax.Array, k: int, threshold: jax.Array,
     ``pack_by_mask(priority="magnitude")`` contract.
     """
     _require_capacity(acc.shape[0], k, density)
-    vals, idxs, count = fused_select_candidates(acc, threshold, density,
-                                                interpret)
+    with jax.named_scope("ef_select"):
+        vals, idxs, count = fused_select_candidates(acc, threshold, density,
+                                                    interpret)
     comp, residual = _pack_candidates(vals, idxs, acc, k)
     return CompressResult(comp, residual, count)
 
@@ -568,8 +574,9 @@ def gaussian_fused_compress(acc: jax.Array, k: int, state: jax.Array,
     del rng, sigma_scale  # registry-signature parity; see the EF form
     n = acc.shape[0]
     _require_capacity(n, k, density)
-    vals, idxs, count = fused_select_candidates(acc, state, density,
-                                                interpret)
+    with jax.named_scope("ef_select"):
+        vals, idxs, count = fused_select_candidates(acc, state, density,
+                                                    interpret)
     sent_idx, val = _select_candidates_topk(vals, idxs, k, n)
     comp, residual = finish_pack(acc, sent_idx, val.astype(acc.dtype))
     valid = sent_idx < n
@@ -603,8 +610,9 @@ def gaussian_fused_compress_batched(
     del rng, sigma_scale  # registry-signature parity; see the EF form
     n_chunks, chunk = x.shape
     _require_capacity(chunk, k, density)
-    vals, idxs, counts = fused_select_candidates_chunked(x, state, density,
-                                                         interpret)
+    with jax.named_scope("ef_select"):
+        vals, idxs, counts = fused_select_candidates_chunked(
+            x, state, density, interpret)
     sent_idx, val = jax.vmap(
         lambda vc, ic: _select_candidates_topk(vc, ic, k, chunk))(vals, idxs)
     val = val.astype(x.dtype)
@@ -650,8 +658,9 @@ def gaussian_fused_ef_compress_batched(
             f"k <= capacity: got chunk={chunk_pad}, k={k}, "
             f"density={density} (ef_padded_chunk -> "
             f"{ef_padded_chunk(chunk_pad, k, density=density)})")
-    acc, vals, idxs, counts = fused_ef_select_candidates_chunked(
-        res2d, g2d, scale, state, density, interpret)
+    with jax.named_scope("ef_select"):
+        acc, vals, idxs, counts = fused_ef_select_candidates_chunked(
+            res2d, g2d, scale, state, density, interpret)
     sent_idx, val = jax.vmap(
         lambda vc, ic: _select_candidates_topk(vc, ic, k, chunk_pad)
     )(vals, idxs)

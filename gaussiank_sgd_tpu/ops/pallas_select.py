@@ -90,6 +90,7 @@ def fused_stats(flat: jax.Array, interpret: Optional[bool] = None
     grid = (x.shape[0] // rows,)
     s, ss, amax = pl.pallas_call(
         _stats_kernel,
+        name="select_stats",
         grid=grid,
         in_specs=[_spec((rows, 128), lambda i: (i, 0))],
         out_specs=(_spec(smem=True), _spec(smem=True), _spec(smem=True)),
@@ -129,6 +130,7 @@ def multi_threshold_counts(flat: jax.Array, thresholds: jax.Array,
     t = thresholds.astype(jnp.float32).reshape(1, _NCAND)
     counts = pl.pallas_call(
         _count_kernel,
+        name="select_counts",
         grid=grid,
         in_specs=[_spec((rows, 128), lambda i: (i, 0)),
                   _spec((1, _NCAND), lambda i: (0, 0), smem=True)],

@@ -58,12 +58,13 @@ class FlatSGDM(NamedTuple):
     def decay(self, m: jax.Array,
               flat_params: Optional[jax.Array]) -> jax.Array:
         """The dense half of the update: mu*m (+ wd*p)."""
-        m = m * self.momentum if self.momentum else jnp.zeros_like(m)
-        if self.weight_decay:
-            # internal invariant: both callers gate on _flat_params_if_wd
-            assert flat_params is not None  # gklint: disable=fail-loud -- narrowing assert; callers gate on _flat_params_if_wd
-            m = m + self.weight_decay * flat_params.astype(m.dtype)
-        return m
+        with jax.named_scope("update"):
+            m = m * self.momentum if self.momentum else jnp.zeros_like(m)
+            if self.weight_decay:
+                # internal invariant: both callers gate on _flat_params_if_wd
+                assert flat_params is not None  # gklint: disable=fail-loud -- narrowing assert; callers gate on _flat_params_if_wd
+                m = m + self.weight_decay * flat_params.astype(m.dtype)
+            return m
 
     def sparse_step(self, m: jax.Array, idx: jax.Array, val: jax.Array,
                     flat_params: Optional[jax.Array],
@@ -71,13 +72,17 @@ class FlatSGDM(NamedTuple):
         """(flat_updates, m') from gathered (idx, val) pairs — the pairs'
         values must already carry the /P average. Padding slots
         (0, 0.0) add zero at index 0: harmless, same as decompression."""
-        m_new = self.decay(m, flat_params).at[idx].add(
-            val.astype(m.dtype).reshape(-1), mode="drop")
-        return -self.lr_at(step) * m_new, m_new
+        decayed = self.decay(m, flat_params)
+        with jax.named_scope("scatter"):
+            m_new = decayed.at[idx].add(
+                val.astype(m.dtype).reshape(-1), mode="drop")
+        with jax.named_scope("update"):
+            return -self.lr_at(step) * m_new, m_new
 
     def dense_step(self, m: jax.Array, flat_g: jax.Array,
                    flat_params: Optional[jax.Array],
                    step: jax.Array) -> tuple:
         """(flat_updates, m') from an (averaged) dense flat gradient."""
-        m_new = self.decay(m, flat_params) + flat_g.astype(m.dtype)
-        return -self.lr_at(step) * m_new, m_new
+        with jax.named_scope("update"):
+            m_new = self.decay(m, flat_params) + flat_g.astype(m.dtype)
+            return -self.lr_at(step) * m_new, m_new

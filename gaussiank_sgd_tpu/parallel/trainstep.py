@@ -617,20 +617,22 @@ def build_dp_train_step(
         """Global L2 norm of the EF residual: local shard sum-of-squares
         psum'd over every mesh axis (each worker owns its own slice), then
         sqrt — replicated like the other metrics, no host sync."""
-        ss = jnp.sum(jnp.square(residual.astype(jnp.float32)))
-        for a in axes:
-            ss = lax.psum(ss, a)
-        return jnp.sqrt(ss)
+        with jax.named_scope("step_metrics"):
+            ss = jnp.sum(jnp.square(residual.astype(jnp.float32)))
+            for a in axes:
+                ss = lax.psum(ss, a)
+            return jnp.sqrt(ss)
 
     def _guard_count(loss: jax.Array, flat_g: jax.Array) -> jax.Array:
         """Global non-finite count: per-worker grad-entry count psum'd over
         every mesh axis (all workers must agree — one worker's NaN pollutes
         the summed exchange for everyone), plus one for a non-finite loss
         (already dp-mean'd, so globally consistent)."""
-        cnt = jnp.sum((~jnp.isfinite(flat_g)).astype(jnp.int32))
-        for a in axes:
-            cnt = lax.psum(cnt, a)
-        return cnt + (~jnp.isfinite(loss)).astype(jnp.int32)
+        with jax.named_scope("guard"):
+            cnt = jnp.sum((~jnp.isfinite(flat_g)).astype(jnp.int32))
+            for a in axes:
+                cnt = lax.psum(cnt, a)
+            return cnt + (~jnp.isfinite(loss)).astype(jnp.int32)
 
     def _guard_commit(ok: jax.Array, old: TrainState,
                       new: TrainState) -> TrainState:
@@ -651,18 +653,36 @@ def build_dp_train_step(
             return jax.tree.map(
                 lambda a, b: a if jnp.issubdtype(a.dtype, jnp.integer)
                 else jnp.where(ok, a, b), n, o)
-        return TrainState(new.step, keep(new.params, old.params),
-                          keep(new.model_state, old.model_state),
-                          keep_opt(new.opt_state, old.opt_state),
-                          keep(new.ef_residual, old.ef_residual),
-                          new.rng, keep(new.carry, old.carry),
-                          keep(new.comp_state, old.comp_state))
+        with jax.named_scope("guard"):
+            return TrainState(new.step, keep(new.params, old.params),
+                              keep(new.model_state, old.model_state),
+                              keep_opt(new.opt_state, old.opt_state),
+                              keep(new.ef_residual, old.ef_residual),
+                              new.rng, keep(new.carry, old.carry),
+                              keep(new.comp_state, old.comp_state))
 
     def _local_grads(state: TrainState, batch: Any, data_rng: jax.Array,
                      pad: int = 0):
-        loss, mstate, aux, new_carry, grads = _microbatch_grads(
-            loss_fn, state.params, state.model_state, batch, data_rng,
-            num_microbatches, state.carry, recurrent)
+        with jax.named_scope("fwd_bwd"):
+            loss, mstate, aux, new_carry, grads = _microbatch_grads(
+                loss_fn, state.params, state.model_state, batch, data_rng,
+                num_microbatches, state.carry, recurrent)
+        with jax.named_scope("flatten"):
+            flat_g, unravel = _flat_grads(grads, pad)
+            flat_g = _clip_by_global_norm(flat_g, clip_norm)
+        # dp-mean of loss/aux/model-state for logging & replicated-stats
+        # consistency (BatchNorm running stats are averaged across workers —
+        # strictly better than the reference's per-GPU local stats). The
+        # carry is NOT averaged: like the batch, it is per-worker data.
+        def pmean_floats(x):
+            return _pmean(x) if jnp.issubdtype(x.dtype, jnp.floating) else x
+        with jax.named_scope("exchange"):
+            mstate = jax.tree.map(pmean_floats, mstate)
+        with jax.named_scope("step_metrics"):
+            loss, aux = _pmean(loss), jax.tree.map(_pmean, aux)
+        return loss, mstate, aux, new_carry, flat_g, unravel
+
+    def _flat_grads(grads: Any, pad: int):
         if pad:
             # fused-EF path: build the flat grad directly at the padded
             # length (tree_leaves order == ravel_pytree order) so the
@@ -678,23 +698,15 @@ def build_dp_train_step(
         else:
             flat_g, unravel = ravel_pytree(grads)
             flat_g = flat_g.astype(grad_dtype)
-        flat_g = _clip_by_global_norm(flat_g, clip_norm)
-        # dp-mean of loss/aux/model-state for logging & replicated-stats
-        # consistency (BatchNorm running stats are averaged across workers —
-        # strictly better than the reference's per-GPU local stats). The
-        # carry is NOT averaged: like the batch, it is per-worker data.
-        def pmean_floats(x):
-            return _pmean(x) if jnp.issubdtype(x.dtype, jnp.floating) else x
-        mstate = jax.tree.map(pmean_floats, mstate)
-        return (_pmean(loss), mstate, jax.tree.map(_pmean, aux), new_carry,
-                flat_g, unravel)
+        return flat_g, unravel
 
     def _apply(state: TrainState, mstate: Any, dense_flat: jax.Array, unravel,
                new_residual: jax.Array, new_carry: Any,
                new_comp_state: Any = None):
-        updates, opt_state = optimizer.update(
-            unravel(dense_flat), state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("update"):
+            updates, opt_state = optimizer.update(
+                unravel(dense_flat), state.opt_state, state.params)
+            params = optax.apply_updates(state.params, updates)
         return TrainState(state.step + 1, params, mstate, opt_state,
                           new_residual, state.rng, new_carry,
                           state.comp_state if new_comp_state is None
@@ -702,7 +714,8 @@ def build_dp_train_step(
 
     def _flat_params_if_wd(state: TrainState):
         if flat_opt.weight_decay:
-            return ravel_pytree(state.params)[0]
+            with jax.named_scope("update"):
+                return ravel_pytree(state.params)[0]
         return None
 
     def _apply_flat(state: TrainState, mstate: Any, upd_flat: jax.Array,
@@ -711,7 +724,8 @@ def build_dp_train_step(
         """Flat sparse-aware optimizer commit (parallel/flat_opt.py): the
         momentum buffer was updated by the caller (sparse scatter or dense
         add); apply the flat update through the unravel views."""
-        params = optax.apply_updates(state.params, unravel(upd_flat))
+        with jax.named_scope("update"):
+            params = optax.apply_updates(state.params, unravel(upd_flat))
         return TrainState(state.step + 1, params, mstate, {"m": m_new},
                           new_residual, state.rng, new_carry,
                           state.comp_state if new_comp_state is None
@@ -735,6 +749,8 @@ def build_dp_train_step(
             # the local ef_residual shard is this worker's PADDED flat row;
             # both it and the padded flat_g view [n_chunks, chunk_pad] are
             # free reshapes — the whole EF+select phase is one kernel pass
+            # (the kernel, the candidate top-k and the pack carry their
+            # own scopes: ops/pallas_pack.py)
             r, cstate = spec.fused_ef_fn(
                 state.ef_residual.reshape(n_chunks, chunk_pad),
                 flat_g.reshape(n_chunks, chunk_pad),
@@ -746,22 +762,30 @@ def build_dp_train_step(
             # sentinel slots (chunk_pad + off) land at/above n_total or on
             # a later chunk's first element with value 0.0 — dropped or a
             # +0.0 under the scatter-add exchanges either way.
-            offs = (jnp.arange(n_chunks, dtype=jnp.int32) * chunk)[:, None]
-            comp = CompressedGrad((r.compressed.indices + offs).reshape(-1),
-                                  r.compressed.values.reshape(-1))
-            words = None
-            if wire_fmt is not None and exchange == "allgather":
-                # wire-pack straight off the select pass's chunk-local
-                # output: the bucket-relative u16 IS the chunk-local index
-                words = pack_wire_words(
-                    r.compressed.indices, r.compressed.values).reshape(-1)
+            with jax.named_scope("pack"):
+                offs = (jnp.arange(n_chunks, dtype=jnp.int32)
+                        * chunk)[:, None]
+                comp = CompressedGrad(
+                    (r.compressed.indices + offs).reshape(-1),
+                    r.compressed.values.reshape(-1))
+                words = None
+                if wire_fmt is not None and exchange == "allgather":
+                    # wire-pack straight off the select pass's chunk-local
+                    # output: the bucket-relative u16 IS the chunk-local
+                    # index
+                    words = pack_wire_words(
+                        r.compressed.indices,
+                        r.compressed.values).reshape(-1)
             return (comp, r.residual.reshape(-1),
                     r.num_selected.astype(jnp.int32).reshape(-1),
                     cstate, None, words)
-        acc = state.ef_residual + scale * flat_g
-        comp, residual, nsel, cstate = compress_buckets(
-            spec, plan, acc, comp_rng,
-            state.comp_state[0] if spec.stateful else ())
+        # a compressor without scopes of its own counts as a whole under
+        # ef_select; the Pallas paths' inner cand_topk/pack win over it
+        with jax.named_scope("ef_select"):
+            acc = state.ef_residual + scale * flat_g
+            comp, residual, nsel, cstate = compress_buckets(
+                spec, plan, acc, comp_rng,
+                state.comp_state[0] if spec.stateful else ())
         return comp, residual, nsel, cstate, acc, None
 
     def _make_sparse_step(use_pipeline: bool, ablate: bool):
@@ -784,15 +808,17 @@ def build_dp_train_step(
         def _gather(x):
             """Single issue point for the allgather-path payload collective
             (gklint collective-outside-pipeline funnel)."""
-            if ablate:
-                return jnp.tile(x, gather_size)
-            return lax.all_gather(x, gather_axis, tiled=True)
+            with jax.named_scope("exchange"):
+                if ablate:
+                    return jnp.tile(x, gather_size)
+                return lax.all_gather(x, gather_axis, tiled=True)
 
         def _psum_outer(x):
             if ablate:
                 return x
-            for a in outer_axes:
-                x = lax.psum(x, a)
+            with jax.named_scope("exchange"):
+                for a in outer_axes:
+                    x = lax.psum(x, a)
             return x
 
         def _pipeline_launch(payload):
@@ -806,8 +832,9 @@ def build_dp_train_step(
                 if ablate:
                     return payload
                 perm = [(j, j ^ 1) for j in range(gather_size)]
-                return tuple(lax.ppermute(p_, gather_axis, perm)
-                             for p_ in payload)
+                with jax.named_scope("exchange"):
+                    return tuple(lax.ppermute(p_, gather_axis, perm)
+                                 for p_ in payload)
             return tuple(_gather(p_) for p_ in payload)
 
         def _chunk_payload(local_idx, val, off_i):
@@ -844,7 +871,8 @@ def build_dp_train_step(
                       state.comp_state[0], offs)
                 acc = None
             else:
-                acc = state.ef_residual + scale * flat_g
+                with jax.named_scope("ef_select"):
+                    acc = state.ef_residual + scale * flat_g
                 padded = n_chunks * chunk
                 x = (jnp.pad(acc, (0, padded - acc.shape[0]))
                      if padded > acc.shape[0] else acc
@@ -865,14 +893,15 @@ def build_dp_train_step(
                         jnp.asarray(scale, jnp.float32), k, st_i[None])
                 else:
                     x_row, st_i, rng_i, off_i = xi
-                    if spec.batched_fn is not None:
-                        r, st_new = spec.batched_fn(x_row[None], k,
-                                                    st_i[None], rng_i[None])
-                    else:
-                        r, st_new = jax.vmap(
-                            lambda c, s, rg: _compressor_call(
-                                spec, c, k, s, rg))(
-                            x_row[None], st_i[None], rng_i[None])
+                    with jax.named_scope("ef_select"):
+                        if spec.batched_fn is not None:
+                            r, st_new = spec.batched_fn(
+                                x_row[None], k, st_i[None], rng_i[None])
+                        else:
+                            r, st_new = jax.vmap(
+                                lambda c, s, rg: _compressor_call(
+                                    spec, c, k, s, rg))(
+                                x_row[None], st_i[None], rng_i[None])
                 return (r.compressed.indices[0], r.compressed.values[0],
                         r.residual[0],
                         r.num_selected.astype(jnp.int32).reshape(-1)[0],
@@ -963,11 +992,12 @@ def build_dp_train_step(
                         o_val = recv[1].reshape(-1)
                         local_val = comp.values
                         round1_bytes = k_packed * 8
-                    m_idx, m_val = merge_sparse(comp.indices, local_val,
-                                                o_idx, o_val, k_packed)
-                    m_idx, m_val, tail_bytes = butterfly_rounds(
-                        m_idx, m_val, mesh.size, gather_axis, wire_fmt,
-                        start_round=1, ablate_comm=ablate)
+                    with jax.named_scope("exchange"):
+                        m_idx, m_val = merge_sparse(
+                            comp.indices, local_val, o_idx, o_val, k_packed)
+                        m_idx, m_val, tail_bytes = butterfly_rounds(
+                            m_idx, m_val, mesh.size, gather_axis, wire_fmt,
+                            start_round=1, ablate_comm=ablate)
                     overlapped = round1_bytes * (n_chunks - 1) // n_chunks
                     gcomp = CompressedGrad(m_idx, m_val)
                     n_rounds = int(math.log2(mesh.size))
@@ -983,16 +1013,19 @@ def build_dp_train_step(
                 else:
                     # trace-time count of the buffers actually ppermuted
                     # (shape x itemsize per round) — measured, not a formula
-                    gcomp, comm = gtopk_allreduce(comp, mesh.size,
-                                                  gather_axis, wire=wire_fmt,
-                                                  ablate_comm=ablate)
-                # the /P average rides the k-sized VALUES, not the n-sized
-                # dense buffer: one full read+write pass saved (r4 floor)
-                gcomp = gcomp._replace(
-                    values=gcomp.values / _all_axes_size())
-                if flat_opt is None:
-                    dense = decompress(gcomp, n_total, grad_dtype)
-                residual = global_residual(acc, gcomp)
+                    with jax.named_scope("exchange"):
+                        gcomp, comm = gtopk_allreduce(
+                            comp, mesh.size, gather_axis, wire=wire_fmt,
+                            ablate_comm=ablate)
+                with jax.named_scope("scatter"):
+                    # the /P average rides the k-sized VALUES, not the
+                    # n-sized dense buffer: one full read+write pass saved
+                    # (r4 floor)
+                    gcomp = gcomp._replace(
+                        values=gcomp.values / _all_axes_size())
+                    if flat_opt is None:
+                        dense = decompress(gcomp, n_total, grad_dtype)
+                    residual = global_residual(acc, gcomp)
                 bytes_sent = jnp.float32(comm.bytes_sent)
             elif wire_fmt is not None:
                 # packed wire exchange (parallel/wire.py): u32 words — u16
@@ -1010,25 +1043,32 @@ def build_dp_train_step(
                     bytes_count = k_packed * 4
                 else:
                     if words is None:   # unfused: encode from global comp
-                        words = wire_mod.encode_grouped(comp, wire_fmt)
+                        with jax.named_scope("pack"):
+                            words = wire_mod.encode_grouped(comp, wire_fmt)
                     g_words = _gather(words)
                     # measured from the concrete packed buffer handed to
                     # the collective — never a closed-form estimate
                     bytes_count = words.size * words.dtype.itemsize
-                g_comp = wire_mod.decode_grouped(g_words, wire_fmt, k_packed)
-                g_idx = g_comp.indices
-                g_val = g_comp.values / _all_axes_size()
+                with jax.named_scope("scatter"):
+                    g_comp = wire_mod.decode_grouped(g_words, wire_fmt,
+                                                     k_packed)
+                    g_idx = g_comp.indices
+                    g_val = g_comp.values / _all_axes_size()
+                    if flat_opt is None:
+                        dense = decompress(CompressedGrad(g_idx, g_val),
+                                           n_total, grad_dtype)
                 if flat_opt is None:
-                    dense = decompress(CompressedGrad(g_idx, g_val), n_total,
-                                       grad_dtype)
                     dense = _psum_outer(dense)
                 # EF absorbs the bf16 rounding on-device in f32: the
                 # committed residual gets back exactly (value - decoded
                 # value) at each sent index, so the quantization error
                 # never accumulates. mode='drop' for pad-chunk slots
                 # at/above the residual length.
-                q_err = comp.values - wire_mod.bf16_roundtrip(comp.values)
-                residual = residual.at[comp.indices].add(q_err, mode="drop")
+                with jax.named_scope("scatter"):
+                    q_err = comp.values - wire_mod.bf16_roundtrip(
+                        comp.values)
+                    residual = residual.at[comp.indices].add(q_err,
+                                                             mode="drop")
                 bytes_sent = jnp.float32(bytes_count)
             else:
                 # allgather of the packed pairs over the (ICI) gather axis,
@@ -1040,18 +1080,22 @@ def build_dp_train_step(
                 # already /P-scaled so the psum-summed result is identical.
                 if use_pipeline:
                     k = plan.buckets[0].k
-                    g_idx = (recv[0].reshape(n_chunks, gather_size, k)
-                             .transpose(1, 0, 2).reshape(-1))
-                    g_val = (recv[1].reshape(n_chunks, gather_size, k)
-                             .transpose(1, 0, 2).reshape(-1)
-                             / _all_axes_size())
+                    with jax.named_scope("scatter"):
+                        g_idx = (recv[0].reshape(n_chunks, gather_size, k)
+                                 .transpose(1, 0, 2).reshape(-1))
+                        g_val = (recv[1].reshape(n_chunks, gather_size, k)
+                                 .transpose(1, 0, 2).reshape(-1)
+                                 / _all_axes_size())
                     overlapped = (n_chunks - 1) * k * 8
                 else:
                     g_idx = _gather(comp.indices)
-                    g_val = _gather(comp.values) / _all_axes_size()
+                    g_val = _gather(comp.values)
+                    with jax.named_scope("scatter"):
+                        g_val = g_val / _all_axes_size()
                 if flat_opt is None:
-                    dense = decompress(CompressedGrad(g_idx, g_val), n_total,
-                                       grad_dtype)
+                    with jax.named_scope("scatter"):
+                        dense = decompress(CompressedGrad(g_idx, g_val),
+                                           n_total, grad_dtype)
                     dense = _psum_outer(dense)
                 # measured from the concrete (idx, val) buffers handed to
                 # the collectives (same count the old closed form produced)
@@ -1086,10 +1130,12 @@ def build_dp_train_step(
             # achieved density, AND the per-bucket breakdown; the EF norm
             # reads the COMMITTED residual so a guard-skipped step reports
             # the state that actually persists
-            sel_per_bucket = _pmean(nsel.astype(jnp.float32))
-            num_selected = jnp.sum(sel_per_bucket)
+            with jax.named_scope("step_metrics"):
+                sel_per_bucket = _pmean(nsel.astype(jnp.float32))
+                num_selected = jnp.sum(sel_per_bucket)
+                grad_norm = _pmean(jnp.linalg.norm(flat_g))
             return new_state, StepMetrics(
-                loss, aux, _pmean(jnp.linalg.norm(flat_g)),
+                loss, aux, grad_norm,
                 num_selected, bytes_sent, skipped, nonfinite,
                 achieved_density=num_selected / n_total,
                 ef_norm=_ef_norm(new_state.ef_residual),
@@ -1110,10 +1156,11 @@ def build_dp_train_step(
         loss, mstate, aux, new_carry, flat_g, unravel = _local_grads(
             state, batch, data_rng)
         scale = fold_lr(state.step) if fold_lr is not None else 1.0
-        dense = scale * flat_g
-        for a in axes:
-            dense = lax.psum(dense, a)
-        dense = dense / _all_axes_size()
+        with jax.named_scope("exchange"):
+            dense = scale * flat_g
+            for a in axes:
+                dense = lax.psum(dense, a)
+            dense = dense / _all_axes_size()
         # Warm-up is compression-off: the EF residual is untouched (and zero
         # if warm-up precedes any sparse step), matching SURVEY.md §2.3.
         if flat_opt is not None:
@@ -1132,8 +1179,10 @@ def build_dp_train_step(
             nonfinite = cnt.astype(jnp.float32)
         else:
             skipped = nonfinite = jnp.float32(0)
+        with jax.named_scope("step_metrics"):
+            grad_norm = _pmean(jnp.linalg.norm(flat_g))
         return new_state, StepMetrics(
-            loss, aux, _pmean(jnp.linalg.norm(flat_g)),
+            loss, aux, grad_norm,
             jnp.float32(n_total), jnp.float32(n_total * 4), skipped,
             nonfinite,
             achieved_density=jnp.float32(1.0),

@@ -27,7 +27,7 @@ from .health import (HealthMonitor, HealthPolicy, HealthServer,
 from .history import (HISTORY_SCHEMA, append_history, build_history_record,
                       load_history)
 from .throughput import ThroughputSignals, ThroughputTracker
-from .tracing import TraceContext, build_chrome_trace
+from .tracing import TraceContext, build_chrome_trace, recorded
 
 __all__ = [
     "EventBus",
@@ -47,6 +47,7 @@ __all__ = [
     "build_chrome_trace",
     "build_history_record",
     "load_history",
+    "recorded",
     "replay_health",
     "validate_record",
     "validate_stream",

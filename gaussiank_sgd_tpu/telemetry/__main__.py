@@ -16,8 +16,7 @@ resilience summaries from the JSONL stream alone; ``validate`` schema-
 checks every record and the seq envelope (truncation, gaps, mixed-run
 resets); ``trace`` renders the stream into Chrome-trace/Perfetto JSON
 (open at ui.perfetto.dev — docs/OBSERVABILITY.md "Tracing &
-trajectory"). Exit codes: 0 ok, 1 validation problems (or, for trace
---require-overlap, no exchange/compute overlap found), 2 usage error.
+trajectory"). Exit codes: 0 ok, 1 validation problems, 2 usage error.
 
 ``merge`` joins N per-process streams (a multi-process launcher pod —
 docs/OBSERVABILITY.md "Merged pod streams") into one stream ordered by
@@ -48,7 +47,7 @@ from typing import List, Optional
 from .events import merge_streams, validate_file, validate_stream
 from .health import format_health, replay_health
 from .report import format_report, load_events, summarize
-from .tracing import build_chrome_trace, chrome_trace_overlap_pairs
+from .tracing import build_chrome_trace
 
 
 def infer_process_index(path: str, fallback: int) -> int:
@@ -92,9 +91,6 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="worker id for this stream's track group; merge "
                          "multi-worker runs by rendering each stream with "
                          "a distinct --pid and concatenating traceEvents")
-    tp.add_argument("--require-overlap", action="store_true",
-                    help="exit 1 unless >= 1 exchange span overlaps a "
-                         "compress/compute span (the pipelining gate)")
 
     mp = sub.add_parser(
         "merge", help="merge per-process pod streams into one JSONL "
@@ -217,16 +213,10 @@ def main(argv: Optional[List[str]] = None) -> int:
             trace = build_chrome_trace(events, pid=args.pid)
             with open(args.out, "w", encoding="utf-8") as fh:
                 json.dump(trace, fh)
-            pairs = chrome_trace_overlap_pairs(trace)
             n_x = sum(1 for ev in trace["traceEvents"]
                       if ev.get("ph") == "X")
             print(f"wrote {args.out}: {len(trace['traceEvents'])} trace "
-                  f"event(s), {n_x} span(s), {pairs} exchange/compute "
-                  f"overlap pair(s)")
-            if args.require_overlap and pairs < 1:
-                print("error: --require-overlap but no exchange span "
-                      "overlaps a compress/compute span", file=sys.stderr)
-                return 1
+                  f"event(s), {n_x} span(s)")
             return 0
 
         rep = validate_file(args.path, strict=args.strict)

@@ -85,11 +85,7 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
                   # exchange time the schedule failed to hide (step
                   # minus its exchange-ablated timing twin)
                   "overlap": STRING, "overlapped_bytes_sent": NUMBER,
-                  "exposed_exchange_ms": NUMBER,
-                  # trace-gated span-source geometry (--trace on only;
-                  # telemetry/tracing.py reconstructs per-chunk device
-                  # phases from these trace-time-static shape facts)
-                  "pipeline_chunks": NUMBER, "comm_rounds": NUMBER},
+                  "exposed_exchange_ms": NUMBER},
     ),
     "eval": EventSchema(
         required={"step": NUMBER, "epoch": NUMBER, "val_loss": NUMBER},
@@ -253,15 +249,21 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
     ),
     # step-timeline tracing (telemetry/tracing.py): one record per host
     # phase span. ``ph`` follows the Chrome-trace vocabulary: "X" complete
-    # (t0 + dur_ms), "B"/"E" begin/end of a long-lived span (the
-    # trajectory), "i" instant marker. ``span_id``/``parent_span`` form
-    # the span tree; validate_stream checks its health as WARNINGS only
-    # (orphans/unclosed are suspicious, not illegal — a crashed run ends
-    # mid-span by design).
+    # (t0_ns + dur_ns), "B"/"E" begin/end of a long-lived span (the
+    # trajectory), "i" instant marker. Every time is a perf_counter_ns
+    # reading; a "B" record also carries ``wall_ns``, the time_ns reading
+    # taken with its ``t0_ns``, which maps the others to wall time. "X"
+    # records are published when the trainer drains them (log steps,
+    # close), so their ``ts`` is not their time. ``ready`` is the one
+    # counter: batches in the prefetch queue when ``data_wait`` opened.
+    # ``span_id``/``parent_span`` form the span tree; validate_stream
+    # checks its health as WARNINGS only (orphans/unclosed are suspicious,
+    # not illegal — a crashed run ends mid-span by design).
     "span": EventSchema(
         required={"name": STRING, "span_id": STRING, "ph": STRING},
         optional={"parent_span": STRING, "trace_id": STRING,
-                  "cat": STRING, "t0": NUMBER, "dur_ms": NUMBER,
+                  "cat": STRING, "t0_ns": NUMBER, "dur_ns": NUMBER,
+                  "wall_ns": NUMBER, "ready": NUMBER,
                   "step": NUMBER, "reason": STRING, "knob": STRING,
                   "path": STRING},
     ),

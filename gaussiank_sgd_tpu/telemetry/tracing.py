@@ -1,26 +1,25 @@
-"""Span-based step tracing over the event bus (docs/OBSERVABILITY.md).
+"""Span-based host tracing over the event bus (docs/OBSERVABILITY.md).
 
-Two halves, deliberately decoupled:
-
-*Online* — :class:`TraceContext` wraps an :class:`~.bus.EventBus` and
-emits ``span`` records for HOST phases only (data wait, step dispatch,
-checkpoint save, rollback, policy apply). It installs a stamp hook on
-the bus so every record published while a span is open carries
-``trace_id``/``span_id`` — producers never change. Nothing here runs
-inside jit; the device timeline is NOT measured online (that would need
-host syncs the hot path forbids).
+*Online* — :class:`TraceContext` times HOST phases (the train loop's
+iteration and its parts, construction, checkpoint save, rollback, policy
+apply) on ONE clock, ``time.perf_counter_ns``, and keeps one
+``(perf_counter_ns, time_ns)`` pair per trajectory to map it to wall time.
+A finished span costs two clock reads and a list append on the hot path:
+it goes to a bounded in-memory list, is published on the bus when the
+owner calls :meth:`TraceContext.drain` (the trainer: at log steps and at
+close), and stays reachable in-process through :func:`recorded` after the
+bus is closed. Each span also opens the annotation it was given
+(``jax.profiler.TraceAnnotation`` from the trainer), so a profile taken
+with host tracing on shows the same spans beside the device operations.
+The context installs a stamp hook on the bus so every record published
+while a span is open carries ``trace_id``/``span_id`` — producers never
+change. Nothing here runs inside jit: the device's side is named by
+``jax.named_scope`` in the step programs and read from a profiler trace
+(telemetry/profiler.py, ``--profile-steps``).
 
 *Offline* — :func:`build_chrome_trace` renders a finished JSONL stream
-into Chrome-trace/Perfetto JSON. Device phases are RECONSTRUCTED from
-instrumentation the step already pays for: the per-phase ablation
-timings on ``train`` records (fwd_bwd_s/select_s/comm_update_s from the
-timing-twin protocol), the pipelined schedule's ``exposed_exchange_ms``
-+ ``overlapped_bytes_sent``, and the per-chunk geometry on
-``bench_overlap`` records. The reconstruction is a model of the step —
-anchored so each interval ENDS at its record's publish timestamp — not
-a hardware trace; its value is making overlap visible (did chunk i's
-exchange hide behind chunk i+1's compress?), and jax.profiler remains
-the ground-truth tool (telemetry/profiler.py).
+into Chrome-trace/Perfetto JSON: the host spans on one track, every other
+record as an instant on a second.
 
 Everything in this module is pure stdlib: the ``trace`` CLI subcommand
 (__main__.py) must run on a machine without jax installed.
@@ -28,18 +27,29 @@ Everything in this module is pure stdlib: the ``trace`` CLI subcommand
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import itertools
 import os
 import threading
 import time
-from typing import (Any, Callable, Dict, Iterable, Iterator, List, Mapping,
-                    Optional, Tuple)
+from typing import (Any, Callable, Deque, Dict, Iterable, Iterator, List,
+                    Mapping, NamedTuple, Optional, Tuple)
 
 __all__ = [
+    "Recording",
+    "Span",
     "TraceContext",
     "build_chrome_trace",
-    "chrome_trace_overlap_pairs",
+    "recorded",
 ]
+
+# spans a recording keeps for in-process readers, and finished spans a
+# context holds before it publishes them without being asked (a run whose
+# log interval is longer than this many spans)
+KEEP_SPANS = 1 << 16
+PENDING_SPANS = 1 << 12
+_KEEP_RECORDINGS = 16
 
 
 def _default_trace_id() -> str:
@@ -47,8 +57,56 @@ def _default_trace_id() -> str:
     return f"{os.getpid():x}-{int(time.time() * 1e3):x}"
 
 
+def _wall_ns(anchors: List[Tuple[int, int]], perf_ns: int) -> int:
+    """A ``perf_counter_ns`` reading on the wall clock, through the newest
+    ``(perf_ns, wall_ns)`` pair taken at or before it (the first pair for
+    what came earlier)."""
+    use = anchors[0]
+    for a in anchors:
+        if a[0] <= perf_ns:
+            use = a
+    return use[1] + (perf_ns - use[0])
+
+
+class Span(NamedTuple):
+    """A finished host span; both times are ``perf_counter_ns`` readings."""
+
+    name: str
+    span_id: str
+    parent: Optional[str]
+    t0_ns: int
+    t1_ns: int
+    cat: str
+    fields: Dict[str, Any]
+
+
+class Recording:
+    """One run's finished spans, newest ``KEEP_SPANS`` of them, and the
+    clock pairs that map them to wall time."""
+
+    def __init__(self, run_id: Optional[str], trace_id: str):
+        self.run_id = run_id
+        self.trace_id = trace_id
+        self.anchors: List[Tuple[int, int]] = []    # (perf_ns, wall_ns)
+        self.spans: Deque[Span] = collections.deque(maxlen=KEEP_SPANS)
+
+    def wall_ns(self, perf_ns: int) -> int:
+        """``perf_ns`` on the wall clock (``time_ns``)."""
+        return _wall_ns(self.anchors, perf_ns)
+
+
+_RECORDINGS: "collections.OrderedDict[str, Recording]" = \
+    collections.OrderedDict()
+
+
+def recorded(run_id: str) -> Optional[Recording]:
+    """The newest recording made under ``run_id`` in this process, also
+    after its trainer was closed and freed; None when there is none."""
+    return _RECORDINGS.get(run_id)
+
+
 class TraceContext:
-    """Allocates span ids and publishes ``span`` records on a bus.
+    """Allocates span ids, times spans and publishes ``span`` records.
 
     Span ids are sequential per-context (``s0001``, ``s0002``, ...) so a
     trace is deterministic given a deterministic schedule; the open-span
@@ -59,25 +117,39 @@ class TraceContext:
     ``span_id`` of the innermost open span when one exists) on the bus;
     without ``install()`` the bus stream is byte-identical to an
     untraced run.
+
+    ``annotate(name)`` and ``step_annotate(name, step_num=...)`` are
+    context-manager factories opened with each span (the profiler's
+    annotations); both default to none.
     """
 
     def __init__(self, bus: Any, trace_id: Optional[str] = None,
-                 clock: Callable[[], float] = time.time,
-                 perf: Callable[[], float] = time.perf_counter):
+                 run_id: Optional[str] = None,
+                 clock_ns: Callable[[], int] = time.perf_counter_ns,
+                 wall_ns: Callable[[], int] = time.time_ns,
+                 annotate: Optional[Callable[..., Any]] = None,
+                 step_annotate: Optional[Callable[..., Any]] = None):
         self._bus = bus
         self.trace_id = trace_id or _default_trace_id()
-        self._clock = clock
-        self._perf = perf
-        self._lock = threading.Lock()
-        self._n = 0
+        self._clock_ns = clock_ns
+        self._wall_ns = wall_ns
+        self._annotate = annotate
+        self._step_annotate = step_annotate
+        self._ids = itertools.count(1)
         self._local = threading.local()
         self._open_names: Dict[str, str] = {}   # B-span id -> name
+        self._pending: List[Span] = []
+        self.recording = Recording(run_id, self.trace_id)
+        self._anchor()
+        if run_id is not None:
+            _RECORDINGS.pop(run_id, None)
+            _RECORDINGS[run_id] = self.recording
+            while len(_RECORDINGS) > _KEEP_RECORDINGS:
+                _RECORDINGS.popitem(last=False)
 
     # ------------------------------------------------------------- ids
     def _next_id(self) -> str:
-        with self._lock:
-            self._n += 1
-            return f"s{self._n:04x}"
+        return f"s{next(self._ids):04x}"
 
     def _stack(self) -> List[str]:
         st = getattr(self._local, "stack", None)
@@ -90,10 +162,16 @@ class TraceContext:
         st = self._stack()
         return st[-1] if st else None
 
+    def _anchor(self) -> Tuple[int, int]:
+        pair = (self._clock_ns(), self._wall_ns())
+        self.recording.anchors.append(pair)
+        return pair
+
     # ----------------------------------------------------- bus stamping
     def stamp(self) -> Dict[str, Any]:
         """Fields merged (setdefault) onto every published record; called
-        under the bus lock by EventBus.publish — must never publish."""
+        by EventBus.publish on the publishing thread — must never
+        publish."""
         out: Dict[str, Any] = {"trace_id": self.trace_id}
         cur = self.current_span()
         if cur is not None:
@@ -109,42 +187,72 @@ class TraceContext:
 
     # ------------------------------------------------------------ spans
     @contextlib.contextmanager
-    def span(self, name: str, cat: str = "host",
+    def span(self, name: str, cat: str = "host", step_num: Any = None,
              **fields: Any) -> Iterator[str]:
-        """Complete ("X") span around a host phase. The record is emitted
-        at CLOSE — a nested child's record lands before its parent's, so
-        readers resolve parents at end-of-stream (events.validate_stream
-        does exactly this)."""
+        """Complete ("X") span around a host phase. With ``step_num`` the
+        span is a step of the run: the step annotation is opened instead
+        of the plain one, and ``step`` is recorded. Nothing is published
+        here: the finished span waits in memory for :meth:`drain`."""
         sid = self._next_id()
-        parent = self.current_span()
-        self._stack().append(sid)
-        t0 = self._clock()
-        p0 = self._perf()
+        stack = self._stack()
+        parent = stack[-1] if stack else None
+        stack.append(sid)
+        note = None
+        if step_num is not None:
+            fields["step"] = step_num
+            if self._step_annotate is not None:
+                note = self._step_annotate(name, step_num=step_num)
+        elif self._annotate is not None:
+            note = self._annotate(name)
+        if note is not None:
+            note.__enter__()
+        t0 = self._clock_ns()
         try:
             yield sid
         finally:
-            st = self._stack()
-            if st and st[-1] == sid:
-                st.pop()
-            elif sid in st:          # defensive: out-of-order close
-                st.remove(sid)
-            rec = {"name": name, "span_id": sid, "ph": "X", "cat": cat,
-                   "t0": round(t0, 6),
-                   "dur_ms": round((self._perf() - p0) * 1e3, 3)}
-            if parent is not None:
-                rec["parent_span"] = parent
-            rec.update(fields)
-            self._bus.emit("span", **rec)
+            t1 = self._clock_ns()
+            if note is not None:
+                note.__exit__(None, None, None)
+            if stack and stack[-1] == sid:
+                stack.pop()
+            elif sid in stack:       # a trajectory rotated underneath it
+                stack.remove(sid)
+            done = Span(name, sid, parent, t0, t1, cat, fields)
+            self.recording.spans.append(done)
+            self._pending.append(done)
+            if len(self._pending) >= PENDING_SPANS:
+                self.drain()
 
-    def begin(self, name: str, cat: str = "host", **fields: Any) -> str:
+    def drain(self) -> int:
+        """Publish the finished spans that wait in memory, in the order
+        they closed (a child before its parent, so readers resolve parents
+        at end-of-stream, as events.validate_stream does). Returns how
+        many."""
+        pending, self._pending = self._pending, []
+        for s in pending:
+            rec = {"name": s.name, "span_id": s.span_id, "ph": "X",
+                   "cat": s.cat, "t0_ns": s.t0_ns,
+                   "dur_ns": s.t1_ns - s.t0_ns}
+            if s.parent is not None:
+                rec["parent_span"] = s.parent
+            rec.update(s.fields)
+            self._bus.emit("span", **rec)
+        return len(pending)
+
+    def begin(self, name: str, cat: str = "host", root: bool = False,
+              **fields: Any) -> str:
         """Open a long-lived ("B") span — e.g. a whole trajectory between
-        rollbacks. Must be closed with :meth:`end`."""
+        rollbacks. Takes a new clock pair and publishes it (``t0_ns``,
+        ``wall_ns``). ``root`` makes it a root of the span tree whatever is
+        open (a trajectory rotated from inside an iteration). Must be
+        closed with :meth:`end`."""
         sid = self._next_id()
-        parent = self.current_span()
+        parent = None if root else self.current_span()
         self._stack().append(sid)
         self._open_names[sid] = name
+        perf, wall = self._anchor()
         rec = {"name": name, "span_id": sid, "ph": "B", "cat": cat,
-               "t0": round(self._clock(), 6)}
+               "t0_ns": perf, "wall_ns": wall}
         if parent is not None:
             rec["parent_span"] = parent
         rec.update(fields)
@@ -157,13 +265,14 @@ class TraceContext:
         if span_id in st:
             st.remove(span_id)
         self._bus.emit("span", name=name, span_id=span_id, ph="E",
-                       cat="host", **fields)
+                       cat="host", t0_ns=self._clock_ns(), **fields)
 
     def instant(self, name: str, cat: str = "host", **fields: Any) -> str:
         """Zero-duration marker (anomaly pending, preemption signal)."""
         sid = self._next_id()
         parent = self.current_span()
-        rec = {"name": name, "span_id": sid, "ph": "i", "cat": cat}
+        rec = {"name": name, "span_id": sid, "ph": "i", "cat": cat,
+               "t0_ns": self._clock_ns()}
         if parent is not None:
             rec["parent_span"] = parent
         rec.update(fields)
@@ -178,166 +287,48 @@ class TraceContext:
 # fixed tid layout, one set per worker (pid). Perfetto shows the thread
 # names from the metadata events; numbers keep rows stably ordered.
 _TID_HOST = 0
-_TID_DEVICE = 1
-_TID_COMM = 2
-_TID_COMPRESS = 3
-_TID_EVENTS = 4
+_TID_EVENTS = 1
 
 _TID_NAMES = {
     _TID_HOST: "host phases",
-    _TID_DEVICE: "device step (reconstructed)",
-    _TID_COMM: "exchange (reconstructed)",
-    _TID_COMPRESS: "compress chunks (reconstructed)",
     _TID_EVENTS: "events",
 }
 
-def _x(name: str, ts_us: float, dur_us: float, tid: int, pid: int,
-       cat: str, args: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
-    ev: Dict[str, Any] = {"name": name, "ph": "X", "ts": round(ts_us, 1),
-                          "dur": round(max(dur_us, 0.0), 1), "pid": pid,
-                          "tid": tid, "cat": cat}
-    if args:
-        ev["args"] = args
-    return ev
+
+def _number(v: Any) -> bool:
+    return isinstance(v, (int, float)) and not isinstance(v, bool)
 
 
-def _pick_ts(rec: Mapping[str, Any]) -> Optional[float]:
-    for key in ("t0", "ts"):
-        v = rec.get(key)
-        if isinstance(v, (int, float)) and not isinstance(v, bool):
-            return float(v)
-    return None
+def _span_wall_s(rec: Mapping[str, Any],
+                 anchors: List[Tuple[int, int]]) -> Optional[float]:
+    """A span record's start on the wall clock, in seconds: its
+    ``t0_ns`` through the newest clock pair published at or before it;
+    the bus's publish time where the stream has neither."""
+    t0 = rec.get("t0_ns")
+    if _number(t0) and anchors:
+        return _wall_ns(anchors, t0) / 1e9
+    ts = rec.get("ts")
+    return float(ts) if _number(ts) else None
 
 
-def _render_span(rec: Mapping[str, Any], us: Callable[[float], float],
-                 pid: int, out: List[Dict[str, Any]]) -> None:
+def _render_span(rec: Mapping[str, Any], t: float,
+                 us: Callable[[float], float], pid: int,
+                 out: List[Dict[str, Any]]) -> None:
     name = str(rec.get("name", "span"))
     ph = rec.get("ph")
-    t = _pick_ts(rec)
-    if t is None:
-        return
     args = {k: rec[k] for k in ("span_id", "parent_span", "step", "reason",
-                                "knob", "path") if k in rec}
-    cat = str(rec.get("cat", "host"))
+                                "knob", "path", "ready") if k in rec}
+    ev: Dict[str, Any] = {"name": name, "ph": ph, "ts": round(us(t), 1),
+                          "pid": pid, "tid": _TID_HOST,
+                          "cat": str(rec.get("cat", "host")), "args": args}
     if ph == "X":
-        dur_ms = rec.get("dur_ms", 0.0)
-        out.append(_x(name, us(t), float(dur_ms) * 1e3, _TID_HOST, pid,
-                      cat, args))
-    elif ph in ("B", "E"):
-        out.append({"name": name, "ph": ph, "ts": round(us(t), 1),
-                    "pid": pid, "tid": _TID_HOST, "cat": cat, "args": args})
+        dur = rec.get("dur_ns", 0)
+        ev["dur"] = round(max(float(dur), 0.0) / 1e3, 1)
     elif ph == "i":
-        out.append({"name": name, "ph": "i", "s": "t",
-                    "ts": round(us(float(rec.get("ts", t))), 1),
-                    "pid": pid, "tid": _TID_HOST, "cat": cat, "args": args})
-
-
-def _render_train(rec: Mapping[str, Any], us: Callable[[float], float],
-                  pid: int, out: List[Dict[str, Any]]) -> None:
-    """One representative step per log interval, anchored to END at the
-    record's publish ts (the interval's metrics are per-step means, so
-    this draws the LAST step of the interval to scale)."""
-    ts = rec.get("ts")
-    step_s = rec.get("step_s")
-    if not isinstance(ts, (int, float)) or not isinstance(step_s, (int, float)):
+        ev["s"] = "t"
+    elif ph not in ("B", "E"):
         return
-    if isinstance(ts, bool) or isinstance(step_s, bool) or step_s <= 0:
-        return
-    t_end = float(ts)
-    t_start = t_end - float(step_s)
-    step = rec.get("step")
-    args = {"step": step, "loss": rec.get("loss")}
-    phases = [("fwd_bwd", rec.get("fwd_bwd_s")),
-              ("select_pack", rec.get("select_s")),
-              ("comm_update", rec.get("comm_update_s"))]
-    have_phases = all(isinstance(v, (int, float)) and not isinstance(v, bool)
-                      for _, v in phases)
-    if have_phases:
-        t = t_start
-        for pname, v in phases:
-            out.append(_x(f"{pname} [step {step}]", us(t), float(v) * 1e6,
-                          _TID_DEVICE, pid, "device", args))
-            t += float(v)
-    else:
-        out.append(_x(f"step {step}", us(t_start), float(step_s) * 1e6,
-                      _TID_DEVICE, pid, "device", args))
-    # pipelined exchange: the exposed tail is what the schedule failed to
-    # hide (step minus its sparse_noexch twin); the overlapped portion is
-    # drawn inside the compute window, scaled by the byte fraction that
-    # was launched early (StepMetrics.overlapped_bytes_sent)
-    if rec.get("overlap") != "pipelined":
-        return
-    exposed_ms = rec.get("exposed_exchange_ms")
-    exposed_s = (float(exposed_ms) / 1e3
-                 if isinstance(exposed_ms, (int, float))
-                 and not isinstance(exposed_ms, bool) else 0.0)
-    exposed_s = min(max(exposed_s, 0.0), float(step_s))
-    if exposed_s > 0:
-        out.append(_x(f"exchange exposed [step {step}]",
-                      us(t_end - exposed_s), exposed_s * 1e6,
-                      _TID_COMM, pid, "exchange",
-                      {"exposed_exchange_ms": exposed_ms}))
-    bs = rec.get("bytes_sent")
-    ob = rec.get("overlapped_bytes_sent")
-    if (isinstance(bs, (int, float)) and isinstance(ob, (int, float))
-            and not isinstance(bs, bool) and not isinstance(ob, bool)
-            and bs > 0 and ob > 0):
-        frac = min(float(ob) / float(bs), 1.0)
-        hidden_s = frac * max(float(step_s) - exposed_s, 0.0)
-        if hidden_s > 0:
-            out.append(_x(f"exchange overlapped [step {step}]",
-                          us(t_end - exposed_s - hidden_s), hidden_s * 1e6,
-                          _TID_COMM, pid, "exchange",
-                          {"overlapped_bytes_sent": ob, "bytes_sent": bs}))
-
-
-def _render_bench_overlap(rec: Mapping[str, Any],
-                          us: Callable[[float], float], pid: int,
-                          out: List[Dict[str, Any]]) -> None:
-    """Per-chunk reconstruction of the pipelined schedule: chunk i's
-    exchange launches when its compress finishes and runs while chunk
-    i+1 compresses — the geometry PR 7's scan actually executes. Chunk
-    durations come from the measured totals: compute = pipe_step_ms
-    minus the exposed tail, split evenly over n_buckets; per-chunk
-    exchange from the sequential arm's exposed time (the full,
-    un-hidden cost) when the noise floor let it through."""
-    ts = rec.get("ts")
-    pipe_ms = rec.get("pipe_step_ms")
-    n = rec.get("n_buckets")
-    if not all(isinstance(v, (int, float)) and not isinstance(v, bool)
-               for v in (ts, pipe_ms, n)):
-        return
-    n = int(n)
-    if n < 1 or float(pipe_ms) <= 0:
-        return
-    key = str(rec.get("key", rec.get("model", "?")))
-    tail_ms = rec.get("exposed_pipe_ms")
-    tail = (float(tail_ms) if isinstance(tail_ms, (int, float))
-            and not isinstance(tail_ms, bool) else 0.0)
-    tail = min(max(tail, 0.0), float(pipe_ms))
-    c = (float(pipe_ms) - tail) / n          # per-chunk compress+compute
-    seq_ms = rec.get("exposed_seq_ms")
-    if isinstance(seq_ms, (int, float)) and not isinstance(seq_ms, bool) \
-            and float(seq_ms) > 0:
-        e = float(seq_ms) / n                # per-chunk exchange cost
-    elif tail > 0:
-        e = tail                             # only the tail was visible
-    else:
-        # both deltas sat below the noise floor: draw a nominal 20%
-        # exchange so the SHAPE of the schedule is still inspectable
-        e = 0.2 * float(pipe_ms) / n
-    t0 = float(ts) - float(pipe_ms) / 1e3
-    args = {"key": key, "n_buckets": n, "pipe_step_ms": pipe_ms,
-            "exposed_pipe_ms": rec.get("exposed_pipe_ms"),
-            "exposed_seq_ms": rec.get("exposed_seq_ms")}
-    for i in range(n):
-        cs = t0 + i * c / 1e3
-        out.append(_x(f"compress[{i}] {key}", us(cs), c * 1e3,
-                      _TID_COMPRESS, pid, "compress", args))
-        # chunk i's exchange starts where its compress ends → it runs
-        # under compress[i+1] for every i < n-1 (the pipeline's point)
-        out.append(_x(f"exchange[{i}] {key}", us(cs + c / 1e3), e * 1e3,
-                      _TID_COMM, pid, "exchange", args))
+    out.append(ev)
 
 
 def build_chrome_trace(events: Iterable[Mapping[str, Any]],
@@ -351,52 +342,38 @@ def build_chrome_trace(events: Iterable[Mapping[str, Any]],
     as hosts share a clock.
     """
     recs = [r for r in events if isinstance(r, Mapping)]
-    base: Optional[float] = None
+    anchors = sorted((r["t0_ns"], r["wall_ns"]) for r in recs
+                     if r.get("event") == "span"
+                     and _number(r.get("t0_ns"))
+                     and _number(r.get("wall_ns")))
+    timed: List[Tuple[float, Mapping[str, Any]]] = []
     for r in recs:
-        t = _pick_ts(r)
+        if r.get("event") == "span":
+            t = _span_wall_s(r, anchors)
+        else:
+            t = float(r["ts"]) if _number(r.get("ts")) else None
         if t is not None:
-            base = t if base is None else min(base, t)
-        # reconstructed intervals START before their record's ts
-        ts = r.get("ts")
-        if not isinstance(ts, (int, float)) or isinstance(ts, bool):
-            continue
-        step_s = r.get("step_s")
-        if isinstance(step_s, (int, float)) and not isinstance(step_s, bool):
-            start = float(ts) - float(step_s)
-            base = start if base is None else min(base, start)
-        pm = r.get("pipe_step_ms")
-        if isinstance(pm, (int, float)) and not isinstance(pm, bool):
-            start = float(ts) - float(pm) / 1e3
-            base = start if base is None else min(base, start)
-    if base is None:
-        base = 0.0
+            timed.append((t, r))
+    base = min((t for t, _ in timed), default=0.0)
 
     def us(t: float) -> float:
         return (t - base) * 1e6
 
     out: List[Dict[str, Any]] = []
-    proc_name = "worker"
-    for r in recs:
+    for t, r in timed:
         ev = r.get("event")
         if ev == "span":
-            _render_span(r, us, pid, out)
-        elif ev == "train":
-            _render_train(r, us, pid, out)
-        elif ev == "bench_overlap":
-            _render_bench_overlap(r, us, pid, out)
-        else:
-            t = _pick_ts(r)
-            if t is None:
-                continue
-            name = str(ev) if isinstance(ev, str) else "<record>"
-            args = {k: v for k, v in r.items()
-                    if isinstance(v, (str, int, float))}
-            out.append({"name": name, "ph": "i", "s": "t",
-                        "ts": round(us(t), 1), "pid": pid,
-                        "tid": _TID_EVENTS, "cat": "event", "args": args})
+            _render_span(r, t, us, pid, out)
+            continue
+        name = str(ev) if isinstance(ev, str) else "<record>"
+        args = {k: v for k, v in r.items()
+                if isinstance(v, (str, int, float))}
+        out.append({"name": name, "ph": "i", "s": "t",
+                    "ts": round(us(t), 1), "pid": pid,
+                    "tid": _TID_EVENTS, "cat": "event", "args": args})
     meta: List[Dict[str, Any]] = [
         {"name": "process_name", "ph": "M", "pid": pid, "tid": 0,
-         "args": {"name": f"{proc_name} {pid}"}},
+         "args": {"name": f"worker {pid}"}},
     ]
     for tid, tname in _TID_NAMES.items():
         meta.append({"name": "thread_name", "ph": "M", "pid": pid,
@@ -404,32 +381,3 @@ def build_chrome_trace(events: Iterable[Mapping[str, Any]],
         meta.append({"name": "thread_sort_index", "ph": "M", "pid": pid,
                      "tid": tid, "args": {"sort_index": tid}})
     return {"traceEvents": meta + out, "displayTimeUnit": "ms"}
-
-
-def chrome_trace_overlap_pairs(trace: Mapping[str, Any]) -> int:
-    """Count (exchange span, compress/compute span) pairs whose time
-    ranges intersect on the same worker but different tracks — the
-    acceptance check "did an exchange actually hide behind compute"."""
-
-    def _ranges(pred: Callable[[Mapping[str, Any]], bool]) \
-            -> List[Tuple[int, int, float, float]]:
-        rs = []
-        for ev in trace.get("traceEvents", []):
-            if ev.get("ph") != "X" or not pred(ev):
-                continue
-            ts, dur = ev.get("ts"), ev.get("dur")
-            if not isinstance(ts, (int, float)) \
-                    or not isinstance(dur, (int, float)) or dur <= 0:
-                continue
-            rs.append((int(ev.get("pid", 0)), int(ev.get("tid", 0)),
-                       float(ts), float(ts) + float(dur)))
-        return rs
-
-    exch = _ranges(lambda e: e.get("cat") == "exchange")
-    comp = _ranges(lambda e: e.get("cat") in ("compress", "device"))
-    pairs = 0
-    for epid, etid, e0, e1 in exch:
-        for cpid, ctid, c0, c1 in comp:
-            if epid == cpid and etid != ctid and max(e0, c0) < min(e1, c1):
-                pairs += 1
-    return pairs

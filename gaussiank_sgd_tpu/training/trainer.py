@@ -57,6 +57,10 @@ from ..telemetry.profiler import ProfilerSession
 from ..telemetry.tracing import TraceContext
 
 
+# what Trainer._span hands out with tracing off
+_NO_SPAN = contextlib.nullcontext()
+
+
 def _dtype_of(name: str):
     return {"bfloat16": jnp.bfloat16, "bf16": jnp.bfloat16,
             "float32": jnp.float32, "fp32": jnp.float32}[name]
@@ -90,7 +94,30 @@ class Trainer:
         self.trace: Optional[TraceContext] = None
         self._traj_span: Optional[str] = None
         if cfg.trace == "on":
-            self.trace = TraceContext(self.bus).install()
+            # each span also opens the profiler's annotation of its name,
+            # so a --profile-steps window shows the host spans beside the
+            # device operations; the finished spans stay reachable after
+            # close() through tracing.recorded(cfg.run_id)
+            self.trace = TraceContext(
+                self.bus, run_id=cfg.run_id,
+                annotate=jax.profiler.TraceAnnotation,
+                step_annotate=jax.profiler.StepTraceAnnotation).install()
+        with self._span("construct"):
+            self._construct(cfg, run_dir)
+        # long-lived trajectory span: every host span and stamped record
+        # between rollbacks parents to it; a rollback rotates it
+        # (_rotate_trajectory), so each trajectory is one span-tree root
+        if self.trace is not None:
+            self._traj_span = self.trace.begin("trajectory", root=True,
+                                               step=self.step)
+
+    def _construct(self, cfg: TrainConfig, run_dir: str) -> None:
+        """Everything ``__init__`` builds, under the ``construct`` span:
+        ``build_data`` (both datasets), ``build_model`` (the module and its
+        initial variables) and ``build_step`` (twice: the mesh; then the
+        plan, the step programs and the placed state) are its children,
+        the rest (resilience, policy, health, the eval step, a resume) its
+        self time."""
         self.tracker = ThroughputTracker(window=cfg.telemetry_window)
         self._flops_per_step: Optional[float] = None
         self._peak_flops: Optional[float] = None
@@ -104,26 +131,8 @@ class Trainer:
 
         # ---- mesh (SURVEY.md §3.1: hvd.init + device binding -> mesh) ----
         self.sp = cfg.sp_size if cfg.sp_size > 1 else 0
-        if self.sp:
-            if cfg.dnn.lower() not in ("transformer_lm", "transformerlm"):
-                raise ValueError(
-                    "sequence parallelism (--sp-size) is the transformer_lm "
-                    "long-context path")
-            if cfg.ici_size or cfg.dcn_size:
-                raise ValueError(
-                    "--sp-size and --ici-size/--dcn-size are mutually "
-                    "exclusive mesh layouts")
-            dp = cfg.nworkers if cfg.nworkers > 0 else (
-                len(jax.devices()) // self.sp)
-            self.mesh = dp_sp_mesh(dp, self.sp)
-            self.nworkers = dp          # dp width: examples per step = bs*dp
-        elif cfg.ici_size > 0 and cfg.dcn_size > 0:
-            self.mesh = hierarchical_dp_mesh(cfg.ici_size, cfg.dcn_size)
-            self.nworkers = self.mesh.size
-        else:
-            n = cfg.nworkers if cfg.nworkers > 0 else None
-            self.mesh = data_parallel_mesh(n)
-            self.nworkers = self.mesh.size
+        with self._span("build_step"):
+            self._build_mesh(cfg)
         # sequence-parallel batches shard dim 1 (sequence) over 'sp'
         self._batch_spec = P(("dp",), "sp") if self.sp else None
 
@@ -135,79 +144,21 @@ class Trainer:
         test_kw = dict(train=False, batch_size=eval_bs)
         train_kw.update(cfg.dataset_kwargs)   # overrides win, never collide
         test_kw.update(cfg.dataset_kwargs)
-        self.train_ds, card = data_lib.make_dataset(
-            cfg.dataset, cfg.data_dir, **train_kw)
-        self.test_ds, _ = data_lib.make_dataset(
-            cfg.dataset, cfg.data_dir, **test_kw)
+        with self._span("build_data"):
+            self.train_ds, card = data_lib.make_dataset(
+                cfg.dataset, cfg.data_dir, **train_kw)
+            self.test_ds, _ = data_lib.make_dataset(
+                cfg.dataset, cfg.data_dir, **test_kw)
 
-        # ---- model: head size = explicit flag > dataset cardinality;
-        # cfg.model_kwargs overrides EVERYTHING (single merged dict, so a
-        # key like num_classes/dtype overrides instead of raising a
-        # duplicate-keyword TypeError) ----
-        model_kw = {"num_classes": cfg.num_classes or card, "dtype": dtype}
-        if cfg.dnn.lower() in ("lstm", "transformer", "transformer_lm",
-                               "transformerlm"):
-            model_kw["vocab_size"] = cfg.num_classes or card
-        elif cfg.dnn.lower() == "lstman4":
-            model_kw["num_labels"] = cfg.num_classes or card
-        model_kw.update(cfg.model_kwargs)
-        if self.sp:
-            model_kw["sp_axis"] = "sp"
-        self.spec = models_lib.get_model(cfg.dnn, cfg.dataset, **model_kw)
-        # mesh axis names only exist inside shard_map: initialize params via
-        # the sp-free twin (identical param structure)
-        init_module = (models_lib.get_model(
-            cfg.dnn, cfg.dataset, **{**model_kw, "sp_axis": None}).module
-            if self.sp else self.spec.module)
-        self.steps_per_epoch = self.train_ds.steps_per_epoch
-        self.total_steps = (cfg.max_steps if cfg.max_steps
-                            else cfg.epochs * self.steps_per_epoch)
-
-        # ---- init model variables ----
-        rng = jax.random.PRNGKey(cfg.seed)
-        init_rng, self.data_rng, state_rng = jax.random.split(rng, 3)
-        dummy = self._dummy_inputs()
-        # jitted: ONE compiled program (persistently cacheable) instead of
-        # an eager compile per initializer primitive and parameter shape
-        def init_variables(rngs, *inputs):
-            return init_module.init(rngs, *inputs, train=False)
-
-        variables = jax.jit(init_variables)(
-            {"params": init_rng, "dropout": init_rng}, *dummy)
-        params = variables["params"]
-        model_state = {k: v for k, v in variables.items() if k != "params"}
+        with self._span("build_model"):
+            params, model_state, state_rng = self._build_model(cfg, card,
+                                                               dtype)
         n_params = sum(int(np.prod(x.shape))
                        for x in jax.tree_util.tree_leaves(params))
 
-        # ---- compression plan + loss fn (static across step rebuilds) ----
-        # LSTM bptt carry across windows (the reference's "repackaging",
-        # SURVEY.md §3.2): hidden state lives in TrainState.carry,
-        # batch-dim sharded; reset at epoch boundaries (train loop).
-        self.recurrent = (cfg.dnn.lower() == "lstm" and cfg.carry_hidden)
-        comp = get_compressor(cfg.compressor, density=cfg.density,
-                              sigma_scale=cfg.sigma_scale)
-        plan = plan_for_params(params, cfg.density, cfg.bucket_size,
-                               policy=cfg.bucket_policy)
-        self.plan = plan
-        self._comp = comp
-        # uint8 pixel batches (imagenet contract) normalize ON DEVICE —
-        # the dtype check inside _prep_pixels is trace-time static, so
-        # float batches pay nothing
-        from .losses import IMAGENET_NORM
-        input_norm = (IMAGENET_NORM if cfg.dataset.lower() == "imagenet"
-                      else None)
-        self._loss_fn = make_loss_fn(self.spec, cfg.label_smoothing,
-                                     recurrent=self.recurrent,
-                                     input_norm=input_norm)
-        self.is_dense_only = comp.name == "none"
-
-        # ---- schedule + optimizer + the fused step programs ----
-        self._lr_scale = 1.0            # compounded rollback LR backoff
-        self._build_steps()
-        carry = (self.spec.module.initial_carry(local_bs)
-                 if self.recurrent else ())
-        self.state = self.ts.init_state(params, state_rng,
-                                        model_state=model_state, carry=carry)
+        with self._span("build_step"):
+            input_norm = self._build_program(cfg, params, model_state,
+                                             state_rng, local_bs)
 
         # ---- resilience runtime (docs/RESILIENCE.md) ----
         self.ckpt_dir = os.path.join(run_dir, "ckpt")
@@ -330,9 +281,9 @@ class Trainer:
             "compressor=%s kernel=%s density=%g buckets=%d k_total=%d "
             "steps/epoch=%d total_steps=%d",
             cfg.dnn, cfg.dataset, n_params / 1e6, self.nworkers,
-            local_bs, comp.name, self.ts.kernel_mode, cfg.density,
-            len(plan.buckets), plan.total_k, self.steps_per_epoch,
-            self.total_steps)
+            local_bs, self._comp.name, self.ts.kernel_mode, cfg.density,
+            len(self.plan.buckets), self.plan.total_k,
+            self.steps_per_epoch, self.total_steps)
         self.bus.publish({"event": "config", **{
             k: getattr(cfg, k) for k in ("dnn", "dataset", "batch_size",
                                          "compressor", "density", "lr")},
@@ -345,11 +296,103 @@ class Trainer:
             os.path.join(run_dir, "profile"), cfg.profile_steps[0],
             cfg.profile_steps[1], bus=self.bus, logger=self.logger)
             if cfg.profile_steps else None)
-        # long-lived trajectory span: every host span and stamped record
-        # between rollbacks parents to it; a rollback rotates it
-        # (_rotate_trajectory), so each trajectory is one span-tree root
-        if self.trace is not None:
-            self._traj_span = self.trace.begin("trajectory", step=self.step)
+
+    def _build_mesh(self, cfg: TrainConfig) -> None:
+        if self.sp:
+            if cfg.dnn.lower() not in ("transformer_lm", "transformerlm"):
+                raise ValueError(
+                    "sequence parallelism (--sp-size) is the transformer_lm "
+                    "long-context path")
+            if cfg.ici_size or cfg.dcn_size:
+                raise ValueError(
+                    "--sp-size and --ici-size/--dcn-size are mutually "
+                    "exclusive mesh layouts")
+            dp = cfg.nworkers if cfg.nworkers > 0 else (
+                len(jax.devices()) // self.sp)
+            self.mesh = dp_sp_mesh(dp, self.sp)
+            self.nworkers = dp          # dp width: examples per step = bs*dp
+        elif cfg.ici_size > 0 and cfg.dcn_size > 0:
+            self.mesh = hierarchical_dp_mesh(cfg.ici_size, cfg.dcn_size)
+            self.nworkers = self.mesh.size
+        else:
+            n = cfg.nworkers if cfg.nworkers > 0 else None
+            self.mesh = data_parallel_mesh(n)
+            self.nworkers = self.mesh.size
+
+    def _build_model(self, cfg: TrainConfig, card: int, dtype):
+        """The model's spec, its initial ``params`` and ``model_state``,
+        and the key the train state starts from."""
+        # ---- model: head size = explicit flag > dataset cardinality;
+        # cfg.model_kwargs overrides EVERYTHING (single merged dict, so a
+        # key like num_classes/dtype overrides instead of raising a
+        # duplicate-keyword TypeError) ----
+        model_kw = {"num_classes": cfg.num_classes or card, "dtype": dtype}
+        if cfg.dnn.lower() in ("lstm", "transformer", "transformer_lm",
+                               "transformerlm"):
+            model_kw["vocab_size"] = cfg.num_classes or card
+        elif cfg.dnn.lower() == "lstman4":
+            model_kw["num_labels"] = cfg.num_classes or card
+        model_kw.update(cfg.model_kwargs)
+        if self.sp:
+            model_kw["sp_axis"] = "sp"
+        self.spec = models_lib.get_model(cfg.dnn, cfg.dataset, **model_kw)
+        # mesh axis names only exist inside shard_map: initialize params via
+        # the sp-free twin (identical param structure)
+        init_module = (models_lib.get_model(
+            cfg.dnn, cfg.dataset, **{**model_kw, "sp_axis": None}).module
+            if self.sp else self.spec.module)
+        self.steps_per_epoch = self.train_ds.steps_per_epoch
+        self.total_steps = (cfg.max_steps if cfg.max_steps
+                            else cfg.epochs * self.steps_per_epoch)
+
+        # ---- init model variables ----
+        rng = jax.random.PRNGKey(cfg.seed)
+        init_rng, self.data_rng, state_rng = jax.random.split(rng, 3)
+        dummy = self._dummy_inputs()
+        # jitted: ONE compiled program (persistently cacheable) instead of
+        # an eager compile per initializer primitive and parameter shape
+        def init_variables(rngs, *inputs):
+            return init_module.init(rngs, *inputs, train=False)
+
+        variables = jax.jit(init_variables)(
+            {"params": init_rng, "dropout": init_rng}, *dummy)
+        params = variables["params"]
+        model_state = {k: v for k, v in variables.items() if k != "params"}
+        return params, model_state, state_rng
+
+    def _build_program(self, cfg: TrainConfig, params, model_state,
+                       state_rng, local_bs: int):
+        """The compression plan, the loss, the step programs and the
+        placed initial state. Returns the input normalisation, which the
+        eval step shares."""
+        # ---- compression plan + loss fn (static across step rebuilds) ----
+        # LSTM bptt carry across windows (the reference's "repackaging",
+        # SURVEY.md §3.2): hidden state lives in TrainState.carry,
+        # batch-dim sharded; reset at epoch boundaries (train loop).
+        self.recurrent = (cfg.dnn.lower() == "lstm" and cfg.carry_hidden)
+        self._comp = get_compressor(cfg.compressor, density=cfg.density,
+                                    sigma_scale=cfg.sigma_scale)
+        self.plan = plan_for_params(params, cfg.density, cfg.bucket_size,
+                                    policy=cfg.bucket_policy)
+        # uint8 pixel batches (imagenet contract) normalize ON DEVICE —
+        # the dtype check inside _prep_pixels is trace-time static, so
+        # float batches pay nothing
+        from .losses import IMAGENET_NORM
+        input_norm = (IMAGENET_NORM if cfg.dataset.lower() == "imagenet"
+                      else None)
+        self._loss_fn = make_loss_fn(self.spec, cfg.label_smoothing,
+                                     recurrent=self.recurrent,
+                                     input_norm=input_norm)
+        self.is_dense_only = self._comp.name == "none"
+
+        # ---- schedule + optimizer + the fused step programs ----
+        self._lr_scale = 1.0            # compounded rollback LR backoff
+        self._build_steps()
+        carry = (self.spec.module.initial_carry(local_bs)
+                 if self.recurrent else ())
+        self.state = self.ts.init_state(params, state_rng,
+                                        model_state=model_state, carry=carry)
+        return input_norm
 
     # ------------------------------------------------------------------
     def _build_steps(self) -> None:
@@ -446,10 +489,18 @@ class Trainer:
         self._iter = None
 
     def _span(self, name: str, **fields):
-        """Host-phase span when tracing is on, else a free nullcontext —
+        """Host-phase span when tracing is on, else the shared nullcontext —
         call sites stay unconditional and trace-off stays zero-record."""
         return (self.trace.span(name, **fields) if self.trace is not None
-                else contextlib.nullcontext())
+                else _NO_SPAN)
+
+    def _drain_spans(self) -> None:
+        """Publish the spans finished since the last log step: the bus is
+        not touched for them in between. What that costs the loop is a span
+        of its own, ``trace_drain``."""
+        if self.trace is not None:
+            with self.trace.span("trace_drain"):
+                self.trace.drain()
 
     def _rotate_trajectory(self, reason: str) -> None:
         """A rollback abandons the old trajectory: close its span and open
@@ -458,7 +509,8 @@ class Trainer:
             return
         if self._traj_span is not None:
             self.trace.end(self._traj_span, reason=reason)
-        self._traj_span = self.trace.begin("trajectory", step=self.step)
+        self._traj_span = self.trace.begin("trajectory", root=True,
+                                           step=self.step)
 
     # ------------------------------------------------------------------
     def _save_checkpoint(self) -> str:
@@ -698,58 +750,90 @@ class Trainer:
     # ------------------------------------------------------------------
     def train(self, num_iters: int, data_iter=None) -> Dict[str, float]:
         """Run ``num_iters`` optimizer steps (reference ``trainer.train(n)``,
-        SURVEY.md §1.1 L4->L3 interface). Returns mean metrics."""
-        cfg = self.cfg
+        SURVEY.md §1.1 L4->L3 interface). Returns mean metrics.
+
+        With tracing on every iteration is one ``iteration`` span whose
+        leaf children each hold exactly one thing (docs/OBSERVABILITY.md):
+        ``data_wait``, ``h2d``, ``step_dispatch``, ``step_sync``,
+        ``step_readback`` and, at a log step, ``log_step`` and
+        ``trace_drain``; what is left of the iteration beside them is the
+        loop's own bookkeeping."""
         losses, last = [], {}
         for _ in range(num_iters):
-            # resolved per iteration: a rollback mid-run invalidates the
-            # cached iterator, and the rebuilt one must be picked up here
-            it = data_iter if data_iter is not None else self._train_iter()
-            self.timers.start("io")
-            with self._span("data_wait"):
-                batch = next(it)
-            batch = shard_batch(self.mesh, batch, spec=self._batch_spec)
-            self._probe_batch = batch      # for _phase_breakdown at log time
-            self.timers.start("step")
+            # cached step — no device sync
             step = self.step if not hasattr(self, "_step_cache") else \
                 self._step_cache
-            if self.profiler is not None:
-                # jax.profiler trace window (SURVEY.md §5 Tracing rebuild
-                # note: real fwd/bwd/comm breakdown comes from device
-                # traces, not host timers); cached step — no device sync
-                self.profiler.maybe_transition(step)
-            if (self.recurrent and step % self.steps_per_epoch == 0
-                    and step > 0):
-                # fresh text stream at each epoch wrap -> fresh carry
-                # (direct _state write: the loop's own advances must not
-                # trip the external-assignment invalidation in the setter)
-                self._state = self._state._replace(carry=jax.tree.map(
-                    jnp.zeros_like, self._state.carry))
-            fn = (self.ts.dense_step if self._in_warmup(step)
-                  else self.ts.sparse_step)
-            if cfg.phase_timing:
-                # this interval's step_s mean will include this program's
-                # jit compile; mark it so _phase_breakdown skips the
-                # interval (ADVICE r4: subtracting compile-free probe times
-                # from a compile-polluted mean attributed the whole compile
-                # to comm_update_s). Keyed on (fn, batch shapes): bucketed
-                # variable-width pipelines (AN4) retrace on each new width,
-                # not only on the first dispatch.
-                key = (fn, _batch_shape_key(batch))
-                if key not in self._dispatched_fns:
-                    self._dispatched_fns.add(key)
-                    self._interval_has_compile = True
-            t_step0 = time.perf_counter()
-            with self._span("step_dispatch", step=step + 1):
-                self._state, m = fn(self._state, batch)
-                # jit dispatch is async: sync before stopping the timer so
-                # step_s/ex-s measure device work, not dispatch latency
-                jax.block_until_ready(m.loss)
-            step_wall = time.perf_counter() - t_step0
-            self._step_cache = step + 1
-            self.timers.stop()
-            losses.append(m)
-            done = step + 1
+            with self._span("iteration", step_num=step + 1):
+                m = self._iteration(step, data_iter)
+                losses.append(m)
+                if (step + 1) % self.cfg.log_every == 0:
+                    last = self._log_step(step + 1, m)
+        if losses and not last:
+            with self._span("log_step"):
+                last = self._log_train(self.step, losses[-1], quiet=True)
+            self._drain_spans()
+        return last
+
+    def _input_ready(self) -> Optional[int]:
+        """Batches waiting in the trainer's own prefetch queue (whoever
+        pulls from it); None when it has none running."""
+        it = getattr(self, "_iter", None)
+        return it.ready() if it is not None else None
+
+    def _iteration(self, step: int, data_iter):
+        """One optimizer step from global step ``step``: wait for the
+        batch, place it, dispatch the program, wait for it, read its
+        scalars back, and do what the step boundary owes (cadence save,
+        preemption). Returns the step's metrics."""
+        cfg = self.cfg
+        # resolved per iteration: a rollback mid-run invalidates the
+        # cached iterator, and the rebuilt one must be picked up here
+        it = data_iter if data_iter is not None else self._train_iter()
+        self.timers.start("io")
+        with (self.trace.span("data_wait", ready=self._input_ready())
+              if self.trace is not None else _NO_SPAN):
+            batch = next(it)
+        with self._span("h2d"):
+            batch = shard_batch(self.mesh, batch, spec=self._batch_spec)
+        self._probe_batch = batch      # for _phase_breakdown at log time
+        self.timers.start("step")
+        if self.profiler is not None:
+            # jax.profiler trace window (SURVEY.md §5 Tracing rebuild
+            # note: real fwd/bwd/comm breakdown comes from device
+            # traces, not host timers)
+            self.profiler.maybe_transition(step)
+        if (self.recurrent and step % self.steps_per_epoch == 0
+                and step > 0):
+            # fresh text stream at each epoch wrap -> fresh carry
+            # (direct _state write: the loop's own advances must not
+            # trip the external-assignment invalidation in the setter)
+            self._state = self._state._replace(carry=jax.tree.map(
+                jnp.zeros_like, self._state.carry))
+        fn = (self.ts.dense_step if self._in_warmup(step)
+              else self.ts.sparse_step)
+        if cfg.phase_timing:
+            # this interval's step_s mean will include this program's
+            # jit compile; mark it so _phase_breakdown skips the
+            # interval (ADVICE r4: subtracting compile-free probe times
+            # from a compile-polluted mean attributed the whole compile
+            # to comm_update_s). Keyed on (fn, batch shapes): bucketed
+            # variable-width pipelines (AN4) retrace on each new width,
+            # not only on the first dispatch.
+            key = (fn, _batch_shape_key(batch))
+            if key not in self._dispatched_fns:
+                self._dispatched_fns.add(key)
+                self._interval_has_compile = True
+        t_step0 = time.perf_counter()
+        with self._span("step_dispatch"):
+            self._state, m = fn(self._state, batch)
+        with self._span("step_sync"):
+            # jit dispatch is async: sync before stopping the timer so
+            # step_s/ex-s measure device work, not dispatch latency
+            jax.block_until_ready(m.loss)
+        step_wall = time.perf_counter() - t_step0
+        self._step_cache = done = step + 1
+        self.timers.stop()
+        with self._span("step_readback"):
             # m.loss is already synced above, so these per-step host reads
             # cost a device_get of ready scalars, not a sync. Guard-off
             # runs skip the read: skipped is a structural zero there.
@@ -769,55 +853,60 @@ class Trainer:
             if self.monitor is not None:
                 self.monitor.observe(done, float(jax.device_get(m.loss)),
                                      sk)
-            pending = (self.monitor.should_rollback()
-                       if self.monitor is not None else None)
-            if cfg.save_every_steps and done % cfg.save_every_steps == 0:
-                if pending is None:
-                    path = self._save_checkpoint()
-                    self.logger.info("checkpoint -> %s", path)
-                else:
-                    # sealing the live state while a rollback is pending
-                    # would make the suspect/diverged state the newest —
-                    # and therefore the rollback target — checkpoint
-                    self.logger.warning(
-                        "cadence save at step %d suppressed: rollback "
-                        "pending (%s)", done, pending)
-            if self.shutdown.requested:
-                # preemption contract (docs/RESILIENCE.md): seal a
-                # checkpoint at the step boundary, then exit cleanly
+        pending = (self.monitor.should_rollback()
+                   if self.monitor is not None else None)
+        if cfg.save_every_steps and done % cfg.save_every_steps == 0:
+            if pending is None:
                 path = self._save_checkpoint()
-                self.bus.publish({"event": "preempt", "step": done,
-                                  "checkpoint": path})
+                self.logger.info("checkpoint -> %s", path)
+            else:
+                # sealing the live state while a rollback is pending
+                # would make the suspect/diverged state the newest —
+                # and therefore the rollback target — checkpoint
                 self.logger.warning(
-                    "shutdown requested: checkpointed %s at step %d",
-                    path, done)
-                raise TrainingPreempted(done, path)
-            if done % cfg.log_every == 0:
-                last = self._log_train(done, m)
-                # policy/resilience ACT only at log intervals (ISSUE
-                # contract); between intervals they only accumulate
-                # observations. Order matters: the engine's probation
-                # watchdog runs BEFORE a pending rollback executes, so a
-                # bad decision's knobs are reverted first and the restored
-                # checkpoint meets the pre-decision program layout.
-                reason = (self.monitor.should_rollback()
-                          if self.monitor is not None else None)
-                # no ticks during dense warm-up: every signal gathered so
-                # far describes the dense program (ef_norm is structurally
-                # 0, no wire/density in play), so a decision here could
-                # only misfire — and nothing can need reverting, since no
-                # decision has ever applied
-                if self.engine is not None and not self._in_warmup(done):
-                    self._policy_tick(rollback_pending=reason is not None)
-                if reason:
-                    # the rollback span closes inside the OLD trajectory
-                    # (it is that trajectory's terminal act); only then is
-                    # the root rotated for the restored one
-                    with self._span("rollback", reason=reason):
-                        self._rollback(reason)
-                    self._rotate_trajectory(reason)
-        if losses and not last:
-            last = self._log_train(self.step, losses[-1], quiet=True)
+                    "cadence save at step %d suppressed: rollback "
+                    "pending (%s)", done, pending)
+        if self.shutdown.requested:
+            # preemption contract (docs/RESILIENCE.md): seal a
+            # checkpoint at the step boundary, then exit cleanly
+            path = self._save_checkpoint()
+            self.bus.publish({"event": "preempt", "step": done,
+                              "checkpoint": path})
+            self.logger.warning(
+                "shutdown requested: checkpointed %s at step %d",
+                path, done)
+            raise TrainingPreempted(done, path)
+        return m
+
+    def _log_step(self, done: int, m) -> Dict[str, float]:
+        """What every ``log_every``-th iteration adds: the train record,
+        the finished spans' way onto the bus, and the acts that wait for
+        an interval's end (policy tick, rollback)."""
+        with self._span("log_step"):
+            last = self._log_train(done, m)
+        self._drain_spans()
+        # policy/resilience ACT only at log intervals (ISSUE
+        # contract); between intervals they only accumulate
+        # observations. Order matters: the engine's probation
+        # watchdog runs BEFORE a pending rollback executes, so a
+        # bad decision's knobs are reverted first and the restored
+        # checkpoint meets the pre-decision program layout.
+        reason = (self.monitor.should_rollback()
+                  if self.monitor is not None else None)
+        # no ticks during dense warm-up: every signal gathered so
+        # far describes the dense program (ef_norm is structurally
+        # 0, no wire/density in play), so a decision here could
+        # only misfire — and nothing can need reverting, since no
+        # decision has ever applied
+        if self.engine is not None and not self._in_warmup(done):
+            self._policy_tick(rollback_pending=reason is not None)
+        if reason:
+            # the rollback span closes inside the OLD trajectory
+            # (it is that trajectory's terminal act); only then is
+            # the root rotated for the restored one
+            with self._span("rollback", reason=reason):
+                self._rollback(reason)
+            self._rotate_trajectory(reason)
         return last
 
     def _train_iter(self):
@@ -971,14 +1060,6 @@ class Trainer:
             ovl = float(jax.device_get(m.overlapped_bytes_sent))
             if ovl:
                 rec["overlapped_bytes_sent"] = int(ovl)
-            if self.trace is not None:
-                # span-source geometry for the offline device-phase
-                # reconstruction (telemetry/tracing.py) — trace-gated so
-                # default streams stay byte-identical to pre-tracing runs
-                rec["pipeline_chunks"] = int(
-                    float(jax.device_get(m.pipeline_chunks)))
-                rec["comm_rounds"] = int(
-                    float(jax.device_get(m.comm_rounds)))
         if len(self.plan.buckets) > 1:
             # per-bucket selection counts (dp-mean); single-bucket plans
             # skip the column — it would duplicate num_selected
@@ -1129,6 +1210,7 @@ class Trainer:
         if self.trace is not None:
             # seal the trajectory root, then detach the stamp hook so a
             # reused bus never inherits a dead trace context
+            self.trace.drain()
             if self._traj_span is not None:
                 self.trace.end(self._traj_span)
                 self._traj_span = None

@@ -3,10 +3,8 @@
 Covers the observability layer this PR adds on top of the event bus
 (docs/OBSERVABILITY.md "Tracing & trajectory"): TraceContext span
 emission, thread-local stamping and the trace-off byte-identity
-guarantee; the offline Chrome-trace renderer (host spans, reconstructed
-device/exchange tracks, the bench_overlap per-chunk geometry and the
-overlap-pair acceptance count); the trace CLI round-trip on a LIVE
-traced run; the chaos span tree (rollback span parented to the dying
+guarantee; the trace CLI round-trip on a LIVE traced run (the host spans
+of the loop, rendered by their own clock); the chaos span tree (rollback span parented to the dying
 trajectory, rotated root afterwards); and the regression sentinel's
 noise-floored classification over the committed bench history.
 """
@@ -27,7 +25,6 @@ from gaussiank_sgd_tpu.telemetry import (EventBus, JSONLExporter,
                                          validate_stream)
 from gaussiank_sgd_tpu.telemetry.__main__ import main as telemetry_cli
 from gaussiank_sgd_tpu.telemetry.events import validate_file
-from gaussiank_sgd_tpu.telemetry.tracing import chrome_trace_overlap_pairs
 from gaussiank_sgd_tpu.training import chaos
 from gaussiank_sgd_tpu.training.config import TrainConfig
 from gaussiank_sgd_tpu.training.trainer import Trainer
@@ -73,6 +70,7 @@ def test_trace_context_nesting_stamp_and_uninstall():
     with tc.span("outer") as outer_sid:
         with tc.span("inner"):
             bus.emit("skip", step=1, nonfinite=1.0)
+    tc.drain()
     tc.end(traj)
     tc.uninstall()
     bus.emit("skip", step=2, nonfinite=1.0)
@@ -83,8 +81,8 @@ def test_trace_context_nesting_stamp_and_uninstall():
     assert inner["parent_span"] == outer_sid == outer["span_id"]
     assert outer["parent_span"] == traj
     assert inner["ph"] == outer["ph"] == "X"
-    assert inner["dur_ms"] >= 0 and "t0" in inner
-    # the inner X record lands BEFORE the outer's (emitted at close)
+    assert inner["dur_ns"] >= 0 and inner["t0_ns"] >= outer["t0_ns"]
+    # the inner X record lands BEFORE the outer's (drained in close order)
     assert recs.index(inner) < recs.index(outer)
 
     stamped = next(r for r in recs
@@ -136,74 +134,14 @@ def test_validate_stream_flags_orphans_and_unclosed():
     assert any("never closed" in w for w in rep.warnings)
 
 
-# ------------------------------------------------- offline reconstruction
-
-def _bench_overlap_rec(n_buckets=6):
-    return {"event": "bench_overlap", "schema_version": 1, "seq": 0,
-            "ts": 100.0, "key": "mnistnet-u8192", "model": "mnistnet",
-            "compressor": "gaussian", "bucket_size": 8192,
-            "n_buckets": n_buckets, "seq_step_ms": 12.0,
-            "pipe_step_ms": 10.0, "seq_overlap": "off",
-            "pipe_overlap": "pipelined", "exposed_seq_ms": 3.0,
-            "exposed_pipe_ms": 0.5, "pipe_vs_seq": 1.2}
-
-
-def test_chrome_trace_bench_overlap_chunks_overlap_compress():
-    """The per-chunk reconstruction draws chunk i's exchange under chunk
-    i+1's compress — ≥ n-1 overlapping (exchange, compress) pairs —
-    and every rendered event has non-negative µs timestamps."""
-    n = 6
-    trace = build_chrome_trace([_bench_overlap_rec(n)])
-    evs = [e for e in trace["traceEvents"] if e.get("ph") == "X"]
-    assert len([e for e in evs if e["cat"] == "compress"]) == n
-    assert len([e for e in evs if e["cat"] == "exchange"]) == n
-    assert all(e["ts"] >= 0 and e["dur"] >= 0 for e in evs)
-    assert chrome_trace_overlap_pairs(trace) >= n - 1
-    # compress chunks tile the pipelined window in order (monotonic ts)
-    comp_ts = [e["ts"] for e in evs if e["cat"] == "compress"]
-    assert comp_ts == sorted(comp_ts)
-
-
-def test_chrome_trace_noise_floored_overlap_still_renders():
-    """Both exposed deltas below the noise floor (omitted fields): the
-    renderer falls back to a nominal exchange so the schedule SHAPE is
-    still inspectable — the overlap count never silently drops to 0."""
-    rec = _bench_overlap_rec()
-    del rec["exposed_seq_ms"], rec["exposed_pipe_ms"], rec["pipe_vs_seq"]
-    trace = build_chrome_trace([rec])
-    assert chrome_trace_overlap_pairs(trace) >= rec["n_buckets"] - 1
-
-
-def test_chrome_trace_train_interval_draws_hidden_exchange():
-    """A pipelined train interval renders the overlapped payload inside
-    the compute window (the byte-fraction model) plus the exposed tail."""
-    rec = {"event": "train", "schema_version": 1, "seq": 0, "ts": 50.0,
-           "step": 10, "epoch": 0, "loss": 1.0, "lr": 0.1, "grad_norm": 1.0,
-           "num_selected": 10.0, "bytes_sent": 1000, "density": 0.01,
-           "io_s": 0.001, "step_s": 0.5, "skipped": 0.0, "nonfinite": 0.0,
-           "overlap": "pipelined", "overlapped_bytes_sent": 600,
-           "exposed_exchange_ms": 50.0}
-    trace = build_chrome_trace([rec])
-    evs = {e["name"]: e for e in trace["traceEvents"] if e.get("ph") == "X"}
-    hidden = evs["exchange overlapped [step 10]"]
-    exposed = evs["exchange exposed [step 10]"]
-    step = evs["step 10"]
-    # hidden = 0.6 * (500ms - 50ms) = 270ms, drawn before the tail
-    assert hidden["dur"] == pytest.approx(270e3, rel=1e-3)
-    assert exposed["dur"] == pytest.approx(50e3, rel=1e-3)
-    assert hidden["ts"] + hidden["dur"] == pytest.approx(exposed["ts"], abs=1)
-    assert step["tid"] != hidden["tid"]
-    assert chrome_trace_overlap_pairs(trace) >= 1
-
-
 # ------------------------------------------------------- live round-trip
 
 def test_trace_cli_round_trip_on_live_run(tmp_path, capsys):
     """ISSUE acceptance (trace half): a live traced run's JSONL validates
-    strictly with a healthy span tree, the trace CLI renders it to
-    Chrome-trace JSON where ≥ 1 exchange span overlaps a compute span,
-    host spans nest under the trajectory, and step_dispatch timestamps
-    are monotonic."""
+    strictly with a healthy span tree, the loop's host spans nest under
+    one `iteration` per step and those under the trajectory, and the trace
+    CLI renders them to Chrome-trace JSON on their own clock: every leaf
+    inside its iteration, dispatches in step order."""
     t = Trainer(make_cfg(tmp_path, overlap="auto", bucket_size=8192,
                          bucket_policy="uniform", save_every_steps=6))
     t.train(12)
@@ -219,29 +157,44 @@ def test_trace_cli_round_trip_on_live_run(tmp_path, capsys):
     traj = spans(events, name="trajectory", ph="B")
     assert len(traj) == 1
     traj_sid = traj[0]["span_id"]
-    for name in ("data_wait", "step_dispatch", "checkpoint_save"):
+    iters = spans(events, name="iteration", ph="X")
+    assert [s["step"] for s in iters] == list(range(1, 13))
+    assert all(s["parent_span"] == traj_sid for s in iters)
+    iter_ids = {s["span_id"] for s in iters}
+    for name in ("data_wait", "h2d", "step_dispatch", "step_sync",
+                 "step_readback"):
         xs = spans(events, name=name, ph="X")
-        assert xs, f"no {name} spans in the stream"
-        assert all(s["parent_span"] == traj_sid for s in xs)
-    dispatch_t0 = [s["t0"] for s in spans(events, name="step_dispatch")]
+        assert len(xs) == 12, f"{name}: {len(xs)} spans for 12 steps"
+        assert all(s["parent_span"] in iter_ids for s in xs)
+    assert len(spans(events, name="log_step", ph="X")) == 2    # steps 5, 10
+    saves = spans(events, name="checkpoint_save", ph="X")
+    assert saves and all(s["parent_span"] in iter_ids for s in saves)
+    dispatch_t0 = [s["t0_ns"] for s in spans(events, name="step_dispatch")]
     assert dispatch_t0 == sorted(dispatch_t0)
-    # sparse intervals carry the trace-gated span-source geometry
+    # the reconstruction's fields are gone from the train records, the
+    # stamp stays
     sparse_train = [r for r in events if r.get("event") == "train"
                     and "wire_format" in r]
     assert sparse_train
-    assert all(r["pipeline_chunks"] > 1 and r["comm_rounds"] >= 1
+    assert all("pipeline_chunks" not in r and "comm_rounds" not in r
                and r["trace_id"] for r in sparse_train)
 
     out = str(tmp_path / "trace.json")
-    rc = telemetry_cli(["trace", path, "-o", out, "--require-overlap"])
+    rc = telemetry_cli(["trace", path, "-o", out])
     assert rc == 0
-    msg = capsys.readouterr().out
-    assert "overlap pair" in msg
+    assert "span(s)" in capsys.readouterr().out
     trace = json.load(open(out))
-    assert chrome_trace_overlap_pairs(trace) >= 1
-    names = {e.get("name") for e in trace["traceEvents"]}
-    assert "trajectory" in names and "step_dispatch" in names
-    assert all(e["ts"] >= 0 for e in trace["traceEvents"] if "ts" in e)
+    evs = trace["traceEvents"]
+    names = {e.get("name") for e in evs}
+    assert {"trajectory", "iteration", "step_dispatch", "train"} <= names
+    assert all(e["ts"] >= 0 for e in evs if "ts" in e)
+    by_id = {e["args"]["span_id"]: e for e in evs
+             if e.get("ph") == "X"}
+    for e in by_id.values():
+        parent = by_id.get(e["args"].get("parent_span"))
+        if parent is not None:      # rendered to a tenth of a microsecond
+            assert parent["ts"] - 0.2 <= e["ts"]
+            assert e["ts"] + e["dur"] <= parent["ts"] + parent["dur"] + 0.2
 
 
 def test_chaos_rollback_span_tree(tmp_path):
@@ -267,15 +220,25 @@ def test_chaos_rollback_span_tree(tmp_path):
     first, second = trajs[0]["span_id"], trajs[1]["span_id"]
     assert len(spans(events, name="trajectory", ph="E")) == 2
 
+    by_id = {s["span_id"]: s for s in spans(events)
+             if s["ph"] in ("X", "B")}
+
+    def root_of(s):
+        while s.get("parent_span") is not None:
+            s = by_id[s["parent_span"]]
+        return s["span_id"]
+
+    # both happen inside an iteration of the dying trajectory
     rb = spans(events, name="rollback", ph="X")
-    assert len(rb) == 1 and rb[0]["parent_span"] == first
+    assert len(rb) == 1 and root_of(rb[0]) == first
+    assert by_id[rb[0]["parent_span"]]["name"] == "iteration"
     assert rb[0]["reason"] == "skip_budget"
     anomaly = spans(events, name="anomaly_pending", ph="i")
-    assert len(anomaly) == 1 and anomaly[0]["parent_span"] == first
+    assert len(anomaly) == 1 and root_of(anomaly[0]) == first
     assert anomaly[0]["reason"] == "skip_budget"
     # post-rollback host spans hang off the NEW root
     post = [s for s in spans(events, name="checkpoint_save", ph="X")
-            if s["parent_span"] == second]
+            if root_of(s) == second]
     assert post, "restored trajectory sealed no checkpoint span"
     # the rollback event record itself is stamped into the old trajectory
     rb_ev = next(r for r in events if r.get("event") == "rollback")
