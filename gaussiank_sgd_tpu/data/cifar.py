@@ -15,7 +15,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-from .loader import ArrayDataset
+from .loader import ArrayDataset, fill_sliced
 from .synthetic import flip_labels, synthetic_images
 
 # standard CIFAR-10 channel stats
@@ -52,19 +52,53 @@ def _normalize(x_u8: np.ndarray) -> np.ndarray:
     return ((x_u8.astype(np.float32) / 255.0) - _MEAN) / _STD
 
 
+_PAD = 4
+
+
+def _reflect(p: np.ndarray, n: int) -> np.ndarray:
+    """Where position ``p`` of an axis of length ``n`` reads from once the
+    axis is padded as ``np.pad(mode="reflect")`` pads it (mirrored about
+    the edge element, the edge not repeated); ``-n < p < 2n - 1``."""
+    p = np.abs(p)
+    return np.where(p >= n, 2 * (n - 1) - p, p)
+
+
+def _take_crops(pixels: np.ndarray, out: np.ndarray, sel: np.ndarray,
+                rows: np.ndarray, cols: np.ndarray, lo: int, hi: int):
+    """Fill ``out[lo:hi]``: image i's pixel (r, c) is pixel
+    (rows[i, r], cols[i, c]) of image ``sel[i]``, read from the data set's
+    ``(N*h*w, c)`` pixel view in one indexed copy."""
+    _, h, w, c = out.shape
+    src = ((sel[lo:hi, None] * h + rows[lo:hi])[:, :, None] * w
+           + cols[lo:hi, None, :])
+    # mode="clip": the indices are in range by construction, and "raise"
+    # would fill a buffer and copy it into ``out`` afterwards
+    np.take(pixels, src.reshape(-1), axis=0,
+            out=out[lo:hi].reshape(-1, c), mode="clip")
+
+
 def _augment(rng: np.random.Generator):
-    def fn(x: np.ndarray, y: np.ndarray):
-        b, h, w, c = x.shape
-        # pad-4 random crop
-        padded = np.pad(x, ((0, 0), (4, 4), (4, 4), (0, 0)), mode="reflect")
-        oy = rng.integers(0, 9, size=b)
-        ox = rng.integers(0, 9, size=b)
-        out = np.empty_like(x)
-        for i in range(b):
-            out[i] = padded[i, oy[i]:oy[i] + h, ox[i]:ox[i] + w]
+    """Pad-4 reflect random crop and horizontal flip as ``ArrayDataset``'s
+    ``augment``. The three draws are made for the whole batch first; crop,
+    border and flip then are index arithmetic on ``b x 32`` integers, and
+    the pixels are copied once, from the data set's array into the batch,
+    in slices (``loader.fill_sliced``; ``slices`` is for the tests)."""
+    def fn(arrays, sel: np.ndarray, slices: Optional[int] = None):
+        x, y = arrays
+        b = len(sel)
+        _, h, w, c = x.shape
+        oy = rng.integers(0, 2 * _PAD + 1, size=b)
+        ox = rng.integers(0, 2 * _PAD + 1, size=b)
         flip = rng.random(b) < 0.5
-        out[flip] = out[flip, :, ::-1]
-        return out, y
+        rows = _reflect(oy[:, None] + np.arange(h) - _PAD, h)
+        across = np.arange(w)
+        cols = _reflect(ox[:, None] - _PAD
+                        + np.where(flip[:, None], w - 1 - across, across), w)
+        pixels = x.reshape(-1, c)       # a view: make_cifar keeps x contiguous
+        out = np.empty((b, h, w, c), x.dtype)
+        fill_sliced(lambda lo, hi: _take_crops(pixels, out, sel, rows, cols,
+                                               lo, hi), b, slices)
+        return out, y[sel]
     return fn
 
 
@@ -141,7 +175,8 @@ def make_cifar(dataset: str = "cifar10", data_dir: Optional[str] = None,
                                 seed=0 if train else 1)
         y = flip_labels(y, num_classes, label_noise, seed=0 if train else 1)
     aug = _augment(np.random.default_rng(seed)) if (train and augment) else None
-    ds = ArrayDataset((x, y), batch_size, shuffle=train, seed=seed,
+    ds = ArrayDataset((np.ascontiguousarray(x), y), batch_size,
+                      shuffle=train, seed=seed,
                       augment=aug)
     return ds, num_classes
 

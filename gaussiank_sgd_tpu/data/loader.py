@@ -1,10 +1,15 @@
 """Host-side batch iteration with background prefetch.
 
 Reference parity: the torch ``DataLoader`` worker pool the reference leans on
-(SURVEY.md §3.2 "io timer ← host dataloader workers"). Here the host work is
-tiny (index shuffling, gather, augment) and the accelerator step dominates,
-so a single prefetch thread with a bounded queue keeps the device fed; the
-optional C++ pipeline (native/) slots in behind the same iterator protocol.
+(SURVEY.md §3.2 "io timer ← host dataloader workers"). One prefetch thread
+per stream pulls whole batches into a bounded queue; the optional C++
+pipeline (native/) slots in behind the same iterator protocol. The host
+work is not small beside the accelerator's step: a batch of 5120 augmented
+CIFAR images is 63 MB of float32, and while one thread cropped it image by
+image the chip sat idle three quarters of the time, the queue empty 99
+times in 100 (PERF.md, PR 24's ledger lines). So a large batch is assembled
+in contiguous slices on a few worker threads (:func:`fill_sliced`); numpy
+releases the GIL inside an indexed copy, so the slices run side by side.
 
 ``ArrayDataset`` serves in-memory numpy arrays — both real files (CIFAR/PTB
 fit comfortably in host RAM, as in the reference) and synthetic data.
@@ -12,6 +17,7 @@ fit comfortably in host RAM, as in the reference) and synthetic data.
 
 from __future__ import annotations
 
+import os
 import queue
 import threading
 import time
@@ -25,6 +31,76 @@ import numpy as np
 # programming error and propagates immediately.
 TRANSIENT_IO_ERRORS: Tuple[type, ...] = (OSError,)
 
+# A batch is assembled in slices of at least _MIN_SLICE examples each (a
+# thread has to have megabytes to copy before handing it work pays), on at
+# most _MAX_SLICES threads: the caller and _MAX_SLICES - 1 daemon workers
+# that every data set of the process shares.
+_MIN_SLICE = 512
+_MAX_SLICES = 8
+
+_workers_lock = threading.Lock()
+_worker_jobs: Optional["queue.SimpleQueue"] = None
+
+
+def _slice_worker(jobs: "queue.SimpleQueue") -> None:
+    """One worker thread: run each job's slice and report on the job's own
+    ``done`` queue what it raised, or None."""
+    while True:
+        fill, lo, hi, done = jobs.get()
+        try:
+            fill(lo, hi)
+        except BaseException as e:  # noqa: BLE001 — raised by fill_sliced
+            done.put(e)
+        else:
+            done.put(None)
+
+
+def _jobs() -> "queue.SimpleQueue":
+    """The slice workers' job queue; the workers start at its first use."""
+    global _worker_jobs
+    with _workers_lock:
+        if _worker_jobs is None:
+            jobs: "queue.SimpleQueue" = queue.SimpleQueue()
+            for i in range(_MAX_SLICES - 1):
+                threading.Thread(target=_slice_worker, args=(jobs,),
+                                 name=f"data-slice-{i}", daemon=True).start()
+            _worker_jobs = jobs
+        return _worker_jobs
+
+
+def slice_count(n: int) -> int:
+    """In how many slices a batch of ``n`` examples is assembled: what its
+    size and the CPUs this process may run on allow, 1 (the calling thread
+    alone) below ``2 * _MIN_SLICE`` examples."""
+    return max(1, min(n // _MIN_SLICE, len(os.sched_getaffinity(0)),
+                      _MAX_SLICES))
+
+
+def fill_sliced(fill: Callable[[int, int], None], n: int,
+                slices: Optional[int] = None) -> None:
+    """Call ``fill(lo, hi)`` for contiguous slices that cover ``range(n)``:
+    the first on the calling thread, the others on the worker threads, and
+    return when all have ended. ``fill`` writes its part of an output the
+    caller preallocated, so the result does not depend on ``slices``
+    (default :func:`slice_count`; tests pass it). What a slice raises is
+    raised here, after every slice has ended."""
+    if slices is None:
+        slices = slice_count(n)
+    slices = max(1, min(slices, n))
+    bounds = [n * i // slices for i in range(slices + 1)]
+    done: "queue.SimpleQueue" = queue.SimpleQueue()
+    if slices > 1:
+        jobs = _jobs()
+        for lo, hi in zip(bounds[1:-1], bounds[2:]):
+            jobs.put((fill, lo, hi, done))
+    try:
+        fill(bounds[0], bounds[1])
+    finally:
+        errors = [done.get() for _ in range(slices - 1)]
+    for e in errors:
+        if e is not None:
+            raise e
+
 
 class ArrayDataset:
     """Shuffled, optionally-augmented minibatches over in-memory arrays.
@@ -32,6 +108,10 @@ class ArrayDataset:
     Yields tuples of numpy arrays with leading dim ``batch_size`` (drops the
     ragged tail, as the reference's samplers do for distributed training —
     every worker must see the same number of steps).
+
+    ``augment(arrays, sel)`` assembles one batch from the data set's own
+    arrays and the epoch's selection ``sel`` (so the gather is not a pass
+    of its own); without it a batch is ``a[sel]`` of every array.
     """
 
     def __init__(self, arrays: Sequence[np.ndarray], batch_size: int,
@@ -57,10 +137,10 @@ class ArrayDataset:
             rng.shuffle(order)
         for s in range(self.steps_per_epoch):
             sel = order[s * self.batch_size:(s + 1) * self.batch_size]
-            batch = tuple(a[sel] for a in self.arrays)
             if self.augment is not None:
-                batch = self.augment(*batch)
-            yield batch
+                yield self.augment(self.arrays, sel)
+            else:
+                yield tuple(a[sel] for a in self.arrays)
 
     def __iter__(self):
         while True:  # epoch-looping stream
@@ -192,8 +272,13 @@ def prefetch(it: Iterator, depth: int = 2, max_retries: int = 0,
     """Run ``it`` in a daemon thread, keeping ``depth`` batches ready.
 
     Overlaps host batch prep with device compute — the role of the
-    reference's DataLoader workers, one thread being plenty for these
-    workloads.
+    reference's DataLoader workers. The thread keeps the device fed only
+    while ``it`` yields a batch in less time than a step takes:
+    ``Prefetcher.ready()`` near ``depth`` says it does, near 0 that the
+    train loop waits for this thread (``vgg16_dp1`` read 0.011 while
+    batches were cropped image by image, PERF.md). Sources whose batches
+    are large assemble them in slices on the package's worker threads
+    (:func:`fill_sliced`), under this one thread's ``next()``.
 
     ``max_retries`` > 0 adds transient-fault tolerance: a pull that raises
     one of :data:`TRANSIENT_IO_ERRORS` is retried up to ``max_retries``

@@ -121,3 +121,214 @@ def test_label_noise_caps_ceiling():
     assert 0.20 < frac < 0.30, frac
     assert y2.min() >= 0 and y2.max() < 10
     np.testing.assert_array_equal(y, flip_labels(y, 10, 0.0, seed=3))
+
+
+# ---- batch assembly in slices (data/loader.fill_sliced, data/cifar._augment)
+
+def _old_augment(rng):
+    """The assembly before the sliced one, kept as the oracle: gather (by
+    the caller), reflect-pad the whole batch, crop image by image, flip."""
+    def fn(x, y):
+        b, h, w, c = x.shape
+        padded = np.pad(x, ((0, 0), (4, 4), (4, 4), (0, 0)), mode="reflect")
+        oy = rng.integers(0, 9, size=b)
+        ox = rng.integers(0, 9, size=b)
+        out = np.empty_like(x)
+        for i in range(b):
+            out[i] = padded[i, oy[i]:oy[i] + h, ox[i]:ox[i] + w]
+        flip = rng.random(b) < 0.5
+        out[flip] = out[flip, :, ::-1]
+        return out, y
+    return fn
+
+
+def _old_dataset(arrays, batch_size, seed):
+    """What ``make_cifar`` builds over ``arrays``, behind the old assembly."""
+    old = _old_augment(np.random.default_rng(seed))
+    return ArrayDataset(
+        arrays, batch_size, shuffle=True, seed=seed,
+        augment=lambda arrays, sel: old(*(a[sel] for a in arrays)))
+
+
+@pytest.fixture(scope="module")
+def cifar_arrays():
+    """(x, y) of the synthetic CIFAR stand-ins, made once a name."""
+    made = {}
+
+    def get(name):
+        if name not in made:
+            ds, _ = make_dataset(name, batch_size=16,
+                                 synthetic_examples=6144)
+            made[name] = ds.arrays
+        return made[name]
+    return get
+
+
+def _assert_batches_equal(got, want):
+    for g, w in zip(got, want, strict=True):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(g, w)
+
+
+@pytest.mark.parametrize("slices", [1, 3, 8])
+@pytest.mark.parametrize("name", ["cifar10", "cifar100"])
+@pytest.mark.parametrize("batch", [16, 128, 1031, 5120])
+def test_cifar_assembly_equals_the_old_loop_bit_for_bit(
+        cifar_arrays, batch, name, slices):
+    """Same generator state in, same batch out, whatever the slice count
+    (1031 is prime: no slice count divides it)."""
+    from gaussiank_sgd_tpu.data.cifar import _augment
+
+    x, y = cifar_arrays(name)
+    sel = np.random.default_rng(batch).permutation(len(x))[:batch]
+    new_rng, old_rng = np.random.default_rng(11), np.random.default_rng(11)
+    got = _augment(new_rng)((x, y), sel, slices=slices)
+    want = _old_augment(old_rng)(x[sel], y[sel])
+    _assert_batches_equal(got, want)
+    assert got[0].dtype == np.float32 and got[0].flags.c_contiguous
+    # and the generator was consumed as the old loop consumed it
+    assert new_rng.random() == old_rng.random()
+
+
+@pytest.mark.parametrize("cpus,n,want", [
+    (8, 16, 1), (8, 128, 1), (8, 1023, 1), (8, 1024, 2), (8, 5120, 8),
+    (13, 20480, 8), (3, 5120, 3), (1, 5120, 1)])
+def test_slice_count_follows_batch_size_and_cpus(monkeypatch, cpus, n, want):
+    from gaussiank_sgd_tpu.data import loader
+
+    monkeypatch.setattr(loader.os, "sched_getaffinity",
+                        lambda pid: set(range(cpus)))
+    assert loader.slice_count(n) == want
+
+
+@pytest.mark.parametrize("n,slices", [(1, 8), (7, 3), (1031, 8), (4096, 1),
+                                      (5120, None)])
+def test_fill_sliced_covers_the_range_once(n, slices):
+    from gaussiank_sgd_tpu.data.loader import fill_sliced
+
+    hits = np.zeros(n, np.int32)
+    spans = []
+
+    def fill(lo, hi):
+        assert lo < hi
+        hits[lo:hi] += 1
+        spans.append((lo, hi))
+    fill_sliced(fill, n, slices)
+    assert (hits == 1).all()
+    if slices is not None:
+        assert len(spans) == min(slices, n)
+
+
+def test_cifar_two_epochs_consume_the_generator_as_before():
+    """steps_per_epoch is 2, so the third batch is the next epoch's first:
+    shuffle and augmentation draws go on as with the old assembly (1536
+    images are assembled in slices wherever there are three CPUs)."""
+    ds, _ = make_dataset("cifar10", batch_size=1536, seed=5,
+                         synthetic_examples=3300)
+    assert ds.steps_per_epoch == 2
+    old = _old_dataset(ds.arrays, ds.batch_size, seed=5)
+    for _, got, want in zip(range(3), ds, old):
+        _assert_batches_equal(got, want)
+
+
+def test_epoch_stream_resumes_on_the_augmented_batch():
+    """EpochStream(ds, seed, start_step=k) replays the epoch up to k, so a
+    fresh data set yields what an uninterrupted stream yields at step k,
+    crop offsets and flips included."""
+    from gaussiank_sgd_tpu.data import EpochStream
+
+    def fresh():
+        return make_dataset("cifar100", batch_size=1100, seed=2,
+                            synthetic_examples=4500)[0]
+    k = 2
+    straight = EpochStream(fresh(), seed=9)
+    for _ in range(k):
+        next(straight)
+    resumed = next(EpochStream(fresh(), seed=9, start_step=k))
+    _assert_batches_equal(resumed, next(straight))
+    # and both are the old assembly's batch at that step
+    ds = fresh()
+    old = EpochStream(_old_dataset(ds.arrays, ds.batch_size, seed=2),
+                      seed=9, start_step=k)
+    _assert_batches_equal(resumed, next(old))
+
+
+def test_slice_failure_reaches_the_consumer_through_prefetch(monkeypatch):
+    """What one slice raises on a worker thread comes out of the prefetch
+    thread as every other producer failure does."""
+    from gaussiank_sgd_tpu.data import EpochStream, cifar
+    from gaussiank_sgd_tpu.data.loader import fill_sliced
+
+    take = cifar._take_crops
+
+    def failing(pixels, out, sel, rows, cols, lo, hi):
+        if lo > 0:
+            raise ValueError(f"slice {lo}:{hi} broke")
+        take(pixels, out, sel, rows, cols, lo, hi)
+    monkeypatch.setattr(cifar, "_take_crops", failing)
+    # three slices whatever this machine's CPUs would allow a batch of 64
+    monkeypatch.setattr(cifar, "fill_sliced",
+                        lambda fill, n, slices=None: fill_sliced(fill, n, 3))
+    ds, _ = make_dataset("cifar10", batch_size=64, synthetic_examples=256)
+    it = prefetch(EpochStream(ds, seed=0))
+    with pytest.raises(RuntimeError, match="data prefetch thread failed") \
+            as err:
+        next(it)
+    assert isinstance(err.value.__cause__, ValueError)
+    assert "broke" in str(err.value.__cause__)
+
+
+def test_two_datasets_on_two_threads_give_what_each_gives_alone():
+    """The benchmark's two trainers share the slice workers: batches drawn
+    from two data sets at once are the ones each yields alone."""
+    import sys
+    import threading
+
+    def fresh(seed):
+        return make_dataset("cifar10", batch_size=2048, seed=seed,
+                            synthetic_examples=4200)[0]
+    n_batches = 5
+    alone = {seed: [b for _, b in zip(range(n_batches), fresh(seed))]
+             for seed in (1, 2)}
+    together = {1: [], 2: []}
+
+    def drive(seed):
+        for _, b in zip(range(n_batches), fresh(seed)):
+            together[seed].append(b)
+    threads = [threading.Thread(target=drive, args=(seed,), daemon=True)
+               for seed in (1, 2)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    for seed in (1, 2):
+        assert len(together[seed]) == n_batches
+        for got, want in zip(together[seed], alone[seed]):
+            _assert_batches_equal(got, want)
+
+
+def test_cifar_files_without_the_native_library_assemble_as_before(tmp_path):
+    """Real records come channel-first, and ``_normalize`` keeps that
+    memory order under its NHWC shape: the assembly reads the pixels of the
+    data set's array by linear index, so make_cifar has to lay them out
+    first. The batch is the old path's on the same records."""
+    from gaussiank_sgd_tpu.data import cifar
+
+    rng = np.random.default_rng(3)
+    for i in range(1, 6):
+        rec = rng.integers(0, 256, size=(24, 3073), dtype=np.uint8)
+        rec[:, 0] %= 10
+        rec.tofile(tmp_path / f"data_batch_{i}.bin")
+    ds, _ = cifar.make_cifar("cifar10", str(tmp_path), batch_size=50,
+                             seed=4, use_native=False)
+    x_u8, y = cifar._read_cifar10_bin(str(tmp_path), True)
+    x = cifar._normalize(x_u8)
+    assert not x.flags.c_contiguous and ds.arrays[0].flags.c_contiguous
+    for _, got, exp in zip(range(3), ds, _old_dataset((x, y), 50, seed=4)):
+        _assert_batches_equal(got, exp)
