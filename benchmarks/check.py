@@ -1,15 +1,16 @@
 """The comparison that decides `correct`.
 
-What is compared is what the timed objects produced: the two trainers that
-the window drives were taken through their first three steps by the window's
-own call and feed (`harness.first_steps`), and their readings are held
-against the plain reference (`reference/`), which follows the same three
-steps in float32 from the benchmark's own weights and the rows that were fed.
+What is compared is what the timed objects produced: the trainers that the
+window drives (the arms of the mix's round) were taken through their first
+three steps by the window's own call and feed (`harness.first_steps`), and
+their readings are held against the plain reference (`reference/`), which
+follows the same three steps in float32 from the benchmark's own weights and
+the rows that were fed.
 
 Numbers compared, each with a limit of its own (the configuration's
 `limits`, set from chip readings; PERF.md section 2 has the readings):
 
-  loss_gap          worst |loss - reference| / reference over both trainers'
+  loss_gap          worst |loss - reference| / reference over the trainers'
                     three steps
   grad_norm_gap     the first gradient, rebuilt from the state after one step
                     (dense: momentum - wd*p0; sparse: that plus the workers'
@@ -120,13 +121,13 @@ def program_readings(arm, weights: Dict[str, np.ndarray], config: dict,
     p0 = np.concatenate([weights[p].reshape(-1) for p in like])
     wd_p0 = wd * p0
     m1 = f["momentum1"][:n]
-    res1 = f["residual1"]
-    nworkers = res1.shape[0]
     arrived = m1 - wd_p0
     out: Dict[str, Any] = {"losses": f["losses"]}
     if arm.name == "dense":
         grad = arrived
     else:
+        res1 = f["residual1"]
+        nworkers = res1.shape[0]
         grad = arrived + res1[:, :n].mean(axis=0, dtype=np.float32)
     out["first_grad"] = harness.split_flat(grad, like)
     out["delta"] = {p: f["params"][p] - weights[p] for p in like}
@@ -162,16 +163,64 @@ def program_readings(arm, weights: Dict[str, np.ndarray], config: dict,
 
 # ------------------------------------------------ the reference's readings
 
+class MemoryProbe:
+    """What the reference holds on its chip, read where `follow_steps` says
+    something is about to happen or has happened (its `probe`): the
+    allocator's `bytes_in_use` before each gradient call, as each returns
+    and as each step ends, and XLA's own account of the gradient program.
+    The allocator counts arrays only, so the peak is the most that was in
+    use before a gradient call plus that program's temporaries and fresh
+    outputs, and never less than the most that was seen in use."""
+
+    def __init__(self):
+        import jax
+        self.device = jax.local_devices()[0]
+        self.start = self.in_use()
+        self.arrays_peak = self.start
+        self.before_call = 0
+        self.program = {"temp": 0, "fresh_output": 0}
+
+    def in_use(self) -> int:
+        return int((self.device.memory_stats() or {}).get("bytes_in_use", 0))
+
+    def __call__(self, event: str, info=None) -> None:
+        if event == "grad_compiled":
+            self.program = {
+                "temp": max(self.program["temp"],
+                            int(info.temp_size_in_bytes)),
+                "fresh_output": max(
+                    self.program["fresh_output"],
+                    int(info.output_size_in_bytes
+                        - info.alias_size_in_bytes))}
+            return
+        now = self.in_use()
+        self.arrays_peak = max(self.arrays_peak, now)
+        if event == "grad_call":
+            self.before_call = max(self.before_call, now)
+
+    def report(self) -> dict:
+        need = self.program["temp"] + self.program["fresh_output"]
+        return {"at_start_bytes": self.start,
+                "arrays_peak_bytes": self.arrays_peak,
+                "grad_call_temp_bytes": self.program["temp"],
+                "grad_call_fresh_output_bytes": self.program["fresh_output"],
+                "peak_bytes": max(self.arrays_peak, self.before_call + need)}
+
+
 def reference_readings(config: dict, mix: dict, seed: int,
-                       batches: Dict[str, list], masks: list,
+                       batches: Dict[str, list], masks: Optional[list],
                        weights: Dict[str, Any], precision: str = "float32",
-                       arms=("dense", "sparse")) -> Dict[str, dict]:
-    """Follow both trainers' first steps with the plain reference.
+                       probe=None) -> Dict[str, dict]:
+    """Follow the trainers' first steps with the plain reference, one arm
+    of `batches` after the other.
     `batches[arm][s]` is the global batch fed at step s (host arrays);
-    `masks[s]` is bool [workers, n]: which entries each worker sent."""
-    import jax.numpy as jnp
+    `masks[s]` is bool [workers, n]: which entries each worker sent (read
+    for the sparse arm only). Batches, masks and weights go to the
+    reference as host arrays: it puts on its chip what one gradient call
+    needs and nothing else (`reference/common.py` `follow_steps`)."""
     from .reference import common as C
 
+    arms = list(batches)
     ref = harness.load_reference(config)
     tr = config["trainer"]
     nworkers = int(mix["nworkers"])
@@ -197,10 +246,10 @@ def reference_readings(config: dict, mix: dict, seed: int,
                 keep = None
                 if "dropout" in config:
                     from .dropout import program_keep_mask
-                    keep = program_keep_mask(
+                    keep = np.asarray(program_keep_mask(
                         seed, s, w, (per_worker, config["dropout"]["width"]),
-                        config["dropout"]["rate"])
-                row.append((jnp.asarray(x[sl]), jnp.asarray(y[sl]), keep))
+                        config["dropout"]["rate"]))
+                row.append((np.asarray(x[sl]), np.asarray(y[sl]), keep))
             shards.append(row)
         # the reference lays parameters out by sorted path; masks come in
         # the program's order, which `order` maps onto it
@@ -211,15 +260,16 @@ def reference_readings(config: dict, mix: dict, seed: int,
                 per = []
                 for w in range(nworkers):
                     parts = harness.split_flat(masks[s][w], like)
-                    per.append(jnp.asarray(np.concatenate(
-                        [parts[p].reshape(-1) for p in order])))
+                    per.append(np.concatenate(
+                        [parts[p].reshape(-1) for p in order]))
                 step_masks.append(per)
         else:
             step_masks = [None] * steps
-        r = C.follow_steps(loss_fn, {p: jnp.asarray(like[p]) for p in like},
+        r = C.follow_steps(loss_fn, {p: np.asarray(like[p]) for p in like},
                            shards, step_masks, lrs=lrs,
                            momentum=float(tr["momentum"]),
-                           weight_decay=float(tr["weight_decay"]))
+                           weight_decay=float(tr["weight_decay"]),
+                           probe=probe)
         shapes = {p: like[p] for p in order}
         grad = _split_sorted(np.asarray(r["first_grad"]), shapes)
         params = _split_sorted(np.asarray(r["params"]), shapes)
@@ -292,7 +342,8 @@ def compare(mine: Dict[str, dict], ref: Dict[str, dict],
     if head_leaf:
         numbers["head_grad_rel_err"] = head_err
     numbers["grad_total_norm_gap"] = g_total
-    numbers["dense_delta_total_norm_gap"] = d_total
+    if "dense" in ref:
+        numbers["dense_delta_total_norm_gap"] = d_total
     numbers["grad_norm_gap"] = g_gap[0]
     numbers["grad_norm_gap_leaf"] = g_gap[1]
     numbers["grad_rel_err"] = g_err
@@ -362,25 +413,84 @@ def judge(numbers: Dict[str, Any], limits: Dict[str, Any]) -> tuple:
     return ok, lines
 
 
+def as_record(numbers: Dict[str, Any], limits: Dict[str, Any]) -> dict:
+    """Each number that has a limit beside it, for the result line."""
+    def plain(v):
+        if isinstance(v, (int, float)) and not np.isfinite(v):
+            return str(v)
+        return v
+    return {name: {"value": plain(numbers.get(name)), "limit": limit}
+            for name, limit in limits.items()}
+
+
+def take_readings(arm, weights: Dict[str, np.ndarray], config: dict,
+                  expected_states: Optional[dict] = None) -> tuple:
+    """One arm's `program_readings`, with its batches and masks, TAKEN out
+    of `arm.first` together with the full-length readings they were made
+    from: whoever goes through the arms one after the other holds one
+    arm's vectors at a time. Returns (readings, batches, masks), the first
+    two keyed by the arm's name as `compare` and `reference_readings` take
+    them."""
+    f = arm.first
+    mine = {arm.name: program_readings(arm, weights, config, expected_states)}
+    batches, masks = {arm.name: f.pop("batches")}, f.pop("masks")
+    for key in ("momentum1", "residual1", "params"):
+        f.pop(key)
+    return mine, batches, masks
+
+
+def worst(per_arm: list) -> Dict[str, Any]:
+    """`compare`'s numbers of one arm at a time, merged: each number is a
+    worst case over the arms, and a `_leaf` goes with its number."""
+    out: Dict[str, Any] = {}
+    for numbers in per_arm:
+        for key, v in numbers.items():
+            if key.endswith("_leaf"):
+                continue
+            if key not in out or v > out[key]:
+                out[key] = v
+                if key + "_leaf" in numbers:
+                    out[key + "_leaf"] = numbers[key + "_leaf"]
+    return out
+
+
 def run_check(cell: dict, seed: int, arms_first: Dict[str, dict],
               weights_host: Dict[str, np.ndarray], window: dict,
               expected_states: Optional[dict] = None,
-              precision: str = "float32") -> tuple:
+              precision: str = "float32",
+              memory: Optional[dict] = None) -> tuple:
     """The whole comparison after the window has closed and the trainers
-    are freed. `arms_first[arm]` is a stand-in for `Arm` with `.name` and
-    `.first`. Returns (correct, numbers, lines, seconds)."""
+    are freed, over the arms that ran. `arms_first[arm]` is a stand-in for
+    `Arm` with `.name` and `.first`.
+
+    One arm after the other, and it CONSUMES what it reads: an arm's
+    full-length readings (`momentum1`, `residual1`, `params`, `masks`,
+    `batches`) are taken out of its `.first` as soon as they are read, and
+    its gradients and parameters are dropped once they are compared, so
+    that at a configuration of hundreds of millions of parameters the host
+    holds one arm's vectors at a time (PERF.md section 4). What the
+    reference held on its chip goes into `memory["check"]` where a dict is
+    given. Returns (correct, numbers, lines, seconds)."""
     t0 = time.perf_counter()
     config, mix = cell["config_data"], cell["mix"]
-    mine = {name: program_readings(arm, weights_host, config, expected_states)
-            for name, arm in arms_first.items()}
-    ref = reference_readings(
-        config, mix, seed,
-        {name: arm.first["batches"] for name, arm in arms_first.items()},
-        arms_first["sparse"].first["masks"], weights_host, precision)
-    numbers = compare(mine, ref, config.get("head_leaf"))
-    numbers.update(mine["sparse"]["exact"])
-    numbers["lost"] = lost_entries(mine["sparse"], ref["sparse"],
-                                   int(arms_first["sparse"].first["k"]))
+    probe = MemoryProbe()
+    per_arm, sparse_only = [], {}
+    for name, arm in arms_first.items():
+        mine, batches, masks = take_readings(arm, weights_host, config,
+                                             expected_states)
+        ref = reference_readings(config, mix, seed, batches, masks,
+                                 weights_host, precision, probe=probe)
+        del batches, masks
+        per_arm.append(compare(mine, ref, config.get("head_leaf")))
+        if name == "sparse":
+            sparse_only = dict(mine[name]["exact"])
+            sparse_only["lost"] = lost_entries(mine[name], ref[name],
+                                               int(arm.first["k"]))
+        del mine, ref
+    if memory is not None:
+        memory["check"] = probe.report()
+    numbers = worst(per_arm)
+    numbers.update(sparse_only)
     numbers["compiles_in_window"] = int(window.get("compiles_in_window", 0))
     numbers["failed_steps"] = int(window.get("failed_steps", 0))
     ok, lines = judge(numbers, config["limits"])

@@ -10,8 +10,8 @@ and entries and edits nothing here.
 From the program (`gaussiank_sgd_tpu`) it takes the system under test, built
 exactly as the CLI builds it (`train.make_trainer(argv)`), and drives it
 through `Trainer.train(n, data_iter=...)`. Weights are the benchmark's own,
-made from the seed by the configuration's reference file and handed to both
-trainers; inputs are the trainer's own seeded stream, timed and copied on the
+made from the seed by the configuration's reference file and handed to every
+trainer of the run; inputs are the trainer's own seeded stream, timed and copied on the
 way through.
 """
 
@@ -41,6 +41,18 @@ _SAVED_EVENT = "/jax/compilation_cache/compile_time_saved_sec"
 # the one batch that was in the producer's hand is ready, and after that
 # many steps its loop is back in the stride of a run with one trainer.
 LEAD_IN_STEPS = 2
+
+# The arms a mix's `round` may name, in the order they are built, and the arm
+# each end-to-end metric is read from. The sparse trainer is the system under
+# test and every round names it. The dense baseline is the control arm: it
+# costs the harness 12 bytes a parameter at rest for the whole run (float32
+# parameters, momentum and the residual the program allocates for it too),
+# so whether a cell carries it is the mix's choice. It has to be a mix's and
+# not the harness's, because a later PR that adds a configuration may add
+# files only: a mix whose round is ["sparse"] runs one trainer.
+ARM_ORDER = ("dense", "sparse")
+METRIC_ARM = {"examples_per_s": "sparse", "step_ms_p95": "sparse",
+              "dense_examples_per_s": "dense"}
 
 
 def say(*parts) -> None:
@@ -74,11 +86,30 @@ def load_cell(name: str, root: str = ROOT) -> dict:
     def mine(metric):
         return "workloads" not in metric or name in metric["workloads"]
 
+    cell["arms"] = arm_names(cell["mix"])
     cell["end_to_end"] = [m for m in bench["end_to_end"] if mine(m)]
+    absent = [m["name"] for m in cell["end_to_end"]
+              if METRIC_ARM.get(m["name"]) not in (None, *cell["arms"])]
+    if absent:
+        raise SystemExit(
+            f"cell {name!r} lists {absent}, read from an arm that its mix "
+            f"{cell['traffic']!r} does not run (round {cell['mix']['round']})"
+            f": take the cell out of those metrics' `workloads`")
     cell["per_layer"] = [m for m in bench["per_layer"] if mine(m)]
     cell["metrics_dir"] = os.path.join(root, bench["paths"][0],
                                        "layer_metrics")
     return cell
+
+
+def arm_names(mix: dict) -> List[str]:
+    """The arms the mix's `round` names, in the order they are built."""
+    rnd = list(mix["round"])
+    if "sparse" not in rnd or set(rnd) - set(ARM_ORDER):
+        raise SystemExit(
+            f"mix {mix.get('name')!r}: `round` is {rnd}; it names the sparse "
+            f"trainer (the system under test) and may name the dense "
+            f"baseline, nothing else")
+    return [a for a in ARM_ORDER if a in rnd]
 
 
 def load_peaks(device_kind: str) -> dict:
@@ -173,7 +204,7 @@ class TimedFeed:
 class GatedStream:
     """The trainer's own input stream (`Trainer._stream()`) behind a gate.
 
-    A run holds two trainers and drives one at a time. The one sitting out
+    A run with two trainers drives one at a time. The one sitting out
     must not go on producing batches: its producer thread would take the
     host from the other one's loop, and its queue would be full at its next
     turn, which no step of a run with one trainer finds once the host is
@@ -285,8 +316,8 @@ def split_flat(flat: np.ndarray, like: Dict[str, Any]) -> Dict[str, np.ndarray]:
 
 
 class Arm:
-    """One of the run's two trainers ("sparse" or "dense") with its timed
-    feed, its bus tap and what was read from its first steps."""
+    """One of the run's trainers ("sparse" or "dense") with its timed feed,
+    its bus tap and what was read from its first steps."""
 
     def __init__(self, name: str, trainer, keep: int):
         self.name = name
@@ -326,8 +357,8 @@ class Arm:
 
 def build_arms(cell: dict, seed: int, out_dir: str, trace: bool,
                first_steps: int = 3) -> Dict[str, Arm]:
-    """Both trainers, built as the CLI builds them, with the benchmark's
-    weights in place of their own."""
+    """The trainers that the mix's round names, built as the CLI builds
+    them, with the benchmark's weights in place of their own."""
     import jax
     from gaussiank_sgd_tpu import train as program
 
@@ -341,7 +372,7 @@ def build_arms(cell: dict, seed: int, out_dir: str, trace: bool,
         f"{sum(int(v.size) for v in weights.values())} parameters, "
         f"{time.perf_counter() - t0:.1f}s")
     arms = {}
-    for name in ("dense", "sparse"):
+    for name in cell["arms"]:
         t0 = time.perf_counter()
         trainer = program.make_trainer(
             trainer_argv(config, mix, seed, name, out_dir, trace))
@@ -351,7 +382,7 @@ def build_arms(cell: dict, seed: int, out_dir: str, trace: bool,
             f"kernel={trainer.ts.kernel_mode} wire={trainer.ts.wire_format} "
             f"ef_numel={trainer.ts.ef_numel} k={trainer.plan.total_k} "
             f"global_batch={trainer.cfg.global_batch_size}")
-    order = leaves_by_path(arms["dense"].trainer.state.params)
+    order = leaves_by_path(arms[cell["arms"][0]].trainer.state.params)
     return arms, {p: weights[p] for p in order}
 
 
@@ -405,7 +436,9 @@ def first_steps(arm: Arm, config: dict, steps: int = 3) -> None:
             masks.append(_host(res[:, :n] == 0) & arrived[None, :])
         if s == 0:
             arm.first["momentum1"] = m
-            arm.first["residual1"] = _host(res)
+            # the dense baseline's residual is allocated and never read
+            arm.first["residual1"] = (_host(res) if arm.name == "sparse"
+                                      else None)
             arm.first["dtypes"] = {
                 "residual_dtype": str(state.ef_residual.dtype),
                 "momentum_dtype": str(state.opt_state["m"].dtype)}
@@ -558,11 +591,16 @@ def percentile(values: List[float], q: float) -> float:
 
 
 def end_to_end(arms: Dict[str, Arm], setup_s: float) -> Dict[str, float]:
-    sp, de = arm_totals(arms["sparse"]), arm_totals(arms["dense"])
-    return {"examples_per_s": sp["examples_per_s"],
-            "dense_examples_per_s": de["examples_per_s"],
-            "step_ms_p95": 1e3 * percentile(sp["iter_s"], 95),
-            "setup_s": setup_s}
+    """The end-to-end metrics that the run's arms give (`METRIC_ARM`): one
+    that needs an arm the mix does not run is not there."""
+    sp = arm_totals(arms["sparse"])
+    out = {"examples_per_s": sp["examples_per_s"],
+           "step_ms_p95": 1e3 * percentile(sp["iter_s"], 95),
+           "setup_s": setup_s}
+    if "dense" in arms:
+        out["dense_examples_per_s"] = arm_totals(
+            arms["dense"])["examples_per_s"]
+    return out
 
 
 def program_memory(arm: Arm) -> dict:
@@ -578,16 +616,30 @@ def program_memory(arm: Arm) -> dict:
                                 - m.alias_size_in_bytes)}
 
 
+def state_bytes(arm: Arm) -> int:
+    """Bytes of the arm's training state at rest on its fullest chip:
+    parameters, optimizer state, residual, whatever else `Trainer._state`
+    holds there."""
+    import jax
+    per: Dict[int, int] = {}
+    for leaf in jax.tree_util.tree_leaves(arm.trainer._state):
+        for shard in getattr(leaf, "addressable_shards", ()):
+            per[shard.device.id] = (per.get(shard.device.id, 0)
+                                    + int(shard.data.nbytes))
+    return max(per.values(), default=0)
+
+
 def device_report(chips: int, arms: Optional[Dict[str, Arm]] = None) -> dict:
-    """The device as JAX reports it, and the peak bytes on the fullest chip.
+    """The device as JAX reports it, the peak bytes on the fullest chip, and
+    under `memory` what the run holds there.
 
     On this runtime the allocator's `peak_bytes_in_use` counts the arrays
     that live on the device and NOT the temporaries of a running XLA program
     (a step whose activations alone are gigabytes leaves it at the size of
-    the state). The peak is therefore what is at rest after the window
-    (both trainers' state, the batches in flight) plus the larger of the two
-    step programs' temporaries and fresh outputs, by XLA's memory analysis
-    of the compiled program; never less than the allocator's own peak."""
+    the state). The peak is therefore what is at rest after the window (the
+    trainers' state, the batches in flight) plus the largest of the step
+    programs' temporaries and fresh outputs, by XLA's memory analysis of
+    the compiled program; never less than the allocator's own peak."""
     import jax
     devs = jax.devices()[:chips] if chips else jax.devices()
     peak = rest = 0
@@ -601,10 +653,51 @@ def device_report(chips: int, arms: Optional[Dict[str, Arm]] = None) -> dict:
         progs = {n: program_memory(a) for n, a in arms.items()}
         need = max(p["temp"] + p["fresh_output"] for p in progs.values())
         report["memory_peak_bytes"] = max(peak, rest + need)
-        say(f"device memory: allocator peak_bytes_in_use {peak} (arrays "
-            f"only), at rest {rest}; step programs by XLA's analysis "
-            f"{progs}; peak on the fullest chip {report['memory_peak_bytes']}")
+        n = next(iter(arms.values())).trainer.plan.total_numel
+        report["memory"] = {
+            "parameters": int(n),
+            "state_bytes": {a: state_bytes(arm) for a, arm in arms.items()},
+            "at_rest_bytes": rest, "allocator_peak_bytes": peak,
+            "step_program_bytes": progs,
+            "window_peak_bytes": report["memory_peak_bytes"]}
     return report
+
+
+def _bytes(x: int, n: float) -> str:
+    return f"{x} ({x / n:.2f} B/param)"
+
+
+def say_memory(device: dict) -> None:
+    """The run's line on what it holds on the fullest chip, in bytes and in
+    bytes a parameter: each arm's state at rest, each step program's
+    temporaries and fresh outputs by XLA's analysis, and the window's peak
+    (the allocator counts arrays only, so: at rest plus the largest
+    program)."""
+    m = device["memory"]
+    n = float(m["parameters"])
+    say(f"device memory, fullest chip, {m['parameters']} parameters: "
+        + ", ".join(f"{a} state at rest {_bytes(v, n)}"
+                    for a, v in m["state_bytes"].items())
+        + f"; all at rest {_bytes(m['at_rest_bytes'], n)}, allocator "
+        f"peak_bytes_in_use {_bytes(m['allocator_peak_bytes'], n)} (arrays "
+        f"only); step programs by XLA's analysis "
+        + ", ".join(f"{a} temp {_bytes(p['temp'], n)} fresh output "
+                    f"{_bytes(p['fresh_output'], n)}"
+                    for a, p in m["step_program_bytes"].items())
+        + f"; window peak {_bytes(m['window_peak_bytes'], n)}")
+
+
+def say_check_memory(device: dict) -> None:
+    """The same for what the reference held on its chip during the check
+    (`check.MemoryProbe.report`)."""
+    m = device["memory"]
+    n, c = float(m["parameters"]), m["check"]
+    say(f"device memory, the check: in use when it began "
+        f"{_bytes(c['at_start_bytes'], n)}, arrays at most "
+        f"{_bytes(c['arrays_peak_bytes'], n)}, gradient call temp "
+        f"{_bytes(c['grad_call_temp_bytes'], n)} fresh output "
+        f"{_bytes(c['grad_call_fresh_output_bytes'], n)}, peak "
+        f"{_bytes(c['peak_bytes'], n)}")
 
 
 def close_arms(arms: Dict[str, Arm]) -> None:

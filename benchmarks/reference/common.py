@@ -136,11 +136,15 @@ def flatten(tree: dict) -> jax.Array:
     return jnp.concatenate([tree[k].reshape(-1) for k in sorted(tree)])
 
 
-def unflatten(flat: jax.Array, like: dict) -> dict:
+def unflatten(flat, like: dict) -> dict:
+    """`like` maps each path to an array or to its shape."""
     out, off = {}, 0
     for k in sorted(like):
-        n = like[k].size
-        out[k] = flat[off:off + n].reshape(like[k].shape)
+        shape = tuple(getattr(like[k], "shape", like[k]))
+        n = 1
+        for d in shape:
+            n *= int(d)
+        out[k] = flat[off:off + n].reshape(shape)
         off += n
     return out
 
@@ -155,8 +159,56 @@ def lr_at(step: int, base_lr: float, nworkers: int, warmup_steps: int) -> float:
     return base_lr + (base_lr * nworkers - base_lr) * frac
 
 
+class _GradCalls:
+    """The reference's one device program, `(tree, batch) -> (loss,
+    gradient tree)`, compiled ahead of time for each batch shape so that
+    XLA's own account of it can be handed to `probe`."""
+
+    def __init__(self, loss_fn, probe):
+        self._fn = jax.jit(jax.value_and_grad(loss_fn))
+        self._compiled = {}
+        self._probe = probe or (lambda *a: None)
+
+    def grad(self, tree, batch):
+        args = (tree, batch)
+        key = (jax.tree_util.tree_structure(args),
+               tuple((a.shape, str(a.dtype))
+                     for a in jax.tree_util.tree_leaves(args)))
+        if key not in self._compiled:
+            self._compiled[key] = self._fn.lower(*args).compile()
+            self._probe("grad_compiled",
+                        self._compiled[key].memory_analysis())
+        self._probe("grad_call")
+        out = self._compiled[key](*args)
+        self._probe("grad_returned")
+        return out
+
+
+# Elements of the flat vectors that the host's arithmetic takes at a time:
+# elementwise float32, so the blocks change no bit, and no temporary is longer
+# than this.
+_CHUNK = 1 << 24
+
+
+def _chunks(n: int):
+    return (slice(lo, min(n, lo + _CHUNK)) for lo in range(0, n, _CHUNK))
+
+
+def _to_host(tree: dict):
+    """A {path: device array} dict as one float32 numpy vector in
+    sorted-path order, leaf by leaf: no flat copy is made on the device."""
+    import numpy as np
+    out = np.empty((sum(int(v.size) for v in tree.values()),), np.float32)
+    off = 0
+    for k in sorted(tree):
+        n = int(tree[k].size)
+        out[off:off + n] = np.asarray(tree[k], np.float32).reshape(-1)
+        off += n
+    return out
+
+
 def follow_steps(loss_fn, params: dict, shards, masks, *, lrs, momentum,
-                 weight_decay):
+                 weight_decay, probe=None):
     """Follow `len(shards)` optimizer steps from `params`.
 
     shards[s][w] is worker w's batch at step s (whatever `loss_fn(params,
@@ -165,37 +217,77 @@ def follow_steps(loss_fn, params: dict, shards, masks, *, lrs, momentum,
     Returns a dict of what the comparison reads: each step's loss (mean over
     workers), the first step's gradient (the workers' mean, and each
     worker's own), and the parameters after the last step, all flat float32.
+
+    In bounded device memory, whatever the worker count. The optimizer's
+    vectors, the residuals, the masks and the sums live on the HOST as
+    float32 numpy, where the step's arithmetic is done in the same float32
+    operations and the same order as the algorithm above is written
+    (`acc = residual + g`, `sent = where(mask, acc, 0)`,
+    `m = mu m + G + wd p`, `p = p - lr m`; IEEE add, multiply and divide
+    round the same in numpy as in XLA), `_CHUNK` elements at a time, so
+    that the host holds p, m, G, one residual a worker, the gradient in
+    hand and the first step's gradients, and nothing else of full length.
+    The device holds the parameter tree that the gradient is taken with
+    respect to (4 bytes a parameter), one gradient (4 more), one worker's
+    batch and what the gradient call needs while it runs; a gradient leaves
+    the device leaf by leaf as soon as it is there. Batches, masks and
+    `params` may be host arrays.
+
+    `probe(event, info=None)` is called with "grad_compiled" (info: XLA's
+    `memory_analysis()` of the gradient program), "grad_call" before and
+    "grad_returned" after each gradient call, and "step_end": for the
+    harness's account of the check's memory and for the tests.
     """
-    grad_fn = jax.jit(jax.value_and_grad(loss_fn))
-    p = flatten(params)
-    m = jnp.zeros_like(p)
+    import numpy as np
+    f32 = np.float32
+    calls = _GradCalls(loss_fn, probe)
+    like = {k: tuple(params[k].shape) for k in params}
+    p = np.concatenate([np.asarray(params[k], f32).reshape(-1)
+                        for k in sorted(like)])
+    n = p.size
+    m = np.zeros_like(p)
     nworkers = len(shards[0])
-    residual = [jnp.zeros_like(p) for _ in range(nworkers)]
+    residual = [np.zeros_like(p) for _ in range(nworkers)
+                if masks[0] is not None]
     losses, first_grad, first_grads = [], None, []
+    mu, wd, workers = f32(momentum), f32(weight_decay), f32(nworkers)
     for s, step_shards in enumerate(shards):
-        tree = unflatten(p, params)
-        G = jnp.zeros_like(p)
-        gsum = jnp.zeros_like(p)
+        tree = {k: jnp.asarray(v) for k, v in unflatten(p, like).items()}
+        G = np.zeros_like(p)
+        # the workers' mean gradient is read of the first step only
+        gsum = np.zeros_like(p) if s == 0 else None
         loss = 0.0
         for w, batch in enumerate(step_shards):
-            l, g = grad_fn(tree, batch)
-            g = flatten(g)
+            l, g = calls.grad(tree, batch)
             loss += float(l) / nworkers
-            gsum = gsum + g
+            g_dev, g = g, _to_host(g)
+            del g_dev
             if s == 0:
                 first_grads.append(g)
-            if masks[s] is None:
-                G = G + g
-            else:
-                acc = residual[w] + g
-                sent = jnp.where(masks[s][w], acc, 0.0)
-                residual[w] = acc - sent
-                G = G + sent
-        G = G / nworkers
-        if first_grad is None:
-            first_grad = gsum / nworkers
+            mask = None if masks[s] is None else np.asarray(masks[s][w])
+            for c in _chunks(n):
+                if gsum is not None:
+                    gsum[c] = gsum[c] + g[c]
+                if mask is None:
+                    G[c] = G[c] + g[c]
+                else:
+                    acc = residual[w][c] + g[c]
+                    sent = np.where(mask[c], acc, f32(0.0))
+                    residual[w][c] = acc - sent
+                    G[c] = G[c] + sent
+            del g
+        del tree
+        for c in _chunks(n):
+            G[c] = G[c] / workers
+            if gsum is not None:
+                gsum[c] = gsum[c] / workers
+            m[c] = mu * m[c] + G[c] + wd * p[c]
+            p[c] = p[c] - f32(lrs[s]) * m[c]
+        if gsum is not None:
+            first_grad = gsum
+        del G
         losses.append(loss)
-        m = momentum * m + G + weight_decay * p
-        p = p - lrs[s] * m
+        if probe is not None:
+            probe("step_end")
     return {"losses": losses, "first_grad": first_grad, "params": p,
             "first_grad_workers": first_grads}

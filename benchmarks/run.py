@@ -94,23 +94,25 @@ def main(argv=None) -> int:
 
 
 def _run(args, cell, peaks, compile_log, out_dir) -> int:
-    """Everything after the look for a chip (the tests start here)."""
+    """Everything after the look for a chip (the tests start here). It
+    builds, drives, totals and checks the arms that the mix's round names,
+    and no other."""
     from benchmarks import check, harness, trace_reduce
     say = harness.say
     config, mix = cell["config_data"], cell["mix"]
     arms, weights_host = harness.build_arms(cell, args.seed, out_dir,
                                             bool(args.trace))
     t_built = time.perf_counter()
-    for name in ("dense", "sparse"):
-        harness.first_steps(arms[name], config)
+    for name, arm in arms.items():
+        harness.first_steps(arm, config)
         say(f"{name} first steps: losses "
-            f"{[round(x, 6) for x in arms[name].first['losses']]}")
+            f"{[round(x, 6) for x in arm.first['losses']]}")
     t_first = time.perf_counter()
-    for name in ("dense", "sparse"):
-        rec = harness.warm_up(arms[name], mix)
-        f = arms[name].first
+    for name, arm in arms.items():
+        rec = harness.warm_up(arm, mix)
+        f = arm.first
         say(f"{name} warm after {f['warm_intervals']} log interval(s): step "
-            f"{f['warm_step_ms']:.3f} ms, {arms[name].steps_per_block} steps "
+            f"{f['warm_step_ms']:.3f} ms, {arm.steps_per_block} steps "
             f"to a block, num_selected {rec.get('num_selected')}, loss "
             f"{rec.get('loss')}")
     setup_s = time.perf_counter() - T_START
@@ -124,17 +126,19 @@ def _run(args, cell, peaks, compile_log, out_dir) -> int:
     trace_dir = os.path.join(out_dir, "trace") if args.trace else None
     window = harness.measure(arms, mix, args.seconds, compile_log, trace_dir)
     device = harness.device_report(cell["chips"], arms)
+    harness.say_memory(device)
     totals = {name: harness.arm_totals(arm) for name, arm in arms.items()}
     e2e = harness.end_to_end(arms, setup_s)
     sp = totals["sparse"]
-    say(f"window {window['window_s']:.3f}s: sparse {sp['steps']} steps "
-        f"({sp['skipped']} skipped) in {sp['wall_s']:.3f}s, dense "
-        f"{totals['dense']['steps']} steps in "
-        f"{totals['dense']['wall_s']:.3f}s; step_ms_p95 over "
-        f"{len(sp['iter_s'])} sparse iterations (median "
-        f"{1e3 * harness.percentile(sp['iter_s'], 50):.3f} ms); "
-        f"sparse:dense {e2e['examples_per_s'] / e2e['dense_examples_per_s']:.4f}"
-        f"; compilations inside the window {compile_log.in_window}")
+    say(f"window {window['window_s']:.3f}s: "
+        + ", ".join(f"{n} {t['steps']} steps ({t['skipped']} skipped) in "
+                    f"{t['wall_s']:.3f}s" for n, t in totals.items())
+        + f"; step_ms_p95 over {len(sp['iter_s'])} sparse iterations (median "
+        f"{1e3 * harness.percentile(sp['iter_s'], 50):.3f} ms)"
+        + (f"; sparse:dense "
+           f"{e2e['examples_per_s'] / e2e['dense_examples_per_s']:.4f}"
+           if e2e.get("dense_examples_per_s") else "")
+        + f"; compilations inside the window {compile_log.in_window}")
 
     run = {"cell": cell, "config": config, "mix": mix, "peaks": peaks,
            "totals": totals, "setup_s": setup_s, "compile_s": compile_s,
@@ -144,6 +148,8 @@ def _run(args, cell, peaks, compile_log, out_dir) -> int:
            "num_params": arms["sparse"].trainer.plan.total_numel,
            "k": arms["sparse"].trainer.plan.total_k,
            "blocks": {n: a.blocks for n, a in arms.items()},
+           # {arm: [directory of each profiled block]}; empty untraced
+           "trace_dirs": window["traced"],
            "log_every": int(mix["log_every"]), "trace": None}
     breakdown = None
     if args.trace:
@@ -168,9 +174,9 @@ def _run(args, cell, peaks, compile_log, out_dir) -> int:
         "failed_steps": sum(t["skipped"] for t in totals.values())}
     harness.close_arms(arms)
     ok, numbers, lines, secs = check.run_check(
-        cell, args.seed, firsts, weights_host, window_info)
-    for line in lines:
-        say(line)
+        cell, args.seed, firsts, weights_host, window_info,
+        memory=device["memory"])
+    harness.say_check_memory(device)
     say(f"the reference and the comparison took {secs:.1f}s (not in setup_s)")
 
     if args.trace:
@@ -189,6 +195,12 @@ def _run(args, cell, peaks, compile_log, out_dir) -> int:
               "metrics": metrics, "device": device}
     if breakdown is not None:
         result["breakdown"] = breakdown
+    # each number compared beside its limit: the run's last lines on
+    # standard error, and last in the result line
+    result["check"] = check.as_record(numbers, config["limits"])
+    for line in lines:
+        say(line)
+        print(line, file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

@@ -47,9 +47,6 @@ parent commit without them), and nothing raises for that.
 
 from __future__ import annotations
 
-import glob
-import json
-import os
 import re
 import struct
 from typing import Any, Dict, Iterator, List, Optional, Tuple
@@ -438,27 +435,6 @@ def name_idle(busy_ns: List[Tuple[float, float]], spans, to_ns,
 
 # ------------------------------------------------------------- the one run
 
-def find_trace_dir(recording, arm: str = "sparse") -> Optional[str]:
-    """The directory this process's profiled block of `arm` was written
-    to: `<root>/runs/bench_*/trace/<arm>_0` of the run directory whose
-    event stream carries the recording's trace id. (`run` does not carry
-    the directory; PERF.md asks the next benchmark issue to put it
-    there.)"""
-    pattern = os.path.join(harness.ROOT, "runs", "bench_*", "trace",
-                           f"{arm}_0")
-    for tdir in sorted(glob.glob(pattern)):
-        stream = os.path.join(os.path.dirname(os.path.dirname(tdir)), arm,
-                              "metrics.jsonl")
-        try:
-            with open(stream) as f:
-                first = json.loads(f.readline() or "{}")
-        except (OSError, ValueError):
-            continue
-        if first.get("trace_id") == recording.trace_id:
-            return tdir
-    return None
-
-
 def reduced(run: dict) -> Optional[dict]:
     """Everything above for one run of the benchmark, computed once and
     kept in `run`; its lines for people are printed as it is made. None
@@ -479,10 +455,11 @@ def _reduce_run(run: dict) -> Optional[dict]:
     host = reduce_host(spans, blocks)
     if host is None:        # a recording, but not of this run's blocks
         return None
+    others = [spans_of(arm) for arm in run["blocks"] if arm != "sparse"]
     out: Dict[str, Any] = {
         "host": host,
-        "setup": reduce_setup([r for r in (sparse, spans_of("dense"))
-                               if r is not None]),
+        "setup": reduce_setup([sparse] + [r for r in others
+                                          if r is not None]),
         "device": None, "clock": None, "idle": None}
     say(f"spans sparse: {host['iterations']} iterations of "
         f"{1e3 * host['iteration_s']:.3f} ms; per iteration "
@@ -491,23 +468,24 @@ def _reduce_run(run: dict) -> Optional[dict]:
         + f", self {1e3 * host['self_s']:.3f} ms; data_wait.ready mean "
         f"{host['ready_mean']}")
     if out["setup"]:
-        say("spans construction, both trainers: " + ", ".join(
+        say("spans construction, all trainers: " + ", ".join(
             f"{k} {v:.3f} s" for k, v in sorted(out["setup"].items())))
+    # the profiled blocks' directories are the run's own (`trace_dirs`)
+    dirs = run.get("trace_dirs") or {}
     traced = [b for b in blocks if b.get("traced")]
-    tdir = find_trace_dir(sparse) if traced and run.get("trace") else None
-    if tdir is None:
+    if not traced or not run.get("trace") or not dirs.get("sparse"):
         return out
     block = traced[0]
-    dev = reduce_device(tdir, block["steps"])
+    dev = reduce_device(dirs["sparse"][0], block["steps"])
     if dev is None:
         return out
     out["device"] = dev
     _say_scopes("sparse", dev)
     # for people: no metric reads the dense arm's scopes
     dense = [b for b in run["blocks"].get("dense", []) if b.get("traced")]
-    dense_dir = os.path.join(os.path.dirname(tdir), "dense_0")
-    if dense and os.path.isdir(dense_dir):
-        _say_scopes("dense", reduce_device(dense_dir, dense[0]["steps"]))
+    if dense and dirs.get("dense"):
+        _say_scopes("dense", reduce_device(dirs["dense"][0],
+                                           dense[0]["steps"]))
     if dev["start_unix_ns"] is not None:
         start = dev["start_unix_ns"]
 

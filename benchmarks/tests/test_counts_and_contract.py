@@ -174,6 +174,31 @@ def test_every_cell_loads_with_its_files():
                                                      m["name"]), "read")
 
 
+def test_the_four_chip_cell_is_the_one_chip_cell_on_four_workers():
+    """`vgg16_dp4` (PR 26): `dp4_blocks` is `dp1_blocks` with `nworkers` 4
+    and nothing else changed; the cell reports every metric that
+    `vgg16_dp1` reports, and the exchange's besides."""
+    one, four = harness.load_cell("vgg16_dp1"), harness.load_cell("vgg16_dp4")
+    differ = {k for k in set(one["mix"]) | set(four["mix"])
+              if one["mix"].get(k) != four["mix"].get(k)}
+    assert differ == {"name", "what", "nworkers"}
+    assert four["mix"]["nworkers"] == four["chips"] == 4
+    assert four["config"] == one["config"] and four["arms"] == one["arms"]
+    assert [m["name"] for m in four["end_to_end"]] == [
+        m["name"] for m in one["end_to_end"]]
+    ones = [m["name"] for m in one["per_layer"]]
+    fours = [m["name"] for m in four["per_layer"]]
+    assert fours == ones + ["exchange_ms"]
+    assert {"dense_loop_examples_per_s", "dense_step_device_ms.shared",
+            "dense_mfu.shared"} <= set(fours)
+    reported = {m["name"] for m in four["end_to_end"]}
+    assert all(m["moves"] in reported for m in four["per_layer"])
+    exchange = four["per_layer"][-1]
+    assert exchange["layer"] == "exchange"
+    assert exchange["workloads"] == ["vgg16_dp4"]
+    assert exchange["moves"] == "examples_per_s"
+
+
 def test_the_dense_rate_is_end_to_end_only_where_the_chip_bounds_the_step():
     """In the host-bound cell the dense rate stands among the per-layer
     metrics under another name, and the dense program's readers move the

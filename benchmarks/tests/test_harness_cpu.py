@@ -31,8 +31,8 @@ def drive(tiny_root, capsys, cell_name, seed=5, seconds=1.0):
 def test_a_run_end_to_end_on_one_device(tiny_root, capsys):
     rc, result, out = drive(tiny_root, capsys, "tiny_dp1")
     assert rc == 0
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "check"]
     assert result["correct"] is True, out
     assert result["failed"] == 0 and result["attempted"] > 0
     assert set(result["metrics"]) == {"examples_per_s",
@@ -41,6 +41,17 @@ def test_a_run_end_to_end_on_one_device(tiny_root, capsys):
     assert all(m["value"] > 0 for m in result["metrics"].values())
     assert result["device"]["platform"] == "cpu"     # never a device number
     assert "check loss_gap:" in out and "check lost:" in out
+    # each number compared beside its limit, last in the result line
+    limits = harness.load_cell("tiny_dp1", root=tiny_root)[
+        "config_data"]["limits"]
+    assert {k: v["limit"] for k, v in result["check"].items()} == limits
+    assert result["check"]["lost"]["value"] == 0
+    # what the run holds: both arms' state, the window's and the check's
+    memory = result["device"]["memory"]
+    assert set(memory["state_bytes"]) == {"dense", "sparse"}
+    assert set(memory["check"]) >= {"arrays_peak_bytes", "peak_bytes"}
+    assert "device memory, fullest chip" in out
+    assert "device memory, the check:" in out
 
 
 def test_a_run_end_to_end_on_four_devices(tiny_root, capsys):
@@ -49,20 +60,61 @@ def test_a_run_end_to_end_on_four_devices(tiny_root, capsys):
     assert "check residual_devices: 4" in out
 
 
+# ------------------------------------------------------- arms by the mix
+
+@pytest.mark.parametrize("cell_name,devices", [("tiny_solo1", 1),
+                                               ("tiny_solo4", 4)])
+def test_a_mix_with_one_arm_runs_one_trainer(tiny_root, capsys, cell_name,
+                                             devices):
+    """A mix whose round is ["sparse"]: no dense trainer is built, driven,
+    totalled or checked, and the metric that needs it is not reported."""
+    rc, result, out = drive(tiny_root, capsys, cell_name)
+    assert rc == 0 and result["correct"] is True, out
+    assert set(result["metrics"]) == {"examples_per_s", "step_ms_p95",
+                                      "setup_s"}
+    assert set(result["device"]["memory"]["state_bytes"]) == {"sparse"}
+    assert "dense trainer built" not in out and "sparse:dense" not in out
+    assert "sparse trainer built" in out
+    assert f"check residual_devices: {devices}" in out
+    assert not [l for l in out.splitlines() if l.startswith("block")
+                and " dense " in l]
+    assert "dense_delta_total_norm_gap" not in out
+
+
+def test_a_cell_that_lists_a_metric_of_the_absent_arm_is_refused(
+        tiny_root, tmp_path, monkeypatch):
+    """Before any trainer is built: `load_cell` is the first thing a run
+    does with its cell."""
+    import shutil
+    root = str(tmp_path / "root")
+    shutil.copytree(tiny_root, root)
+    with open(f"{root}/BENCHMARK.json") as f:
+        bench = json.load(f)
+    dense = [m for m in bench["end_to_end"]
+             if m["name"] == "dense_examples_per_s"][0]
+    dense["workloads"].append("tiny_solo1")
+    with open(f"{root}/BENCHMARK.json", "w") as f:
+        json.dump(bench, f)
+    monkeypatch.setattr(harness, "build_arms", None)
+    with pytest.raises(SystemExit, match="dense_examples_per_s.*does not run"):
+        harness.load_cell("tiny_solo1", root=root)
+    assert harness.load_cell("tiny_dp1", root=root)["arms"] == ["dense",
+                                                                "sparse"]
+    for rnd in (["dense"], ["sparse", "control"], []):
+        with pytest.raises(SystemExit, match="names the sparse trainer"):
+            harness.arm_names({"name": "bad", "round": rnd})
+
+
+@pytest.mark.parametrize("cell_name,workers", [("tiny_dp1", 1),
+                                               ("tiny_dp4", 4)])
 def test_a_part_of_the_batch_left_out_is_not_correct(tiny_root, capsys,
-                                                     monkeypatch):
-    """The timed path broken underneath: the trainers are fed batches whose
-    second half repeats the first, the reference sees the rows as drawn."""
-    sound_next = harness.TimedFeed.__next__
-
-    def next_with_rows_left_out(self):
-        batch = sound_next(self)
-        half = len(batch[0]) // 2
-        return tuple(np.concatenate([a[:half], a[:half]]) for a in batch)
-
-    monkeypatch.setattr(harness.TimedFeed, "__next__",
-                        next_with_rows_left_out)
-    rc, result, out = drive(tiny_root, capsys, "tiny_dp1")
+                                                     cell_name, workers):
+    """The timed path broken underneath: the trainers are fed batches in
+    which one worker's rows repeat another's (with one worker: the second
+    half repeats the first), the reference sees the rows as drawn."""
+    from benchmarks import calibrate
+    with calibrate.worker_rows_left_out(workers):
+        rc, result, out = drive(tiny_root, capsys, cell_name)
     assert rc == 0 and result["correct"] is False
     assert "FAILED" in out
 
@@ -134,8 +186,8 @@ def sound(tiny_root):
     out_dir = harness.make_out_dir()
     try:
         arms, weights = harness.build_arms(cell, 9, out_dir, False)
-        for name in ("dense", "sparse"):
-            harness.first_steps(arms[name], cell["config_data"])
+        for arm in arms.values():
+            harness.first_steps(arm, cell["config_data"])
         harness.warm_up(arms["sparse"], cell["mix"])
         firsts = {n: types.SimpleNamespace(name=n, first=a.first)
                   for n, a in arms.items()}
@@ -146,8 +198,9 @@ def sound(tiny_root):
 
 
 def verdict(cell, firsts, weights):
+    # `run_check` consumes the readings it is given
     ok, numbers, lines, _ = check.run_check(
-        cell, 9, firsts, weights,
+        cell, 9, copy.deepcopy(firsts), weights,
         {"compiles_in_window": 0, "failed_steps": 0})
     return ok, numbers, lines
 
@@ -258,6 +311,30 @@ def test_the_control_in_float8_is_not_correct(sound):
     assert not ok, lines
     ok, lines = check.judge(check.compare(ref, ref), limits)
     assert ok, lines
+
+
+def test_calibrate_reads_what_a_run_compares(tiny_root, sound):
+    """`calibrate.py` goes one arm after the other, as `run_check` does,
+    and reads the same numbers of the same seed; its control is the float8
+    reference in the program's place."""
+    from benchmarks import calibrate
+    cell, firsts, weights = sound
+    _, numbers, _ = verdict(cell, firsts, weights)
+    row = calibrate.readings(cell, 9, control=True)
+    for key, v in row["sound"].items():
+        assert numbers[key] == v, key
+    assert {"loss_gap", "grad_rel_err", "grad_norm_gap",
+            "delta_norm_gap"} <= set(row["control"])
+    assert row["control"]["loss_gap"] > 3 * row["sound"]["loss_gap"]
+    # each set of readings held to the limits, as a run holds its own
+    assert row["verdict"] == {"correct": True, "failed": []}
+    assert row["control_verdict"]["correct"] is False
+    assert row["control_verdict"]["failed"]
+    broken = calibrate.readings(cell, 9, control=False, fault=True)
+    assert broken["verdict"]["correct"] is False and broken["fault"]
+    assert set(row["leaves"]["sound"]) == {
+        "dense.first_grad", "dense.delta", "sparse.first_grad",
+        "sparse.delta"}
 
 
 # ------------------------------------------------------ driven by data

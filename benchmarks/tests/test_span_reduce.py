@@ -373,9 +373,13 @@ def with_new_metrics(root):
 def test_the_real_benchmark_lists_the_new_metrics_last():
     bench = harness.load_benchmark()
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-17:] == NEW_METRICS
-    for m in bench["per_layer"][-17:]:
-        assert m["workloads"] == ["vgg16_dp1", "resnet50_dp1"]
+    # then PR 26's one of the exchange, read in the four-chip cell alone
+    assert names[-18:-1] == NEW_METRICS
+    assert names[-1] == "exchange_ms"
+    for m in bench["per_layer"][-18:]:
+        assert m["workloads"] == (
+            ["vgg16_dp4"] if m["layer"] == "exchange"
+            else ["vgg16_dp1", "resnet50_dp1", "vgg16_dp4"])
         assert os.path.exists(os.path.join(harness.HERE, "layer_metrics",
                                            m["name"] + ".py"))
 

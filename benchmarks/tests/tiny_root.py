@@ -1,5 +1,6 @@
-"""The tests' throw-away benchmark root: a configuration, two traffic mixes,
-two cells and a per-layer metric, written as new files and entries only.
+"""The tests' throw-away benchmark root: a configuration, four traffic mixes
+(two of them with one arm: their round is ["sparse"]), four cells and a
+per-layer metric, written as new files and entries only.
 Nothing that is under `benchmarks/` is edited or copied; the harness finds
 each new file by the name in the new BENCHMARK.json. (No JAX in here:
 `record_trace.py` imports it on the chip, `conftest.py` on the CPU.)"""
@@ -50,9 +51,12 @@ def write_tiny_root(root: str) -> None:
     cfg["limits"] = TINY_LIMITS
     with open(os.path.join(bdir, "configs", "tiny_vgg.json"), "w") as f:
         json.dump(cfg, f)
-    for name, workers in (("quick1", 1), ("quick4", 4)):
+    for name, workers, rnd in (
+            ("quick1", 1, ["dense", "sparse", "sparse"]),
+            ("quick4", 4, ["dense", "sparse", "sparse"]),
+            ("solo1", 1, ["sparse"]), ("solo4", 4, ["sparse"])):
         mix = {"name": name, "nworkers": workers, "block_seconds": 0.2,
-               "round": ["dense", "sparse", "sparse"], "log_every": 10}
+               "round": rnd, "log_every": 10}
         with open(os.path.join(bdir, "traffic", name + ".json"), "w") as f:
             json.dump(mix, f)
     with open(os.path.join(bdir, "layer_metrics", "sparse_steps.py"),
@@ -70,12 +74,17 @@ def write_tiny_root(root: str) -> None:
             {"name": "tiny_dp1", "config": "tiny_vgg", "traffic": "quick1",
              "chips": 1, "why": "test"},
             {"name": "tiny_dp4", "config": "tiny_vgg", "traffic": "quick4",
-             "chips": 4, "why": "test"}],
+             "chips": 4, "why": "test"},
+            {"name": "tiny_solo1", "config": "tiny_vgg", "traffic": "solo1",
+             "chips": 1, "why": "test: one arm, no dense baseline"},
+            {"name": "tiny_solo4", "config": "tiny_vgg", "traffic": "solo4",
+             "chips": 4, "why": "test: one arm on four devices"}],
         "end_to_end": [
             {"name": "examples_per_s", "unit": "examples/s",
              "better": "higher", "bound": 0.1, "source": "host_clock"},
             {"name": "dense_examples_per_s", "unit": "examples/s",
-             "better": "higher", "bound": 0.1, "source": "host_clock"},
+             "better": "higher", "bound": 0.1, "source": "host_clock",
+             "workloads": ["tiny_dp1", "tiny_dp4"]},
             {"name": "step_ms_p95", "unit": "ms", "better": "lower",
              "bound": 0.1, "source": "host_clock"},
             {"name": "setup_s", "unit": "s", "better": "lower", "bound": 0.1,
