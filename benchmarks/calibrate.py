@@ -6,10 +6,12 @@
 For every seed: build the cell's trainers (the arms of its mix) as a run does, take them
 through their first steps and the warm-up, free them, follow the same steps
 with the plain reference, and print every number the comparison reads (the
-sound runs' readings). For every control seed also put the reference in the
-program's place in the nearest precision below the configuration's
-(`float8` operands under bfloat16) and print the same numbers for it (the
-control's readings). A limit goes above the sound runs' largest and below
+sound runs' readings), with what the seed cost: the seconds of the
+program's side and of the reference's, each by part, in the lines a run
+prints (`seed <n> the program by part: ...`). For every control seed also
+put the reference in the program's place in the nearest precision below the
+configuration's (`float8` operands under bfloat16) and print the same
+numbers for it (the control's readings). A limit goes above the sound runs' largest and below
 the control's smallest (PERF.md section 2). For every fault seed the timed
 path is broken underneath (`worker_rows_left_out`): the trainers are fed
 batches in which one worker's rows repeat another's, and the reference sees
@@ -69,21 +71,30 @@ def verdict(numbers: dict, limits: dict, judged=None) -> dict:
 
 
 def readings(cell, seed, control: bool, expected_states=None,
-             fault: bool = False) -> dict:
+             fault: bool = False, compile_log=None) -> dict:
+    """One seed's readings, and what the seed cost: the seconds of the
+    program's side, of the reference's and of the control's, each by part
+    (`harness.Parts`, the lines a run prints), under `seconds`."""
     from benchmarks import check, harness
     out_dir = harness.make_out_dir()
+    program, followed, lowered = (harness.Parts(compile_log)
+                                  for _ in range(3))
     try:
         t0 = time.perf_counter()
         broken = (worker_rows_left_out(int(cell["mix"]["nworkers"]))
                   if fault else contextlib.nullcontext())
         with broken:
-            arms, weights = harness.build_arms(cell, seed, out_dir, False)
-            for arm in arms.values():
-                harness.first_steps(arm, cell["config_data"])
-            harness.warm_up(arms["sparse"], cell["mix"])
+            arms, weights = harness.build_arms(cell, seed, out_dir, False,
+                                               parts=program)
+            for name, arm in arms.items():
+                with program(f"{name} first steps"):
+                    harness.first_steps(arm, cell["config_data"])
+            with program("sparse warm-up"):
+                harness.warm_up(arms["sparse"], cell["mix"])
         firsts = {n: types.SimpleNamespace(name=n, first=a.first)
                   for n, a in arms.items()}
-        harness.close_arms(arms)
+        with program("trainers closed"):
+            harness.close_arms(arms)
         t1 = time.perf_counter()
         config, mix = cell["config_data"], cell["mix"]
         head = config.get("head_leaf")
@@ -95,26 +106,31 @@ def readings(cell, seed, control: bool, expected_states=None,
         # host holds one arm's vectors at a time
         for name, arm in firsts.items():
             t2 = time.perf_counter()
-            mine, batches, masks = check.take_readings(
-                arm, weights, config, expected_states)
+            with followed("the program's readings"):
+                mine, batches, masks = check.take_readings(
+                    arm, weights, config, expected_states)
             ref = check.reference_readings(config, mix, seed, batches, masks,
-                                           weights)
-            sound_by_arm.append(check.compare(mine, ref, head))
-            out["leaves"]["sound"].update(check.leaf_table(mine, ref))
+                                           weights, parts=followed)
+            with followed("compare"):
+                sound_by_arm.append(check.compare(
+                    mine, ref, head, table=out["leaves"]["sound"]))
             out["losses"][name] = [mine[name]["losses"], ref[name]["losses"]]
             if name == "sparse":
-                exact = dict(mine[name]["exact"])
-                exact["lost"] = check.lost_entries(
-                    mine[name], ref[name], arm.first["k"])
+                with followed("lost_entries"):
+                    exact = dict(mine[name]["exact"])
+                    exact["lost"] = check.lost_entries(
+                        mine[name], ref[name], arm.first["k"])
             del mine
             reference_s += time.perf_counter() - t2
             if control:
                 t3 = time.perf_counter()
                 low = check.reference_readings(config, mix, seed, batches,
                                                masks, weights,
-                                               precision="float8")
-                control_by_arm.append(check.compare(low, ref, head))
-                out["leaves"]["control"].update(check.leaf_table(low, ref))
+                                               precision="float8",
+                                               parts=lowered)
+                with lowered("compare"):
+                    control_by_arm.append(check.compare(
+                        low, ref, head, table=out["leaves"]["control"]))
                 out["control_losses"][name] = low[name]["losses"]
                 control_s += time.perf_counter() - t3
                 del low
@@ -127,7 +143,16 @@ def readings(cell, seed, control: bool, expected_states=None,
             dict(out["sound"], compiles_in_window=0, failed_steps=0),
             config["limits"])
         out.update(program_s=t1 - t0, reference_s=reference_s)
+        out["seconds"] = {"program": program.record(t1 - t0),
+                          "reference": followed.record(reference_s)}
+        harness.say(program.line(f"seed {seed} the program by part:",
+                                 t1 - t0))
+        harness.say(followed.line(f"seed {seed} the reference by part:",
+                                  reference_s))
         if control:
+            out["seconds"]["control"] = lowered.record(control_s)
+            harness.say(lowered.line(f"seed {seed} the control by part:",
+                                     control_s))
             out["control"] = check.worst(control_by_arm)
             out["control_verdict"] = verdict(out["control"], config["limits"],
                                              judged=out["control"])
@@ -157,12 +182,14 @@ def main(argv=None) -> int:
         return 2
     from gaussiank_sgd_tpu.compile_cache import enable_compile_cache
     enable_compile_cache()
+    compile_log = harness.CompileLog()
     seeds = [int(s) for s in args.seeds.split(",") if s]
     controls = {int(s) for s in args.control_seeds.split(",") if s}
     faults = {int(s) for s in args.fault_seeds.split(",") if s}
     rows = []
     for seed in seeds:
-        row = readings(cell, seed, seed in controls, fault=seed in faults)
+        row = readings(cell, seed, seed in controls, fault=seed in faults,
+                       compile_log=compile_log)
         rows.append(row)
         for kind, key in (("fault" if row["fault"] else "sound", "verdict"),
                           ("control", "control_verdict")):

@@ -51,16 +51,39 @@ from . import harness
 
 # --------------------------------------------------------------- leaf norms
 
-def leaf_norm_gap(mine: Dict[str, np.ndarray],
-                  ref: Dict[str, np.ndarray]) -> tuple:
+def leaf_sums(mine: Dict[str, np.ndarray],
+              ref: Dict[str, np.ndarray]) -> Dict[str, tuple]:
+    """Per leaf of `ref`: (sum of ref^2, sum of mine^2, sum of (mine -
+    ref)^2), each in float64, from ONE pass over both, a block at a time:
+    every norm and error the comparison reads is made of these. (A block's
+    sums are float64 dot products, added up in the blocks' order: the last
+    bits may differ from a whole-leaf sum's, nothing else.)"""
+    flat = {p: (np.asarray(mine[p]).reshape(-1),
+                np.asarray(ref[p]).reshape(-1)) for p in ref}
+    tasks = [(p, b) for p in ref for b in harness.blocks(flat[p][1].size)]
+
+    def sums(task):
+        p, b = task
+        a = flat[p][0][b].astype(np.float64)
+        r = flat[p][1][b].astype(np.float64)
+        d = a - r
+        return float(np.dot(r, r)), float(np.dot(a, a)), float(np.dot(d, d))
+
+    out = {p: (0.0, 0.0, 0.0) for p in ref}
+    for (p, _), got in zip(tasks, harness.on_blocks(sums, tasks)):
+        out[p] = tuple(x + y for x, y in zip(out[p], got))
+    return out
+
+
+def leaf_norm_gap(sums: Dict[str, tuple]) -> tuple:
     """Worst leaf: the gap between the two norms (not the norm of the
     difference) over the larger of the reference's norm of that leaf and of
     its median leaf. Returns (gap, leaf)."""
-    rn = {p: float(np.linalg.norm(ref[p].astype(np.float64))) for p in ref}
-    mn = {p: float(np.linalg.norm(mine[p].astype(np.float64))) for p in ref}
+    rn = {p: float(np.sqrt(v[0])) for p, v in sums.items()}
+    mn = {p: float(np.sqrt(v[1])) for p, v in sums.items()}
     median = float(np.median(list(rn.values())))
     worst, where = 0.0, ""
-    for p in ref:
+    for p in sums:
         den = max(rn[p], median)
         gap = abs(mn[p] - rn[p])
         gap = (gap / den) if den > 0 else (0.0 if gap == 0 else float("inf"))
@@ -69,84 +92,126 @@ def leaf_norm_gap(mine: Dict[str, np.ndarray],
     return worst, where
 
 
-def leaf_table(mine: Dict[str, dict], ref: Dict[str, dict]) -> dict:
-    """Per arm, quantity and leaf: (reference norm, this norm, norm of the
-    difference). What a limit's choice of number is read from."""
-    out = {}
-    for arm in ref:
-        for q in ("first_grad", "delta"):
-            out[f"{arm}.{q}"] = {
-                p: [float(np.linalg.norm(ref[arm][q][p].astype(np.float64))),
-                    float(np.linalg.norm(mine[arm][q][p].astype(np.float64))),
-                    float(np.linalg.norm(
-                        mine[arm][q][p].astype(np.float64)
-                        - ref[arm][q][p].astype(np.float64)))]
-                for p in ref[arm][q]}
-    return out
+def leaf_table(sums: Dict[str, tuple]) -> dict:
+    """Per leaf: (reference norm, this norm, norm of the difference). What
+    a limit's choice of number is read from."""
+    return {p: [float(np.sqrt(x)) for x in v] for p, v in sums.items()}
 
 
-def rel_err(mine: Dict[str, np.ndarray], ref: Dict[str, np.ndarray]) -> float:
-    num = sum(float(np.sum(np.square(mine[p].astype(np.float64)
-                                     - ref[p].astype(np.float64))))
-              for p in ref)
-    den = sum(float(np.sum(np.square(ref[p].astype(np.float64))))
-              for p in ref)
+def rel_err(sums: Dict[str, tuple]) -> float:
+    """Norm of the difference over the reference's norm, over every leaf
+    of `sums`."""
+    num = sum(v[2] for v in sums.values())
+    den = sum(v[0] for v in sums.values())
     return float(np.sqrt(num / den)) if den > 0 else float("inf")
 
 
-def mantissa_distance(values: np.ndarray, sample: int = 1 << 20) -> float:
+def total_norm_gap(sums: Dict[str, tuple]) -> float:
+    """Gap between the two whole-vector norms over the reference's."""
+    r = float(np.sqrt(sum(v[0] for v in sums.values())))
+    m = float(np.sqrt(sum(v[1] for v in sums.values())))
+    return abs(m - r) / r if r > 0 else float("inf")
+
+
+def mantissa_distance(values, sample: int = 1 << 20) -> float:
     """Median relative distance of non-zero float32 values from their
-    nearest bfloat16."""
+    nearest bfloat16: of all of them, or of every (count // sample)-th in
+    the order they stand in (a 2-D array's row by row). The non-zero values
+    are counted first and only the sampled ones are gathered, a block at a
+    time, so that no full-length copy is made."""
     import ml_dtypes
-    v = np.asarray(values, np.float32).reshape(-1)
-    v = v[v != 0]
-    if v.size == 0:
+    values = np.asarray(values, np.float32)
+    rows = list(values) if values.ndim == 2 else [values.reshape(-1)]
+    tasks = [(i, b) for i, row in enumerate(rows)
+             for b in harness.blocks(row.size)]
+    counts = harness.on_blocks(
+        lambda t: int(np.count_nonzero(rows[t[0]][t[1]])), tasks)
+    total = sum(counts)
+    if total == 0:
         return 0.0
-    if v.size > sample:
-        v = v[:: v.size // sample]
+    step = total // sample if total > sample else 1
+    before = np.concatenate([[0], np.cumsum(counts)[:-1]])
+
+    def pick(j):
+        (i, b), first = tasks[j], int(-before[j] % step)
+        if first >= counts[j]:
+            return np.empty((0,), np.float32)
+        block = rows[i][b]
+        return block[block != 0][first::step]
+
+    v = np.concatenate(harness.on_blocks(pick, range(len(tasks))))
     r = v.astype(ml_dtypes.bfloat16).astype(np.float32)
     return float(np.median(np.abs(v - r) / np.abs(v)))
 
 
 # ------------------------------------------------- the program's readings
 
+def _pieces(like: Dict[str, Any]) -> list:
+    """(path, where in the flat vector of `like`'s order, where in the
+    leaf's own flat view) for every block of every leaf."""
+    out, off = [], 0
+    for p, leaf in like.items():
+        size = int(np.prod(leaf.shape))
+        out += [(p, slice(off + b.start, off + b.stop), b)
+                for b in harness.blocks(size)]
+        off += size
+    return out
+
+
 def program_readings(arm, weights: Dict[str, np.ndarray], config: dict,
                      expected_states: Optional[dict] = None) -> dict:
-    """What the comparison reads of one trainer, from `arm.first`."""
+    """What the comparison reads of one trainer, from `arm.first`. The
+    full-length arithmetic is elementwise float32, a block of a leaf at a
+    time on threads: what it keeps of full length is what it returns (the
+    first gradient, the parameters' change and, of the sparse trainer, the
+    two masks)."""
     f = arm.first
-    tr_cfg = config["trainer"]
-    wd = np.float32(tr_cfg["weight_decay"])
+    wd = np.float32(config["trainer"]["weight_decay"])
     like = f["params"]
     n = sum(int(v.size) for v in like.values())
-    p0 = np.concatenate([weights[p].reshape(-1) for p in like])
-    wd_p0 = wd * p0
     m1 = f["momentum1"][:n]
-    arrived = m1 - wd_p0
-    out: Dict[str, Any] = {"losses": f["losses"]}
-    if arm.name == "dense":
-        grad = arrived
-    else:
-        res1 = f["residual1"]
-        nworkers = res1.shape[0]
-        grad = arrived + res1[:, :n].mean(axis=0, dtype=np.float32)
-    out["first_grad"] = harness.split_flat(grad, like)
-    out["delta"] = {p: f["params"][p] - weights[p] for p in like}
-    if arm.name != "sparse":
-        return out
+    sparse = arm.name == "sparse"
+    res1 = f["residual1"] if sparse else None
+    nworkers = res1.shape[0] if sparse else 0
+    grad = np.empty((n,), np.float32)
+    delta = np.empty((n,), np.float32)
+    sent_any = np.empty((n,), bool) if sparse else None
+    zeroed = np.empty((nworkers, n), bool) if sparse else None
 
-    # exact bookkeeping, from the system's own state after one sparse step
-    sent_any = m1 != wd_p0
-    zeroed = res1[:, :n] == 0
-    kept_all = ~zeroed.any(axis=0)
+    def piece(task):
+        p, at, b = task
+        p0 = weights[p].reshape(-1)[b]
+        wd_p0 = wd * p0
+        arrived = m1[at] - wd_p0
+        np.subtract(np.asarray(f["params"][p]).reshape(-1)[b], p0,
+                    out=delta[at])
+        if not sparse:
+            grad[at] = arrived
+            return None
+        grad[at] = arrived + res1[:, at].mean(axis=0, dtype=np.float32)
+        # exact bookkeeping, from the system's own state after one sparse
+        # step
+        sent = m1[at] != wd_p0
+        zero = res1[:, at] == 0
+        sent_any[at], zeroed[:, at] = sent, zero
+        return (int(np.count_nonzero(sent & ~zero.any(axis=0))),
+                int(np.count_nonzero(sent)), arrived[sent])
+
+    got = harness.on_blocks(piece, _pieces(like))
+    out: Dict[str, Any] = {"losses": f["losses"],
+                           "first_grad": harness.split_flat(grad, like),
+                           "delta": harness.split_flat(delta, like)}
+    if not sparse:
+        return out
     k = int(f["k"])
     exact = {
-        "double_counted": int(np.count_nonzero(sent_any & kept_all)),
+        "double_counted": sum(g[0] for g in got),
         "pad_nonzero": int(np.count_nonzero(res1[:, n:])),
-        "sent_mantissa": mantissa_distance(arrived[sent_any]),
+        "sent_mantissa": mantissa_distance(
+            np.concatenate([g[2] for g in got])),
         "residual_mantissa": mantissa_distance(res1[:, :n]),
         "momentum_mantissa": mantissa_distance(m1),
-        "sent_step1_over_k": float(np.count_nonzero(sent_any)) / (
-            k * nworkers),
+        "sent_step1_over_k": float(sum(g[1] for g in got)) / (k * nworkers),
         "selected_over_k": float(f["warm_selected"]) / k,
     }
     states = dict(f["dtypes"])
@@ -210,7 +275,7 @@ class MemoryProbe:
 def reference_readings(config: dict, mix: dict, seed: int,
                        batches: Dict[str, list], masks: Optional[list],
                        weights: Dict[str, Any], precision: str = "float32",
-                       probe=None) -> Dict[str, dict]:
+                       probe=None, parts=None) -> Dict[str, dict]:
     """Follow the trainers' first steps with the plain reference, one arm
     of `batches` after the other.
     `batches[arm][s]` is the global batch fed at step s (host arrays);
@@ -220,8 +285,10 @@ def reference_readings(config: dict, mix: dict, seed: int,
     needs and nothing else (`reference/common.py` `follow_steps`)."""
     from .reference import common as C
 
+    parts = parts if parts is not None else harness.Parts()
     arms = list(batches)
-    ref = harness.load_reference(config)
+    with parts("rows and masks to the reference's order"):
+        ref = harness.load_reference(config)
     tr = config["trainer"]
     nworkers = int(mix["nworkers"])
     per_worker = int(tr["batch_size"])
@@ -237,51 +304,70 @@ def reference_readings(config: dict, mix: dict, seed: int,
     like = weights          # {path: array} in the program's order
     out = {}
     for arm in arms:
-        shards = []
-        for s in range(steps):
-            x, y = batches[arm][s][:2]
-            row = []
-            for w in range(nworkers):
-                sl = slice(w * per_worker, (w + 1) * per_worker)
-                keep = None
-                if "dropout" in config:
-                    from .dropout import program_keep_mask
-                    keep = np.asarray(program_keep_mask(
-                        seed, s, w, (per_worker, config["dropout"]["width"]),
-                        config["dropout"]["rate"]))
-                row.append((np.asarray(x[sl]), np.asarray(y[sl]), keep))
-            shards.append(row)
-        # the reference lays parameters out by sorted path; masks come in
-        # the program's order, which `order` maps onto it
-        order = sorted(like)
-        if arm == "sparse":
-            step_masks = []
+        with parts("rows and masks to the reference's order"):
+            shards = []
             for s in range(steps):
-                per = []
+                x, y = batches[arm][s][:2]
+                row = []
                 for w in range(nworkers):
-                    parts = harness.split_flat(masks[s][w], like)
-                    per.append(np.concatenate(
-                        [parts[p].reshape(-1) for p in order]))
-                step_masks.append(per)
-        else:
-            step_masks = [None] * steps
+                    sl = slice(w * per_worker, (w + 1) * per_worker)
+                    keep = None
+                    if "dropout" in config:
+                        from .dropout import program_keep_mask
+                        keep = np.asarray(program_keep_mask(
+                            seed, s, w,
+                            (per_worker, config["dropout"]["width"]),
+                            config["dropout"]["rate"]))
+                    row.append((np.asarray(x[sl]), np.asarray(y[sl]), keep))
+                shards.append(row)
+            # the reference lays parameters out by sorted path; masks come
+            # in the program's order
+            order = sorted(like)
+            step_masks = [
+                [_reordered(masks[s][w], like, list(like), order)
+                 for w in range(nworkers)] if arm == "sparse" else None
+                for s in range(steps)]
         r = C.follow_steps(loss_fn, {p: np.asarray(like[p]) for p in like},
                            shards, step_masks, lrs=lrs,
                            momentum=float(tr["momentum"]),
                            weight_decay=float(tr["weight_decay"]),
-                           probe=probe)
-        shapes = {p: like[p] for p in order}
-        grad = _split_sorted(np.asarray(r["first_grad"]), shapes)
-        params = _split_sorted(np.asarray(r["params"]), shapes)
-        out[arm] = {"losses": r["losses"], "first_grad": grad,
-                    "delta": {p: params[p] - np.asarray(like[p])
-                              for p in order},
-                    # each worker's own first gradient, flat in the
-                    # program's order (the masks' order)
-                    "first_grad_workers": [
-                        np.concatenate([part[p].reshape(-1) for p in like])
-                        for part in (_split_sorted(np.asarray(g), shapes)
-                                     for g in r["first_grad_workers"])]}
+                           probe=probe, clock=parts)
+        with parts("gradients and parameters to the program's order"):
+            shapes = {p: like[p] for p in order}
+            delta = np.empty_like(r["params"])
+            params = _split_sorted(r["params"], shapes)
+
+            def change(task):
+                p, at, b = task
+                np.subtract(params[p].reshape(-1)[b],
+                            np.asarray(like[p]).reshape(-1)[b], out=delta[at])
+
+            harness.on_blocks(change, _pieces(shapes))
+            out[arm] = {"losses": r["losses"],
+                        "first_grad": _split_sorted(r["first_grad"], shapes),
+                        "delta": _split_sorted(delta, shapes),
+                        # each worker's own first gradient, flat in the
+                        # program's order (the masks' order)
+                        "first_grad_workers": [
+                            _reordered(g, like, order, list(like))
+                            for g in r["first_grad_workers"]]}
+    return out
+
+
+def _reordered(flat: np.ndarray, like: Dict[str, Any], src: list,
+               dst: list) -> np.ndarray:
+    """A flat vector that holds `like`'s leaves in the order `src`, in the
+    order `dst`: itself where the two orders are one, else one pass."""
+    if list(src) == list(dst):
+        return flat
+    size = {p: int(np.prod(like[p].shape)) for p in like}
+    at, off = {}, 0
+    for p in src:
+        at[p], off = off, off + size[p]
+    out, off = np.empty_like(flat), 0
+    for p in dst:
+        out[off:off + size[p]] = flat[at[p]:at[p] + size[p]]
+        off += size[p]
     return out
 
 
@@ -296,23 +382,15 @@ def _split_sorted(flat: np.ndarray, shapes: Dict[str, Any]) -> dict:
 
 # ------------------------------------------------------------ the verdict
 
-def total_norm_gap(mine: Dict[str, np.ndarray],
-                   ref: Dict[str, np.ndarray]) -> float:
-    """Gap between the two whole-vector norms over the reference's."""
-    def norm(tree):
-        return float(np.sqrt(sum(float(np.sum(np.square(
-            v.astype(np.float64)))) for v in tree.values())))
-    r = norm(ref)
-    return abs(norm(mine) - r) / r if r > 0 else float("inf")
-
-
 def compare(mine: Dict[str, dict], ref: Dict[str, dict],
-            head_leaf: Optional[str] = None) -> Dict[str, Any]:
+            head_leaf: Optional[str] = None,
+            table: Optional[dict] = None) -> Dict[str, Any]:
     """The numbers compared, from two sets of readings ({arm: readings}).
     `head_leaf` names the parameter nearest the loss (the configuration's
     `head_leaf`): its first gradient goes through the forward pass only, so
     its relative error is steady from seed to seed and is what a lower
-    precision moves most against its own spread."""
+    precision moves most against its own spread. Where `table` is a dict
+    it is given `leaf_table` of each arm's two quantities."""
     numbers: Dict[str, Any] = {}
     loss_gap, g_gap, d_gap, g_err = 0.0, (0.0, ""), (0.0, ""), 0.0
     first_gap = head_err = g_total = d_total = 0.0
@@ -323,20 +401,20 @@ def compare(mine: Dict[str, dict], ref: Dict[str, dict],
             loss_gap = max(loss_gap, gap)
             if i == 0:
                 first_gap = max(first_gap, gap)
+        grad = leaf_sums(mine[arm]["first_grad"], ref[arm]["first_grad"])
+        delta = leaf_sums(mine[arm]["delta"], ref[arm]["delta"])
+        if table is not None:
+            table[f"{arm}.first_grad"] = leaf_table(grad)
+            table[f"{arm}.delta"] = leaf_table(delta)
         if head_leaf:
-            head_err = max(head_err, rel_err(
-                {head_leaf: mine[arm]["first_grad"][head_leaf]},
-                {head_leaf: ref[arm]["first_grad"][head_leaf]}))
-        g_total = max(g_total, total_norm_gap(mine[arm]["first_grad"],
-                                              ref[arm]["first_grad"]))
+            head_err = max(head_err, rel_err({head_leaf: grad[head_leaf]}))
+        g_total = max(g_total, total_norm_gap(grad))
         if arm == "dense":
-            d_total = total_norm_gap(mine[arm]["delta"], ref[arm]["delta"])
-        g = leaf_norm_gap(mine[arm]["first_grad"], ref[arm]["first_grad"])
-        d = leaf_norm_gap(mine[arm]["delta"], ref[arm]["delta"])
+            d_total = total_norm_gap(delta)
+        g, d = leaf_norm_gap(grad), leaf_norm_gap(delta)
         g_gap = max(g_gap, (g[0], f"{arm}:{g[1]}"))
         d_gap = max(d_gap, (d[0], f"{arm}:{d[1]}"))
-        g_err = max(g_err, rel_err(mine[arm]["first_grad"],
-                                   ref[arm]["first_grad"]))
+        g_err = max(g_err, rel_err(grad))
     numbers["loss_gap_first"] = first_gap
     numbers["loss_gap"] = loss_gap
     if head_leaf:
@@ -355,15 +433,31 @@ def compare(mine: Dict[str, dict], ref: Dict[str, dict],
 def lost_entries(mine_sparse: dict, ref_sparse: dict, k: int) -> int:
     """Entries among the 2k largest of a worker's own reference gradient
     that are zero in that worker's residual after the first step, so were
-    sent, and never arrived in the momentum."""
+    sent, and never arrived in the momentum. The 2k-th largest magnitude
+    is an order statistic, so it is the same number whether one partition
+    finds it in the whole vector or, as here, in the 2k largest of each of
+    a few long stretches, which the threads take one each."""
     lost = 0
     for w, g in enumerate(ref_sparse["first_grad_workers"]):
-        g = np.abs(g)
         top = min(2 * k, g.size - 1)
-        thr = np.partition(g, g.size - top)[g.size - top]
-        lost += int(np.count_nonzero(
-            mine_sparse["zeroed1"][w] & ~mine_sparse["sent_any1"]
-            & (g >= thr) & (g > 0)))
+        stretches = max(1, min(harness.THREADS, g.size // (8 * max(top, 1))))
+        edges = np.linspace(0, g.size, stretches + 1).astype(np.int64)
+
+        def largest(i):
+            a = np.abs(g[edges[i]:edges[i + 1]])
+            return np.partition(a, a.size - top)[a.size - top:]
+
+        near = (np.concatenate(harness.on_blocks(largest, range(stretches)))
+                if stretches > 1 else np.abs(g))
+        thr = np.partition(near, near.size - top)[near.size - top]
+        zeroed, sent = mine_sparse["zeroed1"][w], mine_sparse["sent_any1"]
+
+        def count(b):
+            a = np.abs(g[b])
+            return int(np.count_nonzero(
+                zeroed[b] & ~sent[b] & (a >= thr) & (a > 0)))
+
+        lost += sum(harness.on_blocks(count, harness.blocks(g.size)))
     return lost
 
 
@@ -458,7 +552,7 @@ def run_check(cell: dict, seed: int, arms_first: Dict[str, dict],
               weights_host: Dict[str, np.ndarray], window: dict,
               expected_states: Optional[dict] = None,
               precision: str = "float32",
-              memory: Optional[dict] = None) -> tuple:
+              memory: Optional[dict] = None, parts=None) -> tuple:
     """The whole comparison after the window has closed and the trainers
     are freed, over the arms that ran. `arms_first[arm]` is a stand-in for
     `Arm` with `.name` and `.first`.
@@ -470,28 +564,39 @@ def run_check(cell: dict, seed: int, arms_first: Dict[str, dict],
     that at a configuration of hundreds of millions of parameters the host
     holds one arm's vectors at a time (PERF.md section 4). What the
     reference held on its chip goes into `memory["check"]` where a dict is
-    given. Returns (correct, numbers, lines, seconds)."""
+    given, and the seconds of each part into `parts` (`harness.Parts`:
+    the run's `check by part` line). Returns (correct, numbers, lines,
+    seconds)."""
     t0 = time.perf_counter()
+    parts = parts if parts is not None else harness.Parts()
     config, mix = cell["config_data"], cell["mix"]
-    probe = MemoryProbe()
+    with parts("the program's readings"):
+        probe = MemoryProbe()
     per_arm, sparse_only = [], {}
     for name, arm in arms_first.items():
-        mine, batches, masks = take_readings(arm, weights_host, config,
-                                             expected_states)
+        with parts("the program's readings"):
+            mine, batches, masks = take_readings(arm, weights_host, config,
+                                                 expected_states)
         ref = reference_readings(config, mix, seed, batches, masks,
-                                 weights_host, precision, probe=probe)
-        del batches, masks
-        per_arm.append(compare(mine, ref, config.get("head_leaf")))
+                                 weights_host, precision, probe=probe,
+                                 parts=parts)
+        with parts("compare"):
+            del batches, masks
+            per_arm.append(compare(mine, ref, config.get("head_leaf")))
         if name == "sparse":
-            sparse_only = dict(mine[name]["exact"])
-            sparse_only["lost"] = lost_entries(mine[name], ref[name],
-                                               int(arm.first["k"]))
-        del mine, ref
-    if memory is not None:
-        memory["check"] = probe.report()
-    numbers = worst(per_arm)
-    numbers.update(sparse_only)
-    numbers["compiles_in_window"] = int(window.get("compiles_in_window", 0))
-    numbers["failed_steps"] = int(window.get("failed_steps", 0))
-    ok, lines = judge(numbers, config["limits"])
+            with parts("lost_entries"):
+                sparse_only = dict(mine[name]["exact"])
+                sparse_only["lost"] = lost_entries(mine[name], ref[name],
+                                                   int(arm.first["k"]))
+        with parts("readings freed"):
+            del mine, ref
+    with parts("judge"):
+        if memory is not None:
+            memory["check"] = probe.report()
+        numbers = worst(per_arm)
+        numbers.update(sparse_only)
+        numbers["compiles_in_window"] = int(
+            window.get("compiles_in_window", 0))
+        numbers["failed_steps"] = int(window.get("failed_steps", 0))
+        ok, lines = judge(numbers, config["limits"])
     return ok, numbers, lines, time.perf_counter() - t0

@@ -17,12 +17,14 @@ way through.
 
 from __future__ import annotations
 
+import contextlib
 import gc
 import importlib
 import importlib.util
 import json
 import os
 import shutil
+import sys
 import tempfile
 import threading
 import time
@@ -78,6 +80,8 @@ def load_cell(name: str, root: str = ROOT) -> dict:
     cfg_entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
     with open(os.path.join(root, cfg_entry["file"])) as f:
         cell["config_data"] = json.load(f)
+    cell["config_data"]["reference_dir"] = os.path.join(
+        root, bench["paths"][0], "reference")
     mix_path = os.path.join(root, bench["paths"][0], "traffic",
                             cell["traffic"] + ".json")
     with open(mix_path) as f:
@@ -122,8 +126,17 @@ def load_peaks(device_kind: str) -> dict:
 
 
 def load_reference(config: dict):
-    return importlib.import_module(
-        f"benchmarks.reference.{config['reference']}")
+    """The configuration's plain reference, `reference/<reference>.py`
+    beside the cell's other files (`load_cell` says where), as a module of
+    the package `benchmarks.reference`, whose `common` it imports."""
+    name = f"benchmarks.reference.{config['reference']}"
+    path = os.path.join(config.get("reference_dir", ""),
+                        config["reference"] + ".py")
+    if name not in sys.modules and os.path.exists(path):
+        spec = importlib.util.spec_from_file_location(name, path)
+        sys.modules[name] = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(sys.modules[name])
+    return importlib.import_module(name)
 
 
 def load_layer_metric(metrics_dir: str, name: str):
@@ -168,6 +181,92 @@ class CompileLog:
         if event.startswith("/jax/compilation_cache/cache_"):
             key = event.rsplit("/", 1)[1]
             self.events[key] = self.events.get(key, 0) + 1
+
+
+def host_peak_gib() -> float:
+    """The process's peak resident size so far, GiB."""
+    import resource
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss / 2**20
+
+
+class Parts:
+    """Seconds by part: named stretches of ONE thread's wall clock, in the
+    order they first ran, so that they add up to the total they are printed
+    beside; with a `CompileLog`, the backend-compile seconds that fell
+    inside each part stand apart; and where the host's peak resident size
+    grew by a quarter GiB or more inside a part, what it grew to.
+    `with parts("name"):` times a stretch (the same name again adds to
+    it); `add` takes seconds measured elsewhere, with `host_peak_gib()`
+    before and after them where it was read."""
+
+    def __init__(self, compile_log: Optional[CompileLog] = None):
+        self.seconds: Dict[str, float] = {}
+        self.compile: Dict[str, float] = {}
+        self.host_peak: Dict[str, float] = {}
+        self._log = compile_log
+
+    def add(self, name: str, seconds: float, compile_s: float = 0.0,
+            host_peak: Optional[tuple] = None) -> None:
+        self.seconds[name] = self.seconds.get(name, 0.0) + seconds
+        if compile_s:
+            self.compile[name] = self.compile.get(name, 0.0) + compile_s
+        if host_peak is not None and host_peak[1] >= host_peak[0] + 0.25:
+            self.host_peak[name] = host_peak[1]
+
+    @contextlib.contextmanager
+    def __call__(self, name: str):
+        t0, peak0 = time.perf_counter(), host_peak_gib()
+        c0 = self._log.spent if self._log is not None else 0.0
+        try:
+            yield
+        finally:
+            self.add(name, time.perf_counter() - t0,
+                     (self._log.spent - c0) if self._log is not None else 0.0,
+                     (peak0, host_peak_gib()))
+
+    def record(self, total: float) -> dict:
+        """{"total_s", "parts": {name: seconds}, "compile": {name: seconds},
+        "host_peak_gib": {name: GiB}, "unnamed_s"}: what the line says, for
+        the result object."""
+        return {"total_s": total, "parts": dict(self.seconds),
+                "compile": dict(self.compile),
+                "host_peak_gib": dict(self.host_peak),
+                "unnamed_s": total - sum(self.seconds.values())}
+
+    def line(self, title: str, total: float) -> str:
+        named = sum(self.seconds.values())
+        parts = ", ".join(
+            f"{name} {secs:.2f}"
+            + (f" (compile {self.compile[name]:.2f})"
+               if name in self.compile else "")
+            + (f" [host peak {self.host_peak[name]:.1f} GiB]"
+               if name in self.host_peak else "")
+            for name, secs in self.seconds.items())
+        return (f"{title} {total:.2f}s = {parts}; named {named:.2f}s, "
+                f"unnamed {total - named:.2f}s "
+                f"({100.0 * (total - named) / max(total, 1e-9):.1f} %)")
+
+
+# Elements a thread takes at a time where the harness or the check goes over
+# a full-length vector on the host, and the threads the blocks are shared
+# among (numpy releases the interpreter's lock inside an operation). The
+# arithmetic is elementwise, so neither changes a bit, and no temporary is
+# longer than a block whatever the configuration's size.
+BLOCK = 1 << 20
+THREADS = 8
+
+
+def blocks(n: int) -> List[slice]:
+    return [slice(lo, min(n, lo + BLOCK)) for lo in range(0, n, BLOCK)]
+
+
+def on_blocks(fn, tasks) -> list:
+    """`fn(task)` for every task on `THREADS` threads; the results in the
+    tasks' order, whichever thread made them (an exception is raised
+    here)."""
+    from concurrent.futures import ThreadPoolExecutor
+    with ThreadPoolExecutor(max_workers=THREADS) as pool:
+        return list(pool.map(fn, tasks))
 
 
 # ------------------------------------------------------------------ the feed
@@ -252,16 +351,27 @@ def trainer_argv(config: dict, mix: dict, seed: int, arm: str, out_dir: str,
     """The argument list the CLI would get: `--config <file>` with the
     benchmark's own copy of the shipped configuration's fields. Everything
     but the seed and the output directory is fixed by the configuration and
-    the mix, so every run compiles the same programs."""
+    the mix, so every run compiles the same programs.
+
+    The data set is the program's synthetic one, sized by the worker: the
+    configuration's `dataset_kwargs` as they stand, and each entry of its
+    `dataset_kwargs_per_worker` times the mix's workers. A configuration
+    that gives neither is an image one: `synthetic_examples` is its
+    `examples_per_worker` a worker."""
     nworkers = int(mix["nworkers"])
     fields = dict(config["trainer"])
+    per_worker = config.get(
+        "dataset_kwargs_per_worker",
+        {"synthetic_examples": config["examples_per_worker"]})
+    dataset_kwargs = dict(config.get("dataset_kwargs", {}))
+    dataset_kwargs.update({key: int(v) * nworkers
+                           for key, v in per_worker.items()})
     fields.update(
         nworkers=nworkers, log_every=int(mix["log_every"]), seed=int(seed),
         compressor=(config["sparse_compressor"] if arm == "sparse"
                     else "none"),
         output_dir=out_dir, run_id=arm, trace="on" if trace else "off",
-        dataset_kwargs={"synthetic_examples":
-                        int(config["examples_per_worker"]) * nworkers})
+        dataset_kwargs=dataset_kwargs)
     path = os.path.join(out_dir, f"{arm}.json")
     with open(path, "w") as f:
         json.dump(fields, f)
@@ -356,41 +466,56 @@ class Arm:
 
 
 def build_arms(cell: dict, seed: int, out_dir: str, trace: bool,
-               first_steps: int = 3) -> Dict[str, Arm]:
+               first_steps: int = 3, parts: Optional[Parts] = None):
     """The trainers that the mix's round names, built as the CLI builds
-    them, with the benchmark's weights in place of their own."""
+    them, with the benchmark's weights in place of their own. Returns
+    (arms, the weights on the host in the program's order); the seconds of
+    each part go to `parts`."""
     import jax
-    from gaussiank_sgd_tpu import train as program
-
+    parts = parts if parts is not None else Parts()
+    with parts("program import"):
+        from gaussiank_sgd_tpu import train as program
     config, mix = cell["config_data"], cell["mix"]
     ref = load_reference(config)
     t0 = time.perf_counter()
-    weights = {p: _host(v) for p, v in jax.jit(
-        lambda k: ref.init_params(k, config))(
-            jax.random.PRNGKey(seed)).items()}
+    with parts("weights from seed"):
+        weights = {p: _host(v) for p, v in jax.jit(
+            lambda k: ref.init_params(k, config))(
+                jax.random.PRNGKey(seed)).items()}
     say(f"weights from seed {seed}: {len(weights)} leaves, "
         f"{sum(int(v.size) for v in weights.values())} parameters, "
         f"{time.perf_counter() - t0:.1f}s")
     arms = {}
     for name in cell["arms"]:
         t0 = time.perf_counter()
-        trainer = program.make_trainer(
-            trainer_argv(config, mix, seed, name, out_dir, trace))
-        give_weights(trainer, weights)
-        arms[name] = Arm(name, trainer, keep=first_steps)
+        with parts(f"{name} trainer"):
+            trainer = program.make_trainer(
+                trainer_argv(config, mix, seed, name, out_dir, trace))
+            give_weights(trainer, weights)
+            arms[name] = Arm(name, trainer, keep=first_steps)
         say(f"{name} trainer built in {time.perf_counter() - t0:.1f}s: "
             f"kernel={trainer.ts.kernel_mode} wire={trainer.ts.wire_format} "
             f"ef_numel={trainer.ts.ef_numel} k={trainer.plan.total_k} "
-            f"global_batch={trainer.cfg.global_batch_size}")
+            f"global_batch={trainer.cfg.global_batch_size}; in use on the "
+            f"fullest chip {_in_use()} bytes")
     order = leaves_by_path(arms[cell["arms"][0]].trainer.state.params)
     return arms, {p: weights[p] for p in order}
 
 
 # ------------------------------------------------------------- first steps
 
-def _host(x) -> np.ndarray:
+def _host(x, out: Optional[np.ndarray] = None) -> np.ndarray:
+    """A device array on the host (into `out` where one is given), in
+    slices on threads where it is long (`reference/common.py` `fetch`)."""
+    from .reference.common import fetch
+    return fetch(x, out)
+
+
+def _in_use() -> int:
+    """Bytes in use on the fullest device, by its allocator (arrays only)."""
     import jax
-    return np.asarray(jax.device_get(x))
+    return max(int((d.memory_stats() or {}).get("bytes_in_use", 0))
+               for d in jax.local_devices())
 
 
 def first_steps(arm: Arm, config: dict, steps: int = 3) -> None:
@@ -404,52 +529,89 @@ def first_steps(arm: Arm, config: dict, steps: int = 3) -> None:
     residual after the step AND something arrived at it in the momentum
     buffer, i.e. the buffer differs there from `mu * m + wd * p` of the
     state before. (Zero in the residual alone also holds where a worker's
-    gradient is exactly zero, a dead unit.)"""
+    gradient is exactly zero, a dead unit.)
+
+    Nothing is kept on the device beside the trainer's state while a step
+    runs (`arm.first["step_held_bytes"]` is what was in use then). After
+    a step the momentum and the parameters cross to the host in slices on
+    threads (`_host`), and the residual's zero test as one bool array made
+    by one fused pass on the device; the arrival test is the host's, in
+    float32 as it is written here, a block at a time on threads."""
+    import jax
     tr = arm.trainer
     n = tr.plan.total_numel
     nworkers = tr.mesh.size
     mu = np.float32(config["trainer"]["momentum"])
     wd = np.float32(config["trainer"]["weight_decay"])
-    losses, masks = [], []
+    sparse = arm.name == "sparse"
+    losses, masks, held = [], [], 0
+    inner = Parts()
     arm.first["k"] = int(tr.plan.total_k)
     arm.first["built"] = {"wire_format": tr.ts.wire_format,
                           "kernel_mode": tr.ts.kernel_mode,
                           "buckets": len(tr.plan.buckets)}
 
-    def flat_params():
-        import jax
-        leaves = jax.device_get(
-            list(leaves_by_path(tr._state.params).values()))
-        return np.concatenate([np.asarray(v).reshape(-1) for v in leaves])
+    @jax.jit
+    def zero_in(residual):
+        return residual.reshape(nworkers, -1)[:, :n] == 0
+
+    def state_to_host(with_zero_test: bool):
+        """(momentum, parameters flat in the program's order, the zero
+        test or None)."""
+        state = tr._state
+        leaves = list(leaves_by_path(state.params).values())
+        flat, off = np.empty((n,), leaves[0].dtype), 0
+        for leaf in leaves:
+            _host(leaf, flat[off:off + leaf.size].reshape(leaf.shape))
+            off += leaf.size
+        return (_host(state.opt_state["m"])[:n], flat,
+                _host(zero_in(state.ef_residual)) if with_zero_test
+                else None)
 
     prev_m = np.zeros((n,), np.float32)
-    prev_p = flat_params()
+    with inner("state to the host"):
+        _, prev_p, _ = state_to_host(False)
     for s in range(steps):
-        rec = arm.train(1)
+        held = max(held, _in_use())
+        with inner("the steps"):
+            rec = arm.train(1)
         losses.append(float(rec["loss"]))
         state = tr._state
-        res = state.ef_residual.reshape(nworkers, -1)
-        m = _host(state.opt_state["m"])[:n]
-        if arm.name == "sparse":
-            quiet = mu * prev_m + wd * prev_p
-            arrived = np.abs(m - quiet) > 1e-5 * np.abs(quiet) + 1e-12
-            masks.append(_host(res[:, :n] == 0) & arrived[None, :])
+        with inner("state to the host"):
+            m, p, zero = state_to_host(sparse)
+        if sparse:
+            mask = np.empty(zero.shape, bool)
+
+            def sent(b):
+                quiet = mu * prev_m[b] + wd * prev_p[b]
+                arrived = (np.abs(m[b] - quiet)
+                           > 1e-5 * np.abs(quiet) + 1e-12)
+                np.logical_and(zero[:, b], arrived[None, :], out=mask[:, b])
+
+            with inner("masks on the host"):
+                on_blocks(sent, blocks(n))
+            masks.append(mask)
         if s == 0:
             arm.first["momentum1"] = m
             # the dense baseline's residual is allocated and never read
-            arm.first["residual1"] = (_host(res) if arm.name == "sparse"
-                                      else None)
+            with inner("state to the host"):
+                arm.first["residual1"] = (
+                    _host(state.ef_residual).reshape(nworkers, -1)
+                    if sparse else None)
             arm.first["dtypes"] = {
                 "residual_dtype": str(state.ef_residual.dtype),
                 "momentum_dtype": str(state.opt_state["m"].dtype)}
             arm.first["residual_devices"] = len(
                 {d.id for d in state.ef_residual.sharding.device_set})
-        prev_m, prev_p = m, flat_params()
+        prev_m, prev_p = m, p
     arm.first["losses"] = losses
     arm.first["masks"] = masks
     arm.first["params"] = split_flat(
         prev_p, leaves_by_path(tr._state.params))
     arm.first["batches"] = list(arm.feed.kept)
+    arm.first["step_held_bytes"] = held
+    say(inner.line(f"{arm.name} first steps by part:",
+                   sum(inner.seconds.values())))
 
 
 def warm_up(arm: Arm, mix: dict, max_intervals: int = 12) -> dict:
@@ -659,6 +821,12 @@ def device_report(chips: int, arms: Optional[Dict[str, Arm]] = None) -> dict:
             "state_bytes": {a: state_bytes(arm) for a, arm in arms.items()},
             "at_rest_bytes": rest, "allocator_peak_bytes": peak,
             "step_program_bytes": progs,
+            # what was in use as a first step began, and with its program
+            "first_step_bytes": {
+                a: {"in_use": held, "with_program": (
+                    held + progs[a]["temp"] + progs[a]["fresh_output"])}
+                for a, held in ((a, int(arm.first.get("step_held_bytes", 0)))
+                                for a, arm in arms.items())},
             "window_peak_bytes": report["memory_peak_bytes"]}
     return report
 
@@ -684,6 +852,10 @@ def say_memory(device: dict) -> None:
         + ", ".join(f"{a} temp {_bytes(p['temp'], n)} fresh output "
                     f"{_bytes(p['fresh_output'], n)}"
                     for a, p in m["step_program_bytes"].items())
+        + "; as a first step began "
+        + ", ".join(f"{a} in use {_bytes(v['in_use'], n)} with its program "
+                    f"{_bytes(v['with_program'], n)}"
+                    for a, v in m["first_step_bytes"].items())
         + f"; window peak {_bytes(m['window_peak_bytes'], n)}")
 
 

@@ -162,53 +162,114 @@ def lr_at(step: int, base_lr: float, nworkers: int, warmup_steps: int) -> float:
 class _GradCalls:
     """The reference's one device program, `(tree, batch) -> (loss,
     gradient tree)`, compiled ahead of time for each batch shape so that
-    XLA's own account of it can be handed to `probe`."""
+    XLA's own account of it can be handed to `probe`. `start` dispatches a
+    call and returns at once; `wait` blocks until its result is there."""
 
-    def __init__(self, loss_fn, probe):
+    def __init__(self, loss_fn, probe, clock):
         self._fn = jax.jit(jax.value_and_grad(loss_fn))
         self._compiled = {}
         self._probe = probe or (lambda *a: None)
+        self._clock = clock
 
-    def grad(self, tree, batch):
+    def start(self, tree, batch):
         args = (tree, batch)
         key = (jax.tree_util.tree_structure(args),
                tuple((a.shape, str(a.dtype))
                      for a in jax.tree_util.tree_leaves(args)))
         if key not in self._compiled:
-            self._compiled[key] = self._fn.lower(*args).compile()
+            with self._clock("reference: gradient program compiled"):
+                self._compiled[key] = self._fn.lower(*args).compile()
             self._probe("grad_compiled",
                         self._compiled[key].memory_analysis())
-        self._probe("grad_call")
-        out = self._compiled[key](*args)
-        self._probe("grad_returned")
+        with self._clock("reference: gradient calls"):
+            self._probe("grad_call")
+            return self._compiled[key](*args)
+
+    def wait(self, out):
+        with self._clock("reference: gradient calls"):
+            out = jax.block_until_ready(out)
+            self._probe("grad_returned")
         return out
 
 
-# Elements of the flat vectors that the host's arithmetic takes at a time:
-# elementwise float32, so the blocks change no bit, and no temporary is longer
-# than this.
-_CHUNK = 1 << 24
+# Elements of the flat vectors that one thread of the host's arithmetic takes
+# at a time: elementwise float32, so the blocks change no bit; a block's
+# operands and temporaries stay in a core's cache from one operation to the
+# next, and no temporary is longer than this.
+_CHUNK = 1 << 20
+# Threads the blocks are shared among (numpy releases the interpreter's lock
+# inside an operation).
+_THREADS = 8
 
 
 def _chunks(n: int):
     return (slice(lo, min(n, lo + _CHUNK)) for lo in range(0, n, _CHUNK))
 
 
-def _to_host(tree: dict):
-    """A {path: device array} dict as one float32 numpy vector in
-    sorted-path order, leaf by leaf: no flat copy is made on the device."""
+# Elements of a device array that cross to the host in one copy, where the
+# array is longer than two of them.
+_SLICE = 1 << 25
+
+
+def fetch(x, out=None):
+    """The device array `x` on the host, as `np.asarray(x)` gives it (into
+    `out` where one is given), made quick for an array of gigabytes: it
+    crosses in slices of `_SLICE` elements, `_THREADS` copies in flight at
+    a time. On the chip's host one whole copy of 2.4 GB takes 1 to 7 s (one
+    thread of the runtime touches every fresh page of the destination,
+    and a fresh page is dear there), the same array in 128 MB slices on
+    threads 1 s. Each distinct shard is copied once, from the device that
+    holds it; the slices are of the shard flattened there."""
     import numpy as np
-    out = np.empty((sum(int(v.size) for v in tree.values()),), np.float32)
-    off = 0
-    for k in sorted(tree):
-        n = int(tree[k].size)
-        out[off:off + n] = np.asarray(tree[k], np.float32).reshape(-1)
-        off += n
+    from concurrent.futures import ThreadPoolExecutor
+    if out is None:
+        out = np.empty(x.shape, x.dtype)
+    if x.size < 2 * _SLICE:
+        out[...] = np.asarray(x)
+        return out
+    jobs, seen = [], set()
+    for shard in x.addressable_shards:
+        index = tuple((s.start, s.stop) for s in shard.index)
+        if index in seen:
+            continue
+        seen.add(index)
+        to = out[shard.index]
+        if not to.flags.c_contiguous:
+            raise ValueError(f"shard {shard.index} of an array of shape "
+                             f"{x.shape} is not one stretch of the host's")
+        flat, to = shard.data.reshape(-1), to.reshape(-1)
+        jobs += [(flat, to, lo) for lo in range(0, flat.size, _SLICE)]
+
+    def copy(job):
+        flat, to, lo = job
+        to[lo:lo + _SLICE] = np.asarray(flat[lo:lo + _SLICE])
+
+    with ThreadPoolExecutor(max_workers=_THREADS) as pool:
+        for _ in pool.map(copy, jobs):
+            pass
+    return out
+
+
+def _to_host(tree: dict, clock, out=None):
+    """A {path: device array} dict as one float32 numpy vector in
+    sorted-path order (`out` where one is given); each leaf is dropped
+    from `tree`, and so from the device, as soon as it is here."""
+    import numpy as np
+    with clock("reference: gradients to the host"):
+        if out is None:
+            out = np.empty((sum(int(v.size) for v in tree.values()),),
+                           np.float32)
+        off = 0
+        for k in sorted(tree):
+            leaf = tree.pop(k)
+            n = int(leaf.size)
+            fetch(leaf, out[off:off + n].reshape(leaf.shape))
+            off += n
     return out
 
 
 def follow_steps(loss_fn, params: dict, shards, masks, *, lrs, momentum,
-                 weight_decay, probe=None):
+                 weight_decay, probe=None, clock=None):
     """Follow `len(shards)` optimizer steps from `params`.
 
     shards[s][w] is worker w's batch at step s (whatever `loss_fn(params,
@@ -224,70 +285,125 @@ def follow_steps(loss_fn, params: dict, shards, masks, *, lrs, momentum,
     operations and the same order as the algorithm above is written
     (`acc = residual + g`, `sent = where(mask, acc, 0)`,
     `m = mu m + G + wd p`, `p = p - lr m`; IEEE add, multiply and divide
-    round the same in numpy as in XLA), `_CHUNK` elements at a time, so
-    that the host holds p, m, G, one residual a worker, the gradient in
-    hand and the first step's gradients, and nothing else of full length.
-    The device holds the parameter tree that the gradient is taken with
-    respect to (4 bytes a parameter), one gradient (4 more), one worker's
-    batch and what the gradient call needs while it runs; a gradient leaves
-    the device leaf by leaf as soon as it is there. Batches, masks and
-    `params` may be host arrays.
+    round the same in numpy as in XLA), `_CHUNK` elements at a time on
+    `_THREADS` threads (elementwise, so neither the blocks nor the threads
+    change a bit), so that the host holds p, m, G, one residual a worker,
+    the gradient in hand and the first step's gradients, and nothing else
+    of full length. The device holds the parameter tree that the gradient
+    is taken with respect to (4 bytes a parameter), one gradient (4 more),
+    a worker's batch and what the gradient call needs while it runs. A
+    gradient leaves the device as soon as it is there, and the next
+    worker's call runs on the device while the host does this worker's
+    arithmetic. Batches, masks and `params` may be host arrays.
 
     `probe(event, info=None)` is called with "grad_compiled" (info: XLA's
     `memory_analysis()` of the gradient program), "grad_call" before and
     "grad_returned" after each gradient call, and "step_end": for the
     harness's account of the check's memory and for the tests.
+    `clock(name)` is a context manager that times a stretch of this
+    thread's wall clock under `name` (`harness.Parts`): the run's
+    `check by part` line.
     """
+    import contextlib
+    from concurrent.futures import ThreadPoolExecutor
     import numpy as np
     f32 = np.float32
-    calls = _GradCalls(loss_fn, probe)
+    clock = clock or (lambda name: contextlib.nullcontext())
+    calls = _GradCalls(loss_fn, probe, clock)
     like = {k: tuple(params[k].shape) for k in params}
-    p = np.concatenate([np.asarray(params[k], f32).reshape(-1)
-                        for k in sorted(like)])
-    n = p.size
-    m = np.zeros_like(p)
     nworkers = len(shards[0])
-    residual = [np.zeros_like(p) for _ in range(nworkers)
+    n = sum(int(np.prod(shape)) for shape in like.values())
+    # a fresh page is dear on the chip's host and dearest to one thread: the
+    # full-length vectors are made once, first touched by the threads, and
+    # used again from step to step
+    p, m, G = (np.empty((n,), f32) for _ in range(3))
+    residual = [np.empty((n,), f32) for _ in range(nworkers)
                 if masks[0] is not None]
+    spare = None                # the gradient's buffer after the first step
     losses, first_grad, first_grads = [], None, []
     mu, wd, workers = f32(momentum), f32(weight_decay), f32(nworkers)
-    for s, step_shards in enumerate(shards):
-        tree = {k: jnp.asarray(v) for k, v in unflatten(p, like).items()}
-        G = np.zeros_like(p)
-        # the workers' mean gradient is read of the first step only
-        gsum = np.zeros_like(p) if s == 0 else None
-        loss = 0.0
-        for w, batch in enumerate(step_shards):
-            l, g = calls.grad(tree, batch)
-            loss += float(l) / nworkers
-            g_dev, g = g, _to_host(g)
-            del g_dev
-            if s == 0:
-                first_grads.append(g)
-            mask = None if masks[s] is None else np.asarray(masks[s][w])
-            for c in _chunks(n):
+
+    with ThreadPoolExecutor(max_workers=_THREADS) as pool:
+
+        def on_chunks(fn):
+            with clock("reference: step arithmetic on the host"):
+                for _ in pool.map(fn, _chunks(n)):
+                    pass
+
+        def laid_out(c):
+            # p from `params`, leaf by leaf in sorted-path order; m and the
+            # residuals 0
+            lo = 0
+            for k in sorted(like):
+                leaf = np.asarray(params[k], f32).reshape(-1)
+                a, b = max(c.start, lo), min(c.stop, lo + leaf.size)
+                if a < b:
+                    p[a:b] = leaf[a - lo:b - lo]
+                lo += leaf.size
+            m[c] = 0
+            for r in residual:
+                r[c] = 0
+
+        on_chunks(laid_out)
+        for s, step_shards in enumerate(shards):
+            with clock("reference: parameters to the device"):
+                tree = jax.block_until_ready(
+                    jax.device_put(unflatten(p, like)))
+            # the workers' mean gradient is read of the first step only;
+            # of one worker it is that worker's gradient, and no vector of
+            # its own (g + 0 and g / 1 are g)
+            gsum = np.empty((n,), f32) if s == 0 and nworkers > 1 else None
+
+            def cleared(c):
+                G[c] = 0
                 if gsum is not None:
-                    gsum[c] = gsum[c] + g[c]
-                if mask is None:
-                    G[c] = G[c] + g[c]
+                    gsum[c] = 0
+
+            on_chunks(cleared)
+            lr, loss = f32(lrs[s]), 0.0
+            ahead = calls.start(tree, step_shards[0])
+            for w in range(nworkers):
+                l, g = calls.wait(ahead)
+                loss += float(l) / nworkers
+                g = _to_host(g, clock, spare)
+                ahead = (calls.start(tree, step_shards[w + 1])
+                         if w + 1 < nworkers else None)
+                if s == 0:
+                    first_grads.append(g)
                 else:
-                    acc = residual[w][c] + g[c]
-                    sent = np.where(mask[c], acc, f32(0.0))
-                    residual[w][c] = acc - sent
-                    G[c] = G[c] + sent
-            del g
-        del tree
-        for c in _chunks(n):
-            G[c] = G[c] / workers
-            if gsum is not None:
-                gsum[c] = gsum[c] / workers
-            m[c] = mu * m[c] + G[c] + wd * p[c]
-            p[c] = p[c] - f32(lrs[s]) * m[c]
-        if gsum is not None:
-            first_grad = gsum
-        del G
-        losses.append(loss)
-        if probe is not None:
-            probe("step_end")
+                    spare = g
+                mask = None if masks[s] is None else np.asarray(masks[s][w])
+
+                def of_worker(c):
+                    if gsum is not None:
+                        np.add(gsum[c], g[c], out=gsum[c])
+                    if mask is None:
+                        np.add(G[c], g[c], out=G[c])
+                    else:
+                        acc = residual[w][c] + g[c]
+                        sent = np.where(mask[c], acc, f32(0.0))
+                        np.subtract(acc, sent, out=residual[w][c])
+                        np.add(G[c], sent, out=G[c])
+
+                on_chunks(of_worker)
+                del g
+            with clock("reference: vectors freed"):
+                del tree
+
+            def of_step(c):
+                np.divide(G[c], workers, out=G[c])
+                if gsum is not None:
+                    np.divide(gsum[c], workers, out=gsum[c])
+                m[c] = mu * m[c] + G[c] + wd * p[c]
+                p[c] = p[c] - lr * m[c]
+
+            on_chunks(of_step)
+            if s == 0:
+                first_grad = gsum if gsum is not None else first_grads[0]
+            if probe is not None:
+                probe("step_end")
+            losses.append(loss)
+        with clock("reference: vectors freed"):
+            del m, residual, G, spare
     return {"losses": losses, "first_grad": first_grad, "params": p,
             "first_grad_workers": first_grads}

@@ -32,8 +32,11 @@ MARKS = []
 
 
 def mark(label: str) -> None:
-    """Set-up by part; printed once the run is known not to be refused."""
-    MARKS.append(f"[{time.perf_counter() - T_START:8.3f}s] {label}")
+    """The stretch of start-up that has just ended, with the clock and the
+    host's peak resident size: the first parts of the run's `set-up by
+    part` line."""
+    from benchmarks import harness
+    MARKS.append((label, time.perf_counter(), harness.host_peak_gib()))
 
 
 def refuse(why: str) -> None:
@@ -58,7 +61,7 @@ def main(argv=None) -> int:
     cell = harness.load_cell(args.workload)
 
     import jax
-    mark("jax imported")
+    mark("python and jax import")
     if jax.default_backend() != "tpu":
         refuse(f"JAX's default backend is {jax.default_backend()!r}, not a "
                f"TPU; a CPU's number is never written under a device "
@@ -72,15 +75,13 @@ def main(argv=None) -> int:
     except KeyError as e:
         refuse(str(e))
 
-    mark(f"backend up: {jax.device_count()} x {kind}")
+    mark("backend start")
     from gaussiank_sgd_tpu.compile_cache import enable_compile_cache
     from gaussiank_sgd_tpu import train as _program  # noqa: F401
-    mark("program imported")
+    mark("program import")
     cache_dir = enable_compile_cache()
     compile_log = harness.CompileLog()
     say = harness.say
-    for line in MARKS:
-        say(line)
     say(f"cell {cell['name']}: config {cell['config']}, mix "
         f"{cell['traffic']}, {cell['chips']} chip(s) of {kind!r}; seed "
         f"{args.seed}, {args.seconds:g}s, trace {args.trace}; compile cache "
@@ -100,16 +101,24 @@ def _run(args, cell, peaks, compile_log, out_dir) -> int:
     from benchmarks import check, harness, trace_reduce
     say = harness.say
     config, mix = cell["config_data"], cell["mix"]
+    setup = harness.Parts(compile_log)
+    t_before, peak_before = T_START, 0.0
+    for label, t, peak in MARKS:
+        setup.add(label, t - t_before, host_peak=(peak_before, peak))
+        t_before, peak_before = t, peak
+    setup.add("to the run", time.perf_counter() - t_before)
     arms, weights_host = harness.build_arms(cell, args.seed, out_dir,
-                                            bool(args.trace))
+                                            bool(args.trace), parts=setup)
     t_built = time.perf_counter()
     for name, arm in arms.items():
-        harness.first_steps(arm, config)
+        with setup(f"{name} first steps"):
+            harness.first_steps(arm, config)
         say(f"{name} first steps: losses "
             f"{[round(x, 6) for x in arm.first['losses']]}")
     t_first = time.perf_counter()
     for name, arm in arms.items():
-        rec = harness.warm_up(arm, mix)
+        with setup(f"{name} warm-up"):
+            rec = harness.warm_up(arm, mix)
         f = arm.first
         say(f"{name} warm after {f['warm_intervals']} log interval(s): step "
             f"{f['warm_step_ms']:.3f} ms, {arm.steps_per_block} steps "
@@ -122,6 +131,7 @@ def _run(args, cell, peaks, compile_log, out_dir) -> int:
         f"{time.perf_counter() - t_first:.1f}s; compile seconds spent "
         f"{compile_s:.1f}, saved by the cache {saved_s:.1f}, cache events "
         f"{compile_log.events}")
+    say(setup.line("set-up by part:", setup_s))
 
     trace_dir = os.path.join(out_dir, "trace") if args.trace else None
     window = harness.measure(arms, mix, args.seconds, compile_log, trace_dir)
@@ -173,11 +183,15 @@ def _run(args, cell, peaks, compile_log, out_dir) -> int:
         "compiles_in_window": compile_log.in_window,
         "failed_steps": sum(t["skipped"] for t in totals.values())}
     harness.close_arms(arms)
+    checked = harness.Parts(compile_log)
     ok, numbers, lines, secs = check.run_check(
         cell, args.seed, firsts, weights_host, window_info,
-        memory=device["memory"])
+        memory=device["memory"], parts=checked)
     harness.say_check_memory(device)
     say(f"the reference and the comparison took {secs:.1f}s (not in setup_s)")
+    say(checked.line("check by part:", secs))
+    device["seconds"] = {"set_up": setup.record(setup_s),
+                         "check": checked.record(secs)}
 
     if args.trace:
         metrics = {}
@@ -201,6 +215,9 @@ def _run(args, cell, peaks, compile_log, out_dir) -> int:
     for line in lines:
         say(line)
         print(line, file=sys.stderr, flush=True)
+    say(f"to the result line {time.perf_counter() - T_START:.1f}s from "
+        f"process start; the host's peak resident size "
+        f"{harness.host_peak_gib():.2f} GiB")
     print(json.dumps(result), flush=True)
     return 0
 

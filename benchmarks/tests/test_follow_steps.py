@@ -1,7 +1,10 @@
 """`reference/common.py` `follow_steps`, which PR 26 rewrote to hold a
-bounded share of the chip: against the implementation it replaces (kept
-here, word for word), bit for bit; and what is alive on the device while it
-runs."""
+bounded share of the chip and PR 27 to do the host's arithmetic in blocks on
+threads, the next worker's gradient call running meanwhile: against the
+implementation up to PR 25 (kept here, word for word), bit for bit; and
+what is alive on the device while it runs."""
+
+import sys
 
 
 import jax
@@ -87,17 +90,20 @@ def tiny(tiny_root):
     return loss_fn, params, shards, masks, n
 
 
+@pytest.mark.parametrize("threads", [1, 8])
 @pytest.mark.parametrize("workers", [1, 4])
 @pytest.mark.parametrize("arm", ["sparse", "dense"])
 def test_the_new_follow_steps_reads_what_the_old_one_read(tiny, workers, arm,
+                                                          threads,
                                                           monkeypatch):
     """Bit for bit: the step's arithmetic is IEEE float32 add, multiply and
     divide in the same order, in numpy on the host where it was XLA's on
     the device one operation at a time, and the gradient program is the
     same program. The host takes the vectors a chunk at a time: here in
-    sixteen chunks, the last one short."""
+    sixteen chunks, the last one short, on one thread and on eight."""
     loss_fn, params, shards, masks, n = tiny
     monkeypatch.setattr(C, "_CHUNK", 4099)
+    monkeypatch.setattr(C, "_THREADS", threads)
     assert n // 4099 == 15 and n % 4099
     shards = [row[:workers] for row in shards]
     masks = ([row[:workers] for row in masks] if arm == "sparse"
@@ -120,6 +126,30 @@ def test_the_new_follow_steps_reads_what_the_old_one_read(tiny, workers, arm,
     # and it moved: a comparison of two vectors that stood still is none
     assert np.abs(new["params"] - C.flatten(
         {k: jnp.asarray(v) for k, v in params.items()})).max() > 1e-4
+
+
+def test_more_threads_than_cores_change_no_bit(tiny, monkeypatch):
+    """The threads write disjoint chunks of the shared vectors and nothing
+    else: thirty-two of them on 251-element chunks, the interpreter
+    switching between them as often as it can, read what one thread
+    reads."""
+    loss_fn, params, shards, masks, n = tiny
+    kw = dict(lrs=[0.05, 0.06, 0.07], momentum=0.9, weight_decay=5e-4)
+    monkeypatch.setattr(C, "_CHUNK", 251)
+    monkeypatch.setattr(C, "_THREADS", 1)
+    one = C.follow_steps(loss_fn, params, shards, masks, **kw)
+    monkeypatch.setattr(C, "_THREADS", 32)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        many = C.follow_steps(loss_fn, params, shards, masks, **kw)
+    finally:
+        sys.setswitchinterval(interval)
+    assert many["losses"] == one["losses"]
+    for key in ("first_grad", "params"):
+        np.testing.assert_array_equal(many[key], one[key], key)
+    for a, b in zip(many["first_grad_workers"], one["first_grad_workers"]):
+        np.testing.assert_array_equal(a, b)
 
 
 # ------------------------------------------ a loss without the tiny VGG
@@ -211,3 +241,38 @@ def test_the_checks_memory_probe_counts_the_gradient_program():
     assert set(got) == {"at_start_bytes", "arrays_peak_bytes",
                         "grad_call_temp_bytes",
                         "grad_call_fresh_output_bytes", "peak_bytes"}
+
+
+# ------------------------------------------------ long arrays to the host
+
+@pytest.mark.parametrize("shape,spec", [
+    ((3, 5, 7, 11), None),                      # a leaf, one device
+    ((4 * 1000,), "dp"),                        # a residual, a row a device
+    ((4096,), ()),                              # a momentum on four devices
+])
+def test_fetch_reads_what_asarray_reads(shape, spec, monkeypatch):
+    """In slices (here of 100 elements, the last one short) on threads,
+    each distinct shard once, into a buffer of the caller's or a new
+    one."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    monkeypatch.setattr(C, "_SLICE", 100)
+    rng = np.random.default_rng(8)
+    host = rng.standard_normal(shape).astype(np.float32)
+    if spec is None:
+        x = jnp.asarray(host)
+    else:
+        mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
+        x = jax.device_put(host, NamedSharding(mesh, P(*([spec] if spec
+                                                         else []))))
+        assert len(x.addressable_shards) == 4
+    got = C.fetch(x)
+    assert got.dtype == np.float32 and got.shape == host.shape
+    np.testing.assert_array_equal(got, host)
+    mine = np.full(shape, 7.0, np.float32)
+    assert C.fetch(x, mine) is mine
+    np.testing.assert_array_equal(mine, host)
+    mask = jnp.asarray(host > 0)
+    np.testing.assert_array_equal(C.fetch(mask), host > 0)
+    # a short array crosses whole
+    monkeypatch.setattr(C, "_SLICE", 1 << 25)
+    np.testing.assert_array_equal(C.fetch(x), host)

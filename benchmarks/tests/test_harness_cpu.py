@@ -52,6 +52,43 @@ def test_a_run_end_to_end_on_one_device(tiny_root, capsys):
     assert set(memory["check"]) >= {"arrays_peak_bytes", "peak_bytes"}
     assert "device memory, fullest chip" in out
     assert "device memory, the check:" in out
+    assert memory["first_step_bytes"]["sparse"]["with_program"] >= \
+        memory["first_step_bytes"]["sparse"]["in_use"] >= 0
+    # where the seconds went, by part: two lines, the same in the result,
+    # and the parts add up to the totals they stand beside
+    seconds = result["device"]["seconds"]
+    assert seconds["set_up"]["total_s"] == \
+        result["metrics"]["setup_s"]["value"]
+    wanted = {"set_up": ["to the run", "weights from seed", "dense trainer",
+                         "sparse trainer", "dense first steps",
+                         "sparse first steps", "dense warm-up",
+                         "sparse warm-up"],
+              "check": ["the program's readings",
+                        "rows and masks to the reference's order",
+                        "reference: gradient program compiled",
+                        "reference: parameters to the device",
+                        "reference: gradient calls",
+                        "reference: gradients to the host",
+                        "reference: step arithmetic on the host",
+                        "gradients and parameters to the program's order",
+                        "compare", "lost_entries", "judge"]}
+    for title, key in (("set-up by part:", "set_up"),
+                       ("check by part:", "check")):
+        line = [l for l in out.splitlines() if l.startswith(title)]
+        assert len(line) == 1, title
+        rec = seconds[key]
+        assert set(wanted[key]) <= set(rec["parts"]), rec["parts"]
+        assert all(name in line[0] for name in rec["parts"])
+        assert f"{title} {rec['total_s']:.2f}s" in line[0]
+        named = sum(rec["parts"].values())
+        assert rec["unnamed_s"] == pytest.approx(rec["total_s"] - named)
+        # within 2 %, and at this size the interpreter's few hundredths
+        # of a second between the parts
+        assert abs(rec["unnamed_s"]) <= 0.02 * rec["total_s"] + 0.05, line[0]
+    # compilation inside a part stands apart: the step programs' is in the
+    # first steps, the reference's in its own part
+    assert "(compile " in out.split("set-up by part:")[1].splitlines()[0]
+    assert "sparse first steps by part:" in out
 
 
 def test_a_run_end_to_end_on_four_devices(tiny_root, capsys):
@@ -335,6 +372,17 @@ def test_calibrate_reads_what_a_run_compares(tiny_root, sound):
     assert set(row["leaves"]["sound"]) == {
         "dense.first_grad", "dense.delta", "sparse.first_grad",
         "sparse.delta"}
+    assert set(row["leaves"]["control"]) == set(row["leaves"]["sound"])
+    # what the seed cost, by part, on each side
+    assert set(row["seconds"]) == {"program", "reference", "control"}
+    for side, total in (("program", row["program_s"]),
+                        ("reference", row["reference_s"]),
+                        ("control", row["control_s"])):
+        rec = row["seconds"][side]
+        assert rec["total_s"] == total and rec["parts"]
+        assert abs(rec["unnamed_s"]) <= 0.05 * total + 0.05, (side, rec)
+    assert "reference: gradient calls" in row["seconds"]["control"]["parts"]
+    assert "sparse first steps" in row["seconds"]["program"]["parts"]
 
 
 # ------------------------------------------------------ driven by data
