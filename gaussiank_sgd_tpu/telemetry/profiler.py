@@ -54,16 +54,28 @@ class ProfilerSession:
             self._logger.info("profiler %s at step %d -> %s", action, step,
                               self.logdir)
 
+    def _start_due(self, step: int) -> bool:
+        return (not self.active and not self._done
+                and self.start_step <= step < self.stop_step)
+
+    def _stop_due(self, step: int) -> bool:
+        return self.active and step >= self.stop_step
+
+    def transition_due(self, step: int) -> bool:
+        """Whether :meth:`maybe_transition` would start or stop the trace
+        at ``step``: a loop that keeps a step in flight lets none run
+        across the window's edge."""
+        return self._start_due(step) or self._stop_due(step)
+
     def maybe_transition(self, step: int) -> None:
         """Start/stop the trace according to the armed window."""
         import jax
 
-        if (not self.active and not self._done
-                and self.start_step <= step < self.stop_step):
+        if self._start_due(step):
             jax.profiler.start_trace(self.logdir)
             self.active = True
             self._emit("start", step)
-        elif self.active and step >= self.stop_step:
+        elif self._stop_due(step):
             jax.profiler.stop_trace()
             self.active = False
             self._done = True

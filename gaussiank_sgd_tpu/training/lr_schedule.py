@@ -15,6 +15,7 @@ from __future__ import annotations
 from typing import Callable, Sequence
 
 import jax.numpy as jnp
+import numpy as np
 
 
 def warmup_milestone_schedule(base_lr: float, nworkers: int,
@@ -29,14 +30,22 @@ def warmup_milestone_schedule(base_lr: float, nworkers: int,
     """
     peak = base_lr * max(1, nworkers)
     warmup_steps = max(1, int(warmup_epochs * steps_per_epoch))
-    boundaries = jnp.asarray([int(m * total_steps) for m in milestones])
+    host_boundaries = np.asarray([int(m * total_steps) for m in milestones])
+    boundaries = jnp.asarray(host_boundaries)
 
     def schedule(step):
-        step = jnp.asarray(step, jnp.float32)
-        frac = jnp.clip(step / warmup_steps, 0.0, 1.0)
-        lr = base_lr + (peak - base_lr) * frac if nworkers > 1 else jnp.full_like(
+        # A Python number (the trainer's log line) is worked out on the
+        # host: the same expression in eager jnp is a handful of small
+        # device programs, and they queue behind a step in flight and hold
+        # the caller until it has ended. An array or a tracer (inside the
+        # jitted step) takes jnp.
+        xp, bnd = ((np, host_boundaries) if isinstance(step, (int, float))
+                   else (jnp, boundaries))
+        step = xp.asarray(step, xp.float32)
+        frac = xp.clip(step / warmup_steps, 0.0, 1.0)
+        lr = base_lr + (peak - base_lr) * frac if nworkers > 1 else xp.full_like(
             frac, base_lr)
-        n_decays = jnp.sum(step >= boundaries)
+        n_decays = xp.sum(step >= bnd)
         return lr * (decay ** n_decays)
 
     return schedule

@@ -12,7 +12,6 @@ from __future__ import annotations
 import logging
 import os
 import sys
-import time
 from typing import Any, Dict, Optional
 
 from ..telemetry.exporters import JSONLExporter
@@ -53,35 +52,28 @@ class JSONLWriter(JSONLExporter):
 
 
 class PhaseTimers:
-    """Wall-clock phase timers: io / step (fwd+bwd+comm fused under XLA).
+    """Wall-clock phase means: io / step (fwd+bwd+comm fused under XLA).
 
     Reference parity: the io/fwd/bwd/comm breakdown in ``dl_trainer.py``
     (SURVEY.md §3.2, §5 Tracing). One jitted program owns fwd+bwd+comm here,
     so the honest breakdown is io vs device-step; finer slicing comes from
-    ``jax.profiler`` traces (trainer.profile hooks), not host timers.
+    ``jax.profiler`` traces (trainer.profile hooks), not host timers. The
+    trainer times each phase itself: with one step in flight a step's
+    phases are not one stretch of the clock (its dispatch, then the step
+    before it, then its sync).
     """
 
     def __init__(self):
         self.sums: Dict[str, float] = {}
         self.counts: Dict[str, int] = {}
-        self._t0: Optional[float] = None
-        self._phase: Optional[str] = None
 
-    def start(self, phase: str) -> None:
-        now = time.perf_counter()
-        if self._phase is not None:
-            self.sums[self._phase] = self.sums.get(self._phase, 0.0) + (
-                now - self._t0)
-            self.counts[self._phase] = self.counts.get(self._phase, 0) + 1
-        self._phase, self._t0 = phase, now
-
-    def stop(self) -> None:
-        self.start("_idle")
-        self._phase = None
+    def add(self, phase: str, seconds: float) -> None:
+        """One occurrence of ``phase``."""
+        self.sums[phase] = self.sums.get(phase, 0.0) + seconds
+        self.counts[phase] = self.counts.get(phase, 0) + 1
 
     def means(self) -> Dict[str, float]:
-        return {k: self.sums[k] / max(1, self.counts[k])
-                for k in self.sums if not k.startswith("_")}
+        return {k: self.sums[k] / max(1, self.counts[k]) for k in self.sums}
 
     def reset(self) -> None:
         self.sums.clear()

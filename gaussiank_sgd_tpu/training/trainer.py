@@ -25,7 +25,7 @@ import math
 import os
 import threading
 import time
-from typing import Any, Dict, Optional
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -59,6 +59,16 @@ from ..telemetry.tracing import TraceContext
 
 # what Trainer._span hands out with tracing off
 _NO_SPAN = contextlib.nullcontext()
+
+
+class _Flight(NamedTuple):
+    """A step program that was dispatched and has not been waited for."""
+
+    done: int           # the global step it ends
+    metrics: Any        # its StepMetrics: futures until the sync
+    t0: float           # perf_counter as its dispatch opened
+    io_s: float         # pulling and placing its batch
+    dispatch_s: float
 
 
 def _dtype_of(name: str):
@@ -123,6 +133,8 @@ class Trainer:
         self._peak_flops: Optional[float] = None
         self._mfu_probed = False
         self.timers = PhaseTimers()
+        # perf_counter at the end of the last step the loop waited for
+        self._t_synced = 0.0
         # phase-breakdown compile hygiene (ADVICE r4): programs whose first
         # dispatch (= jit compile) already happened, and whether the current
         # log interval contains such a first dispatch
@@ -476,6 +488,9 @@ class Trainer:
         self._state = new_state
         self._invalidate_data_iter()
         self.__dict__.pop("_step_cache", None)
+        # a step the loop had dispatched ahead ran on the state this one
+        # replaces: it is dropped with it, never waited for nor reported
+        self._flight: Optional[_Flight] = None
         # external assignment starts a NEW trajectory: steps re-reached
         # after a resume-from-older/rollback may collide with sealed
         # checkpoints of the old one, which must be overwritten, not
@@ -750,29 +765,72 @@ class Trainer:
     # ------------------------------------------------------------------
     def train(self, num_iters: int, data_iter=None) -> Dict[str, float]:
         """Run ``num_iters`` optimizer steps (reference ``trainer.train(n)``,
-        SURVEY.md §1.1 L4->L3 interface). Returns mean metrics.
+        SURVEY.md §1.1 L4->L3 interface). Returns the last log record.
+
+        The loop keeps ONE step in flight: iteration t pulls and places
+        batch t and dispatches step t on the state that step t-1 returns,
+        not yet ready, and only then waits for step t-1, reads its scalars
+        and logs it. The device has its next program and input queued while
+        it runs the last one; the programs, their arguments and their order
+        are those of a loop that waits for every step. The call pulls
+        exactly ``num_iters`` batches and returns when all its steps have
+        ended. It does not run ahead of a step after which the host acts on
+        the finished state (:meth:`_host_acts_after`, and the call's last).
 
         With tracing on every iteration is one ``iteration`` span whose
         leaf children each hold exactly one thing (docs/OBSERVABILITY.md):
-        ``data_wait``, ``h2d``, ``step_dispatch``, ``step_sync``,
-        ``step_readback`` and, at a log step, ``log_step`` and
-        ``trace_drain``; what is left of the iteration beside them is the
-        loop's own bookkeeping."""
-        losses, last = [], {}
-        for _ in range(num_iters):
+        ``data_wait``, ``h2d``, ``step_dispatch`` for the step it
+        dispatches; ``step_sync``, ``step_readback`` and, at a log step,
+        ``log_step`` and ``trace_drain`` for each step it waits for (the
+        one before; its own too where the loop does not run ahead); what
+        is left of the iteration beside them is the loop's own
+        bookkeeping."""
+        last: Dict[str, float] = {}
+        ended: Optional[_Flight] = None
+        # a call that raised may have left a step in flight: its state is
+        # the trainer's, its scalars are lost
+        self._flight = None
+        for i in range(num_iters):
             # cached step — no device sync
             step = self.step if not hasattr(self, "_step_cache") else \
                 self._step_cache
             with self._span("iteration", step_num=step + 1):
-                m = self._iteration(step, data_iter)
-                losses.append(m)
-                if (step + 1) % self.cfg.log_every == 0:
-                    last = self._log_step(step + 1, m)
-        if losses and not last:
+                behind = self._flight
+                self._flight = self._dispatch(step, data_iter)
+                if behind is not None:
+                    last = self._finish(behind, ahead=1) or last
+                    ended = behind
+                # None where a rollback at that log step dropped it with
+                # the state it restored over (the state setter)
+                flight = self._flight
+                if flight is not None and (
+                        i + 1 == num_iters or self.shutdown.requested
+                        or self._host_acts_after(flight.done)):
+                    self._flight = None
+                    last = self._finish(flight, ahead=0) or last
+                    ended = flight
+        if ended is not None and not last:
             with self._span("log_step"):
-                last = self._log_train(self.step, losses[-1], quiet=True)
+                last = self._log_train(ended.done, ended.metrics, quiet=True)
             self._drain_spans()
         return last
+
+    def _host_acts_after(self, done: int) -> bool:
+        """Whether the host reads or replaces the finished state once
+        global step ``done`` has ended, so that step ``done + 1`` must not
+        be dispatched before: a cadence save; a log step at which the
+        policy engine may rebuild the programs or the phase probes time
+        programs of their own on the device; a profiler window that opens
+        or closes there. Known from the step number and the configuration
+        alone."""
+        cfg = self.cfg
+        if cfg.save_every_steps and done % cfg.save_every_steps == 0:
+            return True
+        if done % cfg.log_every == 0 and (self.engine is not None
+                                          or cfg.phase_timing):
+            return True
+        return (self.profiler is not None
+                and self.profiler.transition_due(done))
 
     def _input_ready(self) -> Optional[int]:
         """Batches waiting in the trainer's own prefetch queue (whoever
@@ -780,23 +838,23 @@ class Trainer:
         it = getattr(self, "_iter", None)
         return it.ready() if it is not None else None
 
-    def _iteration(self, step: int, data_iter):
-        """One optimizer step from global step ``step``: wait for the
-        batch, place it, dispatch the program, wait for it, read its
-        scalars back, and do what the step boundary owes (cadence save,
-        preemption). Returns the step's metrics."""
+    def _dispatch(self, step: int, data_iter) -> _Flight:
+        """The first half of the optimizer step from global step ``step``:
+        wait for the batch, place it, dispatch the program on the state as
+        it stands (ready or not). ``_state`` and ``_step_cache`` move here,
+        together."""
         cfg = self.cfg
         # resolved per iteration: a rollback mid-run invalidates the
         # cached iterator, and the rebuilt one must be picked up here
         it = data_iter if data_iter is not None else self._train_iter()
-        self.timers.start("io")
+        t_io = time.perf_counter()
         with (self.trace.span("data_wait", ready=self._input_ready())
               if self.trace is not None else _NO_SPAN):
             batch = next(it)
         with self._span("h2d"):
             batch = shard_batch(self.mesh, batch, spec=self._batch_spec)
         self._probe_batch = batch      # for _phase_breakdown at log time
-        self.timers.start("step")
+        io_s = time.perf_counter() - t_io
         if self.profiler is not None:
             # jax.profiler trace window (SURVEY.md §5 Tracing rebuild
             # note: real fwd/bwd/comm breakdown comes from device
@@ -823,16 +881,34 @@ class Trainer:
             if key not in self._dispatched_fns:
                 self._dispatched_fns.add(key)
                 self._interval_has_compile = True
-        t_step0 = time.perf_counter()
+        t0 = time.perf_counter()
         with self._span("step_dispatch"):
             self._state, m = fn(self._state, batch)
-        with self._span("step_sync"):
-            # jit dispatch is async: sync before stopping the timer so
-            # step_s/ex-s measure device work, not dispatch latency
+        self._step_cache = step + 1
+        return _Flight(step + 1, m, t0, io_s, time.perf_counter() - t0)
+
+    def _finish(self, flight: _Flight, ahead: int) -> Dict[str, float]:
+        """The second half, for a step that was dispatched: wait for it,
+        read its scalars back, do what its boundary owes (cadence save,
+        preemption) and, at a log step, log it. ``ahead`` is the number of
+        step programs dispatched behind it (0 or 1). Returns the log
+        record, empty where it is no log step."""
+        cfg, done, m = self.cfg, flight.done, flight.metrics
+        t_sync = time.perf_counter()
+        with self._span("step_sync", ahead=ahead):
+            # jit dispatch is async: the step has ended when its loss is
+            # ready
             jax.block_until_ready(m.loss)
-        step_wall = time.perf_counter() - t_step0
-        self._step_cache = done = step + 1
-        self.timers.stop()
+        now = time.perf_counter()
+        # one step's end to the next; from its own dispatch for a step
+        # dispatched after the last one had ended. So ex/s is the loop's
+        # rate, run ahead or not
+        step_wall = now - max(flight.t0, self._t_synced)
+        self._t_synced = now
+        # io_s + step_s: what the host spent on the step, on its input and
+        # on dispatching and awaiting its program
+        self.timers.add("io", flight.io_s)
+        self.timers.add("step", flight.dispatch_s + now - t_sync)
         with self._span("step_readback"):
             # m.loss is already synced above, so these per-step host reads
             # cost a device_get of ready scalars, not a sync. Guard-off
@@ -855,6 +931,8 @@ class Trainer:
                                      sk)
         pending = (self.monitor.should_rollback()
                    if self.monitor is not None else None)
+        # the loop does not run ahead of a cadence save's step, so the
+        # state here is the one after ``done``
         if cfg.save_every_steps and done % cfg.save_every_steps == 0:
             if pending is None:
                 path = self._save_checkpoint()
@@ -866,9 +944,11 @@ class Trainer:
                 self.logger.warning(
                     "cadence save at step %d suppressed: rollback "
                     "pending (%s)", done, pending)
-        if self.shutdown.requested:
+        if self.shutdown.requested and not ahead:
             # preemption contract (docs/RESILIENCE.md): seal a
-            # checkpoint at the step boundary, then exit cleanly
+            # checkpoint at the step boundary, then exit cleanly. With a
+            # step in flight that boundary is the end of that step: the
+            # loop waits for it next
             path = self._save_checkpoint()
             self.bus.publish({"event": "preempt", "step": done,
                               "checkpoint": path})
@@ -876,7 +956,9 @@ class Trainer:
                 "shutdown requested: checkpointed %s at step %d",
                 path, done)
             raise TrainingPreempted(done, path)
-        return m
+        if done % cfg.log_every == 0:
+            return self._log_step(done, m)
+        return {}
 
     def _log_step(self, done: int, m) -> Dict[str, float]:
         """What every ``log_every``-th iteration adds: the train record,
@@ -1030,8 +1112,10 @@ class Trainer:
         loss = float(jax.device_get(m.loss))
         means = self.timers.means()
         lr = float(self.schedule(step))
+        # by the step's number: the state may be a later step's by now
+        epoch = step // self.steps_per_epoch
         rec = {
-            "event": "train", "step": step, "epoch": self.epoch,
+            "event": "train", "step": step, "epoch": epoch,
             "loss": loss, "lr": lr,
             "grad_norm": float(jax.device_get(m.grad_norm)),
             "num_selected": float(jax.device_get(m.num_selected)),
@@ -1112,7 +1196,7 @@ class Trainer:
                 phases += f" comm={1e3 * rec['comm_update_s']:.1f}ms"
             self.logger.info(
                 "step %d (ep %d) loss=%.4f lr=%.4g io=%.1fms step=%.1fms "
-                "(%.0f ex/s)%s sent=%dB %s", step, self.epoch, loss, lr,
+                "(%.0f ex/s)%s sent=%dB %s", step, epoch, loss, lr,
                 1e3 * rec["io_s"], 1e3 * rec["step_s"], imgs, phases,
                 rec["bytes_sent"],
                 " ".join(f"{k}={float(v):.4f}" for k, v in aux.items()))

@@ -568,3 +568,99 @@ def test_trainer_stream_retry_exhaustion_fails_loud(tmp_path):
     assert isinstance(ei.value.__cause__, chaos.TransientIOError)
     assert flaky.raised == 4         # initial + io_retries (3)
     t.close()
+
+
+# ---------------------------------------------------------------------------
+# the loop keeps one step in flight (PR 28): where the host acts on the
+# finished state, it acts on the state of THAT step
+# ---------------------------------------------------------------------------
+
+def _params_bytes(state):
+    return [np.asarray(jax.device_get(x)).tobytes()
+            for x in jax.tree_util.tree_leaves(
+                (state.params, state.opt_state, state.ef_residual))]
+
+
+def _after(tmp_path, steps, **kw):
+    """The state after `steps` steps of an undisturbed run."""
+    ref = Trainer(make_cfg(tmp_path / f"ref{steps}", max_steps=12, **kw))
+    ref.train(steps)
+    got = _params_bytes(ref.state)
+    ref.close()
+    return got
+
+
+def test_cadence_save_holds_the_state_after_its_own_step(tmp_path):
+    """`train(7)` with a save every 3 steps: the checkpoints of steps 3 and
+    6 hold the state after those steps and no later one (the loop does not
+    dispatch step 4 before step 3 is sealed; the state is donated)."""
+    from gaussiank_sgd_tpu.training.checkpoint import restore_checkpoint
+    t = Trainer(make_cfg(tmp_path, max_steps=12, save_every_steps=3))
+    t.train(7)
+    assert t.step == 7
+    saved = [r["step"] for r in read_events(t, "checkpoint")]
+    assert saved == [3, 6]
+    for step, path in list_checkpoints(t.ckpt_dir):
+        state = restore_checkpoint(path, t.state, t.mesh,
+                                   padded_numel=t.ts.ef_numel)
+        assert int(jax.device_get(state.step)) == step
+        assert _params_bytes(state) == _after(tmp_path, step)
+    t.close()
+
+
+def test_shutdown_with_a_step_in_flight_seals_at_that_steps_boundary(
+        tmp_path):
+    """The request arrives while batch 3 is pulled, with step 2 dispatched
+    and not yet waited for. The loop dispatches step 3, reports step 2,
+    waits for step 3, seals it and raises with it: 3 pulls, no step 4."""
+    t = Trainer(make_cfg(tmp_path, max_steps=12))
+    stream = t._train_iter()
+
+    class Feed:
+        pulled = 0
+
+        def __iter__(self):
+            return self
+
+        def __next__(self):
+            self.pulled += 1
+            if self.pulled == 3:
+                assert t._flight is not None and t._flight.done == 2
+                t.shutdown.request()
+            return next(stream)
+
+    feed = Feed()
+    with pytest.raises(TrainingPreempted) as ei:
+        t.train(8, data_iter=feed)
+    assert ei.value.step == 3 and feed.pulled == 3
+    assert ei.value.ckpt_path.endswith("step_00000003")
+    assert is_committed(ei.value.ckpt_path)
+    assert [r["step"] for r in read_events(t, "preempt")] == [3]
+    assert t.step == 3 and t._flight is None
+    assert _params_bytes(t.state) == _after(tmp_path, 3)
+    t.close()
+
+
+def test_rollback_at_a_log_step_drops_the_step_in_flight(tmp_path):
+    """The poisoned step 9 is skipped under its own number while step 10
+    is in flight; the skip budget trips, and the rollback at log step 10,
+    with step 11 dispatched, restores step 8's checkpoint over it. The
+    dropped step is never reported; the call's other steps replay from the
+    restored one: 6 dispatches = 9, 10, 11 (dropped), 9, 10, 11."""
+    t = Trainer(make_cfg(tmp_path, max_steps=40, log_every=2, lr=0.01,
+                         save_every_steps=4, max_consecutive_skips=1))
+    t.train(8)
+    fired = chaos.inject_nan_batches(t, {8})    # the batch of step 9
+    t.train(6)
+    assert fired == {8}
+    assert [r["step"] for r in read_events(t, "skip")] == [9]
+    rb = read_events(t, "rollback")
+    assert len(rb) == 1 and rb[0]["to_step"] == 8
+    assert rb[0]["reason"] == "skip_budget"
+    assert t.step == 11 and t._flight is None
+    steps = [r["step"] for r in read_events(t, "train")]
+    assert steps == [2, 4, 6, 8, 10, 10]
+    recs = read_events(t, "train")
+    assert recs[-2]["skipped"] == 0.0 and recs[-2]["lr_scale"] == 1.0
+    assert recs[-1]["lr_scale"] == 0.5
+    t.close()

@@ -19,7 +19,11 @@ SPARSE_SCOPES = ("fwd_bwd", "flatten", "ef_select", "cand_topk", "pack",
                  "exchange", "scatter", "update", "guard", "step_metrics")
 DENSE_SCOPES = ("fwd_bwd", "flatten", "exchange", "update", "guard",
                 "step_metrics")
-LEAVES = ["data_wait", "h2d", "step_dispatch", "step_sync", "step_readback"]
+# what an iteration does for the step it dispatches, and for a step it waits
+# for
+FEED = ["data_wait", "h2d", "step_dispatch"]
+ENDS = ["step_sync", "step_readback"]
+LEAVES = FEED + ENDS
 PREFETCH_DEPTH = 2
 
 
@@ -100,17 +104,24 @@ def test_recording_survives_close(traced_run):
     assert [s.name for s in rec.spans].count("iteration") == 3
 
 
+def kids_of(spans, it):
+    return sorted((s for s in spans if s.parent == it.span_id),
+                  key=lambda s: s.t0_ns)
+
+
 def test_one_iteration_per_step_with_its_children_in_order(traced_run):
+    """The loop keeps one step in flight: an iteration pulls, places and
+    dispatches its own step, then waits for, reads and logs the step
+    before; the call's last iteration waits for its own step too."""
     rec, _ = traced_run
     spans = list(rec.spans)
     iters = [s for s in spans if s.name == "iteration"]
     assert [s.fields["step"] for s in iters] == [1, 2, 3]
+    want = {1: FEED, 2: FEED + ENDS,
+            3: FEED + ENDS + ["log_step", "trace_drain"] + ENDS}
     for it in iters:
-        kids = sorted((s for s in spans if s.parent == it.span_id),
-                      key=lambda s: s.t0_ns)
-        want = LEAVES + (["log_step", "trace_drain"]
-                         if it.fields["step"] == 2 else [])
-        assert [k.name for k in kids] == want
+        kids = kids_of(spans, it)
+        assert [k.name for k in kids] == want[it.fields["step"]]
         # inside the parent, one after the other, on a monotone clock
         edges = [it.t0_ns]
         for k in kids:
@@ -119,6 +130,60 @@ def test_one_iteration_per_step_with_its_children_in_order(traced_run):
         assert edges == sorted(edges)
         ready = kids[0].fields["ready"]
         assert isinstance(ready, int) and 0 <= ready <= PREFETCH_DEPTH
+    # `ahead`: the step programs dispatched behind the one waited for
+    syncs = [s for s in spans if s.name == "step_sync"]
+    assert [s.fields["ahead"] for s in syncs] == [1, 1, 0]
+    # every leaf hangs off an iteration, and none holds another span
+    leaves = [s for s in spans if s.name in LEAVES]
+    assert {s.parent for s in leaves} == {it.span_id for it in iters}
+    assert not {s.span_id for s in leaves} & {s.parent for s in spans}
+
+
+@pytest.mark.parametrize("kw,n,ahead", [
+    # all steps of a call but its last
+    (dict(), 5, [1, 1, 1, 1, 0]),
+    # a cadence save: step 2 and step 4 are waited for before the next
+    # step is dispatched
+    (dict(save_every_steps=2), 5, [1, 0, 1, 0, 0]),
+    # a log step at which the phase probes time programs of their own
+    (dict(phase_timing=True, log_every=3), 5, [1, 1, 0, 1, 0]),
+    # a log step at which the policy engine may rebuild the programs
+    (dict(policy="adaptive", log_every=3), 5, [1, 1, 0, 1, 0]),
+    # a profiler window over steps [2, 3): it opens before step index 2 is
+    # dispatched and closes before step index 3 is
+    (dict(profile_steps=(2, 3)), 5, [1, 0, 0, 1, 0]),
+], ids=["free", "cadence-save", "phase-timing", "policy-engine",
+        "profiler-window"])
+def test_the_loop_does_not_run_ahead_where_the_host_acts(tmp_path, kw, n,
+                                                         ahead):
+    t = Trainer(make_cfg(tmp_path, run_id="ahead", **kw))
+    t.train(n)
+    t.close()
+    spans = list(tracing.recorded("ahead").spans)
+    syncs = sorted((s for s in spans if s.name == "step_sync"),
+                   key=lambda s: s.t0_ns)
+    assert [s.fields["ahead"] for s in syncs] == ahead
+    iters = [s for s in spans if s.name == "iteration"]
+    assert len(iters) == n
+    for it in iters:
+        names = [k.name for k in kids_of(spans, it)]
+        assert names[:3] == FEED
+        assert set(names[3:]) <= set(ENDS) | {
+            "log_step", "trace_drain", "checkpoint_save", "policy_apply"}
+    # one sync and one read-back a step, whoever's iteration holds them
+    assert [s.name for s in spans].count("step_readback") == n
+
+
+def test_a_call_of_one_step_never_runs_ahead(tmp_path):
+    t = Trainer(make_cfg(tmp_path, run_id="ones"))
+    for _ in range(3):
+        t.train(1)
+    t.close()
+    spans = list(tracing.recorded("ones").spans)
+    for it in (s for s in spans if s.name == "iteration"):
+        assert [k.name for k in kids_of(spans, it)][:5] == LEAVES
+    assert [s.fields["ahead"] for s in spans
+            if s.name == "step_sync"] == [0, 0, 0]
 
 
 def test_iterations_hang_off_the_trajectory_and_construct_is_a_root(

@@ -174,3 +174,121 @@ def test_trainer_warmup_switches_to_sparse(tmp_path):
     # params -> dense/sparse byte ratio = 50x
     assert tr[4]["bytes_sent"] > 20 * tr[8]["bytes_sent"]
     t.close()
+
+
+# ---------------------------------------------------------------------------
+# the loop keeps one step in flight (PR 28): the same mathematics, the same
+# batches in the same order, n pulls, drained at the call's end
+# ---------------------------------------------------------------------------
+
+LSTM = dict(dnn="lstm", dataset="ptb", batch_size=2, clip_norm=0.25,
+            lr=0.5, momentum=0.9,
+            model_kwargs=dict(embed_dim=16, hidden_dim=16),
+            dataset_kwargs=dict(vocab_size=64, bptt=8,
+                                synthetic_tokens_n=2 * 8 * 5 + 1))
+
+
+def _state_leaves(t):
+    import jax
+    s = t.state
+    return {"/".join(str(k) for k in path): np.asarray(jax.device_get(leaf))
+            for path, leaf in jax.tree_util.tree_flatten_with_path(
+                (s.params, s.model_state, s.opt_state, s.ef_residual,
+                 s.carry, s.step))[0]}
+
+
+def _train_losses(t):
+    return [(r["step"], r["loss"]) for r in map(json.loads, open(
+        os.path.join(t.run_dir, "metrics.jsonl")))
+        if r.get("event") == "train"]
+
+
+@pytest.mark.parametrize("kw", [
+    dict(nworkers=1, compressor="auto", compress_warmup_steps=0),
+    dict(nworkers=8, compressor="gaussian", compress_warmup_steps=0),
+    dict(nworkers=1, compressor="none"),
+    dict(nworkers=8, compressor="none"),
+    # the carry is reset at the epoch wrap (4 steps an epoch), by the step
+    # number, on the state's futures
+    dict(nworkers=1, compressor="gaussian", compress_warmup_steps=2, **LSTM),
+], ids=["sparse-1", "sparse-8", "dense-1", "dense-8", "lstm-epoch-wrap"])
+def test_train_n_is_n_times_train_1_bit_for_bit(tmp_path, kw):
+    """`train(n)` runs ahead of all its steps but the last; n calls of
+    `train(1)` never do. Parameters, momentum, residual, carry and every
+    step's loss are the same bits."""
+    n = 7
+    cfg = dict(max_steps=20, log_every=1, momentum=0.9, weight_decay=1e-4)
+    cfg.update(kw)
+    ahead = Trainer(make_cfg(tmp_path / "ahead", **cfg))
+    ahead.train(n)
+    blocking = Trainer(make_cfg(tmp_path / "blocking", **cfg))
+    for _ in range(n):
+        blocking.train(1)
+    if kw.get("dnn") == "lstm":
+        assert ahead.recurrent and 1 < ahead.steps_per_epoch < n - 1
+    a, b = _state_leaves(ahead), _state_leaves(blocking)
+    assert a.keys() == b.keys() and len(a) > 4
+    for path in a:
+        assert a[path].tobytes() == b[path].tobytes(), path
+    assert _train_losses(ahead) == _train_losses(blocking)
+    assert [s for s, _ in _train_losses(ahead)] == list(range(1, n + 1))
+    ahead.close()
+    blocking.close()
+
+
+def test_train_pulls_exactly_n_batches_and_ends_on_its_last_step(tmp_path):
+    """What the callers of `train(n, data_iter)` rely on: n pulls from the
+    iterator they gave, and on return every step has ended: `_state`,
+    `_step_cache` and `_probe_batch` are the last step's, nothing is in
+    flight."""
+    import jax
+    t = Trainer(make_cfg(tmp_path, nworkers=1, max_steps=40, log_every=4))
+
+    class Counted:
+        def __init__(self, it):
+            self.it, self.pulled = it, []
+
+        def __iter__(self):
+            return self
+
+        def __next__(self):
+            self.pulled.append(next(self.it))
+            return self.pulled[-1]
+
+    feed = Counted(t._train_iter())
+    for n, total in ((5, 5), (1, 6), (3, 9)):
+        rec = t.train(n, data_iter=feed)
+        assert len(feed.pulled) == total
+        assert t._step_cache == total and t._flight is None
+        assert all(leaf.is_ready()
+                   for leaf in jax.tree_util.tree_leaves(t._state))
+        assert int(jax.device_get(t._state.step)) == total
+        for mine, fed in zip(jax.tree_util.tree_leaves(t._probe_batch),
+                             feed.pulled[-1]):
+            np.testing.assert_array_equal(np.asarray(mine), fed)
+        assert rec["event"] == "train"
+    # train(5) logged step 4 and returned it; train(1) and train(3) end
+    # between log steps on the quiet record of their own last step
+    assert rec["step"] == 8
+    t.close()
+
+
+@pytest.mark.parametrize("nworkers", [1, 8])
+def test_the_log_lines_lr_runs_no_device_program(nworkers):
+    """`_log_train` asks the schedule with a Python int while the next
+    step is in flight; an eager jnp expression would queue its programs
+    behind that step and hold the loop until it ends (on the chip the log
+    step took a whole step, PERF.md PR 28). A Python number is worked out
+    in numpy and agrees with what the jitted step computes."""
+    import jax
+    import jax.numpy as jnp
+    from gaussiank_sgd_tpu.training.lr_schedule import (
+        warmup_milestone_schedule)
+    sched = warmup_milestone_schedule(0.1, nworkers, 7, 100, 2.5,
+                                      (0.5, 0.75), 0.1)
+    for step in (0, 3, 17, 18, 49, 50, 74, 75, 99):
+        host = sched(step)
+        assert not isinstance(host, jax.Array)
+        assert float(host) == pytest.approx(
+            float(jax.jit(sched)(jnp.asarray(step))), rel=1e-6)
+    assert isinstance(sched(jnp.asarray(3)), jax.Array)
