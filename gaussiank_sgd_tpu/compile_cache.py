@@ -1,9 +1,8 @@
 """Persistent XLA compilation cache placement — the ONE copy.
 
 Every entry point that compiles the step programs (``train.py``,
-``bench.py``, ``chip_smoke.py``, ``tests/conftest.py``, the analysis
-scripts) calls :func:`enable_compile_cache` before its first jit. The
-directory is a deployment setting with one knob, JAX's own:
+``chip_smoke.py``, ``tests/conftest.py``, the analysis scripts) calls
+:func:`enable_compile_cache` before its first jit. The directory is a deployment setting with one knob, JAX's own:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set — jax already reads it into
   ``jax_compilation_cache_dir`` at import; this module sets no directory

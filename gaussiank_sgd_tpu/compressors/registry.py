@@ -193,17 +193,17 @@ NAMES = ("none", "topk", "approxtopk", "approxtopk16", "gaussian",
 # ONE fixed choice a user inherits without measuring their own workload:
 # ``gaussian_fused`` — warm-started GaussianK threshold selection with the
 # Pallas fused select+pack kernel (ops/pallas_pack.py) on the hot path.
-# Rationale, from the r4 measurements (analysis/artifacts/
-# sparse_ablation.json, bench_matrix*.json): the kernel removes the
+# Rationale, from the r4 measurements (CHANGELOG_r4.md; the artifacts
+# are gone): the kernel removes the
 # n-scale approx_max_k select+pack that made the r3 selector choice
 # model-dependent (approxtopk won transformers, gaussian_warm won VGG;
 # neither cleared >=0.90 everywhere), leaving an overhead small enough
-# that one selector holds on all five BASELINE configs. bench.py's
-# headline uses exactly this constant; it is not a per-window winner.
+# that one selector holds on all five BASELINE configs. Every benchmark
+# cell runs exactly this constant; it is not a per-window winner.
 #
 # ``default_selector(model)`` exists so a future per-model exception can
-# be codified HERE (and inherited by bench.py and --compressor auto)
-# rather than living in a benchmark script or a README table.
+# be codified HERE (and inherited by --compressor auto) rather than
+# living in a benchmark script or a README table.
 DEFAULT_SELECTOR = "gaussian_fused"
 MODEL_DEFAULT_SELECTORS: dict = {}      # model-name overrides; empty = one
                                         # selector everywhere

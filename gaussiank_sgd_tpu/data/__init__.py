@@ -36,7 +36,7 @@ def make_imagenet(data_dir: Optional[str] = None, train: bool = True,
     (synthetic path, or a u8 ``.npy``) and normalized ON DEVICE inside the
     jitted step (training/losses.py ``_prep_pixels``) — 4x less
     host->device traffic than pre-normalized f32, which is what lets the
-    224^2 pipeline keep a chip fed (analysis/io_pipeline_bench.py). An f32
+    224^2 pipeline keep a chip fed. An f32
     ``.npy`` (already normalized offline) passes through unchanged.
     """
     split = "train" if train else "val"

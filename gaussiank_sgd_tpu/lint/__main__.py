@@ -329,7 +329,7 @@ def _events_main(argv: List[str]) -> int:
                     ".gklint-events.json")
     ap.add_argument("paths", nargs="*",
                     help="files/dirs to scan (default: the package plus "
-                         "bench.py and analysis/)")
+                         "analysis/)")
     _add_format_flags(ap)
     ap.add_argument("--events-file", default=None,
                     help="committed snapshot (default: "

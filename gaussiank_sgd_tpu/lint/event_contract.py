@@ -3,7 +3,7 @@
 ``telemetry/events.py`` catalogs every event kind the runtime may put on
 the bus (``EVENT_SCHEMAS``); ``validate_record`` enforces it at runtime.
 This tier closes the loop *statically*: it resolves every ``publish(`` /
-``.emit(`` site in the package (plus ``bench.py`` and ``analysis/``) to
+``.emit(`` site in the package (plus ``analysis/``) to
 its event ``kind`` and literal payload keys, then cross-checks against
 the catalog — the same way ``.gklint-programs.json`` pins the jitted
 programs:
@@ -63,10 +63,9 @@ def default_scan_paths() -> List[str]:
     pkg_dir = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     root = os.path.dirname(pkg_dir)
     out = [pkg_dir]
-    for extra in ("bench.py", "analysis"):
-        p = os.path.join(root, extra)
-        if os.path.exists(p):
-            out.append(p)
+    analysis = os.path.join(root, "analysis")
+    if os.path.exists(analysis):
+        out.append(analysis)
     return out
 
 

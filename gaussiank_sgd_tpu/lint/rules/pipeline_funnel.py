@@ -7,10 +7,10 @@ step builder, or ``butterfly_rounds`` in parallel/gtopk.py. A raw
 ``lax.all_gather`` / ``lax.ppermute`` added elsewhere in ``parallel/``
 silently bypasses three invariants at once: the eligibility gate (the
 collective runs sequentially even when the build says "pipelined"), the
-noexch ablation twin (``exposed_exchange_ms`` stops ablating it, so the
-telemetry under-reports exposed time), and the overlapped-bytes
-accounting. This rule flags payload collectives in ``parallel/`` whose
-enclosing-function chain contains no sanctioned funnel name;
+``exchange`` scope (a device trace no longer books it as exchange time),
+and the overlapped-bytes accounting. This rule flags payload collectives
+in ``parallel/`` whose enclosing-function chain contains no sanctioned
+funnel name;
 deliberately sequential call sites (parallel/collectives.py's reference
 implementations) carry an inline suppression with their justification.
 
@@ -73,6 +73,6 @@ class Rule:
                          f"the sanctioned pipeline funnels "
                          f"({', '.join(sorted(_SANCTIONED_FUNNELS))}): "
                          f"it bypasses the overlap eligibility gate, the "
-                         f"noexch ablation twin, and the overlapped-bytes "
+                         f"exchange scope, and the overlapped-bytes "
                          f"accounting (parallel/trainstep.py)"),
                 source_line=ctx.src(node))

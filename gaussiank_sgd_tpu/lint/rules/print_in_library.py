@@ -1,9 +1,9 @@
 """Rule: print-in-library.
 
 Library code must not write to stdout with bare ``print()``: stdout is a
-machine-readable channel here (bench.py's one-JSON-line driver contract,
-the telemetry JSONL exporters) and a stray print corrupts it; diagnostics
-belong on the logger (training/metrics.make_logger) or the telemetry bus
+machine-readable channel here (benchmarks/run.py's one-JSON-line driver
+contract, the telemetry JSONL exporters) and a stray print corrupts it;
+diagnostics belong on the logger (training/metrics.make_logger) or the telemetry bus
 (docs/OBSERVABILITY.md).
 
 Allowlisted: ``__main__.py`` CLI entrypoints (the lint and telemetry

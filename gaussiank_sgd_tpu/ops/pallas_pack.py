@@ -5,7 +5,7 @@ SURVEY.md §7 stage 6): the reference's ``GaussianCompressor`` select+pack
 (``compression.py``) re-built as a TPU kernel that *emits packed (index,
 value) pairs* instead of composing XLA sort/select primitives.
 
-Why it exists (measured, analysis/artifacts/sparse_ablation.json r3): at 57M
+Why it exists (measured in r3, CHANGELOG_r4.md; the artifact is gone): at 57M
 params the XLA pack (`abs` + bf16 key + ``lax.approx_max_k`` + gather) costs
 6.5-8.6 ms — ~3-4x over raw HBM-bandwidth theory, and the dominant term of
 the whole sparse-step overhead. A threshold select is informationally one

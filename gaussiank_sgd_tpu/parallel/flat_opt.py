@@ -1,11 +1,11 @@
 """Flat sparse-aware SGD(+momentum, +weight-decay) — the TPU-first
 optimizer path for compressed exchanges.
 
-Why it exists (r5 overhead decomposition, analysis/artifacts/
-sparse_ablation.json + overhead_microbench.json): after the r5 kernel work
-the sparse step's largest remaining term is the EF/exchange floor, and a
-full HBM pass of it is the *decompression* detour — scatter the gathered
-(index, value) pairs into a zeros buffer, hand the dense result to optax,
+Why it exists (r5 overhead decomposition; the artifacts are gone, the
+scopes ``update`` and ``scatter`` price it on the chip now): after the r5
+kernel work the sparse step's largest remaining term is the EF/exchange
+floor, and a full HBM pass of it is the *decompression* detour — scatter
+the gathered (index, value) pairs into a zeros buffer, hand the dense result to optax,
 which immediately streams it back in to form the momentum update. The
 gradient is k-sparse; the only DENSE consumer is the momentum buffer. So
 scatter the pairs **directly into the decayed momentum**:

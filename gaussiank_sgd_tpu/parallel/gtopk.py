@@ -94,7 +94,7 @@ def merge_sparse(idx_a: jax.Array, val_a: jax.Array, idx_b: jax.Array,
 def butterfly_rounds(idx: jax.Array, val: jax.Array, num_devices: int,
                      axis_name: str,
                      wire: Optional[wire_mod.WireFormat] = None,
-                     start_round: int = 0, ablate_comm: bool = False,
+                     start_round: int = 0,
                      ) -> Tuple[jax.Array, jax.Array, int]:
     """Rounds ``start_round .. log2(P)-1`` of the XOR butterfly over an
     already-merged k-entry sparse set; returns ``(idx, val, bytes_sent)``.
@@ -105,11 +105,6 @@ def butterfly_rounds(idx: jax.Array, val: jax.Array, num_devices: int,
     inline loop), and the bucket-pipelined step (trainstep.py) runs round
     0 per-chunk inside its scan and hands the merged buffers here with
     ``start_round=1`` for the remaining hops.
-
-    ``ablate_comm`` replaces each ppermute with the identity — the
-    'sparse_noexch' timing twin used to measure EXPOSED exchange time
-    (every compute op, byte count, and merge still runs; only the wire
-    hop is elided). Never used by a training program.
     """
     p = num_devices
     assert p & (p - 1) == 0, f"gtopk needs power-of-2 workers, got {p}"
@@ -127,27 +122,20 @@ def butterfly_rounds(idx: jax.Array, val: jax.Array, num_devices: int,
             words, counts = wire_mod.encode_sorted(idx, val, wire)
             bytes_sent += (words.size * words.dtype.itemsize
                            + counts.size * counts.dtype.itemsize)
-            if ablate_comm:
-                o_words, o_counts = words, counts
-            else:
-                o_words = lax.ppermute(words, axis_name, perm)
-                o_counts = lax.ppermute(counts, axis_name, perm)
+            o_words = lax.ppermute(words, axis_name, perm)
+            o_counts = lax.ppermute(counts, axis_name, perm)
             o_idx, o_val = wire_mod.decode_sorted(o_words, o_counts, wire)
         else:
             bytes_sent += (idx.size * idx.dtype.itemsize
                            + val.size * val.dtype.itemsize)
-            if ablate_comm:
-                o_idx, o_val = idx, val
-            else:
-                o_idx = lax.ppermute(idx, axis_name, perm)
-                o_val = lax.ppermute(val, axis_name, perm)
+            o_idx = lax.ppermute(idx, axis_name, perm)
+            o_val = lax.ppermute(val, axis_name, perm)
         idx, val = merge_sparse(idx, val, o_idx, o_val, k)
     return idx, val, bytes_sent
 
 
 def gtopk_allreduce(comp: CompressedGrad, num_devices: int, axis_name: str,
                     wire: Optional[wire_mod.WireFormat] = None,
-                    ablate_comm: bool = False,
                     ) -> Tuple[CompressedGrad, GtopkCommStats]:
     """Butterfly gTop-k: log2(P) ppermute rounds; result identical on every
     worker (the global top-k of the summed sparse gradients, k entries).
@@ -162,9 +150,6 @@ def gtopk_allreduce(comp: CompressedGrad, num_devices: int, axis_name: str,
     count (ADVICE r3). ``rounds``/``entries_per_round`` feed the telemetry
     stream's comms accounting (docs/OBSERVABILITY.md).
 
-    ``ablate_comm``: identity in place of every ppermute — the noexch
-    timing twin (see ``butterfly_rounds``); never a training program.
-
     ``wire``: an active ``parallel/wire.py`` format packs each round's
     payload as u32 words (sorted by global index + an ``int32[n_buckets]``
     count vector — ``encode_sorted``) instead of (i32, f32) pairs. The
@@ -177,7 +162,7 @@ def gtopk_allreduce(comp: CompressedGrad, num_devices: int, axis_name: str,
     k = comp.indices.shape[0]
     idx, val, bytes_sent = butterfly_rounds(
         comp.indices, comp.values, num_devices, axis_name, wire,
-        start_round=0, ablate_comm=ablate_comm)
+        start_round=0)
     n_rounds = int(math.log2(num_devices))
     stats = GtopkCommStats(
         bytes_sent=bytes_sent, rounds=n_rounds,

@@ -134,8 +134,8 @@ class StepMetrics(NamedTuple):
                               # issued INSIDE the bucket-pipelined scan
                               # body, where XLA can latency-hide the
                               # collective behind the next chunk's
-                              # compress (docs/PERFORMANCE.md pipeline
-                              # section). 0 on the sequential program and
+                              # compress (docs/ARCHITECTURE.md, the
+                              # pipeline). 0 on the sequential program and
                               # the dense path. Trace-time static, f32
                               # for the same wrap-safety as bytes_sent.
     # --- span-source geometry (telemetry/tracing.py): trace-time-static
@@ -340,16 +340,6 @@ class DPTrainStep(NamedTuple):
     init_state: Callable[..., TrainState]
     plan: BucketPlan
     mesh: Mesh
-    # ('sparse'|'dense', n) -> jitted (state, batch) -> (state, last_metrics)
-    # running n steps in ONE device-side fori_loop — one dispatch for n
-    # steps, so benchmarks measure device work, not host dispatch.
-    make_multi_step: Callable[[str, int], Callable]
-    # () -> {'grads': fn, 'select': fn}: jitted NON-donating prefix
-    # programs of the sparse step (fwd+bwd only; fwd+bwd+EF+compress) for
-    # the trainer's per-phase log breakdown (SURVEY.md §5 Tracing row,
-    # VERDICT r3 item 6). Built lazily — compiling them costs real time at
-    # large models and most short runs never log.
-    make_probes: Callable[[], dict]
     # Per-worker EF-residual row size: plan.total_numel on the unfused
     # path, the block-aligned padded size when the fused EF+select kernel
     # owns the accumulate (ops/pallas_pack.py padded-EF contract). The
@@ -359,13 +349,13 @@ class DPTrainStep(NamedTuple):
     # Wire format of this build's sparse exchange (parallel/wire.py):
     # "u16bf16" when the packed format passed the eligibility gate,
     # "i32f32" otherwise (legacy, bit-identical to the pre-wire program).
-    # Telemetry/bench report it next to every bytes_sent claim.
+    # Telemetry reports it next to every bytes_sent claim.
     wire_format: str = wire_mod.WIRE_LEGACY
     # "pipelined" when this build's sparse step runs the bucket-pipelined
     # schedule (per-chunk EF+select with the collective for chunk i issued
     # while chunk i+1 compresses — the double-buffered lax.scan), "off"
     # when it runs the historical sequential program (--overlap off or an
-    # ineligible plan). Telemetry/bench report it next to every timing.
+    # ineligible plan). Telemetry reports it next to every timing.
     overlap: str = "off"
     # How this build's Pallas kernels execute: "mosaic" (compiled for the
     # TPU), "interpret" (the Pallas interpreter, CPU meshes) or "none" (the
@@ -429,7 +419,7 @@ def build_dp_train_step(
     in-step because a NaN that reaches ``ef_residual`` is re-sent by error
     feedback on every later step. Cost: one ``isfinite`` pass over the
     grads + one select pass over params/opt_state/residual, both
-    elementwise and fused by XLA (<2% of a step; bench via benchlib).
+    elementwise and fused by XLA (scope ``guard`` in a device trace).
 
     ``sp_axis``: ring-attention sequence parallelism (long-context path).
     Must name the mesh's LAST axis; the batch's dim 0 then shards over the
@@ -788,34 +778,21 @@ def build_dp_train_step(
                 state.comp_state[0] if spec.stateful else ())
         return comp, residual, nsel, cstate, acc, None
 
-    def _make_sparse_step(use_pipeline: bool, ablate: bool):
-        """Build one sparse step program.
+    def _make_sparse_step(use_pipeline: bool):
+        """Build the sparse step program.
 
         ``use_pipeline`` selects the bucket-pipelined schedule (the
         double-buffered lax.scan — see the ``overlap`` docstring) vs. the
         historical sequential program; both are bit-identical in output.
-
-        ``ablate`` builds the 'sparse_noexch' TIMING TWIN: every compute
-        op, reassembly, byte count, and metric collective stays, but the
-        exchange collectives (all_gather / ppermute of the payload, the
-        outer-axis dense psum) become local identities of the same shape.
-        step_time(sparse) - step_time(noexch) is therefore the EXPOSED
-        exchange time — the part XLA failed to hide behind compute. The
-        twin's numerics are garbage by construction (every worker sees
-        only its own payload); it never trains, only times.
         """
 
         def _gather(x):
             """Single issue point for the allgather-path payload collective
             (gklint collective-outside-pipeline funnel)."""
             with jax.named_scope("exchange"):
-                if ablate:
-                    return jnp.tile(x, gather_size)
                 return lax.all_gather(x, gather_axis, tiled=True)
 
         def _psum_outer(x):
-            if ablate:
-                return x
             with jax.named_scope("exchange"):
                 for a in outer_axes:
                     x = lax.psum(x, a)
@@ -829,8 +806,6 @@ def build_dp_train_step(
             the remaining log2(P)-1 rounds need the merged buffer and run
             post-scan via butterfly_rounds."""
             if exchange == "gtopk":
-                if ablate:
-                    return payload
                 perm = [(j, j ^ 1) for j in range(gather_size)]
                 with jax.named_scope("exchange"):
                     return tuple(lax.ppermute(p_, gather_axis, perm)
@@ -997,7 +972,7 @@ def build_dp_train_step(
                             comp.indices, local_val, o_idx, o_val, k_packed)
                         m_idx, m_val, tail_bytes = butterfly_rounds(
                             m_idx, m_val, mesh.size, gather_axis, wire_fmt,
-                            start_round=1, ablate_comm=ablate)
+                            start_round=1)
                     overlapped = round1_bytes * (n_chunks - 1) // n_chunks
                     gcomp = CompressedGrad(m_idx, m_val)
                     n_rounds = int(math.log2(mesh.size))
@@ -1015,8 +990,7 @@ def build_dp_train_step(
                     # (shape x itemsize per round) — measured, not a formula
                     with jax.named_scope("exchange"):
                         gcomp, comm = gtopk_allreduce(
-                            comp, mesh.size, gather_axis, wire=wire_fmt,
-                            ablate_comm=ablate)
+                            comp, mesh.size, gather_axis, wire=wire_fmt)
                 with jax.named_scope("scatter"):
                     # the /P average rides the k-sized VALUES, not the
                     # n-sized dense buffer: one full read+write pass saved
@@ -1149,7 +1123,7 @@ def build_dp_train_step(
 
         return sparse_step_fn
 
-    sparse_step_fn = _make_sparse_step(pipelined, False)
+    sparse_step_fn = _make_sparse_step(pipelined)
 
     def dense_step_fn(state: TrainState, batch: Any):
         data_rng, _ = _step_rngs(state)
@@ -1217,79 +1191,6 @@ def build_dp_train_step(
     def _wrap(fn):
         return jax.jit(_smap(fn), donate_argnums=(0,))
 
-    def make_probes() -> dict:
-        """Jitted prefix programs for phase timing. 'grads' runs fwd+bwd
-        (+ the metric pmeans); 'select' adds EF accumulate + per-bucket
-        compression. The returned scalars fold every output in, so XLA
-        cannot dead-code the phases being timed. The residual write is
-        represented by a reduction over the residual (comparable HBM
-        traffic to the real step's write) — the decomposition is
-        logging-grade observability, not benchmark methodology (that is
-        benchlib.ablation_specs + analysis/bench_matrix.py)."""
-
-        def probe_grads_fn(state: TrainState, batch: Any):
-            data_rng, _ = _step_rngs(state)
-            # same padded prefix as the sparse step, so select - grads
-            # isolates exactly the compression phase
-            loss, mstate, aux, new_carry, flat_g, unravel = _local_grads(
-                state, batch, data_rng, ef_numel - n_total)
-            return _pmean(jnp.linalg.norm(flat_g)) + 0.0 * loss
-
-        def probe_select_fn(state: TrainState, batch: Any):
-            data_rng, comp_rng = _step_rngs(state)
-            loss, mstate, aux, new_carry, flat_g, unravel = _local_grads(
-                state, batch, data_rng, ef_numel - n_total)
-            scale = fold_lr(state.step) if fold_lr is not None else 1.0
-            comp, residual, nsel, _cstate, _acc, _words = _compress_phase(
-                state, flat_g, scale, comp_rng)
-            sink = (jnp.sum(nsel).astype(jnp.float32)
-                    + jnp.sum(comp.values)
-                    + jnp.sum(residual[:1]) + jnp.sum(residual[-1:]))
-            return _pmean(sink) + 0.0 * loss
-
-        return {
-            "grads": jax.jit(shard_map(
-                probe_grads_fn, mesh=mesh,
-                in_specs=(state_spec, batch_spec), out_specs=P(),
-                check_vma=False)),
-            "select": jax.jit(shard_map(
-                probe_select_fn, mesh=mesh,
-                in_specs=(state_spec, batch_spec), out_specs=P(),
-                check_vma=False)),
-            # the noexch TIMING TWIN of the full sparse step (exchange
-            # collectives -> same-shape local identities; see
-            # _make_sparse_step): step_s - t(noexch) is the EXPOSED
-            # exchange time logged as exposed_exchange_ms. NON-donating
-            # and returns the full (state, metrics) so no part of the
-            # step — the optimizer scatter included — is dead-coded out
-            # of the timed program.
-            "noexch": jax.jit(_smap(_make_sparse_step(pipelined, True))),
-        }
-
-    def make_multi_step(kind: str, n: int):
-        """n chained steps in one jitted program (benchmark-grade timing).
-
-        ``kind``: 'sparse', 'dense', or 'sparse_noexch' — the sparse
-        step's comm-ablated timing twin (benchlib measures the exposed
-        exchange time as the noise-floored sparse - sparse_noexch delta).
-        """
-        fns = {"sparse": sparse_step_fn, "dense": dense_step_fn,
-               "sparse_noexch": _make_sparse_step(pipelined, True)}
-        if kind not in fns:
-            raise ValueError(f"unknown multi-step kind {kind!r}")
-        smapped = _smap(fns[kind])
-
-        def run(state: TrainState, batch: Any):
-            state, metrics = smapped(state, batch)
-
-            def body(_, carry):
-                s, _m = carry
-                return smapped(s, batch)
-
-            return lax.fori_loop(1, n, body, (state, metrics))
-
-        return jax.jit(run, donate_argnums=(0,))
-
     def init_state(params: Any, rng: jax.Array,
                    model_state: Any = None, carry: Any = ()) -> TrainState:
         """A fresh TrainState, created UNDER the step's shardings: the
@@ -1336,8 +1237,7 @@ def build_dp_train_step(
         )
 
     return DPTrainStep(_wrap(sparse_step_fn), _wrap(dense_step_fn),
-                       init_state, plan, mesh, make_multi_step, make_probes,
-                       ef_numel,
+                       init_state, plan, mesh, ef_numel,
                        wire_fmt.name if wire_fmt is not None
                        else wire_mod.WIRE_LEGACY,
                        "pipelined" if pipelined else "off", kernel_mode)

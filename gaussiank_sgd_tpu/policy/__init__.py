@@ -23,7 +23,6 @@ from .rules import (
     RuleContext,
     SelectorRule,
     default_rules,
-    load_roofline_floor,
 )
 from .signals import PolicySignals, SignalSnapshot
 
@@ -39,7 +38,6 @@ __all__ = [
     "ExchangePromotionRule",
     "OverlapPromotionRule",
     "default_rules",
-    "load_roofline_floor",
     "KNOBS",
     "KNOB_COMPRESSOR",
     "KNOB_DENSITY",

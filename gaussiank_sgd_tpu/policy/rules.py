@@ -14,7 +14,8 @@ switch:
 * :class:`SelectorRule` — overhead-vs-roofline-floor selector switching:
   when the measured sparse overhead (steady-state step EMA minus the
   measured dense reference) exceeds ``floor_factor ×`` the per-config HBM
-  floor (analysis/roofline.py artifact), the current selector is leaving
+  floor (``RuleContext.roofline_floor_ms``, where the engine was built
+  with one), the current selector is leaving
   measured headroom on the table — try the next untried candidate; once
   every candidate has a steady-state record, commit to the argmin and
   switch again only on sustained regret against the best record.
@@ -40,8 +41,6 @@ switch:
 
 from __future__ import annotations
 
-import json
-import os
 from dataclasses import dataclass, field
 from typing import Dict, FrozenSet, Optional, Sequence, Tuple
 
@@ -85,8 +84,8 @@ class PolicyDecision:
 class RuleContext:
     """What the engine knows beyond the signals: the knob values currently
     live, the quarantine set (knob, value) pairs reverted decisions left
-    behind, and the per-config roofline floor when an artifact priced on
-    this platform exists."""
+    behind, and the per-config roofline floor where the engine was built
+    with one."""
 
     knobs: Dict[str, str] = field(default_factory=dict)
     quarantine: FrozenSet[Tuple[str, str]] = frozenset()
@@ -114,8 +113,7 @@ class SelectorRule(Rule):
     floor, the rule proposes nothing until at least two arms have
     steady-state records (so a well-priced default never pays exploration
     compiles); with both, it explores exactly while the measured overhead
-    exceeds ``floor_factor × floor`` — the same 1.3× acceptance band the
-    bench roofline gate uses (analysis/roofline.py).
+    exceeds ``floor_factor × floor``.
     """
 
     name = "selector_overhead"
@@ -295,45 +293,9 @@ class OverlapPromotionRule(Rule):
                    f"schedule (output bit-identical; recompile only)")
 
 
-# -- roofline floor lookup -------------------------------------------------
-
-# trainer model name -> roofline/bench config key (analysis/roofline.py
-# CONFIG_MODELS); models outside the 5-config matrix have no floor
-MODEL_CONFIG_KEYS = {
-    "resnet20": "resnet20",
-    "vgg16": "vgg16",
-    "resnet50": "resnet50",
-    "lstm": "lstm_ptb",
-    "transformer": "transformer_wmt",
-}
-
-
-def load_roofline_floor(model: str, platform: str,
-                        artifacts: Optional[str] = None) -> Optional[float]:
-    """floor_ms for ``model`` from analysis/artifacts/roofline.json, iff
-    the artifact was priced on ``platform`` (a CPU floor says nothing
-    about a TPU overhead and vice versa — same rule as bench.py)."""
-    if artifacts is None:
-        artifacts = os.path.join(
-            os.path.dirname(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__)))), "analysis", "artifacts")
-    path = os.path.join(artifacts, "roofline.json")
-    key = MODEL_CONFIG_KEYS.get(model.lower())
-    if key is None or not os.path.exists(path):
-        return None
-    try:
-        with open(path) as f:
-            roof = json.load(f)
-        if roof.get("platform") != platform:
-            return None
-        return float(roof["configs"][key]["floor_ms"])
-    except (ValueError, KeyError, OSError):
-        return None
-
-
-def default_rules(cfg, floor_ms: Optional[float] = None) -> list:
-    """The shipped rule stack for a TrainConfig — the same selector
-    candidate set bench.py sweeps (registry default first), the density
+def default_rules(cfg) -> list:
+    """The shipped rule stack for a TrainConfig — the selector candidate
+    set (registry default first), the density
     ladder centered on the configured density, and wire promotion."""
     from ..compressors import DEFAULT_SELECTOR
     candidates = [DEFAULT_SELECTOR, "gaussian_warm", "approxtopk16"]

@@ -60,13 +60,6 @@ class SignalSnapshot:
     last_rollback_step: Optional[int] = None
     arm_step_s: Dict[str, float] = field(default_factory=dict)
     arm_intervals: Dict[str, int] = field(default_factory=dict)
-    # cross-run sentinel verdicts ingested from bench_regression records
-    # (analysis/regression_sentinel.py --emit-event): how many times the
-    # tree this run is on was flagged, and the worst config named last —
-    # a standing caution the rules can weigh (a flagged tree is a bad
-    # time to explore aggressive density cuts)
-    bench_regressions: int = 0
-    last_bench_regression: Optional[str] = None
     # latest run-health verdict ingested from health_status records
     # (telemetry/health.py, --health on): 0 ok / 1 degraded / 2 critical
     # plus the attributed causes. The engine holds exploration while the
@@ -121,8 +114,6 @@ class PolicySignals:
         self._last_rollback: Optional[int] = None
         self._arm_ema: Dict[str, float] = {}
         self._arm_n: Dict[str, int] = {}
-        self._bench_regressions = 0
-        self._last_bench_regression: Optional[str] = None
         self._health_state = 0
         self._health_causes: Tuple[str, ...] = ()
 
@@ -211,14 +202,6 @@ class PolicySignals:
                     c for c in (causes if isinstance(causes, (list, tuple))
                                 else ())
                     if isinstance(c, str))
-        elif event == "bench_regression":
-            with self._lock:
-                if record.get("status") == "regressed":
-                    self._bench_regressions += 1
-                    wc = record.get("worst_config")
-                    self._last_bench_regression = (
-                        wc if isinstance(wc, str)
-                        else str(record.get("new_rev", "unknown")))
 
     def _ingest_train(self, record: Mapping[str, object]) -> None:
         def num(key) -> Optional[float]:
@@ -298,8 +281,6 @@ class PolicySignals:
                 last_rollback_step=self._last_rollback,
                 arm_step_s=dict(self._arm_ema),
                 arm_intervals=dict(self._arm_n),
-                bench_regressions=self._bench_regressions,
-                last_bench_regression=self._last_bench_regression,
                 health_state=self._health_state,
                 health_causes=self._health_causes,
             )

@@ -2,7 +2,7 @@
 
 One event stream for everything the runtime observes: the trainer's step
 metrics, the data loader's io_retry events, the resilience runtime's
-skip/rollback/preempt events, and bench.py's per-model records all flow
+skip/rollback/preempt events and the policy engine's decisions all flow
 through one schema-versioned :class:`EventBus` with a monotonic sequence
 number, fan out to pluggable exporters (JSONL file, Prometheus textfile,
 in-memory ring buffer), and are reconstructed offline by the report CLI
@@ -24,15 +24,12 @@ from .exporters import (Exporter, JSONLExporter, MemoryExporter,
                         PrometheusTextfileExporter)
 from .health import (HealthMonitor, HealthPolicy, HealthServer,
                      replay_health)
-from .history import (HISTORY_SCHEMA, append_history, build_history_record,
-                      load_history)
 from .throughput import ThroughputSignals, ThroughputTracker
 from .tracing import TraceContext, build_chrome_trace, recorded
 
 __all__ = [
     "EventBus",
     "Exporter",
-    "HISTORY_SCHEMA",
     "HealthMonitor",
     "HealthPolicy",
     "HealthServer",
@@ -43,10 +40,7 @@ __all__ = [
     "ThroughputSignals",
     "ThroughputTracker",
     "TraceContext",
-    "append_history",
     "build_chrome_trace",
-    "build_history_record",
-    "load_history",
     "recorded",
     "replay_health",
     "validate_record",

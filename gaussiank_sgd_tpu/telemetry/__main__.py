@@ -114,11 +114,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     hp.add_argument("path", help="telemetry JSONL event stream")
     hp.add_argument("--json", action="store_true", dest="as_json",
                     help="machine-readable summary")
-    hp.add_argument("--floor-ms", type=float, default=None,
-                    dest="floor_ms",
-                    help="roofline exchange floor for the "
-                         "exposed_exchange detector (live runs read it "
-                         "from analysis/artifacts/roofline.json)")
 
     args = ap.parse_args(argv)
 
@@ -177,7 +172,7 @@ def main(argv: Optional[List[str]] = None) -> int:
             print(f"error: no telemetry records in {args.path}",
                   file=sys.stderr)
             return 3
-        _, mon = replay_health(events, floor_ms=args.floor_ms)
+        _, mon = replay_health(events)
         health = mon.summary()
         print(json.dumps(health, indent=2, default=float)
               if args.as_json else format_health(health))

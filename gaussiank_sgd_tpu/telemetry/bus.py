@@ -38,7 +38,7 @@ class EventBus:
     """Thread-safe publish/fan-out hub for telemetry records.
 
     ``validate=True`` schema-checks every record at publish time and
-    raises on a violation — the fail-loud mode tests and the bench smoke
+    raises on a violation — the fail-loud mode tests and CI smokes
     run under; production trainers keep it off (a telemetry bug must not
     kill a training run that is otherwise healthy... but a SCHEMA bug
     should be caught in CI, where validate is on).

@@ -70,22 +70,17 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         optional={"density_achieved": NUMBER, "ef_norm": NUMBER,
                   "ex_per_s": NUMBER, "mfu": NUMBER,
                   "sel_per_bucket": ARRAY, "consecutive_skips": NUMBER,
-                  "lr_scale": NUMBER, "fwd_bwd_s": NUMBER,
-                  "select_s": NUMBER, "comm_update_s": NUMBER,
-                  "phase_skipped": STRING,
+                  "lr_scale": NUMBER,
                   # wire format of the bytes_sent payload (ISSUE 5,
                   # parallel/wire.py): "u16bf16" packed or "i32f32"
                   # legacy — a bytes claim never travels without its
                   # format name (BASELINE.md protocol)
                   "wire_format": STRING,
                   # bucket-pipelined schedule (ISSUE 7): which step
-                  # schedule produced this interval ("pipelined"/"off"),
-                  # how much of bytes_sent was launched while later
-                  # chunks were still compressing, and the measured
-                  # exchange time the schedule failed to hide (step
-                  # minus its exchange-ablated timing twin)
-                  "overlap": STRING, "overlapped_bytes_sent": NUMBER,
-                  "exposed_exchange_ms": NUMBER},
+                  # schedule produced this interval ("pipelined"/"off")
+                  # and how much of bytes_sent was launched while later
+                  # chunks were still compressing
+                  "overlap": STRING, "overlapped_bytes_sent": NUMBER},
     ),
     "eval": EventSchema(
         required={"step": NUMBER, "epoch": NUMBER, "val_loss": NUMBER},
@@ -176,59 +171,6 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
     "profile": EventSchema(
         required={"action": STRING, "step": NUMBER, "logdir": STRING},
     ),
-    # bench.py machine-readable records (one per model config)
-    "bench_model": EventSchema(
-        required={"key": STRING, "model": STRING, "dataset": STRING,
-                  "batch": NUMBER, "dense_step_ms": NUMBER,
-                  "sparse_step_ms": NUMBER, "ratio_median": NUMBER,
-                  "compressor": STRING},
-        optional={"ratio_min": NUMBER, "ratio_max": NUMBER,
-                  "mfu_dense": NUMBER, "mfu_sparse": NUMBER,
-                  "ex_per_s_chip": NUMBER,
-                  # measurement-protocol + roofline-gate fields (ISSUE 4):
-                  # how many paired rounds back the median, and the
-                  # achieved compression overhead against the per-config
-                  # HBM floor (analysis/roofline.py artifact)
-                  "rounds": NUMBER, "overhead_ms": NUMBER,
-                  "roofline_floor_ms": NUMBER,
-                  "overhead_vs_floor": NUMBER,
-                  # measurement-power fields (ISSUE 6): rounds are run in
-                  # M independent windows; per-window paired medians ship
-                  # with the record and the config's headline ratio is
-                  # their MIN, so a >= 0.90 claim survives re-measurement
-                  "windows": NUMBER, "window_medians": ARRAY,
-                  "ratio_window_min": NUMBER,
-                  # comms wire accounting (ISSUE 5, parallel/wire.py):
-                  # the fixed selector's measured per-step exchange
-                  # payload and the format it was packed in
-                  "wire_format": STRING, "bytes_sent": NUMBER,
-                  # bucket-pipelined schedule (ISSUE 7): which schedule
-                  # the sparse column ran under. (The per-config exposed
-                  # exchange time lives on ``bench_overlap`` records —
-                  # the main arm never measured it, so the field was
-                  # dropped here; lint events flags such dead fields.)
-                  "overlap": STRING},
-    ),
-    # bench.py overlap arm (ISSUE 7): one record per config that ran the
-    # off-vs-auto schedule comparison on a pipeline-eligible uniform plan.
-    # exposed_*_ms fields are omitted when the paired delta sits below
-    # that cell's round-to-round noise (benchlib.noise_floored_delta_ms)
-    "bench_overlap": EventSchema(
-        required={"key": STRING, "model": STRING, "compressor": STRING,
-                  "bucket_size": NUMBER, "n_buckets": NUMBER,
-                  "seq_step_ms": NUMBER, "pipe_step_ms": NUMBER,
-                  "seq_overlap": STRING, "pipe_overlap": STRING},
-        optional={"exposed_seq_ms": NUMBER, "exposed_pipe_ms": NUMBER,
-                  "overlapped_bytes_sent": NUMBER, "wire_format": STRING,
-                  "bytes_sent": NUMBER, "pipe_vs_seq": NUMBER,
-                  "rounds": NUMBER, "windows": NUMBER},
-    ),
-    "bench_summary": EventSchema(
-        required={"metric": STRING, "value": NUMBER,
-                  "worst_config": STRING},
-        optional={"smoke": NUMBER,      # bool passes NUMBER (see above)
-                  "windows": NUMBER, "rounds": NUMBER},
-    ),
     # adaptive policy engine (docs/ADAPTIVE.md): knob retunes applied at
     # the recompile-safe boundary, and probation reverts; published from
     # the trainer thread (never from the engine's bus-exporter side)
@@ -279,17 +221,6 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
                   "step_s_p95": NUMBER, "step_s_p99": NUMBER,
                   "step_s_trend": NUMBER, "data_wait_frac": NUMBER},
     ),
-    # cross-run regression sentinel (analysis/regression_sentinel.py):
-    # the newest bench_history.jsonl record vs a baseline, classified
-    # with noise-floored paired deltas. Published so the policy engine's
-    # signals can ingest the verdict (policy/signals.py).
-    "bench_regression": EventSchema(
-        required={"status": STRING, "baseline_rev": STRING,
-                  "new_rev": STRING, "n_regressed": NUMBER,
-                  "n_improved": NUMBER, "n_flat": NUMBER},
-        optional={"worst_config": STRING, "worst_delta": NUMBER,
-                  "tolerance": NUMBER, "smoke": NUMBER},  # bool -> NUMBER
-    ),
 }
 
 
@@ -300,8 +231,8 @@ def validate_record(record: Mapping[str, Any],
     Non-strict (the default) implements the compatible-reader contract:
     absent envelope fields and unknown event kinds pass (old files, newer
     writers). ``strict`` additionally requires the full envelope and a
-    known event kind — the mode the CI bench smoke validates freshly
-    written streams with.
+    known event kind — the mode CI validates freshly written streams
+    with.
     """
     errors: List[str] = []
     event = record.get("event")

@@ -1,7 +1,7 @@
 """Exporters — where bus records go, behind one interface.
 
 Three concrete sinks cover the runtime's needs: an append-only JSONL file
-(the trainer's metrics stream and bench.py's machine-readable records), a
+(the trainer's metrics stream), a
 Prometheus node-exporter textfile (latest numeric gauges for scrape-based
 monitoring), and a bounded in-memory ring buffer (tests and interactive
 inspection). All are individually thread-safe: the bus serializes its own
@@ -37,9 +37,9 @@ class JSONLExporter(Exporter):
     """Append-only JSONL stream (one dict per line, line-buffered).
 
     ``path=None`` is a no-op sink (tests construct trainers without run
-    dirs). ``mode='w'`` truncates — bench.py uses it so each run's event
-    file validates as a single-run stream; the trainer keeps the default
-    append so a resumed run extends its own history.
+    dirs). ``mode='w'`` truncates, so the file validates as a single-run
+    stream; the trainer keeps the default append so a resumed run extends
+    its own history.
     """
 
     def __init__(self, path: Optional[str], mode: str = "a"):
@@ -120,9 +120,7 @@ class PrometheusTextfileExporter(Exporter):
     ``<prefix>_train_overlapped_bytes_sent_total`` sum the logged
     per-step payloads across intervals (sampled totals — the trainer
     logs every ``log_every`` steps, so multiply by the cadence for an
-    absolute estimate). The exposed exchange time stays a gauge
-    (``<prefix>_train_exposed_exchange_ms``): it is a level, not a
-    volume.
+    absolute estimate).
 
     ``health_status`` records (telemetry/health.py) additionally publish
     ``<prefix>_health_state`` (the 0/1/2 ok/degraded/critical code) and

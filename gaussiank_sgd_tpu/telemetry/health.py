@@ -2,9 +2,8 @@
 
 The stream already carries everything needed to say whether a run is
 healthy: per-interval ``train`` records (step/io seconds, EF norm,
-achieved density, exposed exchange), resilience ``skip``/``rollback``
-events, loader ``io_retry`` events, ``policy_revert`` records, and the
-sentinel's ``bench_regression`` verdicts. :class:`HealthMonitor`
+achieved density), resilience ``skip``/``rollback`` events, loader
+``io_retry`` events and ``policy_revert`` records. :class:`HealthMonitor`
 subscribes to the EventBus as an exporter, maintains rolling windows
 over those signals, and at every log boundary synthesizes ONE
 schema-validated ``health_status`` record: ``ok`` / ``degraded`` /
@@ -59,13 +58,11 @@ STATE_NAMES = {OK: "ok", DEGRADED: "degraded", CRITICAL: "critical"}
 
 # attributed-cause vocabulary (docs/OBSERVABILITY.md "Run health")
 CAUSE_DATA_WAIT = "data_wait"
-CAUSE_EXPOSED_EXCHANGE = "exposed_exchange"
 CAUSE_EF_PRESSURE = "ef_pressure"
 CAUSE_DENSITY_DRIFT = "density_drift"
 CAUSE_INSTABILITY = "instability"
 CAUSE_STEP_TIME = "step_time_regression"
 CAUSE_POLICY_THRASH = "policy_thrash"
-CAUSE_BENCH_REGRESSION = "bench_regression"
 # multi-process pod rig (training/launch.py): a worker process died
 # (supervisor's worker_lost records), or coordinator bootstrap is
 # retrying/exhausted (bootstrap_retry records)
@@ -79,7 +76,7 @@ CAUSE_RESIZE = "resize"
 # critical verdicts for these causes pre-arm the resilience monitor's
 # rollback (Trainer wiring). Deliberately narrow: instability's
 # skip-budget / loss-spike detectors already arm rollback themselves,
-# and a data stall or exposed exchange is a performance fault a rewind
+# and a data stall is a performance fault a rewind
 # cannot fix — only unbounded EF growth threatens the trajectory itself
 # before the loss detectors can see it.
 PRE_ARM_CAUSES = (CAUSE_EF_PRESSURE,)
@@ -88,9 +85,8 @@ PRE_ARM_CAUSES = (CAUSE_EF_PRESSURE,)
 @dataclass(frozen=True)
 class HealthPolicy:
     """Thresholds for the cause detectors. Every detector degrades
-    gracefully when its signal is absent from the stream (no
-    phase-timing probe -> no exposed-exchange verdict, dense warm-up ->
-    no EF/density verdicts), so a partial stream yields verdicts about
+    gracefully when its signal is absent from the stream (dense warm-up
+    -> no EF/density verdicts), so a partial stream yields verdicts about
     what it does carry instead of failing."""
 
     # rolling window, in logged train intervals
@@ -101,10 +97,6 @@ class HealthPolicy:
     data_wait_critical: float = 0.60
     io_retry_degraded: int = 2
     io_retry_critical: int = 6
-    # exposed_exchange: window-median exposed exchange ms vs the
-    # roofline floor when one is known, else vs the step time itself
-    exposed_vs_floor_degraded: float = 3.0
-    exposed_frac_degraded: float = 0.5
     # ef_pressure: EMA of ef_norm/grad_norm over sparse intervals —
     # degraded when high AND rising, critical when runaway
     ef_ratio_degraded: float = 10.0
@@ -158,10 +150,8 @@ class HealthMonitor:
     HTTP threads) serialize on this object's own lock."""
 
     def __init__(self, policy: Optional[HealthPolicy] = None,
-                 floor_ms: Optional[float] = None,
                  density_target: Optional[float] = None):
         self.policy = policy if policy is not None else HealthPolicy()
-        self._floor_ms = floor_ms
         self._density_target = density_target
         self._lock = threading.Lock()
         w = self.policy.window
@@ -182,8 +172,6 @@ class HealthMonitor:
         self._ef_recent: Deque[float] = deque(maxlen=4)
         self._quarantined = 0
         self._bootstrap_exhausted = False
-        self._bench_regressions = 0
-        self._last_bench_regression: Optional[str] = None
         # verdict / incident bookkeeping
         self._ticks = 0
         self._last_tick_step: Optional[int] = None
@@ -218,13 +206,6 @@ class HealthMonitor:
                     mx = _num(record, "max_retries")
                     if att is not None and mx is not None and att >= mx:
                         self._bootstrap_exhausted = True
-        elif event == "bench_regression":
-            with self._lock:
-                if record.get("status") == "regressed":
-                    self._bench_regressions += 1
-                    wc = record.get("worst_config")
-                    if isinstance(wc, str):
-                        self._last_bench_regression = wc
         elif event == "config":
             with self._lock:
                 if self._density_target is None:
@@ -260,7 +241,6 @@ class HealthMonitor:
                 "step": _num(record, "step"),
                 "step_s": _num(record, "step_s"),
                 "io_s": _num(record, "io_s"),
-                "exposed_ms": _num(record, "exposed_exchange_ms"),
                 "achieved": _num(record, "density_achieved"),
                 "sparse": sparse,
             })
@@ -302,25 +282,6 @@ class HealthMonitor:
                 flag(CAUSE_DATA_WAIT, levels[CAUSE_DATA_WAIT],
                      data_wait_frac=round(frac, 4), io_retries=retries,
                      intervals=n)
-
-            # exposed_exchange: median exposed ms vs the roofline floor
-            # (absolute budget) or, floorless, vs the step itself
-            exposed = sorted(r["exposed_ms"] for r in win
-                             if r["exposed_ms"] is not None)
-            if exposed:
-                med = statistics.median(exposed)
-                if self._floor_ms is not None and self._floor_ms > 0:
-                    if med > p.exposed_vs_floor_degraded * self._floor_ms:
-                        flag(CAUSE_EXPOSED_EXCHANGE, DEGRADED,
-                             exposed_ms_median=round(med, 3),
-                             floor_ms=round(self._floor_ms, 3))
-                elif step_s:
-                    sfrac = med / max(statistics.median(step_s) * 1e3,
-                                      1e-9)
-                    if sfrac > p.exposed_frac_degraded:
-                        flag(CAUSE_EXPOSED_EXCHANGE, DEGRADED,
-                             exposed_ms_median=round(med, 3),
-                             exposed_frac_of_step=round(sfrac, 4))
 
             # ef_pressure: high-and-rising, or runaway, EF/grad ratio
             ema = self._ef_ratio_ema
@@ -417,13 +378,6 @@ class HealthMonitor:
                 flag(CAUSE_COORDINATOR_STALL, DEGRADED,
                      bootstrap_retries=boots)
 
-            # bench_regression: the sentinel flagged this tree — a
-            # standing caution for the rest of the run
-            if self._bench_regressions > 0:
-                flag(CAUSE_BENCH_REGRESSION, DEGRADED,
-                     verdicts=self._bench_regressions,
-                     worst_config=self._last_bench_regression or "?")
-
             state = max(levels.values(), default=OK)
             active = sorted((c for c, lv in levels.items() if lv > OK),
                             key=lambda c: (-levels[c], c))
@@ -506,7 +460,6 @@ class HealthMonitor:
 
 def replay_health(events: Iterable[Mapping[str, Any]],
                   policy: Optional[HealthPolicy] = None,
-                  floor_ms: Optional[float] = None,
                   density_target: Optional[float] = None,
                   ) -> Tuple[List[Dict[str, Any]], HealthMonitor]:
     """Replay a recorded stream through a fresh monitor, ticking once
@@ -514,8 +467,7 @@ def replay_health(events: Iterable[Mapping[str, Any]],
     verdicts plus the monitor (for :meth:`HealthMonitor.summary`).
     Recorded ``health_status`` lines are skipped so a live-monitored
     stream replays to the same verdicts it logged."""
-    mon = HealthMonitor(policy=policy, floor_ms=floor_ms,
-                        density_target=density_target)
+    mon = HealthMonitor(policy=policy, density_target=density_target)
     out: List[Dict[str, Any]] = []
     prev_step = 0
     for rec in events:

@@ -2,10 +2,8 @@
 
 ``python -m gaussiank_sgd_tpu.telemetry report run.jsonl`` rebuilds, from
 the file alone, what the reference printed per display interval
-(SURVEY.md §3.2/§5): per-phase timing (io vs device step, plus the
-fwd/bwd | select | comm+update probe decomposition when --phase-timing
-logged it), comms volume (bytes over the wire per step/worker and the
-run-total estimate), compression efficiency (achieved vs target density,
+(SURVEY.md §3.2/§5): per-phase timing (io vs device step), comms volume
+(bytes over the wire per step/worker and the run-total estimate), compression efficiency (achieved vs target density,
 bytes vs a dense exchange), throughput, and the resilience history
 (skips, rollbacks, preemptions, io retries).
 
@@ -119,12 +117,6 @@ def summarize(events: List[Dict[str, Any]],
         "io_s_mean": _mean(_collect(train, "io_s")),
         "step_s_mean": _mean(_collect(train, "step_s")),
     }
-    # probe decomposition (only on --phase-timing runs, and only from
-    # intervals that were not compile-polluted)
-    for k in ("fwd_bwd_s", "select_s", "comm_update_s"):
-        vals = _collect(train, k)
-        if vals:
-            phases[f"{k}_mean"] = _mean(vals)
     summary["steps"] = {
         "logged_intervals": len(train),
         "last_step": last_step,
@@ -170,9 +162,8 @@ def summarize(events: List[Dict[str, Any]],
     summary["comms"] = comms
     summary["compression"] = compression
 
-    # overlap efficiency (docs/PERFORMANCE.md §5): how much of the sparse
-    # payload the pipelined schedule launched while later chunks were
-    # still compressing, and the exchange time it still left exposed
+    # overlap efficiency: how much of the sparse payload the pipelined
+    # schedule launched while later chunks were still compressing
     pipelined = [r for r in train if r.get("overlap") == "pipelined"]
     if pipelined:
         fracs = [float(r["overlapped_bytes_sent"]) / float(r["bytes_sent"])
@@ -183,17 +174,7 @@ def summarize(events: List[Dict[str, Any]],
         summary["overlap"] = {
             "pipelined_intervals": len(pipelined),
             "overlapped_frac_mean": _mean(fracs),
-            "exposed_exchange_ms_mean": _mean(
-                _collect(pipelined, "exposed_exchange_ms")),
         }
-    bench_ovl = by_kind.get("bench_overlap", [])
-    if bench_ovl:
-        summary["bench_overlap"] = [
-            {k: r.get(k) for k in ("key", "n_buckets", "seq_step_ms",
-                                   "pipe_step_ms", "pipe_vs_seq",
-                                   "exposed_seq_ms", "exposed_pipe_ms",
-                                   "overlapped_bytes_sent")}
-            for r in bench_ovl]
 
     # adaptive policy decision log (docs/ADAPTIVE.md): applies + reverts
     # in stream order, so the report shows WHAT the closed loop did and
@@ -288,11 +269,6 @@ def format_report(summary: Dict[str, Any]) -> str:
     lines.append("== per-phase timing (interval means) ==")
     lines.append(f"  io    {_fmt(t['io_s_mean'], ' ms', 1e3)}")
     lines.append(f"  step  {_fmt(t['step_s_mean'], ' ms', 1e3)}")
-    for key, label in (("fwd_bwd_s_mean", "fwd+bwd"),
-                       ("select_s_mean", "select"),
-                       ("comm_update_s_mean", "comm+update")):
-        if key in t:
-            lines.append(f"    {label:<12}{_fmt(t[key], ' ms', 1e3)}")
 
     tp = s["throughput"]
     lines.append("== throughput ==")
@@ -335,23 +311,7 @@ def format_report(summary: Dict[str, Any]) -> str:
         lines.append(
             f"  pipelined intervals  {ov['pipelined_intervals']}  "
             f"overlapped payload "
-            f"{_fmt(ov['overlapped_frac_mean'])} of bytes_sent  "
-            f"exposed exchange "
-            f"{_fmt(ov['exposed_exchange_ms_mean'], ' ms', digits=4)}")
-    if "bench_overlap" in s:
-        lines.append("== bench overlap arm (off vs pipelined) ==")
-        for row in s["bench_overlap"]:
-            exp = (f"exposed {_fmt(row.get('exposed_seq_ms'), digits=4)}"
-                   f" -> {_fmt(row.get('exposed_pipe_ms'), digits=4)} ms"
-                   if row.get("exposed_seq_ms") is not None
-                   or row.get("exposed_pipe_ms") is not None
-                   else "exposed delta below noise floor")
-            lines.append(
-                f"  {row.get('key', '?'):<24} "
-                f"{_fmt(row.get('seq_step_ms'), digits=4)} -> "
-                f"{_fmt(row.get('pipe_step_ms'), digits=4)} ms "
-                f"({_fmt(row.get('pipe_vs_seq'))}x, "
-                f"{row.get('n_buckets', '?')} buckets)  {exp}")
+            f"{_fmt(ov['overlapped_frac_mean'])} of bytes_sent")
 
     if "policy" in s:
         p = s["policy"]

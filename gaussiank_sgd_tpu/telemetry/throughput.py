@@ -13,7 +13,9 @@ owns both corrections:
   post-restore throughput is measured on the new trajectory only.
 
 MFU uses the same convention: only useful (unskipped) steps count model
-FLOPs, against the chip's peak (benchlib.PEAK_FLOPS_BY_KIND).
+FLOPs, against the chip's peak (:data:`PEAK_FLOPS_BY_KIND`); the step's
+FLOPs come from XLA's cost analysis of the program that ran
+(:func:`program_flops`).
 
 The tracker is thread-safe, and :meth:`signals` returns the one canonical
 :class:`ThroughputSignals` snapshot both the trainer's log line and the
@@ -27,6 +29,54 @@ import threading
 from collections import deque
 from dataclasses import dataclass
 from typing import Optional
+
+# Dense bf16 peak FLOP/s per chip, keyed by the EXACT jax ``device_kind``
+# (spellings as in jax's own pallas/mosaic/tpu_info.py). Source of every
+# figure: the Google Cloud TPU documentation page of that generation.
+# MFU here = model FLOPs / (step time * peak).
+PEAK_FLOPS_BY_KIND = {
+    "TPU v4": 275e12,        # "TPU v4"
+    "TPU v5 lite": 197e12,   # "TPU v5e"
+    "TPU v5": 459e12,        # "TPU v5p"
+    "TPU v5p": 459e12,
+    "TPU v6 lite": 918e12,   # "TPU v6e" (Trillium)
+}
+
+
+def device_peak_flops(device) -> Optional[float]:
+    """bf16 peak FLOP/s of the chip ``device`` (a ``jax.Device``). None
+    off-TPU (no MFU on CPU — the field is absent, "not measured"); a TPU
+    whose ``device_kind`` is not in the table raises — a missing peak is
+    an error, never another generation's figure."""
+    if device.platform != "tpu":
+        return None
+    if device.device_kind not in PEAK_FLOPS_BY_KIND:
+        raise KeyError(
+            f"no peak FLOP/s on record for TPU device_kind "
+            f"{device.device_kind!r}; add it to PEAK_FLOPS_BY_KIND "
+            f"(telemetry/throughput.py) with its source")
+    return PEAK_FLOPS_BY_KIND[device.device_kind]
+
+
+def program_flops(jitted, *args) -> Optional[float]:
+    """FLOP count of a jitted program from XLA's HLO cost analysis.
+
+    An *analytic* count computed from HLO op shapes (conv/matmul terms
+    dominate), not a measurement, and exact for the program actually
+    compiled. Lowers and compiles ``jitted`` for ``args`` — a cache hit
+    when that program already ran, a full compile when it has not. Errors
+    propagate; None only when the backend's analysis reports no FLOPs.
+    """
+    flops = jitted.lower(*args).compile().cost_analysis().get("flops", 0.0)
+    return float(flops) if flops else None
+
+
+def mfu(flops_per_step: Optional[float], step_seconds: float,
+        peak: Optional[float]) -> Optional[float]:
+    """Model-FLOPs utilization; None when FLOPs or peak are unavailable."""
+    if not flops_per_step or not peak or step_seconds <= 0:
+        return None
+    return flops_per_step / (step_seconds * peak)
 
 
 @dataclass(frozen=True)

@@ -167,16 +167,6 @@ class TrainConfig:
                                             # None = JSONL only
     telemetry_window: int = 50              # rolling window (steps) for the
                                             # throughput/MFU tracker
-    phase_timing: bool = False              # fwd/bwd + select + comm ms in
-                                            # every log line (the reference's
-                                            # per-interval io/fwd/bwd/comm
-                                            # breakdown, SURVEY.md §5).
-                                            # Opt-in: the two probe
-                                            # dispatches per interval cost
-                                            # ~2 fwd+bwd per log_every (~20%
-                                            # at log_every=10) plus two
-                                            # one-time compiles — real money
-                                            # at 57M params (code-review r4)
 
     def to_json(self) -> str:
         return json.dumps(dataclasses.asdict(self), default=str, indent=2)
@@ -331,12 +321,6 @@ def add_args(p: argparse.ArgumentParser, suppress_defaults: bool = False) -> Non
     p.add_argument("--output-dir", dest="output_dir", default=d.output_dir)
     p.add_argument("--log-every", dest="log_every", type=int,
                    default=d.log_every)
-    p.add_argument("--phase-timing", dest="phase_timing",
-                   action=argparse.BooleanOptionalAction,
-                   default=d.phase_timing,
-                   help="log fb=/sel=/comm= per interval via two probe "
-                        "dispatches (reference-style breakdown; costs ~2 "
-                        "extra fwd+bwd per log interval)")
     p.add_argument("--save-every-epochs", dest="save_every_epochs", type=int,
                    default=d.save_every_epochs)
     p.add_argument("--resume", default=None)

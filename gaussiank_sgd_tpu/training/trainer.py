@@ -50,7 +50,8 @@ from .metrics import PhaseTimers, make_logger
 from .resilience import (GracefulShutdown, ResilienceMonitor,
                          ResiliencePolicy, TrainingPreempted)
 from ..telemetry import (EventBus, JSONLExporter,
-                         PrometheusTextfileExporter, ThroughputTracker)
+                         PrometheusTextfileExporter, ThroughputTracker,
+                         throughput)
 from ..telemetry.health import (CRITICAL, PRE_ARM_CAUSES, HealthMonitor,
                                 HealthServer)
 from ..telemetry.profiler import ProfilerSession
@@ -74,13 +75,6 @@ class _Flight(NamedTuple):
 def _dtype_of(name: str):
     return {"bfloat16": jnp.bfloat16, "bf16": jnp.bfloat16,
             "float32": jnp.float32, "fp32": jnp.float32}[name]
-
-
-def _batch_shape_key(batch):
-    """Hashable (shape, dtype) signature of a batch tree — the retrace key
-    jit uses, so 'first dispatch at this key' == 'this dispatch compiles'."""
-    return tuple((tuple(a.shape), str(a.dtype))
-                 for a in jax.tree.leaves(batch))
 
 
 class Trainer:
@@ -135,11 +129,6 @@ class Trainer:
         self.timers = PhaseTimers()
         # perf_counter at the end of the last step the loop waited for
         self._t_synced = 0.0
-        # phase-breakdown compile hygiene (ADVICE r4): programs whose first
-        # dispatch (= jit compile) already happened, and whether the current
-        # log interval contains such a first dispatch
-        self._dispatched_fns: set = set()
-        self._interval_has_compile = False
 
         # ---- mesh (SURVEY.md §3.1: hvd.init + device binding -> mesh) ----
         self.sp = cfg.sp_size if cfg.sp_size > 1 else 0
@@ -202,14 +191,12 @@ class Trainer:
                 raise ValueError(
                     "--policy adaptive retunes the sparse exchange; "
                     "--compressor none has no knobs to retune")
-            from ..policy import (PolicyEngine, default_rules,
-                                  load_roofline_floor)
-            floor = load_roofline_floor(cfg.dnn, jax.default_backend())
+            from ..policy import PolicyEngine, default_rules
             self.engine = PolicyEngine(
                 default_rules(cfg),
                 publish=lambda event, payload: self.bus.publish(
                     {"event": event, **payload}),
-                knobs=self._policy_knobs(), floor_ms=floor)
+                knobs=self._policy_knobs())
             # the engine rides the bus as an exporter: its emit() only
             # ingests signals (never publishes — the bus lock is held)
             self.bus.attach(self.engine)
@@ -226,11 +213,7 @@ class Trainer:
         self.health: Optional[HealthMonitor] = None
         self._health_server: Optional[HealthServer] = None
         if cfg.health == "on" or cfg.health_port is not None:
-            from ..policy import load_roofline_floor
-            self.health = HealthMonitor(
-                floor_ms=load_roofline_floor(cfg.dnn,
-                                             jax.default_backend()),
-                density_target=cfg.density)
+            self.health = HealthMonitor(density_target=cfg.density)
             self.bus.attach(self.health)
             if cfg.health_port is not None:
                 self._health_server = HealthServer(
@@ -462,11 +445,6 @@ class Trainer:
             wire=cfg.wire,
             overlap=cfg.overlap,
         )
-        # drop caches keyed on the replaced programs (phase-timing probes,
-        # first-dispatch bookkeeping)
-        self._dispatched_fns = set()
-        self.__dict__.pop("_probes", None)
-        self.__dict__.pop("_probe_shapes", None)
 
     # ------------------------------------------------------------------
     @property
@@ -819,15 +797,13 @@ class Trainer:
         """Whether the host reads or replaces the finished state once
         global step ``done`` has ended, so that step ``done + 1`` must not
         be dispatched before: a cadence save; a log step at which the
-        policy engine may rebuild the programs or the phase probes time
-        programs of their own on the device; a profiler window that opens
-        or closes there. Known from the step number and the configuration
-        alone."""
+        policy engine may rebuild the programs; a profiler window that
+        opens or closes there. Known from the step number and the
+        configuration alone."""
         cfg = self.cfg
         if cfg.save_every_steps and done % cfg.save_every_steps == 0:
             return True
-        if done % cfg.log_every == 0 and (self.engine is not None
-                                          or cfg.phase_timing):
+        if done % cfg.log_every == 0 and self.engine is not None:
             return True
         return (self.profiler is not None
                 and self.profiler.transition_due(done))
@@ -853,7 +829,7 @@ class Trainer:
             batch = next(it)
         with self._span("h2d"):
             batch = shard_batch(self.mesh, batch, spec=self._batch_spec)
-        self._probe_batch = batch      # for _phase_breakdown at log time
+        self._probe_batch = batch      # for _maybe_probe_mfu at log time
         io_s = time.perf_counter() - t_io
         if self.profiler is not None:
             # jax.profiler trace window (SURVEY.md §5 Tracing rebuild
@@ -869,18 +845,6 @@ class Trainer:
                 jnp.zeros_like, self._state.carry))
         fn = (self.ts.dense_step if self._in_warmup(step)
               else self.ts.sparse_step)
-        if cfg.phase_timing:
-            # this interval's step_s mean will include this program's
-            # jit compile; mark it so _phase_breakdown skips the
-            # interval (ADVICE r4: subtracting compile-free probe times
-            # from a compile-polluted mean attributed the whole compile
-            # to comm_update_s). Keyed on (fn, batch shapes): bucketed
-            # variable-width pipelines (AN4) retrace on each new width,
-            # not only on the first dispatch.
-            key = (fn, _batch_shape_key(batch))
-            if key not in self._dispatched_fns:
-                self._dispatched_fns.add(key)
-                self._interval_has_compile = True
         t0 = time.perf_counter()
         with self._span("step_dispatch"):
             self._state, m = fn(self._state, batch)
@@ -1019,68 +983,6 @@ class Trainer:
         end-of-stream."""
         return data_lib.EpochStream(self.train_ds, self.cfg.seed, self.step)
 
-    def _phase_breakdown(self, step_s: float) -> Dict[str, object]:
-        # values are float seconds, except the string-valued
-        # 'phase_skipped' marker on compile-polluted intervals
-        """fwd/bwd, select+pack, and comm+update ms for the CURRENT state —
-        the reference's per-interval io/fwd/bwd/comm log breakdown
-        (SURVEY.md §5 Tracing row, VERDICT r3 item 6). Times two jitted
-        prefix programs of the sparse step on the last batch; comm+update
-        is the full step's remainder. Single-dispatch timings are
-        logging-grade — benchmark-grade phase numbers come from
-        analysis/bench_matrix.py's paired-round probe columns."""
-        if getattr(self, "_probe_batch", None) is None:
-            return {}          # nothing trained yet this process
-        if self._interval_has_compile:
-            # the interval-mean step_s includes the main step's jit compile
-            # while the probes' compiles are excluded below — subtracting
-            # would book the whole compile as comm_update_s (observed:
-            # comm=7202ms on a 112ms step). Skip this interval; the next
-            # one is compile-free (ADVICE r4). The flag is cleared when the
-            # timer interval closes (_log_train -> timers.reset()), so a
-            # quiet final log can't leak it into the next clean interval.
-            return {"phase_skipped": "compile_in_interval"}
-        if not hasattr(self, "_probes"):
-            self._probes = self.ts.make_probes()
-            self._probe_shapes = set()
-        skey = _batch_shape_key(self._probe_batch)
-        if skey not in self._probe_shapes:
-            # compile OUTSIDE the timed windows: the first timed call would
-            # otherwise report jit compilation (seconds-to-minutes at 57M)
-            # as fb=/sel= phase time (code-review r4). Per batch-shape key:
-            # bucketed pipelines retrace the probes on each new width too.
-            for fn in self._probes.values():
-                jax.block_until_ready(fn(self.state, self._probe_batch))
-            self._probe_shapes.add(skey)
-        t0 = time.perf_counter()
-        jax.block_until_ready(self._probes["grads"](self.state,
-                                                    self._probe_batch))
-        t_grads = time.perf_counter() - t0
-        out = {"fwd_bwd_s": round(t_grads, 6)}
-        if not self._in_warmup(self.step):
-            t0 = time.perf_counter()
-            jax.block_until_ready(self._probes["select"](self.state,
-                                                         self._probe_batch))
-            t_sel = time.perf_counter() - t0
-            out["select_s"] = round(max(t_sel - t_grads, 0.0), 6)
-            out["comm_update_s"] = round(max(step_s - t_sel, 0.0), 6)
-            if "noexch" in self._probes:
-                # the full-step comm-ablated twin (trainstep.py
-                # 'sparse_noexch'): step minus twin is the EXPOSED
-                # exchange time — what the pipelined schedule is paid to
-                # shrink. Logging-grade single dispatch; the
-                # noise-floored benchmark-grade number comes from
-                # bench.py's sparse_noexch arm.
-                t0 = time.perf_counter()
-                jax.block_until_ready(self._probes["noexch"](
-                    self.state, self._probe_batch))
-                t_nx = time.perf_counter() - t0
-                out["exposed_exchange_ms"] = round(
-                    max(step_s - t_nx, 0.0) * 1e3, 3)
-        else:
-            out["comm_update_s"] = round(max(step_s - t_grads, 0.0), 6)
-        return out
-
     def _maybe_probe_mfu(self, fn) -> None:
         """Resolve flops/step + device peak once (lazily, off the first
         logged interval) so the tracker can report MFU. Runs only where
@@ -1092,13 +994,13 @@ class Trainer:
         if self._mfu_probed or getattr(self, "_probe_batch", None) is None:
             return
         self._mfu_probed = True
-        from ..benchlib import device_peak_flops, program_flops
-        self._peak_flops = device_peak_flops(self.mesh.devices.flat[0])
+        self._peak_flops = throughput.device_peak_flops(
+            self.mesh.devices.flat[0])
         if self._peak_flops is None:
             return
         t0 = time.perf_counter()
-        self._flops_per_step = program_flops(fn, self._state,
-                                             self._probe_batch)
+        self._flops_per_step = throughput.program_flops(
+            fn, self._state, self._probe_batch)
         if self._flops_per_step is None:
             raise RuntimeError(
                 "XLA cost analysis reported no FLOPs for the step program "
@@ -1165,8 +1067,6 @@ class Trainer:
         if self.monitor is not None:
             rec["consecutive_skips"] = self.monitor.consecutive_skips
             rec["lr_scale"] = self._lr_scale
-        if self.cfg.phase_timing and not quiet:
-            rec.update(self._phase_breakdown(rec["step_s"]))
         aux = jax.device_get(m.aux)
         rec.update({k: float(v) for k, v in aux.items()})
         self.bus.publish(rec)
@@ -1188,20 +1088,13 @@ class Trainer:
                         break
         if not quiet:
             imgs = self.cfg.global_batch_size / max(rec["step_s"], 1e-9)
-            phases = ""
-            if "fwd_bwd_s" in rec:
-                phases = f" fb={1e3 * rec['fwd_bwd_s']:.1f}ms"
-                if "select_s" in rec:
-                    phases += f" sel={1e3 * rec['select_s']:.1f}ms"
-                phases += f" comm={1e3 * rec['comm_update_s']:.1f}ms"
             self.logger.info(
                 "step %d (ep %d) loss=%.4f lr=%.4g io=%.1fms step=%.1fms "
-                "(%.0f ex/s)%s sent=%dB %s", step, epoch, loss, lr,
-                1e3 * rec["io_s"], 1e3 * rec["step_s"], imgs, phases,
+                "(%.0f ex/s) sent=%dB %s", step, epoch, loss, lr,
+                1e3 * rec["io_s"], 1e3 * rec["step_s"], imgs,
                 rec["bytes_sent"],
                 " ".join(f"{k}={float(v):.4f}" for k, v in aux.items()))
         self.timers.reset()
-        self._interval_has_compile = False
         return rec
 
     # ------------------------------------------------------------------

@@ -38,7 +38,6 @@ import numpy as np
 from jax.experimental import topologies
 from jax.sharding import Mesh
 
-from gaussiank_sgd_tpu.benchlib import make_batch
 from gaussiank_sgd_tpu.compressors import get_compressor
 from gaussiank_sgd_tpu.models import get_model
 from gaussiank_sgd_tpu.parallel.bucketing import plan_for_params
@@ -49,6 +48,21 @@ from gaussiank_sgd_tpu.training.losses import make_loss_fn
 _COLLECTIVE = re.compile(
     r" (all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
     r"(-start)?\(")
+
+
+def _batch_shapes(spec, batch_size: int):
+    """The (x, y) shapes and dtypes of one batch of the model task."""
+    def ints(*shape):
+        return jax.ShapeDtypeStruct((batch_size,) + shape, jnp.int32)
+    x_float = jax.ShapeDtypeStruct((batch_size,) + spec.input_shape,
+                                   jnp.float32)
+    if spec.task == "classify":
+        return x_float, ints()
+    if spec.task in ("lm", "seq2seq"):
+        return ints(spec.input_shape[0]), ints(spec.input_shape[0])
+    if spec.task == "ctc":
+        return x_float, ints(16)
+    raise ValueError(spec.task)
 
 
 def main(argv=None) -> None:
@@ -70,9 +84,8 @@ def main(argv=None) -> None:
 
     spec = get_model(args.model, args.dataset, dtype=jnp.bfloat16)
     recurrent = args.model == "lstm"
-    batch = jax.eval_shape(
-        lambda: make_batch(spec, args.batch_size * args.chips))
-    two = jax.eval_shape(lambda: make_batch(spec, 2))
+    batch = _batch_shapes(spec, args.batch_size * args.chips)
+    two = _batch_shapes(spec, 2)
     init_in = two if spec.task == "seq2seq" else two[:1]
     variables = jax.eval_shape(
         lambda *a: spec.module.init({"params": jax.random.PRNGKey(0)}, *a,

@@ -98,25 +98,6 @@ def test_data_wait_io_retry_burst_without_train_records():
     assert v["state"] == "ok"
 
 
-def test_exposed_exchange_vs_floor_and_fraction_fallback():
-    mon = HealthMonitor(floor_ms=2.0)
-    v = feed(mon, [train_rec((i + 1) * 2, exposed_exchange_ms=9.0)
-                   for i in range(4)])
-    assert v[-1]["causes"] == ["exposed_exchange"]
-    assert v[-1]["evidence"]["exposed_exchange"]["floor_ms"] == 2.0
-    # under the 3x floor band: ok
-    mon2 = HealthMonitor(floor_ms=2.0)
-    v2 = feed(mon2, [train_rec((i + 1) * 2, exposed_exchange_ms=4.0)
-                     for i in range(4)])
-    assert v2[-1]["state"] == "ok"
-    # floorless fallback: exposed > half the median step
-    mon3 = HealthMonitor()
-    v3 = feed(mon3, [train_rec((i + 1) * 2, step_s=0.01,
-                               exposed_exchange_ms=8.0)
-                     for i in range(4)])
-    assert v3[-1]["causes"] == ["exposed_exchange"]
-
-
 def test_ef_pressure_critical_and_pre_arm_vocabulary():
     mon = HealthMonitor()
     v = feed(mon, [train_rec((i + 1) * 2, ef_norm=200.0 + i)
@@ -174,7 +155,7 @@ def test_step_time_regression_compares_windows():
     assert feed(mon2, rev)[-1]["state"] == "ok"
 
 
-def test_policy_thrash_and_bench_regression_standing_caution():
+def test_policy_thrash_ages_out_of_the_window():
     mon = HealthMonitor()
     for step in (2, 4):
         mon.emit({"event": "policy_revert", "step": step, "rule": "r",
@@ -183,15 +164,10 @@ def test_policy_thrash_and_bench_regression_standing_caution():
     v = mon.tick(4)
     assert "policy_thrash" in v["causes"]
     assert v["evidence"]["policy_thrash"]["quarantined"] == 2
-    mon.emit({"event": "bench_regression", "status": "regressed",
-              "baseline_rev": "a", "new_rev": "b", "n_regressed": 1,
-              "n_improved": 0, "n_flat": 3, "worst_config": "mnist"})
-    v = mon.tick(6)
-    assert "bench_regression" in v["causes"]
-    # sticky: still flagged many quiet intervals later
-    for step in range(8, 30, 2):
+    # not sticky: the reverts leave the window once quiet intervals pass
+    for step in range(6, 30, 2):
         v = mon.tick(step)
-    assert v["causes"] == ["bench_regression"]
+    assert v["state"] == "ok" and v["causes"] == []
 
 
 # ---------------------------------------------- record contract & replay

@@ -12,9 +12,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from gaussiank_sgd_tpu import benchlib
-from gaussiank_sgd_tpu.benchlib import (device_peak_flops, mfu,
-                                        program_flops)
+from gaussiank_sgd_tpu.telemetry import throughput
+from gaussiank_sgd_tpu.telemetry.throughput import (device_peak_flops, mfu,
+                                                    program_flops)
 
 
 def test_program_flops_matches_matmul_analytic():
@@ -95,8 +95,8 @@ def test_trainer_mfu_probe_reuses_the_compiled_step(tmp_path, monkeypatch):
     a cache hit on the program that just ran, never a compile of its own
     (at the dense->sparse boundary it used to pick the step that had not
     run yet and paid its whole compile inside the log call)."""
-    monkeypatch.setattr(benchlib, "device_peak_flops",
-                        lambda device=None: 1e12)
+    monkeypatch.setattr(throughput, "device_peak_flops",
+                        lambda device: 1e12)
     in_probe, compiled_in_probe = [False], []
 
     def on_duration(event, duration, **kw):
@@ -129,9 +129,9 @@ def test_trainer_mfu_probe_failure_is_an_error_where_a_peak_is_known(
     def boom(jitted, *args):
         raise RuntimeError("cost analysis unavailable")
 
-    monkeypatch.setattr(benchlib, "device_peak_flops",
-                        lambda device=None: 1e12)
-    monkeypatch.setattr(benchlib, "program_flops", boom)
+    monkeypatch.setattr(throughput, "device_peak_flops",
+                        lambda device: 1e12)
+    monkeypatch.setattr(throughput, "program_flops", boom)
     t = _mfu_trainer(tmp_path)
     with pytest.raises(RuntimeError, match="cost analysis unavailable"):
         t.fit()

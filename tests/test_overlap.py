@@ -6,7 +6,7 @@ is BIT-IDENTICAL to the sequential program after N steps — params, opt
 state, EF residual, compressor state — across both exchange paths, both
 wire modes, rng-consuming selectors, the flat optimizer, and the fused
 EF+select kernel. Ineligible builds and `--overlap off` keep the
-sequential program. Plus: the exchange-ablated noexch twin, the
+sequential program. Plus: the
 overlapped-bytes metric, elastic restore across overlap geometry, and
 the policy-engine treatment of the overlap knob as a program-layout
 change (arm-record reset + recompile charge, mirroring density/bucket).
@@ -178,32 +178,6 @@ def test_overlap_off_is_sequential_and_validated():
         build_dp_train_step(loss_fn, optax.sgd(0.05),
                             get_compressor("topk", density=0.25),
                             plan, mesh, overlap="always")
-
-
-# ----------------------------------------------------------- noexch twin
-
-def test_noexch_multi_step_and_probe():
-    """The exchange-ablated timing twin: compiles and runs under both
-    schedules, keeps the loss finite, and rides make_probes as 'noexch'
-    (the trainer's exposed_exchange_ms probe)."""
-    params, loss_fn, make_batch = make_problem()
-    mesh = data_parallel_mesh()
-    plan = plan_for_params(params, 0.25, 128, policy="uniform")
-    batch = shard_batch(mesh, make_batch(64))
-    for overlap in ("off", "auto"):
-        ts = build_dp_train_step(loss_fn, optax.sgd(0.05),
-                                 get_compressor("topk", density=0.25),
-                                 plan, mesh, overlap=overlap)
-        fn = ts.make_multi_step("sparse_noexch", 2)
-        state, m = fn(ts.init_state(params, jax.random.PRNGKey(42)), batch)
-        assert np.isfinite(float(m.loss))
-        probes = ts.make_probes()
-        assert "noexch" in probes
-        _, mp = probes["noexch"](
-            ts.init_state(params, jax.random.PRNGKey(42)), batch)
-        assert np.isfinite(float(mp.loss))
-    with pytest.raises(ValueError):
-        ts.make_multi_step("bogus_kind", 2)
 
 
 # ------------------------------------------- elastic restore across geometry

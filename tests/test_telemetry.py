@@ -188,14 +188,14 @@ def test_prometheus_textfile_exporter(tmp_path):
 
 def test_prometheus_comms_counters_accumulate(tmp_path):
     """bytes_sent/overlapped_bytes_sent additionally export as monotonic
-    *_total counters (rate()-able wire traffic), while exposed_exchange_ms
-    stays a latest-value gauge — and the write is still tmp+rename."""
+    *_total counters (rate()-able wire traffic), while a level such as
+    ef_norm stays a latest-value gauge — and the write is still
+    tmp+rename."""
     path = str(tmp_path / "gksgd.prom")
     ex = PrometheusTextfileExporter(path)
-    for exposed in (2.0, 1.5):
+    for ef_norm in (2.0, 1.5):
         ex.emit({"event": "train", "step": 1, "bytes_sent": 100,
-                 "overlapped_bytes_sent": 60,
-                 "exposed_exchange_ms": exposed})
+                 "overlapped_bytes_sent": 60, "ef_norm": ef_norm})
     ex.emit({"event": "skip", "step": 2, "nonfinite": 1.0})  # no counters
     ex.close()
     lines = dict(l.rsplit(" ", 1) for l in open(path).read().splitlines()
@@ -203,7 +203,7 @@ def test_prometheus_comms_counters_accumulate(tmp_path):
     assert float(lines["gksgd_train_bytes_sent_total"]) == 200
     assert float(lines["gksgd_train_overlapped_bytes_sent_total"]) == 120
     assert float(lines["gksgd_train_bytes_sent"]) == 100       # gauge: last
-    assert float(lines["gksgd_train_exposed_exchange_ms"]) == 1.5
+    assert float(lines["gksgd_train_ef_norm"]) == 1.5
     assert "gksgd_skip_step_total" not in lines
     assert not [f for f in os.listdir(tmp_path) if ".tmp." in f]
 
@@ -587,7 +587,7 @@ def test_report_program_audit_join(tmp_path):
 
     # keyless stream: no match, and the report says so rather than
     # listing every arm
-    s2 = summarize([{"event": "bench_summary", "schema_version": 1}],
+    s2 = summarize([{"event": "checkpoint", "schema_version": 1}],
                    audit=audit)
     assert s2["program_audit"]["matched_arms"] == []
     assert "no audited arm matches" in format_report(s2)

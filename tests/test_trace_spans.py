@@ -145,15 +145,12 @@ def test_one_iteration_per_step_with_its_children_in_order(traced_run):
     # a cadence save: step 2 and step 4 are waited for before the next
     # step is dispatched
     (dict(save_every_steps=2), 5, [1, 0, 1, 0, 0]),
-    # a log step at which the phase probes time programs of their own
-    (dict(phase_timing=True, log_every=3), 5, [1, 1, 0, 1, 0]),
     # a log step at which the policy engine may rebuild the programs
     (dict(policy="adaptive", log_every=3), 5, [1, 1, 0, 1, 0]),
     # a profiler window over steps [2, 3): it opens before step index 2 is
     # dispatched and closes before step index 3 is
     (dict(profile_steps=(2, 3)), 5, [1, 0, 0, 1, 0]),
-], ids=["free", "cadence-save", "phase-timing", "policy-engine",
-        "profiler-window"])
+], ids=["free", "cadence-save", "policy-engine", "profiler-window"])
 def test_the_loop_does_not_run_ahead_where_the_host_acts(tmp_path, kw, n,
                                                          ahead):
     t = Trainer(make_cfg(tmp_path, run_id="ahead", **kw))

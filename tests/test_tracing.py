@@ -1,12 +1,11 @@
-"""Step-timeline tracing + cross-run regression sentinel.
+"""Step-timeline tracing.
 
 Covers the observability layer this PR adds on top of the event bus
 (docs/OBSERVABILITY.md "Tracing & trajectory"): TraceContext span
 emission, thread-local stamping and the trace-off byte-identity
 guarantee; the trace CLI round-trip on a LIVE traced run (the host spans
-of the loop, rendered by their own clock); the chaos span tree (rollback span parented to the dying
-trajectory, rotated root afterwards); and the regression sentinel's
-noise-floored classification over the committed bench history.
+of the loop, rendered by their own clock); and the chaos span tree
+(rollback span parented to the dying trajectory, rotated root afterwards).
 """
 
 import json
@@ -14,14 +13,9 @@ import os
 
 import pytest
 
-from analysis.regression_sentinel import (_perturb, classify_config,
-                                          compare, pick_baseline)
-from analysis.regression_sentinel import main as sentinel_main
 from gaussiank_sgd_tpu.telemetry import (EventBus, JSONLExporter,
                                          MemoryExporter, TraceContext,
-                                         append_history,
                                          build_chrome_trace,
-                                         build_history_record, load_history,
                                          validate_stream)
 from gaussiank_sgd_tpu.telemetry.__main__ import main as telemetry_cli
 from gaussiank_sgd_tpu.telemetry.events import validate_file
@@ -243,163 +237,3 @@ def test_chaos_rollback_span_tree(tmp_path):
     # the rollback event record itself is stamped into the old trajectory
     rb_ev = next(r for r in events if r.get("event") == "rollback")
     assert rb_ev["span_id"] == rb[0]["span_id"]
-
-
-# ------------------------------------------------------ history + sentinel
-
-def _history_rec(rev, ts, ratios=(0.90, 0.92), smoke=True, key="mnistnet"):
-    med = sorted(ratios)[0]
-    return {"history_schema": 1, "ts": ts, "git_rev": rev, "smoke": smoke,
-            "platform": "cpu", "metric": "ratio_window_min_min",
-            "value": med, "worst_config": key,
-            "arms": {"wire": True, "overlap": True, "policy": None},
-            "configs": {key: {
-                "ratio_median": sum(ratios) / len(ratios),
-                "ratio_window_min": med,
-                "window_medians": list(ratios), "windows": len(ratios),
-                "rounds": 12}}}
-
-
-def test_history_record_round_trip(tmp_path):
-    result = {"metric": "ratio_window_min_min", "value": 0.9,
-              "detail": {"platform": "cpu", "worst_config": "mnistnet",
-                         "configs": {"mnistnet": {
-                             "ratio_median": 0.91, "ratio_window_min": 0.9,
-                             "window_medians": [0.9, 0.92], "windows": 2,
-                             "rounds": 12, "noise": "dropme",
-                             "overlap_arm": {"exposed_seq_ms": 2.0,
-                                             "n_buckets": 52}}}}}
-    rec = build_history_record(result, smoke=True, ts=123.4567,
-                               git_rev="abc1234")
-    path = str(tmp_path / "hist.jsonl")
-    append_history(path, rec)
-    # a record from a FUTURE schema must be skipped, not fatal
-    append_history(path, {"history_schema": 99, "git_rev": "future"})
-    loaded = load_history(path)
-    assert len(loaded) == 1
-    got = loaded[0]
-    assert got["git_rev"] == "abc1234" and got["smoke"] is True
-    cell = got["configs"]["mnistnet"]
-    assert cell["window_medians"] == [0.9, 0.92]
-    assert "noise" not in cell          # only catalogued fields travel
-    assert cell["overlap_arm"]["n_buckets"] == 52
-    assert got["arms"]["overlap"] is True
-
-
-def test_sentinel_detects_regression_and_ignores_jitter():
-    """The classifier fires on a 10% ratio drop and stays quiet when the
-    window medians move by round-to-round noise only (the reused
-    noise_floored_delta_ms MAD floor)."""
-    base = _history_rec("aaa0000", 100.0)
-    degraded = _perturb(base, 0.90)
-    v = compare(base, degraded, tol=0.05)
-    assert v["status"] == "regressed" and v["n_regressed"] == 1
-    assert v["worst_config"] == "mnistnet" and v["worst_delta"] < 0
-    jittered = _perturb(base, 1.0, jitter=0.003)
-    assert compare(base, jittered, tol=0.05)["status"] != "regressed"
-    improved = _perturb(base, 1.10)
-    assert compare(base, improved, tol=0.05)["status"] == "improved"
-
-
-def test_sentinel_scalar_fallback_without_window_medians():
-    a = _history_rec("aaa0000", 100.0)
-    b = _history_rec("bbb1111", 200.0, ratios=(0.80, 0.82))
-    for rec in (a, b):
-        del rec["configs"]["mnistnet"]["window_medians"]
-    status, delta = classify_config(a, b, "mnistnet", tol=0.05)
-    assert status == "regressed" and delta == pytest.approx(-0.10, abs=1e-6)
-
-
-def test_sentinel_baseline_scoping():
-    """Baseline picking skips records with a different smoke flag, later
-    timestamps, disjoint configs, and hand-authored synthetic rows."""
-    hist = [
-        _history_rec("real0000", 50.0, smoke=False),
-        _history_rec("other000", 60.0, key="vgg16"),
-        _history_rec("good0000", 70.0),
-        _history_rec("new00000", 100.0),
-    ]
-    base = pick_baseline(hist, hist[-1], None, None)
-    assert base is not None and base["git_rev"] == "good0000"
-    only = [_history_rec("lonely00", 10.0)]
-    assert pick_baseline(only, only[0], None, None) is None
-    # a "synthetic": true seed row must never anchor a verdict on the
-    # auto path — but an explicit --baseline-rev still reaches it
-    fake = dict(_history_rec("fake0000", 80.0), synthetic=True)
-    hist_f = [_history_rec("good0000", 70.0), fake,
-              _history_rec("new00000", 100.0)]
-    base = pick_baseline(hist_f, hist_f[-1], None, None)
-    assert base is not None and base["git_rev"] == "good0000"
-    newest = _history_rec("new00000", 100.0)
-    assert pick_baseline([fake, newest], newest, None, None) is None
-    explicit = pick_baseline(hist_f, hist_f[-1], "fake0000", None)
-    assert explicit is not None and explicit["git_rev"] == "fake0000"
-
-
-def test_sentinel_cli_end_to_end(tmp_path, capsys):
-    """Exit codes + emitted event: 1 on regression (with a strict-valid
-    bench_regression record for the policy signals to ingest), 0 on
-    improvement, 0 with 'nothing to compare' on a single-record history,
-    2 on an empty file."""
-    hist = str(tmp_path / "hist.jsonl")
-    base = _history_rec("aaa0000", 100.0)
-    append_history(hist, base)
-    append_history(hist, _perturb(base, 0.90))
-    ev_path = str(tmp_path / "verdict.jsonl")
-    rc = sentinel_main(["--history", hist, "--emit-event", ev_path])
-    out = capsys.readouterr().out
-    assert rc == 1 and "REGRESSED" in out and "bench trajectory" in out
-    rep = validate_file(ev_path, strict=True)
-    assert rep.ok, rep.errors
-    verdict = json.loads(open(ev_path).read().strip())
-    assert verdict["event"] == "bench_regression"
-    assert verdict["status"] == "regressed"
-    assert verdict["worst_config"] == "mnistnet"
-
-    hist2 = str(tmp_path / "hist2.jsonl")
-    append_history(hist2, base)
-    append_history(hist2, _perturb(base, 1.10))
-    assert sentinel_main(["--history", hist2]) == 0
-    assert "IMPROVED" in capsys.readouterr().out
-
-    hist3 = str(tmp_path / "hist3.jsonl")
-    append_history(hist3, base)
-    assert sentinel_main(["--history", hist3]) == 0
-    assert "nothing to compare" in capsys.readouterr().out
-
-    assert sentinel_main(["--history", str(tmp_path / "missing.jsonl")]) == 2
-    capsys.readouterr()
-
-    # --self-test: the CI wiring check passes on a real history
-    assert sentinel_main(["--history", hist, "--self-test"]) == 0
-    assert "self-test OK" in capsys.readouterr().out
-
-
-def test_sentinel_verdict_feeds_policy_signals():
-    """The emitted bench_regression record is ingestible by the policy
-    engine's signals (the closed-loop satellite): regressed verdicts
-    count, non-regressed ones don't."""
-    from gaussiank_sgd_tpu.policy.signals import PolicySignals
-    sig = PolicySignals()
-    sig.update({"event": "bench_regression", "status": "regressed",
-                "worst_config": "vgg16-u8192", "new_rev": "abc"})
-    sig.update({"event": "bench_regression", "status": "improved",
-                "new_rev": "def"})
-    snap = sig.snapshot()
-    assert snap.bench_regressions == 1
-    assert snap.last_bench_regression == "vgg16-u8192"
-
-
-def test_committed_history_is_sentinel_clean():
-    """The repo's committed bench history must load, self-test, and not
-    classify the committed tip as regressed — the CI gate's contract."""
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "analysis", "artifacts",
-        "bench_history.jsonl")
-    hist = load_history(path)
-    assert hist, "committed bench_history.jsonl is missing or empty"
-    assert all(r.get("history_schema") == 1 for r in hist)
-    new = hist[-1]
-    base = pick_baseline(hist, new, None, None)
-    if base is not None:
-        assert compare(base, new, tol=0.05)["status"] != "regressed"
