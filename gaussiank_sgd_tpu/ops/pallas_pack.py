@@ -32,11 +32,13 @@ TPU-shaped two-level scheme:
      over the winner's one-hot. HBM traffic is exactly one read of the
      buffer plus the (tiny) candidate tiles.
   2. **In-XLA (small)**: the candidate buffer has ``nc = n/SEG`` slots
-     (64x smaller than the gradient at the contract density), so a top-k
-     over candidate magnitudes — exact ``lax.top_k`` up to
-     {EXACT_CAND_MAX_K}k candidates (``_EXACT_CAND_MAX``),
-     ``approx_max_k`` beyond (misses defer to EF) — picks the final k
-     pairs in f32.
+     (64x smaller than the gradient at the contract density), of which
+     about k hold a candidate. The k of largest magnitude are picked in
+     f32 WITHOUT a sort (``_cand_top_k``): the k-th magnitude by 31
+     counting passes over the buffer, the slots at or above it ranked in
+     position order by prefix counts, and the k pairs read by a k-sized
+     row gather. Exactly ``lax.top_k``'s set; what it passes over (only
+     ever candidates below the k-th magnitude) stays in the residual.
 
 The fused **EF+select** form (``_ef_select_kernel`` /
 ``gaussian_fused_ef_compress_batched``) additionally folds the error-
@@ -447,44 +449,125 @@ def fused_select_candidates(
     return vals[0], idxs[0], counts[0]
 
 
-_EXACT_CAND_MAX = 1 << 17
+def _lane_prefix(mask: jax.Array, *, inclusive: bool) -> jax.Array:
+    """How many True lanes lie before (or at) each lane of its row, for a
+    ``[rows, 128]`` mask: 0/1 in bf16 times a triangle of ones on the MXU
+    with f32 sums — exact, a row holds at most 128."""
+    ones = jnp.ones((_LANES, _LANES), jnp.bfloat16)
+    tri = jnp.triu(ones, 0 if inclusive else 1)
+    return jnp.dot(mask.astype(jnp.bfloat16), tri,
+                   preferred_element_type=jnp.float32).astype(jnp.int32)
 
-# The module docstring's candidate-count claim is DERIVED from the constant
-# (ADVICE r5: the prose said 512k while the code said 128k for a whole
-# round — a placeholder + substitution makes divergence impossible;
-# tests/test_pallas_pack.py asserts the substitution happened).
-if __doc__:  # -OO strips docstrings
-    __doc__ = __doc__.replace("{EXACT_CAND_MAX_K}",
-                              str(_EXACT_CAND_MAX >> 10))
+
+def _kth_key(key: jax.Array, k: int) -> jax.Array:
+    """The k-th largest of the int32 ranking keys: the largest ``t`` with
+    ``count(key >= t) >= k``, or 1 when fewer than k keys are positive
+    (every valid slot then passes ``key >= t``). Built bit by bit from the
+    top in 31 counting passes over the buffer — non-negative floats order
+    as their bit patterns, which the kernel's key already relies on."""
+    t = jnp.zeros((), jnp.int32)
+    for bit in range(30, -1, -1):
+        cand = t | (1 << bit)
+        t = jnp.where(jnp.sum(key >= cand) >= k, cand, t)
+    return jnp.maximum(t, 1)
 
 
-def _cand_top_k(vals: jax.Array, k: int):
-    """Top-k over the candidate magnitudes: exact ``lax.top_k`` while the
-    buffer is small, ``approx_max_k`` (recall 0.95) beyond — sort-based
-    top_k is TPU-slow (measured ~1.1 ms at 890k candidates vs ~0.8 ms
-    approx; the 128k ceiling also routes the 15-25M CNN configs' 234-391k
-    buffers to the approx path). The ~5% approx misses at the k-boundary
-    stay in the EF residual and are re-selected next step."""
+def _cand_top_k(vals: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
+    """Where the k largest candidate magnitudes sit, over the ``[nc/128,
+    128]`` view the kernel wrote: ``(rank [nc/128, 128], row [k])``.
+    ``rank[r, l]`` counts the chosen slots at or before ``(r, l)`` in
+    row-major order, so the j-th chosen slot is the first position of row
+    ``row[j]`` whose rank exceeds j, and an output slot past the last
+    chosen one finds none (:func:`_read_slots`). The chosen slots are
+    exactly the set ``lax.top_k(|vals|, k)`` picks (ties at the k-th
+    magnitude go to the lower position, as there), zeros never.
+
+    No sort. About k of the nc slots hold a candidate and the order of the
+    k pairs is free, so ranking all nc is wasted work — and dear: under the
+    batched forms' ``vmap`` the sort's operand is ``[1, nc]``, which the
+    chip sorts ten times slower than a flat ``[nc]`` (3.47 ms a step at
+    nc = 399 360, the longest operation of ResNet-50's whole step; PERF.md
+    section 6, PR 30). Instead: the k-th magnitude by counting
+    (:func:`_kth_key`), the slots at or above it ranked in position order
+    by prefix counts (lanes on the MXU, rows by a cumulative sum), and the
+    row of every output slot from the row totals. One path for every
+    (nc, k): flat, batched, cold start, a dead bucket."""
     with jax.named_scope("cand_topk"):
-        key = jnp.abs(vals)
-        if vals.shape[0] <= _EXACT_CAND_MAX:
-            return lax.top_k(key, k)
-        return lax.approx_max_k(key, k, recall_target=0.95)
+        rows = vals.shape[0] // _LANES
+        key = lax.bitcast_convert_type(
+            jnp.abs(vals), jnp.int32).reshape(rows, _LANES)
+        t = _kth_key(key, k)
+        above = key > t
+        tied = key == t
+        # of the slots tied at the k-th magnitude, the first in position
+        # order fill what `above` leaves of k
+        room = k - jnp.sum(above)
+        tied_in_row = jnp.sum(tied, axis=1)
+        tied_before = ((jnp.cumsum(tied_in_row) - tied_in_row)[:, None]
+                       + _lane_prefix(tied, inclusive=False))
+        chosen = above | (tied & (tied_before < room))
+        chosen_in_row = jnp.sum(chosen, axis=1)
+        through_row = jnp.cumsum(chosen_in_row)
+        rank = ((through_row - chosen_in_row)[:, None]
+                + _lane_prefix(chosen, inclusive=True))
+        # row of slot j = how many rows' running totals are <= j: a mark
+        # per row at its total (an nc/128-sized scatter), summed along j
+        row_ends = jnp.zeros((k,), jnp.int32).at[through_row].add(
+            1, mode="drop", indices_are_sorted=True)
+        row = jnp.minimum(jnp.cumsum(row_ends), rows - 1)
+        return rank, row
+
+
+# output slots read per pass of _read_slots: three [block, 128] int32 row
+# gathers live at once (50 MB) whatever k is
+_SLOT_BLOCK = 1 << 15
+
+
+def _read_slots(rank: jax.Array, bits: jax.Array, idxs: jax.Array,
+                row: jax.Array, j: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """(value bits, index) of output slots ``j`` (rows ``row``) from the
+    ``[nc/128, 128]`` views: each slot gathers its ROW of ranks, finds its
+    lane by comparing them with j, and picks that lane of the row's values
+    and indices. A 128-lane row gather costs the chip a third of a scalar
+    gather from the flat buffer. A slot past the last chosen one finds no
+    lane and reads (0, 0)."""
+    with jax.named_scope("cand_topk"):
+        lane = jnp.sum(rank[row] <= j[:, None], axis=1)
+    with jax.named_scope("pack"):
+        hit = (lax.broadcasted_iota(jnp.int32, (j.shape[0], _LANES), 1)
+               == lane[:, None])
+        return (jnp.sum(jnp.where(hit, bits[row], 0), axis=1),
+                jnp.sum(jnp.where(hit, idxs[row], 0), axis=1))
 
 
 def _select_candidates_topk(vals: jax.Array, idxs: jax.Array, k: int,
                             n: int) -> Tuple[jax.Array, jax.Array]:
     """The selection half of the fused pack: ``(sent_idx [k], val [k])``
-    with the out-of-range sentinel ``n`` on invalid slots (kv > 0 validity
-    rule: a selected subnormal whose key rounds to the 0 sentinel stays in
-    the residual). Small outputs only, so stateful wrappers can route the
-    result through a ``lax.cond`` without paying the big-buffer
-    cond-boundary copy (see base.select_by_mask)."""
-    kv, kpos = _cand_top_k(vals, k)
+    with the out-of-range sentinel ``n`` on invalid slots (|val| > 0
+    validity rule: a selected subnormal whose key rounds to the 0 sentinel
+    stays in the residual). Small outputs only, so stateful wrappers can
+    route the result through a ``lax.cond`` without paying the big-buffer
+    cond-boundary copy (see base.select_by_mask). Values travel as bits:
+    what is sent is the kernel's float32 bit for bit."""
+    rank, row = _cand_top_k(vals, k)
     with jax.named_scope("pack"):
-        valid = kv > 0
-        val = jnp.where(valid, vals[kpos], 0.0)
-        sent_idx = jnp.where(valid, idxs[kpos], n).astype(jnp.int32)
+        read = functools.partial(
+            _read_slots, rank,
+            lax.bitcast_convert_type(vals, jnp.int32).reshape(rank.shape),
+            idxs.reshape(rank.shape))
+        j = jnp.arange(k, dtype=jnp.int32)
+        if k <= _SLOT_BLOCK:
+            bits, idx = read(row, j)
+        else:       # block by block, so the row gathers stay small
+            pad = -k % _SLOT_BLOCK
+            blocks = [jnp.pad(a, (0, pad), constant_values=c).reshape(
+                -1, _SLOT_BLOCK) for a, c in ((row, 0), (j, k))]
+            bits, idx = (a.reshape(-1)[:k] for a in
+                         lax.map(lambda rj: read(*rj), tuple(blocks)))
+        val = lax.bitcast_convert_type(bits, jnp.float32)
+        valid = jnp.abs(val) > 0      # an unfilled slot read (0, 0)
+        val = jnp.where(valid, val, 0.0)
+        sent_idx = jnp.where(valid, idx, n).astype(jnp.int32)
     return sent_idx, val
 
 

@@ -15,6 +15,7 @@ prove (chip_smoke.py).
 
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ import pytest
 
 from gaussiank_sgd_tpu.compressors import get_compressor
 from gaussiank_sgd_tpu.ops.pallas_pack import (
-    ef_padded_chunk, fused_ef_select_candidates_chunked,
+    _chunk_geometry, ef_padded_chunk, fused_ef_select_candidates_chunked,
     fused_select_candidates_chunked, gaussian_fused_compress_batched,
     gaussian_fused_ef_compress_batched)
 
@@ -90,6 +91,63 @@ def test_fused_ef_kernel_lowers_or_gate_says_no(n_chunks, chunk, density):
                           density=density, interpret=False),
         _f32(n_chunks, cp), _f32(n_chunks, cp), _f32(),
         state=_f32(n_chunks))
+
+
+def _loc_names(txt):
+    """op line -> every quoted name on its location chain (the name stack
+    with its scopes, and the functions of the call sites)."""
+    table = dict(re.findall(r"^(#loc\d+) = loc\((.*)\)$", txt, re.M))
+
+    @functools.lru_cache(maxsize=None)
+    def names(ref):
+        body = table.get(ref, "")
+        found = frozenset(re.findall(r'"([^"]*)"', body))
+        for inner in re.findall(r"#loc\d+", body):
+            found |= names(inner)
+        return found
+
+    # @main only: a private function (a jitted `jnp.where`) names its body
+    # relative to itself, and its `call` carries the caller's scopes
+    main = txt[txt.index("func.func public @main"):]
+    for line in main[:main.index("\n  }")].splitlines():
+        ref = re.search(r"loc\((#loc\d+)\)\s*$", line)
+        if ref and ("stablehlo." in line or "chlo." in line
+                    or " call @" in line):
+            yield line, names(ref.group(1))
+
+
+def test_selection_sorts_no_candidate_buffer_and_keeps_its_scopes():
+    """ResNet-50's one bucket, the benchmark's `resnet50_dp1`: no sort or
+    top-k of the lowered compression takes an nc-sized operand (the parent's
+    `approx_top_k` did: one full sort of 399 360 pairs), and every operation
+    of the selection is under the scope a device trace books it by —
+    `cand_topk` for finding the slots, `cand_topk` or `pack` for reading
+    them (`cand_topk_ms` would read nothing from a selection without it)."""
+    n, density = MODEL_NUMEL["resnet50"], 0.001
+    k = math.ceil(density * n)
+    cp = ef_padded_chunk(n, k, density=density)
+    nc = _chunk_geometry(cp, density)[3]
+    assert nc == 399_360
+    txt = jax.jit(functools.partial(
+        gaussian_fused_ef_compress_batched, k=k, density=density,
+        interpret=False)).trace(
+            _f32(1, cp), _f32(1, cp), _f32(), state=_f32(1)).lower(
+                lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert "tpu_custom_call" in txt
+    found = {"cand_topk": 0, "pack": 0}
+    for line, names in _loc_names(txt):
+        if re.search(r"\bsort\b|top_k|TopK", line.split(" loc(")[0]):
+            sizes = [math.prod(int(d) for d in dims.split("x") if d)
+                     for dims in re.findall(r"tensor<((?:\d+x)+)", line)]
+            assert max(sizes, default=0) < nc, line
+        scopes = {s for name in names for s in re.split(r"[/()]", name)}
+        if "_cand_top_k" in names:
+            assert "cand_topk" in scopes, line
+            found["cand_topk"] += 1
+        elif names & {"_read_slots", "_select_candidates_topk"}:
+            assert scopes & {"cand_topk", "pack"}, line
+            found["pack"] += "pack" in scopes
+    assert found["cand_topk"] > 30 and found["pack"] > 5, found
 
 
 def test_vgg16_whole_model_bucket_is_the_smoke_geometry():

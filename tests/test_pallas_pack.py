@@ -354,14 +354,86 @@ def test_registry_entry_and_train_step():
     assert int(state.step) == 6
 
 
-def test_docstring_candidate_count_derived_from_constant():
-    """ADVICE r5: the module prose once said 512k while the code said 128k.
-    The docstring now substitutes {EXACT_CAND_MAX_K} from _EXACT_CAND_MAX —
-    assert the substitution ran and agrees with the constant."""
-    from gaussiank_sgd_tpu.ops import pallas_pack as pp
+# the candidate buffers of the two one-chip benchmark cells (VGG-16 and
+# ResNet-50 at density 0.001: nc = padded n / 64), a uniform bucket's, and
+# one whose k output slots are read in two blocks (k > _SLOT_BLOCK)
+_SELECT_SHAPES = [(1024, 66), (235_520, 14_987), (399_360, 25_558),
+                  (131_072, 40_000)]
+_SELECT_CASES = ["half", "k", "2k", "cold", "dead", "ties", "crowded",
+                 "vmap3"]
 
-    assert "{EXACT_CAND_MAX_K}" not in pp.__doc__
-    assert f"{pp._EXACT_CAND_MAX >> 10}k candidates" in pp.__doc__
+
+def _candidates(case, nc, k, n, seed):
+    """A candidate buffer as the kernel writes one: (value, index) per
+    slot, (0, 0) where the slot holds nothing; valid indices distinct."""
+    rng = np.random.default_rng(seed)
+    count = {"half": k // 2, "k": k, "2k": 2 * k, "cold": nc, "dead": 0,
+             "ties": 2 * k, "crowded": 2 * k}[case]
+    vals = np.zeros(nc, np.float32)
+    if case == "crowded":       # every lane of the first few rows
+        pos = np.arange(count)
+    else:
+        pos = rng.permutation(nc)[:count]
+    mag = rng.uniform(1e-3, 1.0, count).astype(np.float32)
+    if case == "ties":          # 40 equal magnitudes astride the k-th
+        order = np.argsort(-mag)
+        mag[order[k - 20:k + 20]] = mag[order[k]]
+    vals[pos] = mag * rng.choice(np.float32([-1, 1]), count)
+    idxs = np.zeros(nc, np.int32)
+    idxs[pos] = rng.permutation(n)[:count]
+    return vals, idxs
+
+
+def _top_k_pairs(vals, idxs, k):
+    """The contract: ``lax.top_k`` over the magnitudes, zeros left out,
+    as a set of (index, float32 bit pattern)."""
+    kv, kpos = jax.lax.top_k(jnp.abs(jnp.asarray(vals)), k)
+    kpos = np.asarray(kpos)[np.asarray(kv) > 0]
+    return set(zip(idxs[kpos].tolist(),
+                   vals[kpos].view(np.uint32).tolist()))
+
+
+@pytest.mark.parametrize("case", _SELECT_CASES)
+@pytest.mark.parametrize("nc,k", _SELECT_SHAPES)
+def test_selection_is_top_k_of_the_candidates(nc, k, case):
+    """What is sent is the k valid candidates of largest magnitude (all of
+    them when fewer are valid), bit for bit, invalid slots (n, 0), and
+    the rest stays in the residual — at the benchmark cells' own shapes."""
+    from gaussiank_sgd_tpu.ops.pallas_pack import (_pack_candidates,
+                                                   _select_candidates_topk)
+    n = 4 * nc
+    select = jax.jit(_select_candidates_topk, static_argnums=(2, 3))
+    if case == "vmap3":         # as the batched forms call it
+        chunks = [_candidates(c, nc, k, n, seed)
+                  for seed, c in enumerate(["k", "cold", "dead"])]
+        sent, val = jax.jit(jax.vmap(
+            lambda v, i: _select_candidates_topk(v, i, k, n)))(
+                jnp.stack([v for v, _ in chunks]),
+                jnp.stack([i for _, i in chunks]))
+    else:
+        chunks = [_candidates(case, nc, k, n, seed=nc)]
+        sent, val = (a[None] for a in select(*chunks[0], k, n))
+    sent, val = np.asarray(sent), np.asarray(val)
+    assert sent.shape == val.shape == (len(chunks), k)
+    for (vals, idxs), s, v in zip(chunks, sent, val):
+        valid = s < n
+        assert (s[~valid] == n).all() and (v[~valid] == 0).all()
+        want = _top_k_pairs(vals, idxs, k)
+        assert valid.sum() == len(want) == min(k, (vals != 0).sum())
+        assert set(zip(s[valid].tolist(),
+                       v[valid].view(np.uint32).tolist())) == want
+    if case != "vmap3":
+        # what was passed over is still in the residual after finish_pack
+        vals, idxs = chunks[0]
+        buf = np.zeros(n, np.float32)
+        buf[idxs[vals != 0]] = vals[vals != 0]
+        comp, residual = jax.jit(_pack_candidates, static_argnums=3)(
+            vals, idxs, jnp.asarray(buf), k)
+        dense = np.zeros(n, np.float32)
+        np.add.at(dense, np.asarray(comp.indices), np.asarray(comp.values))
+        assert np.array_equal(dense + np.asarray(residual), buf)
+        assert np.count_nonzero(np.asarray(residual)) == (
+            (vals != 0).sum() - len(want))
 
 
 def test_ef_padded_chunk_geometry():
