@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from .alexnet import AlexNet
 from .lstm import LSTMLM
+from .mellum2 import Mellum2
 from .mnistnet import MnistNet
 from .resnet import CifarResNet, ResNet50
 from .speech import LSTMAN4
@@ -30,6 +31,9 @@ class ModelSpec(NamedTuple):
     input_dtype: Any
     num_classes: int
     task: str                         # 'classify' | 'lm' | 'ctc' | 'seq2seq'
+    # the module's `__call__` takes `return_counters=True` and then answers
+    # (output, {name: scalar}); the loss function logs them (`StepMetrics.aux`)
+    counters: bool = False
 
 
 _CIFAR = (32, 32, 3)
@@ -88,9 +92,19 @@ def get_model(dnn: str, dataset: Optional[str] = None, *,
         m = TransformerLM(vocab_size=vocab, dtype=dtype, **kw)
         return ModelSpec("transformer_lm", m, (seq_len,), jnp.int32, vocab,
                          "lm")
+    if dnn == "mellum2":
+        # sparse experts, window and full attention mixed (models/mellum2.py);
+        # `vocab_size` is the rows held, `seq_len` only sizes the init input
+        vocab = kw.pop("vocab_size", 98304)
+        seq_len = kw.pop("seq_len", 128)
+        if kw.get("layer_types") is not None:
+            kw["layer_types"] = tuple(kw["layer_types"])
+        m = Mellum2(vocab_size=vocab, dtype=dtype, **kw)
+        return ModelSpec("mellum2", m, (seq_len,), jnp.int32, vocab, "lm",
+                         counters=True)
     raise ValueError(f"unknown dnn {dnn!r}")
 
 
 NAMES = ("resnet20", "resnet32", "resnet44", "resnet56", "resnet110",
          "resnet50", "vgg16", "alexnet", "mnistnet", "lstm", "lstman4",
-         "transformer", "transformer_lm")
+         "transformer", "transformer_lm", "mellum2")

@@ -1,0 +1,598 @@
+"""Mellum 2 (JetBrains/Mellum2-12B-A2.5B-Instruct): a decoder-only language
+model with sparse experts in every layer and window and full attention
+mixed three to one. Defaults are the published widths (config.json of the
+source): hidden 2304, 32 query and 4 key/value heads of 128, 64 experts of
+896 with 8 a token, window 1024, 28 layers `sliding, sliding, sliding, full`.
+
+Per layer, pre-norm (RMSNorm, eps 1e-6, no biases anywhere):
+
+    h = x + Wo . attention(rope(Wq n1(x)), rope(Wk n1(x)), Wv n1(x))
+    y = h + sum_e p_e W2_e (silu(W1_e n2(h)) * W3_e n2(h))
+
+Attention is causal, each key/value head serving 8 query heads; a
+`sliding_attention` layer sees keys `0 <= i - j < window` under plain rotary
+positions, a `full_attention` layer all earlier keys under yarn-scaled
+ones. `p = softmax(n2(h) Wr)` over all experts in float32, its
+`experts_per_token` largest kept and renormalised.
+
+**One chip's share of an expert group.** The layer is told which experts it
+holds, `(expert_share, expert_shares)`: experts `share * E / shares` up to
+the next share's first. It routes over all E and adds only its own experts'
+terms; what the absent experts would add is left out and the partial result
+goes on (on one chip there is no exchange, and nothing stands in for one).
+`vocab_size` is the number of embedding and head rows held: a sliced
+vocabulary is a smaller vocabulary.
+
+No token is dropped: the (token, expert) assignments are sorted by held
+expert, each one's row gathered, and the experts' three products run as
+grouped products over the rows each expert got (`lax.ragged_dot`, which
+the TPU compiler serves with its own grouped-product kernel and visits only
+the tiles that hold rows). Shapes are static, so there is room for twice
+an even load's rows where the step sees that they suffice and for every
+assignment (all of a token's experts held) where not: a `lax.cond`, not a
+capacity.
+
+Attention never builds `[S, S]`. With `kernels` (the default on a TPU) it is
+the Pallas splash-attention kernel of `jax.experimental`, which skips the
+blocks a mask leaves empty, so a window layer's work goes with S x window;
+elsewhere (the CPU tests) blocks of queries against the keys their mask can
+reach.
+
+Not in the published config and so not built: QK-norm, a shared expert, an
+auxiliary load-balance loss, a multi-token-prediction head (the benchmark's
+configuration file lists these under `assumed`).
+
+Device scopes (`jax.named_scope`, read by `benchmarks/model_scopes.py`):
+`attn_window`, `attn_full`, `moe_router`, `moe_experts`, `lm_head`.
+Counters (returned with `return_counters=True`, logged through the loss
+function's auxiliary output): `moe_held_assignments`,
+`moe_load_max_over_mean`, `moe_tokens_unserved`.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+import types
+from typing import Any, Optional, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+_PERIOD = (SLIDING, SLIDING, SLIDING, FULL)
+_INIT = nn.initializers.normal(0.02)
+# query rows a block of the plain attention path takes at a time
+_PLAIN_BLOCK = 128
+# splash attention's tiles on a v5e (queries x keys, forward and backward)
+_SPLASH_BLOCK = 512
+# What a recomputed layer keeps from its first forward pass: the attention
+# kernel's output and row statistics (0.4 GB a layer at the benchmark's
+# size), so that kernel does not run a second time. The experts' products do
+# (their backward pass keeps more than their output).
+_SAVED = "attn_kernel_out"
+
+
+# ------------------------------------------------------------------ rotary
+
+def rope_inv_freq(head_dim: int, theta: float) -> np.ndarray:
+    """`rope_type` default: theta ** (-2i / d) for each of the d / 2 pairs."""
+    return 1.0 / theta ** (np.arange(0, head_dim, 2, dtype=np.float64)
+                           / head_dim)
+
+
+def yarn_inv_freq(head_dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float, beta_slow: float,
+                  truncate: bool = True) -> np.ndarray:
+    """`rope_type` yarn (Peng et al., arXiv:2309.00071, as transformers'
+    `_compute_yarn_parameters`): pairs that turn more than `beta_fast`
+    times within the original context keep their frequency, those that turn
+    less than `beta_slow` times are slowed by `factor`, a linear ramp over
+    the pair index between."""
+    def pair_of(turns):
+        return (head_dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low, high = pair_of(beta_fast), pair_of(beta_slow)
+    if truncate:
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    plain = rope_inv_freq(head_dim, theta)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def apply_rope(x, inv_freq: np.ndarray, scale: float = 1.0):
+    """Rotate `x` [B, S, H, D] by its position: pair i is (x[i], x[i +
+    D/2]); cos and sin times `scale` (yarn's `attention_factor`). In
+    float32, returned in float32."""
+    pos = jnp.arange(x.shape[1], dtype=jnp.float32)
+    ang = pos[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    cos = (jnp.cos(ang) * scale)[None, :, None, :]
+    sin = (jnp.sin(ang) * scale)[None, :, None, :]
+    x = x.astype(jnp.float32)
+    a, b = jnp.split(x, 2, axis=-1)
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+# --------------------------------------------------------------- attention
+
+def allowed(q_pos, k_pos, window: Optional[int]):
+    """The mask: key j is seen from query i when `0 <= i - j` and, in a
+    window layer, `i - j < window`."""
+    d = q_pos[:, None] - k_pos[None, :]
+    ok = d >= 0
+    return ok & (d < window) if window else ok
+
+
+def plain_attention(q, k, v, window: Optional[int], block: int = _PLAIN_BLOCK):
+    """softmax(q k^T + mask) v in blocks of queries, no kernel. q [B, S,
+    Hkv, G, D] (scaled), k and v [B, S, Hkv, D]. A block of a window layer
+    takes the `block + window` keys its mask can reach, a block of a full
+    layer all S: scores are `[block, keys]`, never `[S, S]`."""
+    b, s, hkv, g, d = q.shape
+    block = min(block, s)
+    if s % block:
+        raise ValueError(f"{s} positions are no whole number of blocks of "
+                         f"{block} queries")
+    span = s if not window else min(
+        s, -(-(window - 1) // block) * block + block)
+    nblk = s // block
+
+    def one(i):
+        q_i = lax.dynamic_slice_in_dim(q, i * block, block, axis=1)
+        start = jnp.clip(i * block + block - span, 0, s - span)
+        k_i = lax.dynamic_slice_in_dim(k, start, span, axis=1)
+        v_i = lax.dynamic_slice_in_dim(v, start, span, axis=1)
+        scores = jnp.einsum("bqhgd,bkhd->bhgqk", q_i, k_i,
+                            preferred_element_type=jnp.float32)
+        ok = allowed(i * block + jnp.arange(block), start + jnp.arange(span),
+                     window)
+        scores = jnp.where(ok[None, None, None], scores, -1e30)
+        p = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        return jnp.einsum("bhgqk,bkhd->bqhgd", p, v_i)
+
+    out = lax.map(jax.checkpoint(one), jnp.arange(nblk))
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, hkv, g, d)
+
+
+def splash_attention(q, k, v, window: Optional[int]):
+    """The same by the Pallas splash-attention kernel: one call a sequence
+    and key/value head, its 8 query heads against the one key/value head
+    (`make_splash_mqa`), forward and backward; the blocks a mask leaves
+    empty are never visited."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as kernel, splash_attention_mask as masks)
+    b, s, hkv, g, d = q.shape
+    blk = min(_SPLASH_BLOCK, s)
+    mask = (masks.LocalMask((s, s), (window - 1, 0), 0) if window
+            else masks.CausalMask((s, s)))
+    sizes = kernel.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk, block_q_dkv=blk,
+        block_kv_dkv=blk, block_kv_dkv_compute=blk, block_q_dq=blk,
+        block_kv_dq=blk)
+    call = kernel.make_splash_mqa_single_device(
+        masks.MultiHeadMask([mask] * g), block_sizes=sizes,
+        residual_checkpoint_name=_SAVED)
+    out = jax.vmap(jax.vmap(call))(
+        jnp.transpose(q, (0, 2, 3, 1, 4)), jnp.transpose(k, (0, 2, 1, 3)),
+        jnp.transpose(v, (0, 2, 1, 3)))                 # [B, Hkv, G, S, D]
+    return jnp.transpose(out, (0, 3, 1, 2, 4))
+
+
+def use_kernels(kernels: Optional[bool]) -> bool:
+    """`kernels` where it is given; else whether the process's default
+    backend is a TPU."""
+    return jax.default_backend() == "tpu" if kernels is None else kernels
+
+
+class RMSNorm(nn.Module):
+    eps: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        x = x.astype(jnp.float32)
+        x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + self.eps)
+        return (x * scale).astype(self.dtype)
+
+
+class Attention(nn.Module):
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    window: Optional[int]           # None: a full layer
+    inv_freq: Tuple[float, ...]
+    rope_scale: float
+    kernels: Optional[bool]
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, hidden = x.shape
+        hq, hkv, d = self.num_heads, self.num_kv_heads, self.head_dim
+
+        def proj(name, heads):
+            return nn.DenseGeneral((heads, d), use_bias=False,
+                                   dtype=self.dtype, kernel_init=_INIT,
+                                   name=name)(x)
+
+        inv_freq = np.asarray(self.inv_freq)
+        q = apply_rope(proj("q_proj", hq), inv_freq, self.rope_scale)
+        k = apply_rope(proj("k_proj", hkv), inv_freq, self.rope_scale)
+        v = proj("v_proj", hkv)
+        q = (q * d ** -0.5).astype(self.dtype).reshape(b, s, hkv, hq // hkv, d)
+        k = k.astype(self.dtype)
+        with jax.named_scope("attn_window" if self.window else "attn_full"):
+            if use_kernels(self.kernels):
+                out = splash_attention(q, k, v, self.window)
+            else:
+                out = plain_attention(q, k, v, self.window)
+        return nn.DenseGeneral(hidden, axis=(-2, -1), use_bias=False,
+                               dtype=self.dtype, kernel_init=_INIT,
+                               name="o_proj")(out.reshape(b, s, hq, d))
+
+
+# ----------------------------------------------------------------- experts
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def to_rows(x, first, inverse, live, top: int):
+    """The token's row for each of the sorted assignments `first` [rows]
+    (assignment a is token `a // top`): `x[first // top]`. Its cotangent
+    comes back by `to_tokens`: gathered, not scattered."""
+    return x[first // top]
+
+
+def _to_rows_fwd(x, first, inverse, live, top):
+    return x[first // top], (first, inverse, live)
+
+
+def _to_rows_bwd(top, res, g):
+    first, inverse, live = res
+    ones = jnp.ones(inverse.shape, g.dtype)
+    return (to_tokens(g, ones, first, inverse, live, top).astype(g.dtype),
+            None, None, None)
+
+
+to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
+
+
+def _picked(r, inverse, live):
+    return jnp.where(live[:, None], r[jnp.minimum(inverse, r.shape[0] - 1)],
+                     jnp.zeros((), r.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def to_tokens(r, scale, first, inverse, live, top: int):
+    """For every token the sum over its `top` assignments a of `scale[a]`
+    times the row `r[inverse[a]]` that assignment a was sorted to, over the
+    `live` assignments (those of held experts, sorted before `r`'s end),
+    in float32."""
+    rows = _picked(r, inverse, live).reshape(-1, top, r.shape[-1])
+    return jnp.einsum("tkh,tk->th", rows, scale.reshape(-1, top),
+                      preferred_element_type=jnp.float32)
+
+
+def _to_tokens_fwd(r, scale, first, inverse, live, top):
+    return (to_tokens(r, scale, first, inverse, live, top),
+            (r, scale, first, inverse, live))
+
+
+def _to_tokens_bwd(top, res, g):
+    r, scale, first, inverse, live = res
+    # in the sorted rows' order: every live row has one assignment
+    sorted_live = (jnp.arange(first.shape[0]) < jnp.sum(live))[:, None]
+    g_rows = jnp.where(sorted_live, g[first // top], 0.0)
+    d_r = (g_rows * scale[first][:, None].astype(g.dtype)).astype(r.dtype)
+    d_sorted = jnp.sum(g_rows * r.astype(g.dtype), axis=-1)
+    d_scale = jnp.where(
+        live, d_sorted[jnp.minimum(inverse, first.shape[0] - 1)], 0.0)
+    return d_r, d_scale.astype(scale.dtype), None, None, None
+
+
+to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
+
+
+def _live_rows(x, sizes):
+    """`x` with the rows past the last group's end zeroed: they belong to
+    absent experts, and a grouped product leaves them as it finds them
+    (on the TPU: uninitialised)."""
+    live = jnp.arange(x.shape[0]) < jnp.sum(sizes)
+    return jnp.where(live[:, None], x, jnp.zeros((), x.dtype))
+
+
+# the weights' cotangent: rows of one group contracted, a group at a time
+_BY_GROUP = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+@jax.custom_vjp
+def grouped_product(x, w, sizes):
+    """`x[rows of group e] @ w[e]` for every group (`lax.ragged_dot`), `w`
+    the float32 parameter, multiplied as `x.dtype`. Zero in the rows past
+    the last group's, forward and backward: what the product leaves there
+    must not reach a sum, and 0 times it is no 0. The weights' cotangent
+    leaves the product in float32 (a bfloat16 one would round every
+    gradient of an expert to 8 bits before it is accumulated)."""
+    return _live_rows(lax.ragged_dot(x, w.astype(x.dtype), sizes), sizes)
+
+
+def _grouped_fwd(x, w, sizes):
+    return grouped_product(x, w, sizes), (x, w, sizes)
+
+
+def _grouped_bwd(res, g):
+    x, w, sizes = res
+    g = _live_rows(g, sizes)
+    dx = lax.ragged_dot(g, jnp.swapaxes(w.astype(x.dtype), 1, 2), sizes)
+    dw = lax.ragged_dot_general(x, g, sizes, _BY_GROUP,
+                                preferred_element_type=jnp.float32)
+    return _live_rows(dx, sizes), dw.astype(w.dtype), None
+
+
+grouped_product.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+def _terms(cap: int, top: int, x, weights, order, inverse, sizes, w1, w3,
+           w2):
+    """The held experts' part of the layer's output, float32 [T, h], over
+    the first `cap` sorted assignments, which hold every live one."""
+    first = order[:cap]
+    live = inverse < jnp.sum(sizes)
+    rows = to_rows(x, first, inverse, live, top)
+    gate = jax.nn.silu(grouped_product(rows, w1, sizes))
+    out = grouped_product(gate * grouped_product(rows, w3, sizes), w2, sizes)
+    return to_tokens(out, weights.reshape(-1), first, inverse, live, top)
+
+
+def _by_rows(enough: int, sizes, small, large, *args):
+    """`small(*args)` where the live rows fit `enough`, else `large`."""
+    return lax.cond(jnp.sum(sizes) <= enough, small, large, *args)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def expert_terms(enough: int, top: int, x, weights, order, inverse, sizes,
+                 w1, w3, w2):
+    """`_terms` with room for `enough` rows where the live ones fit and for
+    all `T * top` where not. A `lax.cond` that is differentiated through
+    keeps BOTH sides' residuals (7 GB more at the benchmark's size), so the
+    choice is made again in the backward pass: the small side keeps what
+    its backward pass needs, the large side (an uneven load, seldom taken)
+    keeps nothing and is recomputed from the arguments."""
+    return _expert_terms_fwd(enough, top, x, weights, order, inverse, sizes,
+                             w1, w3, w2)[0]
+
+
+def _floats_of(cap, top, order, inverse, sizes):
+    """`_terms` as a function of what it is differentiated by."""
+    return lambda x, weights, w1, w3, w2: _terms(
+        cap, top, x, weights, order, inverse, sizes, w1, w3, w2)
+
+
+def _expert_terms_fwd(enough, top, *args):
+    x, weights, order, inverse, sizes, w1, w3, w2 = args
+    floats, full = (x, weights, w1, w3, w2), order.shape[0]
+    if enough >= full:
+        y, back = jax.vjp(_floats_of(full, top, order, inverse, sizes),
+                          *floats)
+        return y, (back, args)
+
+    def small(*args):
+        x, weights, order, inverse, sizes, w1, w3, w2 = args
+        return jax.vjp(_floats_of(enough, top, order, inverse, sizes),
+                       x, weights, w1, w3, w2)
+
+    # the backward function is a pytree: its leaves are what it keeps, and
+    # the two sides of a `cond` have to hand out the same leaves
+    kept, function = jax.tree.flatten(jax.eval_shape(small, *args)[1])
+
+    def small_kept(*args):
+        y, back = small(*args)
+        return y, jax.tree.leaves(back)
+
+    def large_kept(*args):
+        return (_terms(full, top, *args),
+                [jnp.zeros(r.shape, r.dtype) for r in kept])
+
+    y, leaves = _by_rows(enough, sizes, small_kept, large_kept, *args)
+    return y, (jax.tree.unflatten(function, leaves), args)
+
+
+def _expert_terms_bwd(enough, top, res, g):
+    back, args = res
+    full, sizes = args[2].shape[0], args[4]
+
+    def recomputed(back, g, *args):
+        x, weights, order, inverse, sizes, w1, w3, w2 = args
+        return jax.vjp(_floats_of(full, top, order, inverse, sizes),
+                       x, weights, w1, w3, w2)[1](g)
+
+    if enough >= full:
+        dx, dweights, dw1, dw3, dw2 = back(g)
+    else:
+        dx, dweights, dw1, dw3, dw2 = _by_rows(
+            enough, sizes, lambda back, g, *args: back(g), recomputed,
+            back, g, *args)
+    return dx, dweights, None, None, None, dw1, dw3, dw2
+
+
+expert_terms.defvjp(_expert_terms_fwd, _expert_terms_bwd)
+
+
+def route(probs, top: int, first: int, held: int):
+    """From the router's probabilities [T, E]: each token's `top` largest,
+    renormalised to sum 1; which rows of the `T * top` assignments go to
+    which of the `held` experts from `first` on.
+
+    Returns (weights [T, top]; `order` [T * top], the assignments sorted by
+    held expert, those of absent experts last; its inverse; `sizes`
+    [held], the rows each held expert got; `served` [T], whether any of a
+    token's experts is held)."""
+    weights, experts = lax.top_k(probs, top)
+    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    local = experts - first
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held).reshape(-1)
+    order = jnp.argsort(group, stable=True)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    sizes = jnp.sum(group[:, None] == jnp.arange(held)[None, :], axis=0,
+                    dtype=jnp.int32)
+    return weights, order, inverse, sizes, jnp.any(mine, axis=-1)
+
+
+class Experts(nn.Module):
+    """The router over all `num_experts` and the gated experts held here."""
+    num_experts: int
+    experts_per_token: int
+    width: int
+    share: int
+    shares: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        b, s, hidden = x.shape
+        tokens, top = b * s, self.experts_per_token
+        if self.num_experts % self.shares or not (
+                0 <= self.share < self.shares):
+            raise ValueError(
+                f"share {self.share} of {self.shares} does not divide "
+                f"{self.num_experts} experts")
+        held = self.num_experts // self.shares
+        x = x.reshape(tokens, hidden)
+        with jax.named_scope("moe_router"):
+            # float32 throughout: a near tie decides which expert is paid
+            router = self.param("router", _INIT, (hidden, self.num_experts),
+                                jnp.float32)
+            logits = jnp.dot(x.astype(jnp.float32), router,
+                             precision=lax.Precision.HIGHEST)
+            weights, order, inverse, sizes, served = route(
+                jax.nn.softmax(logits, axis=-1), top, self.share * held,
+                held)
+        shape = (held, hidden, self.width)
+        w1 = self.param("w1", _INIT, shape, jnp.float32)
+        w3 = self.param("w3", _INIT, shape, jnp.float32)
+        w2 = self.param("w2", _INIT, (held, self.width, hidden), jnp.float32)
+        with jax.named_scope("moe_experts"):
+            # Room for every assignment (all of a token's experts held)
+            # costs gathers of `tokens * top` rows; an even load fills a
+            # `shares`-th of them. So: twice the even load's rows where
+            # they suffice, which the step can see, else all of them. No
+            # token is dropped on either side.
+            full = tokens * top
+            enough = min(full, -(-2 * full // self.shares // 8) * 8)
+            y = expert_terms(enough, top, x, weights, order, inverse, sizes,
+                             w1, w3, w2)
+        load = sizes.astype(jnp.float32)
+        counters = {
+            "moe_held_assignments": jnp.sum(load),
+            "moe_load_max_over_mean": jnp.max(load) / jnp.maximum(
+                jnp.mean(load), 1.0),
+            "moe_tokens_unserved": 1.0 - jnp.mean(served.astype(jnp.float32))}
+        return y.astype(self.dtype).reshape(b, s, hidden), counters
+
+
+class Layer(nn.Module):
+    m: Any                          # the model's own fields, as a namespace
+    window: Optional[int]
+
+    @nn.compact
+    def __call__(self, x):
+        m = self.m
+        if self.window:
+            inv_freq, scale = rope_inv_freq(m.head_dim, m.rope_theta), 1.0
+        else:
+            inv_freq = yarn_inv_freq(m.head_dim, m.rope_theta, m.yarn_factor,
+                                     m.yarn_original_max, m.yarn_beta_fast,
+                                     m.yarn_beta_slow)
+            scale = m.yarn_attention_factor
+        h = RMSNorm(m.rms_norm_eps, m.dtype, name="input_norm")(x)
+        x = x + Attention(m.num_heads, m.num_kv_heads, m.head_dim,
+                          self.window, tuple(inv_freq.tolist()), scale,
+                          m.kernels, m.dtype, name="attn")(h)
+        h = RMSNorm(m.rms_norm_eps, m.dtype, name="post_attn_norm")(x)
+        y, counters = Experts(m.num_experts, m.experts_per_token,
+                              m.expert_width, m.expert_share,
+                              m.expert_shares, m.dtype, name="moe")(h)
+        return x + y, counters
+
+
+class Mellum2(nn.Module):
+    vocab_size: int = 98304         # embedding and head rows held here
+    hidden_size: int = 2304
+    num_layers: int = 28
+    layer_types: Optional[Tuple[str, ...]] = None   # None: the period above
+    num_heads: int = 32
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    sliding_window: int = 1024
+    num_experts: int = 64           # the router's width, never cut
+    experts_per_token: int = 8
+    expert_width: int = 896
+    expert_share: int = 0           # which share of the experts is held,
+    expert_shares: int = 1          # of how many
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 500000.0
+    yarn_factor: float = 16.0
+    yarn_original_max: int = 8192
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.2772588722239782
+    kernels: Optional[bool] = None  # None: where the backend is a TPU
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, tokens, train: bool = True,
+                 return_counters: bool = False):
+        # tokens int32 [B, S] -> logits float32 [B, S, vocab_size]
+        kinds = tuple(self.layer_types or _PERIOD * (self.num_layers // 4 + 1)
+                      )[:self.num_layers]
+        if len(kinds) != self.num_layers or set(kinds) - {SLIDING, FULL}:
+            raise ValueError(f"{self.num_layers} layers, layer_types "
+                             f"{self.layer_types}")
+        # unit embeddings: at the products' 0.02 a layer's output swamps
+        # them at random weights, every token's router input then shares
+        # one direction and the experts' load collapses onto a few
+        x = nn.Embed(self.vocab_size, self.hidden_size, dtype=self.dtype,
+                     embedding_init=nn.initializers.normal(1.0),
+                     name="embed")(tokens)
+        # a layer is recomputed in its backward pass, but for `_SAVED`
+        layer = nn.remat(Layer, policy=jax.checkpoint_policies
+                         .save_only_these_names(_SAVED))
+        # a module may not be another's field: the layers get the numbers
+        widths = types.SimpleNamespace(**{
+            f.name: getattr(self, f.name) for f in dataclasses.fields(self)
+            if f.name not in ("parent", "name")})
+        per_layer = []
+        for i, kind in enumerate(kinds):
+            x, counters = layer(
+                widths, self.sliding_window if kind == SLIDING else None,
+                name=f"layers_{i}")(x)
+            per_layer.append(counters)
+        x = RMSNorm(self.rms_norm_eps, self.dtype, name="norm")(x)
+        with jax.named_scope("lm_head"):
+            head = self.param("lm_head", _INIT,
+                              (self.hidden_size, self.vocab_size),
+                              jnp.float32)
+            logits = jnp.dot(x, head.astype(self.dtype),
+                             preferred_element_type=jnp.float32)
+        if not return_counters:
+            return logits
+        stacked = jax.tree.map(lambda *v: jnp.stack(v), *per_layer)
+        return logits, {
+            "moe_held_assignments": jnp.sum(
+                stacked["moe_held_assignments"]),
+            "moe_load_max_over_mean": jnp.max(
+                stacked["moe_load_max_over_mean"]),
+            "moe_tokens_unserved": jnp.mean(stacked["moe_tokens_unserved"])}

@@ -88,11 +88,19 @@ def make_loss_fn(spec: ModelSpec, label_smoothing: float = 0.0,
     if task == "lm":
         def loss_fn(params, mstate, batch, rng):
             x, y = batch
-            logits, mstate = _apply(spec, params, mstate, rng, x)
+            # a model with counters of its own (`ModelSpec.counters`) hands
+            # them out beside its logits; they ride the auxiliary output
+            # into the `train` record
+            counters = {}
+            if spec.counters:
+                (logits, counters), mstate = _apply(
+                    spec, params, mstate, rng, x, return_counters=True)
+            else:
+                logits, mstate = _apply(spec, params, mstate, rng, x)
             loss = optax.softmax_cross_entropy_with_integer_labels(
                 logits, y).mean()
             # perplexity = exp(loss); report loss, exp on host
-            return loss, (mstate, {"ce_per_token": loss})
+            return loss, (mstate, {"ce_per_token": loss, **counters})
         return loss_fn
 
     if task == "ctc":

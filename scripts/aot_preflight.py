@@ -15,11 +15,15 @@ numerics, not time. Those are chip_smoke.py's, on the chip.
     python scripts/aot_preflight.py --chips 4
     python scripts/aot_preflight.py --model transformer --dataset wmt \\
         --batch-size 32
+    python scripts/aot_preflight.py --model mellum2 --dataset ptb \\
+        --batch-size 2 --model-kwargs '{"num_layers": 4, "expert_shares": 8,
+        "vocab_size": 12288, "seq_len": 8192, "kernels": true}'
 """
 
 from __future__ import annotations
 
 import argparse
+import json
 import os
 import re
 import sys
@@ -75,6 +79,8 @@ def main(argv=None) -> None:
     ap.add_argument("--compressor", default="auto")
     ap.add_argument("--density", type=float, default=0.001)
     ap.add_argument("--topology", default="v5e:2x2")
+    ap.add_argument("--model-kwargs", type=json.loads, default={},
+                    help="JSON, as TrainConfig.model_kwargs")
     args = ap.parse_args(argv)
 
     topo = topologies.get_topology_desc(args.topology, "tpu")
@@ -82,7 +88,8 @@ def main(argv=None) -> None:
     print(f"target: {args.chips} x {topo.devices[0].device_kind!r} "
           f"(device-less); this process's backend: {jax.default_backend()}")
 
-    spec = get_model(args.model, args.dataset, dtype=jnp.bfloat16)
+    spec = get_model(args.model, args.dataset, dtype=jnp.bfloat16,
+                     **args.model_kwargs)
     recurrent = args.model == "lstm"
     batch = _batch_shapes(spec, args.batch_size * args.chips)
     two = _batch_shapes(spec, 2)

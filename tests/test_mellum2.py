@@ -1,0 +1,393 @@
+"""`models/mellum2.py` at tiny widths on the CPU (hidden 64, 4 query and 2
+key/value heads of 16, 8 experts top-2, window 8, 32 positions, 4 layers
+`s, s, s, f`), against the benchmark's plain reference
+(`benchmarks/reference/mellum2_12b_a2p5b.py`, which imports nothing of the
+program) and against direct formulas."""
+
+import json
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmarks.reference import mellum2_12b_a2p5b as ref
+from gaussiank_sgd_tpu.models import get_model, mellum2
+from gaussiank_sgd_tpu.training.losses import make_loss_fn
+
+VOCAB, POSITIONS, WINDOW = 50, 32, 8
+KINDS = ["sliding_attention"] * 3 + ["full_attention"]
+ROPE = {
+    "full_attention": {
+        "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+        "original_max_position_embeddings": 16, "beta_fast": 32,
+        "beta_slow": 1, "attention_factor": 1.2772588722239782},
+    "sliding_attention": {"rope_type": "default", "rope_theta": 500000}}
+
+
+def tiny(share=0, shares=2, dtype=jnp.float32, **kw):
+    """(the program's model, the reference's configuration) of one share."""
+    kw = dict(dict(
+        hidden_size=64, num_layers=4, layer_types=KINDS, num_heads=4,
+        num_kv_heads=2, head_dim=16, sliding_window=WINDOW, num_experts=8,
+        experts_per_token=2, expert_width=32, expert_share=share,
+        expert_shares=shares, yarn_original_max=16), **kw)
+    spec = get_model("mellum2", "ptb", vocab_size=VOCAB, dtype=dtype, **kw)
+    cfg = {"hidden_size": 64, "num_hidden_layers": kw["num_layers"],
+           "layer_types": KINDS, "num_attention_heads": 4,
+           "num_key_value_heads": 2, "head_dim": 16, "sliding_window": WINDOW,
+           "num_experts": 8 // shares, "num_experts_per_tok": 2,
+           "moe_intermediate_size": 32, "vocab_size": VOCAB,
+           "rms_norm_eps": 1e-6, "rope_parameters": ROPE,
+           "published": {"num_experts": 8},
+           "share": {"expert_share": share, "expert_shares": shares}}
+    return spec, cfg
+
+
+def by_path(tree):
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {"/".join(str(k.key) for k in p): v for p, v in flat}
+
+
+def as_tree(flat):
+    out = {}
+    for path, v in flat.items():
+        node = out
+        *parents, leaf = path.split("/")
+        for p in parents:
+            node = node.setdefault(p, {})
+        node[leaf] = v
+    return out
+
+
+@pytest.fixture(scope="module")
+def batch():
+    rng = np.random.default_rng(3)
+    toks = rng.integers(0, VOCAB, (2, POSITIONS + 1)).astype(np.int32)
+    return jnp.asarray(toks[:, :-1]), jnp.asarray(toks[:, 1:])
+
+
+def test_parameter_paths_and_count_are_the_references():
+    spec, cfg = tiny()
+    shapes = jax.eval_shape(
+        lambda x: spec.module.init(jax.random.PRNGKey(0), x, train=False),
+        jnp.zeros((2, POSITIONS), jnp.int32))["params"]
+    mine = {p: tuple(v.shape) for p, v in by_path(shapes).items()}
+    assert mine == {p: tuple(s) for p, s in ref.param_shapes(cfg).items()}
+
+
+def test_published_widths_give_the_cells_parameter_count():
+    """The benchmark's cut (4 layers, 8 of 64 experts, 12 288 rows) at the
+    published widths: 340 349 184 parameters, from shapes alone."""
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                           "configs", "mellum2_12b_a2p5b.json")) as f:
+        cfg = json.load(f)
+    n = sum(math.prod(s) for s in ref.param_shapes(cfg).values())
+    assert n == cfg["arch"]["num_params"] == 340349184
+    kw = cfg["trainer"]["model_kwargs"]
+    spec = get_model("mellum2", "ptb", vocab_size=cfg["vocab_size"],
+                     **{k: v for k, v in kw.items() if k != "seq_len"})
+    shapes = jax.eval_shape(
+        lambda x: spec.module.init(jax.random.PRNGKey(0), x, train=False),
+        jnp.zeros((1, 128), jnp.int32))["params"]
+    assert ({p: tuple(v.shape) for p, v in by_path(shapes).items()}
+            == {p: tuple(s) for p, s in ref.param_shapes(cfg).items()})
+    # every width is the published one
+    m = spec.module
+    assert (m.hidden_size, m.num_heads, m.num_kv_heads, m.head_dim,
+            m.expert_width, m.num_experts, m.experts_per_token,
+            m.sliding_window) == (
+        cfg["hidden_size"], cfg["num_attention_heads"],
+        cfg["num_key_value_heads"], cfg["head_dim"],
+        cfg["moe_intermediate_size"], cfg["published"]["num_experts"],
+        cfg["num_experts_per_tok"], cfg["sliding_window"]) == (
+        2304, 32, 4, 128, 896, 64, 8, 1024)
+    yarn = cfg["rope_parameters"]["full_attention"]
+    assert (m.rope_theta, m.yarn_factor, m.yarn_original_max,
+            m.yarn_beta_fast, m.yarn_beta_slow, m.yarn_attention_factor) == (
+        yarn["rope_theta"], yarn["factor"],
+        yarn["original_max_position_embeddings"], yarn["beta_fast"],
+        yarn["beta_slow"], yarn["attention_factor"])
+
+
+@pytest.mark.parametrize("dtype,precision,loss_tol,grad_tol", [
+    # float32 against float32, reduction order only: over three seeds the
+    # loss reads at most 1.3e-7 off, the gradient 5.5e-8
+    (jnp.float32, "float32", 2e-6, 2e-6),
+    # bfloat16 products against the float32 reference, 8 bits of mantissa
+    # through 4 layers: over three seeds the loss reads at most 3.5e-5 off,
+    # the gradient and the head's 0.0046; the float8 control reads 0.029
+    # at least, so the limit lies between
+    (jnp.bfloat16, "float32", 5e-4, 0.012),
+])
+def test_loss_and_gradients_against_the_reference(batch, dtype, precision,
+                                                  loss_tol, grad_tol):
+    spec, cfg = tiny(dtype=dtype)
+    weights = ref.init_params(jax.random.PRNGKey(7), cfg)
+    params = as_tree(weights)
+    loss_fn = make_loss_fn(spec)
+    (mine, (_, aux)), g_mine = jax.value_and_grad(loss_fn, has_aux=True)(
+        params, {}, batch, jax.random.PRNGKey(0))
+    theirs, g_ref = jax.value_and_grad(ref.loss)(
+        weights, (batch[0], batch[1], None), cfg, precision)
+    assert abs(float(mine) - float(theirs)) <= loss_tol * float(theirs)
+    assert float(aux["ce_per_token"]) == float(mine)
+    g_mine = by_path(g_mine)
+    num = sum(float(jnp.sum((g_mine[p] - g_ref[p]) ** 2)) for p in g_ref)
+    den = sum(float(jnp.sum(g_ref[p] ** 2)) for p in g_ref)
+    assert math.sqrt(num / den) <= grad_tol
+    head = float(jnp.linalg.norm(g_mine["lm_head"] - g_ref["lm_head"])
+                 / jnp.linalg.norm(g_ref["lm_head"]))
+    assert head <= grad_tol
+    # rows of the embedding that the batch never names: exactly zero
+    named = np.zeros(VOCAB, bool)
+    named[np.unique(np.asarray(batch[0]))] = True
+    assert not np.asarray(g_mine["embed/embedding"])[~named].any()
+
+
+def test_the_float8_control_is_further_from_the_program_than_float32(batch):
+    spec, cfg = tiny(dtype=jnp.bfloat16)
+    weights = ref.init_params(jax.random.PRNGKey(7), cfg)
+    g_mine = by_path(jax.grad(lambda p: make_loss_fn(spec)(
+        p, {}, batch, jax.random.PRNGKey(0))[0])(as_tree(weights)))["lm_head"]
+
+    def err(precision):
+        g = jax.grad(ref.loss)(weights, (batch[0], batch[1], None), cfg,
+                               precision)["lm_head"]
+        return float(jnp.linalg.norm(g_mine - g) / jnp.linalg.norm(g))
+
+    assert err("float8") > 2 * err("float32")
+
+
+@pytest.mark.parametrize("window", [None, WINDOW, 1, POSITIONS])
+def test_mask_against_the_direct_formula(window):
+    got = np.asarray(mellum2.allowed(jnp.arange(POSITIONS),
+                                     jnp.arange(POSITIONS), window))
+    want = np.array([[0 <= i - j and (window is None or i - j < window)
+                      for j in range(POSITIONS)] for i in range(POSITIONS)])
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("window", [None, WINDOW, 20])
+def test_blocked_attention_is_softmax_over_the_masked_scores(window):
+    rng = np.random.default_rng(5)
+    q = jnp.asarray(rng.normal(size=(2, POSITIONS, 2, 2, 16)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(2, POSITIONS, 2, 16)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(2, POSITIONS, 2, 16)), jnp.float32)
+    got = mellum2.plain_attention(q, k, v, window, block=8)
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k)
+    ok = mellum2.allowed(jnp.arange(POSITIONS), jnp.arange(POSITIONS), window)
+    p = jax.nn.softmax(jnp.where(ok, scores, -jnp.inf), axis=-1)
+    want = jnp.einsum("bhgqk,bkhd->bqhgd", p, v)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def test_default_rotary_against_the_direct_formula():
+    d, theta = 16, 500000.0
+    inv = mellum2.rope_inv_freq(d, theta)
+    np.testing.assert_allclose(
+        inv, [theta ** (-2 * i / d) for i in range(d // 2)], rtol=1e-12)
+    x = np.random.default_rng(0).normal(size=(1, 6, 1, d)).astype(np.float32)
+    got = np.asarray(mellum2.apply_rope(jnp.asarray(x), inv))
+    for s in range(6):
+        for i in range(d // 2):
+            a, b = x[0, s, 0, i], x[0, s, 0, i + d // 2]
+            c, sn = math.cos(s * inv[i]), math.sin(s * inv[i])
+            np.testing.assert_allclose(
+                [got[0, s, 0, i], got[0, s, 0, i + d // 2]],
+                [a * c - b * sn, b * c + a * sn], atol=1e-5)
+
+
+def test_yarn_rotary_against_the_direct_formula():
+    """The published full-attention section at head size 128: pairs faster
+    than 32 turns in 8192 positions keep their frequency, pairs slower than
+    one turn are slowed 16 times, a linear ramp between (truncated ends);
+    cos and sin carry the published attention factor."""
+    d, theta, factor, orig = 128, 500000.0, 16.0, 8192
+    got = mellum2.yarn_inv_freq(d, theta, factor, orig, 32.0, 1.0)
+
+    def pair_of(turns):
+        return d * math.log(orig / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low, high = math.floor(pair_of(32.0)), math.ceil(pair_of(1.0))
+    assert 0 < low < high < d // 2
+    for i in range(d // 2):
+        f = theta ** (-2 * i / d)
+        ramp = min(max((i - low) / (high - low), 0.0), 1.0)
+        assert got[i] == pytest.approx(f * (1 - ramp) + f / factor * ramp,
+                                       rel=1e-12)
+    assert got[0] == 1.0 and got[-1] == pytest.approx(
+        theta ** (-(d - 2) / d) / factor)
+    # the reference's own, written apart, agrees
+    theirs, scale = ref.inv_frequencies(
+        {"rope_type": "yarn", "rope_theta": theta, "factor": factor,
+         "original_max_position_embeddings": orig, "beta_fast": 32,
+         "beta_slow": 1, "attention_factor": 1.2772588722239782}, d)
+    np.testing.assert_allclose(got, theirs, rtol=1e-12)
+    assert scale == pytest.approx(0.1 * math.log(factor) + 1.0)
+    x = jnp.ones((1, 3, 1, d), jnp.float32)
+    np.testing.assert_allclose(
+        np.asarray(mellum2.apply_rope(x, got, scale))[0, 0, 0],
+        np.full(d, scale), rtol=1e-6)
+
+
+def _expert_layer(share, shares, experts=8, top=2):
+    return mellum2.Experts(num_experts=experts, experts_per_token=top,
+                           width=32, share=share, shares=shares,
+                           dtype=jnp.float32)
+
+
+def _uncut(x, router, w1, w3, w2, top=2):
+    experts = router.shape[1]
+    cfg = {"num_experts": experts, "num_experts_per_tok": top,
+           "share": {"expert_share": 0, "expert_shares": 1}}
+    weights = {"router": router, "w1": w1, "w3": w3, "w2": w2}
+    return ref.experts(x.reshape(-1, x.shape[-1]), weights, "", cfg,
+                       "float32").reshape(x.shape)
+
+
+def _layer_weights(rng, experts):
+    router = jnp.asarray(rng.normal(size=(64, experts)), jnp.float32)
+    w1, w3 = (jnp.asarray(0.1 * rng.normal(size=(experts, 64, 32)),
+                          jnp.float32) for _ in range(2))
+    w2 = jnp.asarray(0.1 * rng.normal(size=(experts, 32, 64)), jnp.float32)
+    return router, w1, w3, w2
+
+
+# (experts, a token's, shares): the last two leave room for twice an even
+# load's rows beside room for all, so the layer chooses as it runs
+@pytest.mark.parametrize("experts,top,shares", [
+    (8, 2, 1), (8, 2, 2), (8, 2, 4), (8, 2, 8), (16, 4, 4), (16, 4, 8)])
+def test_the_shares_add_up(experts, top, shares):
+    """The expert layer's outputs over all `shares`, each with its own
+    experts, sum to the uncut reference's layer, and so do the gradients
+    of what every share is given alike; the counters count every
+    assignment once."""
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.normal(size=(2, POSITIONS, 64)), jnp.float32)
+    router, w1, w3, w2 = _layer_weights(rng, experts)
+    probe = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+    held = experts // shares
+    total, assigned, d_x, d_router = 0.0, 0.0, 0.0, 0.0
+    for share in range(shares):
+        mine = slice(share * held, (share + 1) * held)
+        layer = _expert_layer(share, shares, experts, top)
+
+        def part(x, router):
+            y, counters = layer.apply(
+                {"params": {"router": router, "w1": w1[mine],
+                            "w3": w3[mine], "w2": w2[mine]}}, x)
+            return jnp.sum(y * probe), (y, counters)
+
+        (_, (y, counters)), (gx, gr) = jax.value_and_grad(
+            part, argnums=(0, 1), has_aux=True)(x, router)
+        total, d_x, d_router = total + y, d_x + gx, d_router + gr
+        assigned += float(counters["moe_held_assignments"])
+        assert 0.0 <= float(counters["moe_tokens_unserved"]) < 1.0
+    assert assigned == 2 * POSITIONS * top      # every assignment, once
+    want, (wx, wr) = jax.value_and_grad(
+        lambda x, r: jnp.sum(_uncut(x, r, w1, w3, w2, top) * probe),
+        argnums=(0, 1))(x, router)
+    np.testing.assert_allclose(
+        np.asarray(total), np.asarray(_uncut(x, router, w1, w3, w2, top)),
+        atol=3e-5)
+    np.testing.assert_allclose(np.asarray(d_x), np.asarray(wx), atol=2e-4)
+    np.testing.assert_allclose(np.asarray(d_router), np.asarray(wr),
+                               atol=2e-4)
+
+
+@pytest.mark.parametrize("experts,top,shares,forced", [
+    (8, 2, 1, (1,)),            # room for every assignment, and no other
+    (16, 4, 4, (1,)),           # twice an even load's rows suffice
+    (16, 4, 4, (0, 1, 2))])     # they do not: 3 T rows here, room for 2 T
+def test_no_token_is_dropped_when_every_token_goes_to_one_expert(
+        experts, top, shares, forced):
+    """A router forced to send every token to the `forced` experts: each
+    of them gets all T rows (no capacity), and the output is the
+    reference's. A share that holds none of a token's experts serves it
+    nothing."""
+    rng = np.random.default_rng(13)
+    tokens, held = 2 * POSITIONS, experts // shares
+    x = jnp.asarray(rng.normal(size=(2, POSITIONS, 64)), jnp.float32)
+    router, w1, w3, w2 = _layer_weights(rng, experts)
+    # n2(h) is not applied here: a column along the tokens' common part
+    x = x.at[..., 0].set(5.0)
+    router = 0.01 * np.asarray(router)
+    router[0, list(forced)] = 10.0
+    router = jnp.asarray(router, jnp.float32)
+    probs = jax.nn.softmax(x.reshape(tokens, 64) @ router, axis=-1)
+    _, _, _, sizes, served = mellum2.route(probs, top, 0, held)
+    assert [int(sizes[e]) for e in forced] == [tokens] * len(forced)
+    assert bool(served.all())
+    layer = _expert_layer(0, shares, experts, top)
+    y, counters = layer.apply({"params": {
+        "router": router, "w1": w1[:held], "w3": w3[:held],
+        "w2": w2[:held]}}, x)
+    assert float(counters["moe_held_assignments"]) == float(sizes.sum())
+    assert float(counters["moe_load_max_over_mean"]) == pytest.approx(
+        tokens / (float(sizes.sum()) / held))
+    assert float(counters["moe_tokens_unserved"]) == 0.0
+    rest = [_expert_layer(s, shares, experts, top).apply({"params": {
+        "router": router, "w1": w1[s * held:(s + 1) * held],
+        "w3": w3[s * held:(s + 1) * held],
+        "w2": w2[s * held:(s + 1) * held]}}, x) for s in range(1, shares)]
+    np.testing.assert_allclose(
+        np.asarray(y + sum(r[0] for r in rest)),
+        np.asarray(_uncut(x, router, w1, w3, w2, top)), atol=3e-5)
+    # and its backward pass, whichever room the forward pass took
+    got = jax.grad(lambda w: jnp.sum(layer.apply({"params": {
+        "router": router, "w1": w, "w3": w3[:held], "w2": w2[:held]}},
+        x)[0] ** 2))(w1[:held])
+    rest_y = sum(r[0] for r in rest)
+    want = jax.grad(lambda w: jnp.sum((_uncut(
+        x, router, jnp.concatenate([w, w1[held:]]), w3, w2, top)
+        - rest_y) ** 2))(w1[:held])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-3,
+                               rtol=2e-3)
+    for other, c in rest:
+        unserved = float(c["moe_tokens_unserved"])
+        assert 0.0 <= unserved < 1.0
+        quiet = np.asarray(jnp.all(other == 0, axis=-1)).mean()
+        assert quiet == pytest.approx(unserved)
+    # the forced experts' rows alone are more than twice an even load's
+    enough = -(-2 * tokens * top // shares // 8) * 8
+    assert (float(sizes.sum()) > enough) == (len(forced) == 3)
+
+
+def test_through_the_trainer_for_a_few_sparse_steps(tmp_path):
+    """`--dnn mellum2 --dataset ptb` builds through `make_trainer` like
+    every other model, trains sparse steps on two workers, and its `train`
+    record carries the router's counters."""
+    from gaussiank_sgd_tpu import train
+    kw = {"hidden_size": 64, "num_layers": 4, "num_heads": 4,
+          "num_kv_heads": 2, "head_dim": 16, "sliding_window": WINDOW,
+          "num_experts": 8, "experts_per_token": 2, "expert_width": 32,
+          "expert_share": 0, "expert_shares": 2, "yarn_original_max": 16,
+          "seq_len": POSITIONS}
+    data = {"vocab_size": VOCAB, "bptt": POSITIONS,
+            "synthetic_tokens_n": 4 * (12 * POSITIONS + 1)}
+    trainer = train.make_trainer([
+        "--dnn", "mellum2", "--dataset", "ptb", "--nworkers", "2",
+        "--batch-size", "2", "--compressor", "auto", "--density", "0.01",
+        "--lr", "0.05", "--weight-decay", "0.0001", "--compute-dtype",
+        "float32", "--max-steps", "8", "--log-every", "2",
+        "--model-kwargs", json.dumps(kw), "--dataset-kwargs",
+        json.dumps(data), "--output-dir", str(tmp_path)])
+    try:
+        assert trainer.spec.name == "mellum2" and trainer.spec.task == "lm"
+        first = trainer.train(2)
+        rec = trainer.train(4)
+    finally:
+        trainer.close()
+    assert np.isfinite(rec["loss"]) and rec["loss"] < first["loss"] + 0.5
+    assert rec["num_selected"] > 0
+    # 2 sequences x 32 positions x top-2 a worker, half the experts held
+    assert 0 < rec["moe_held_assignments"] <= 4 * 2 * POSITIONS * 2
+    assert rec["moe_load_max_over_mean"] >= 1.0
+    assert 0.0 <= rec["moe_tokens_unserved"] < 1.0
+    with open(os.path.join(trainer.run_dir, "metrics.jsonl")) as f:
+        trains = [r for r in map(json.loads, f) if r.get("event") == "train"]
+    assert trains and all("moe_held_assignments" in r for r in trains)
