@@ -411,15 +411,19 @@ def build_dp_train_step(
     ``guard_nonfinite``: fuse a non-finite anomaly guard into both step
     programs (training/resilience.py is the host half). The local grads'
     non-finite entry count is psum'd over the mesh so EVERY worker agrees,
-    and an anomalous step commits the OLD state through elementwise
-    ``jnp.where`` selects — no ``lax.cond`` (whose branches diverge under
-    shard_map batching) and no host sync; the step counter AND the integer
-    (counter) leaves of opt_state still advance so the LR schedule and
-    data stream stay aligned on every optimizer path. Containment must be
-    in-step because a NaN that reaches ``ef_residual`` is re-sent by error
-    feedback on every later step. Cost: one ``isfinite`` pass over the
-    grads + one select pass over params/opt_state/residual, both
-    elementwise and fused by XLA (scope ``guard`` in a device trace).
+    and the step is committed ONCE (``_commit``): ``ok`` is known before
+    anything is written, one ``lax.cond`` holds everything that follows
+    the exchange, its commit side updates parameters and optimizer state
+    in place in the donated buffers and its keep side hands the old state
+    back untouched — every worker takes the same side and neither holds a
+    collective, so nothing diverges under shard_map; no host sync; the
+    step counter AND the integer (counter) leaves of opt_state still
+    advance so the LR schedule and data stream stay aligned on every
+    optimizer path. Containment must be in-step because a NaN that
+    reaches ``ef_residual`` is re-sent by error feedback on every later
+    step. Cost: one reduction over the grads, which also yields the
+    gradient norm (scope ``guard`` in a device trace), and no pass whose
+    only work is ``where(ok, new, old)``. False: no count, no guard.
 
     ``sp_axis``: ring-attention sequence parallelism (long-context path).
     Must name the mesh's LAST axis; the batch's dim 0 then shards over the
@@ -613,43 +617,88 @@ def build_dp_train_step(
                 ss = lax.psum(ss, a)
             return jnp.sqrt(ss)
 
-    def _guard_count(loss: jax.Array, flat_g: jax.Array) -> jax.Array:
-        """Global non-finite count: per-worker grad-entry count psum'd over
-        every mesh axis (all workers must agree — one worker's NaN pollutes
-        the summed exchange for everyone), plus one for a non-finite loss
-        (already dp-mean'd, so globally consistent)."""
+    def _grad_count_and_norm(loss: jax.Array, flat_g: jax.Array):
+        """``(cnt, grad_norm)`` from ONE pass over the flat gradient.
+
+        ``cnt`` is the global non-finite count: the per-worker count of
+        gradient entries psum'd over every mesh axis (all workers must
+        agree — one worker's NaN pollutes the summed exchange for
+        everyone), plus one for a non-finite loss (already dp-mean'd, so
+        globally consistent); None when the guard is off. ``grad_norm`` is
+        the dp-mean of the per-worker L2 norms. Count and sum of squares
+        are the two outputs of one reduction, so the gradient is streamed
+        once for both; the pass keeps the scope ``guard``."""
+        if not guard_nonfinite:
+            with jax.named_scope("step_metrics"):
+                return None, _pmean(jnp.linalg.norm(flat_g))
         with jax.named_scope("guard"):
-            cnt = jnp.sum((~jnp.isfinite(flat_g)).astype(jnp.int32))
+            g32 = flat_g.astype(jnp.float32)
+            cnt, ss = lax.reduce(
+                ((~jnp.isfinite(flat_g)).astype(jnp.int32), g32 * g32),
+                (jnp.int32(0), jnp.float32(0)),
+                lambda a, b: (a[0] + b[0], a[1] + b[1]), (0,))
             for a in axes:
                 cnt = lax.psum(cnt, a)
-            return cnt + (~jnp.isfinite(loss)).astype(jnp.int32)
+            cnt = cnt + (~jnp.isfinite(loss)).astype(jnp.int32)
+        with jax.named_scope("step_metrics"):
+            return cnt, _pmean(jnp.sqrt(ss).astype(flat_g.dtype))
 
-    def _guard_commit(ok: jax.Array, old: TrainState,
-                      new: TrainState) -> TrainState:
-        """Commit ``new`` when ``ok``, else keep ``old``'s training state
-        bit-identically — elementwise ``jnp.where`` on a replicated scalar
-        predicate, so there is no branch divergence and no host sync. The
-        step counter and rng always come from ``new`` (a skipped step still
-        advances the schedule/data position), and so do the INTEGER leaves
-        of opt_state: they are step/schedule counters (optax
-        ScaleByScheduleState.count and kin) whose value must track
-        state.step — guarding them would make the optax-path LR schedule
-        lag the global step by one per skip. Counter increments never
-        touch the gradient, so a NaN cannot leak through them; float
-        leaves (momentum/trace buffers) are guarded."""
-        def keep(n, o):
-            return jax.tree.map(lambda a, b: jnp.where(ok, a, b), n, o)
-        def keep_opt(n, o):
-            return jax.tree.map(
-                lambda a, b: a if jnp.issubdtype(a.dtype, jnp.integer)
-                else jnp.where(ok, a, b), n, o)
-        with jax.named_scope("guard"):
-            return TrainState(new.step, keep(new.params, old.params),
-                              keep(new.model_state, old.model_state),
-                              keep_opt(new.opt_state, old.opt_state),
-                              keep(new.ef_residual, old.ef_residual),
-                              new.rng, keep(new.carry, old.carry),
-                              keep(new.comp_state, old.comp_state))
+    def _commit(cnt: Optional[jax.Array], state: TrainState, mstate: Any,
+                residual: jax.Array, new_carry: Any, comp_state: Any,
+                update: Callable[[Any, Any], Tuple[Any, Any]]):
+        """Commit the step ONCE: the guard decides first, then every
+        buffer is written at most once. Returns ``(state', skipped,
+        nonfinite)``, the last two for ``StepMetrics``.
+
+        ``ok = cnt == 0`` (replicated: the psum'd count says the same on
+        every worker) is known before anything is written. One ``lax.cond``
+        holds everything that follows the exchange: its ``commit`` side
+        runs ``update(params, opt_state) -> (params', opt_state')`` — the
+        optimizer, in place in the donated buffers — and takes the new
+        model state, residual, carry and compressor state; its ``keep``
+        side hands the old ones back untouched, so a skipped step is
+        bit-identical on every float leaf (a momentum entry of -0.0
+        included: nothing is added to it) and neither side holds a
+        collective. No old-or-new select over a vector of n survives, on
+        this or on the optax path. The step counter and rng advance on
+        both sides (a skipped step still moves the schedule and the data
+        position), and so do the INTEGER leaves of opt_state: they are
+        step/schedule counters (optax ScaleByScheduleState.count and kin)
+        whose value must track state.step — holding them would make the
+        optax-path LR schedule lag the global step by one per skip.
+        Counter increments never touch the gradient, so a NaN cannot leak
+        through them; ``keep`` takes them from the same ``update`` (whose
+        float work XLA drops there) and every float leaf from the old
+        state. ``cnt`` None (``guard_nonfinite=False``): no guard, the
+        commit side alone."""
+        def is_counter(x):
+            return jnp.issubdtype(x.dtype, jnp.integer)
+
+        def commit(old):
+            params, opt_state = update(old.params, old.opt_state)
+            return (params, mstate, opt_state, residual, new_carry,
+                    comp_state)
+
+        def keep(old):
+            _, stepped = update(old.params, old.opt_state)
+            opt_state = jax.tree.map(
+                lambda new, o: new if is_counter(o) else o, stepped,
+                old.opt_state)
+            return (old.params, old.model_state, opt_state,
+                    old.ef_residual, old.carry, old.comp_state)
+
+        if cnt is None:
+            out = commit(state)
+            skipped = nonfinite = jnp.float32(0)
+        else:
+            with jax.named_scope("guard"):
+                out = lax.cond(cnt == 0, commit, keep, state)
+                skipped = (cnt > 0).astype(jnp.float32)
+                nonfinite = cnt.astype(jnp.float32)
+        params, model_state, opt_state, ef_residual, carry, comp_state = out
+        return TrainState(state.step + 1, params, model_state, opt_state,
+                          ef_residual, state.rng, carry, comp_state
+                          ), skipped, nonfinite
 
     def _local_grads(state: TrainState, batch: Any, data_rng: jax.Array,
                      pad: int = 0):
@@ -690,36 +739,14 @@ def build_dp_train_step(
             flat_g = flat_g.astype(grad_dtype)
         return flat_g, unravel
 
-    def _apply(state: TrainState, mstate: Any, dense_flat: jax.Array, unravel,
-               new_residual: jax.Array, new_carry: Any,
-               new_comp_state: Any = None):
-        with jax.named_scope("update"):
-            updates, opt_state = optimizer.update(
-                unravel(dense_flat), state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
-        return TrainState(state.step + 1, params, mstate, opt_state,
-                          new_residual, state.rng, new_carry,
-                          state.comp_state if new_comp_state is None
-                          else new_comp_state)
-
-    def _flat_params_if_wd(state: TrainState):
-        if flat_opt.weight_decay:
+    def _optax_update(dense_flat: jax.Array, unravel):
+        """The optax path's ``update`` for ``_commit``."""
+        def update(params, opt_state):
             with jax.named_scope("update"):
-                return ravel_pytree(state.params)[0]
-        return None
-
-    def _apply_flat(state: TrainState, mstate: Any, upd_flat: jax.Array,
-                    m_new: jax.Array, unravel, new_residual: jax.Array,
-                    new_carry: Any, new_comp_state: Any = None):
-        """Flat sparse-aware optimizer commit (parallel/flat_opt.py): the
-        momentum buffer was updated by the caller (sparse scatter or dense
-        add); apply the flat update through the unravel views."""
-        with jax.named_scope("update"):
-            params = optax.apply_updates(state.params, unravel(upd_flat))
-        return TrainState(state.step + 1, params, mstate, {"m": m_new},
-                          new_residual, state.rng, new_carry,
-                          state.comp_state if new_comp_state is None
-                          else new_comp_state)
+                updates, opt_state = optimizer.update(
+                    unravel(dense_flat), opt_state, params)
+                return optax.apply_updates(params, updates), opt_state
+        return update
 
     def _compress_phase(state: TrainState, flat_g: jax.Array, scale,
                         comp_rng: jax.Array):
@@ -1082,23 +1109,19 @@ def build_dp_train_step(
                 # momentum (flat_opt.py): no dense gradient buffer exists
                 if exchange == "gtopk":
                     g_idx, g_val = gcomp.indices, gcomp.values
-                upd, m_new = flat_opt.sparse_step(
-                    state.opt_state["m"], g_idx.reshape(-1), g_val,
-                    _flat_params_if_wd(state), state.step)
-                new_state = _apply_flat(
-                    state, mstate, upd, m_new, unravel, residual, new_carry,
-                    cstate[None, :] if spec.stateful else ())
+
+                def update(params, opt_state):
+                    params, m = flat_opt.sparse_step(
+                        params, opt_state["m"], g_idx.reshape(-1), g_val,
+                        state.step)
+                    return params, {"m": m}
             else:
-                new_state = _apply(state, mstate, dense, unravel, residual,
-                                   new_carry,
-                                   cstate[None, :] if spec.stateful else ())
-            if guard_nonfinite:
-                cnt = _guard_count(loss, flat_g)
-                new_state = _guard_commit(cnt == 0, state, new_state)
-                skipped = (cnt > 0).astype(jnp.float32)
-                nonfinite = cnt.astype(jnp.float32)
-            else:
-                skipped = nonfinite = jnp.float32(0)
+                update = _optax_update(dense, unravel)
+            cnt, grad_norm = _grad_count_and_norm(loss, flat_g)
+            new_state, skipped, nonfinite = _commit(
+                cnt, state, mstate, residual, new_carry,
+                cstate[None, :] if spec.stateful else state.comp_state,
+                update)
             # on-device comms/compression accounting (telemetry): one pmean
             # of the per-bucket count vector serves num_selected, the
             # achieved density, AND the per-bucket breakdown; the EF norm
@@ -1107,7 +1130,6 @@ def build_dp_train_step(
             with jax.named_scope("step_metrics"):
                 sel_per_bucket = _pmean(nsel.astype(jnp.float32))
                 num_selected = jnp.sum(sel_per_bucket)
-                grad_norm = _pmean(jnp.linalg.norm(flat_g))
             return new_state, StepMetrics(
                 loss, aux, grad_norm,
                 num_selected, bytes_sent, skipped, nonfinite,
@@ -1138,23 +1160,16 @@ def build_dp_train_step(
         # Warm-up is compression-off: the EF residual is untouched (and zero
         # if warm-up precedes any sparse step), matching SURVEY.md §2.3.
         if flat_opt is not None:
-            upd, m_new = flat_opt.dense_step(
-                state.opt_state["m"], dense, _flat_params_if_wd(state),
-                state.step)
-            new_state = _apply_flat(state, mstate, upd, m_new, unravel,
-                                    state.ef_residual, new_carry)
+            def update(params, opt_state):
+                params, m = flat_opt.dense_step(params, opt_state["m"],
+                                                dense, state.step)
+                return params, {"m": m}
         else:
-            new_state = _apply(state, mstate, dense, unravel,
-                               state.ef_residual, new_carry)
-        if guard_nonfinite:
-            cnt = _guard_count(loss, flat_g)
-            new_state = _guard_commit(cnt == 0, state, new_state)
-            skipped = (cnt > 0).astype(jnp.float32)
-            nonfinite = cnt.astype(jnp.float32)
-        else:
-            skipped = nonfinite = jnp.float32(0)
-        with jax.named_scope("step_metrics"):
-            grad_norm = _pmean(jnp.linalg.norm(flat_g))
+            update = _optax_update(dense, unravel)
+        cnt, grad_norm = _grad_count_and_norm(loss, flat_g)
+        new_state, skipped, nonfinite = _commit(
+            cnt, state, mstate, state.ef_residual, new_carry,
+            state.comp_state, update)
         return new_state, StepMetrics(
             loss, aux, grad_norm,
             jnp.float32(n_total), jnp.float32(n_total * 4), skipped,

@@ -5,8 +5,12 @@ libtpu compiles for a *topology description* on a machine that has no chip, so
 the full dense and sparse step — Mosaic kernel included — can be compiled here
 on the CPU host before chip budget is spent on a run that would have died in
 the compiler. It prints, per program: compile seconds, ``tpu_custom_call``
-count, the collectives left in the optimized HLO, XLA's FLOP count and its
-memory analysis.
+count, the collectives left in the optimized HLO, XLA's FLOP count, its
+memory analysis, and what the program does AFTER the gradient as a count of
+work: the bytes that the optimized HLO's operations read and write under each
+of the step's own scopes, in passes over one n-vector (``passes_by_scope``;
+ROADMAP S10's list is sized from this). The optimizer is the cells' (momentum
+0.9 and a weight decay), so the program compiled is the one a cell runs.
 
 It proves COMPILATION ONLY. It runs nothing: not start-up, not placement, not
 numerics, not time. Those are chip_smoke.py's, on the chip.
@@ -42,6 +46,7 @@ import numpy as np
 from jax.experimental import topologies
 from jax.sharding import Mesh
 
+from benchmarks.span_reduce import scope_of
 from gaussiank_sgd_tpu.compressors import get_compressor
 from gaussiank_sgd_tpu.models import get_model
 from gaussiank_sgd_tpu.parallel.bucketing import plan_for_params
@@ -52,6 +57,203 @@ from gaussiank_sgd_tpu.training.losses import make_loss_fn
 _COLLECTIVE = re.compile(
     r" (all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)"
     r"(-start)?\(")
+
+
+# ------------------------------------------------ passes over an n-vector
+# What the step program does after the gradient is elementwise work over
+# vectors of n parameters, so its cost is how often it streams one. This
+# counts that from the optimized HLO: a count of work, never a time.
+
+# the scopes printed: what follows the gradient (benchmarks/span_reduce.py
+# names them all and reads them from an operation's `op_name`)
+_PRINTED = ("flatten", "ef_select", "scatter", "update", "guard",
+            "step_metrics")
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2,
+                "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+                "f64": 8}
+_ARRAY = re.compile(r"\b([a-z]+[0-9]*)\[([0-9,]*)\]")
+_INSTR = re.compile(r"^\s*(ROOT )?%([\w.\-]+) = ")
+_NAME = re.compile(r"%([\w.\-]+)")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(r"(?:calls|body|(?:true|false)_computation)=%([\w.\-]+)"
+                     r"|branch_computations=\{([^}]*)\}")
+# operations that move nothing: views, tuples, and the half of an async
+# pair that only waits
+_FREE = frozenset((
+    "parameter", "tuple", "get-tuple-element", "bitcast", "constant", "iota",
+    "after-all", "partition-id", "replica-id", "opt-barrier", "copy-done",
+    "slice-done", "async-done", "all-reduce-done", "all-gather-done",
+    "collective-permute-done"))
+_VIEWS = ("bitcast", "reshape", "copy", "convert")
+
+
+def _type_bytes(t: str) -> int:
+    """Bytes of an HLO type; a tuple's are its elements' sum."""
+    total = 0
+    for dtype, dims in _ARRAY.findall(t):
+        size = _DTYPE_BYTES.get(dtype, 0)    # token[], opaque: nothing
+        for d in dims.split(","):
+            size *= int(d) if d else 1
+        total += size
+    return total
+
+
+def _balanced(s: str, i: int) -> int:
+    """Index just past the parenthesis that closes the one at ``s[i]``."""
+    depth = 0
+    for j in range(i, len(s)):
+        depth += (s[j] == "(") - (s[j] == ")")
+        if depth == 0:
+            return j + 1
+    return len(s)
+
+
+def _parse_hlo(hlo: str):
+    """({computation: [instruction]}, entry's name) of an HLO module's text;
+    an instruction is a dict of name, type, op, args (the text between its
+    parentheses), operands (the names in it), attrs (what follows), root."""
+    comps, cur, entry = {}, None, None
+    for line in hlo.splitlines():
+        if line.endswith("{") and "->" in line and line[:1] not in " \t":
+            head = line.split()
+            is_entry = head[0] == "ENTRY"
+            name = head[1 if is_entry else 0].lstrip("%")
+            cur = comps.setdefault(name, [])
+            entry = name if is_entry else entry
+            continue
+        m = _INSTR.match(line)
+        if m is None or cur is None:
+            continue
+        rest = line[m.end():]
+        cut = _balanced(rest, 0) if rest.startswith("(") else rest.index(" ")
+        typ, rest = rest[:cut], rest[cut:].lstrip()
+        par = rest.index("(")
+        end = _balanced(rest, par)
+        args = rest[par + 1:end - 1]
+        cur.append({"name": m.group(2), "type": typ, "op": rest[:par],
+                    "args": args, "operands": _NAME.findall(args),
+                    "attrs": rest[end:], "root": bool(m.group(1))})
+    return comps, entry
+
+
+def _called(instr) -> list:
+    out = []
+    for one, many in _CALLED.findall(instr["attrs"]):
+        out += [one] if one else _NAME.findall(many)
+    return out
+
+
+def _fusion_bytes(instr, types, comps):
+    """(read, written) of one fusion. An operand that the fused computation
+    only slices is read as far as its slices go. A fusion that ends in a
+    ``dynamic-update-slice`` or a ``scatter`` into one of its operands
+    writes the update, in place, and does not read the buffer that it
+    updates (XLA aliases that operand with the result)."""
+    body = comps.get((_called(instr) or [""])[0], [])
+    by_name = {i["name"]: i for i in body}
+    users = {}
+    for i in body:
+        for o in i["operands"]:
+            users.setdefault(o, []).append(i)
+
+    def behind_views(i):
+        while i is not None and i["op"] in _VIEWS and i["operands"]:
+            i = by_name.get(i["operands"][0])
+        return i
+
+    written = _type_bytes(instr["type"])
+    in_place = set()
+    root = next((i for i in body if i["root"]), None)
+    roots = ([by_name.get(o) for o in root["operands"]]
+             if root is not None and root["op"] == "tuple" else [root])
+    for r in map(behind_views, roots):
+        if r is None or r["op"] not in ("dynamic-update-slice", "scatter"):
+            continue
+        target = behind_views(by_name.get(r["operands"][0]))
+        update = by_name.get(
+            r["operands"][1 if r["op"] == "dynamic-update-slice" else -1])
+        if target is not None and target["op"] == "parameter" \
+                and update is not None:
+            in_place.add(target["name"])
+            written += _type_bytes(update["type"]) - _type_bytes(r["type"])
+    read = 0
+    for i in body:
+        if i["op"] != "parameter" or i["name"] in in_place:
+            continue
+        uses = users.get(i["name"], [])
+        full = _type_bytes(types.get(instr["operands"][int(i["args"])],
+                                     i["type"]))
+        if uses and all(u["op"] in ("slice", "dynamic-slice") for u in uses):
+            read += min(full, sum(_type_bytes(u["type"]) for u in uses))
+        else:
+            read += full
+    return read, written
+
+
+def passes_by_scope(hlo: str, n: int) -> dict:
+    """{label: [passes read, passes written]} of a compiled step program:
+    the bytes that the optimized HLO's operations read and write, by the
+    program's scope on their ``op_name``, in units of one float32 n-vector
+    (4 n bytes). A ``while``'s body counts once; of a ``conditional``'s
+    branches the one that moves the most counts (the step that commits,
+    not the step that the guard skips). Under no scope only the operations
+    that are nothing but movement count, as ``"no scope, <opcode>"``: a
+    concatenation's ``dynamic-update-slice`` and the copies lose their
+    ``op_name``."""
+    comps, entry = _parse_hlo(hlo)
+    unit = 4.0 * n
+
+    def walk(comp) -> dict:
+        acc = {}
+
+        def add(label, read, written):
+            a = acc.setdefault(label, [0.0, 0.0])
+            a[0] += read
+            a[1] += written
+
+        types = {i["name"]: i["type"] for i in comps[comp]}
+        for i in comps[comp]:
+            op = i["op"]
+            if op in _FREE:
+                continue
+            if op in ("conditional", "while", "call"):
+                inner = [walk(c) for c in _called(i)]
+                if op == "conditional":
+                    inner = [max(inner, key=lambda d: sum(
+                        map(sum, d.values())))]
+                for d in inner:
+                    for label, (read, written) in d.items():
+                        add(label, read, written)
+                continue
+            if op == "fusion":
+                read, written = _fusion_bytes(i, types, comps)
+            elif op == "dynamic-update-slice":
+                read = written = _type_bytes(types.get(i["operands"][1], ""))
+            elif op in ("slice", "dynamic-slice", "copy", "copy-start",
+                        "slice-start"):
+                read = written = _type_bytes(i["type"]) // (
+                    2 if op.endswith("-start") else 1)
+            else:
+                read = sum(_type_bytes(types.get(o, ""))
+                           for o in i["operands"])
+                written = _type_bytes(i["type"])
+            m = _OP_NAME.search(i["attrs"])
+            scope = scope_of(m.group(1)) if m else None
+            if scope in _PRINTED:
+                add(scope, read / unit, written / unit)
+            elif scope is None and op in ("dynamic-update-slice", "copy"):
+                add(f"no scope, {op}", read / unit, written / unit)
+        return acc
+
+    acc = walk(entry)
+    return {**{s: [0.0, 0.0] for s in _PRINTED}, **acc}
+
+
+def print_passes(hlo: str, n: int) -> None:
+    print(f"    passes over one n-vector ({4 * n / 1e9:.3f} GB) by scope, "
+          f"read + written (a count of work from the optimized HLO):")
+    for label, (r, w) in passes_by_scope(hlo, n).items():
+        print(f"      {label:<31}{r:6.2f} + {w:5.2f} = {r + w:6.2f}")
 
 
 def _batch_shapes(spec, batch_size: int):
@@ -103,7 +305,8 @@ def main(argv=None) -> None:
     ts = build_dp_train_step(
         make_loss_fn(spec, recurrent=recurrent), None,
         get_compressor(args.compressor, density=args.density), plan, mesh,
-        recurrent=recurrent, flat_opt=FlatSGDM(lr=0.1, momentum=0.9))
+        recurrent=recurrent,
+        flat_opt=FlatSGDM(lr=0.1, momentum=0.9, weight_decay=1e-4))
     carry = (jax.eval_shape(lambda: spec.module.initial_carry(
         args.batch_size * args.chips)) if recurrent else ())
     state = jax.eval_shape(
@@ -123,10 +326,12 @@ def main(argv=None) -> None:
               f"{lowered.as_text().count('tpu_custom_call')} "
               f"tpu_custom_call, "
               f"{compiled.cost_analysis().get('flops', 0):.4g} flop/step")
-        for line in compiled.as_text().splitlines():
+        hlo = compiled.as_text()
+        for line in hlo.splitlines():
             if _COLLECTIVE.search(line):
                 print("   ", line.strip()[:200])
         print("   ", compiled.memory_analysis())
+        print_passes(hlo, plan.total_numel)
 
 
 if __name__ == "__main__":

@@ -546,3 +546,197 @@ def test_exchange_scope_is_on_the_collective(exchange, collective):
         assert name.startswith("exchange/"), name
     dense = ts.dense_step.lower(state, batch).as_text(debug_info=True)
     assert 'loc("exchange/psum"' in dense
+
+
+# ---------------------------------------------------------------------------
+# the step is committed once (PR 32): the guard decides before the update,
+# one lax.cond holds everything after the exchange, nothing is selected
+# ---------------------------------------------------------------------------
+
+def _walk_eqns(jaxpr):
+    """(equation, the jaxpr that holds it) over the whole nested jaxpr."""
+    from gaussiank_sgd_tpu.lint.program_audit import _sub_jaxprs
+    for eqn in jaxpr.eqns:
+        yield eqn, jaxpr
+        for sub in _sub_jaxprs(eqn):
+            yield from _walk_eqns(sub)
+
+
+def _build_commit(path, workers, wd=0.01):
+    from gaussiank_sgd_tpu.parallel.flat_opt import FlatSGDM
+    params, loss_fn, make_batch = make_problem()
+    mesh = data_parallel_mesh(workers)
+    spec = get_compressor("gaussian_fused", density=0.05)
+    plan = plan_for_params(params, 0.05)
+    if path == "flat":
+        ts = build_dp_train_step(
+            loss_fn, None, spec, plan, mesh, wire="off",
+            flat_opt=FlatSGDM(lr=0.05, momentum=0.9, weight_decay=wd))
+    else:
+        opt = optax.sgd(optax.constant_schedule(0.05), momentum=0.9)
+        ts = build_dp_train_step(loss_fn, opt, spec, plan, mesh, wire="off")
+    state = ts.init_state(params, jax.random.PRNGKey(42))
+    return ts, state, make_batch, mesh, plan.total_numel
+
+
+@pytest.mark.parametrize("path", ["flat", "optax"])
+@pytest.mark.parametrize("workers", [1, 2])
+@pytest.mark.parametrize("program", ["sparse", "dense"])
+def test_the_step_is_committed_by_one_cond_and_no_select(program, workers,
+                                                         path):
+    """Form (a) of ISSUE 32, pinned on the step's jaxpr (a count of work):
+    ONE ``cond`` returns the state's n-length leaves, none of its branches
+    holds a collective (every worker takes the same side: ``ok`` is the
+    psum'd count), no n-length ``select_n`` on a broadcast scalar predicate
+    — the old ``where(ok, new, old)`` — is left anywhere, and on the flat
+    path ``-lr*m'`` is no vector of its own (no n-length product is cut
+    into the leaves' pieces)."""
+    from gaussiank_sgd_tpu.lint.program_audit import (collect_primitives,
+                                                      collective_inventory)
+    ts, state, make_batch, mesh, n = _build_commit(path, workers)
+    step = ts.sparse_step if program == "sparse" else ts.dense_step
+    closed = jax.make_jaxpr(step)(state, shard_batch(mesh, make_batch(16)))
+    eqns = list(_walk_eqns(closed.jaxpr))
+
+    def size(v):
+        return int(np.prod(v.aval.shape)) if hasattr(v.aval, "shape") else 0
+
+    commits = [e for e, _ in eqns if e.primitive.name == "cond"
+               and any(size(v) >= n for v in e.outvars)]
+    assert len(commits) == 1, [str(e.primitive) for e in commits]
+    for branch in commits[0].params["branches"]:
+        held = collective_inventory(collect_primitives(branch.jaxpr))
+        assert not held, held
+    for e, jaxpr in eqns:
+        if e.primitive.name != "select_n" or size(e.outvars[0]) < n:
+            continue
+        made = [p for p in jaxpr.eqns if e.invars[0] in p.outvars]
+        scalar = size(e.invars[0]) == 1 or (
+            made and made[0].primitive.name == "broadcast_in_dim"
+            and size(made[0].invars[0]) == 1)
+        assert not scalar, (
+            "an n-length select on a scalar predicate: the old commit")
+    if path == "flat":
+        cut = {"slice", "dynamic_slice", "split", "reshape", "gather"}
+        for e, jaxpr in eqns:
+            if e.primitive.name == "mul" and size(e.outvars[0]) == n:
+                users = {u.primitive.name for u in jaxpr.eqns
+                         if e.outvars[0] in u.invars}
+                assert not users & cut, (
+                    f"an n-length product is cut into leaves: {users}")
+
+
+def _with_negative_zeros(state, mesh):
+    """The state with -0.0 planted in every float optimizer leaf and in the
+    residual: an added +0.0 would flip them, a step left alone does not."""
+    def plant(x):
+        if not jnp.issubdtype(x.dtype, jnp.floating) or x.size < 4:
+            return x
+        flat = np.array(jax.device_get(x)).reshape(-1)
+        flat[1::3] = -0.0
+        return jax.device_put(flat.reshape(x.shape), x.sharding)
+    return state._replace(opt_state=jax.tree.map(plant, state.opt_state),
+                          ef_residual=plant(state.ef_residual))
+
+
+@pytest.mark.parametrize("path", ["flat", "optax"])
+@pytest.mark.parametrize("workers", [1, 2])
+@pytest.mark.parametrize("where", ["gradient", "loss"])
+def test_a_skipped_step_is_bit_identical_with_negative_zeros(where, workers,
+                                                             path):
+    """A skipped sparse step leaves every float leaf bit-identical, the
+    sign of a -0.0 in the momentum and the residual included, whether the
+    non-finite value is in the gradient or in the loss alone (finite
+    gradient); the step counter and the integer optimizer leaves advance."""
+    from gaussiank_sgd_tpu.parallel.flat_opt import FlatSGDM
+    params, base_loss, make_batch = make_problem()
+
+    def loss_fn(p, mstate, batch, rng):
+        loss, out = base_loss(p, mstate, batch, rng)
+        # a batch flagged by its first label poisons the loss alone
+        poison = jnp.where(batch[1][0, 0] > 1e6, jnp.inf, 0.0)
+        return loss + jax.lax.stop_gradient(poison), out
+
+    mesh = data_parallel_mesh(workers)
+    spec = get_compressor("gaussian_fused", density=0.05)
+    plan = plan_for_params(params, 0.05)
+    if path == "flat":
+        ts = build_dp_train_step(
+            loss_fn, None, spec, plan, mesh, wire="off",
+            flat_opt=FlatSGDM(lr=0.05, momentum=0.9, weight_decay=0.01))
+    else:
+        ts = build_dp_train_step(
+            loss_fn, optax.sgd(optax.constant_schedule(0.05), momentum=0.9),
+            spec, plan, mesh, wire="off")
+    state = ts.init_state(params, jax.random.PRNGKey(42))
+    batch = shard_batch(mesh, make_batch(16))
+    for _ in range(2):                   # a momentum and a residual exist
+        state, _m = ts.sparse_step(state, batch)
+    state = _with_negative_zeros(state, mesh)
+
+    def bits(tree):
+        return [np.asarray(jax.device_get(x)).view(np.uint8).copy()
+                for x in jax.tree_util.tree_leaves(tree)
+                if jnp.issubdtype(x.dtype, jnp.floating)]
+
+    def counters(tree):
+        return [int(x) for x in jax.tree_util.tree_leaves(tree)
+                if jnp.issubdtype(x.dtype, jnp.integer)]
+
+    frozen = (state.params, state.model_state, state.opt_state,
+              state.ef_residual, state.carry, state.comp_state)
+    before, counted, step0 = bits(frozen), counters(state.opt_state), \
+        int(state.step)
+    assert any((b.view(np.float32) == 0).any() for b in before)
+    x, y = make_batch(16)
+    if where == "gradient":
+        bad = (x.at[0, 0].set(jnp.nan), y)
+    else:
+        bad = (x, y.at[0, 0].set(1e7))
+    state, m = ts.sparse_step(state, shard_batch(mesh, bad))
+    assert float(m.skipped) == 1.0 and float(m.nonfinite) >= 1
+    after = bits((state.params, state.model_state, state.opt_state,
+                  state.ef_residual, state.carry, state.comp_state))
+    for a, b in zip(before, after):
+        assert np.array_equal(a, b)
+    assert int(state.step) == step0 + 1
+    assert counters(state.opt_state) == [c + 1 for c in counted]
+    assert (path == "optax") == bool(counted)
+    state, m = ts.sparse_step(state, batch)         # and the next one commits
+    assert float(m.skipped) == 0.0
+
+
+@pytest.mark.parametrize("wd", [0.0, 0.01])
+@pytest.mark.parametrize("program", ["sparse", "dense"])
+def test_three_steps_of_the_flat_update_match_the_optax_chain(program, wd):
+    """Three steps of either program through the commit equal
+    ``test_flat_opt_matches_optax_trajectory``'s reference (the optax
+    chain, f32 exchange) as before, with weight decay on and off: momentum
+    and parameters both."""
+    from gaussiank_sgd_tpu.parallel.flat_opt import FlatSGDM
+    params, loss_fn, make_batch = make_problem()
+    mesh = data_parallel_mesh()
+    spec = get_compressor("topk", density=0.25)
+    plan = plan_for_params(params, 0.25, None)
+    chain = ([optax.add_decayed_weights(wd)] if wd else []) \
+        + [optax.sgd(0.05, momentum=0.9)]
+    ts_ref = build_dp_train_step(loss_fn, optax.chain(*chain), spec, plan,
+                                 mesh, wire="off")
+    ts_flat = build_dp_train_step(
+        loss_fn, None, spec, plan, mesh, wire="off",
+        flat_opt=FlatSGDM(lr=0.05, momentum=0.9, weight_decay=wd))
+    s_ref = ts_ref.init_state(params, jax.random.PRNGKey(42))
+    s_flat = ts_flat.init_state(params, jax.random.PRNGKey(42))
+    batch = shard_batch(mesh, make_batch(64))
+    for _ in range(3):
+        s_ref, _m = getattr(ts_ref, program + "_step")(s_ref, batch)
+        s_flat, _m = getattr(ts_flat, program + "_step")(s_flat, batch)
+    np.testing.assert_allclose(
+        np.asarray(ravel_pytree(s_flat.params)[0]),
+        np.asarray(ravel_pytree(s_ref.params)[0]), rtol=1e-5, atol=1e-6)
+    trace = [x for x in jax.tree_util.tree_leaves(s_ref.opt_state)
+             if getattr(x, "ndim", 0)]
+    np.testing.assert_allclose(
+        np.asarray(s_flat.opt_state["m"]),
+        np.concatenate([np.asarray(t).reshape(-1) for t in trace]),
+        rtol=1e-5, atol=1e-6)
