@@ -1,0 +1,12 @@
+"""Layer: model. Scope `moe_shared` inside `fwd_bwd`: the shared expert that
+every token passes beside the routed ones (three dense products of width
+768), forward, recomputed forward and backward. Self time of the device
+operations whose `op_name` carries the scope, per step of the profiled
+sparse block, averaged over the chips. None where the program names no such
+scope. Moves `examples_per_s`. Source: device_trace."""
+
+from benchmarks import model_scopes
+
+
+def read(run):
+    return model_scopes.scope_ms(run, "moe_shared")

@@ -14,6 +14,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax.numpy as jnp
 
 from .alexnet import AlexNet
+from .joyai_flash import JoyAIFlash
 from .lstm import LSTMLM
 from .mellum2 import Mellum2
 from .mnistnet import MnistNet
@@ -34,6 +35,10 @@ class ModelSpec(NamedTuple):
     # the module's `__call__` takes `return_counters=True` and then answers
     # (output, {name: scalar}); the loss function logs them (`StepMetrics.aux`)
     counters: bool = False
+    # > 0 (lm): the module takes `next_tokens=` (the targets) and then
+    # answers (logits, logits two tokens ahead); the loss adds this much of
+    # the second cross-entropy (a multi-token-prediction module)
+    mtp_lambda: float = 0.0
 
 
 _CIFAR = (32, 32, 3)
@@ -102,9 +107,23 @@ def get_model(dnn: str, dataset: Optional[str] = None, *,
         m = Mellum2(vocab_size=vocab, dtype=dtype, **kw)
         return ModelSpec("mellum2", m, (seq_len,), jnp.int32, vocab, "lm",
                          counters=True)
-    raise ValueError(f"unknown dnn {dnn!r}")
+    if dnn == "joyai_flash":
+        # latent attention, a sigmoid router with a shared expert, a leading
+        # dense layer, a multi-token-prediction module
+        # (models/joyai_flash.py); `vocab_size` is the rows held
+        vocab = kw.pop("vocab_size", 129280)
+        seq_len = kw.pop("seq_len", 128)
+        # DeepSeek-V3 (arXiv:2412.19437, whose keys the config carries)
+        # weighs the module's loss 0.3; the config itself gives no weight
+        mtp_lambda = kw.pop("mtp_lambda", 0.3)
+        m = JoyAIFlash(vocab_size=vocab, dtype=dtype, **kw)
+        return ModelSpec("joyai_flash", m, (seq_len,), jnp.int32, vocab,
+                         "lm", counters=True, mtp_lambda=(
+                             mtp_lambda if m.num_nextn_predict_layers
+                             else 0.0))
+    raise ValueError(f"unknown dnn {dnn!r}; known: {', '.join(NAMES)}")
 
 
 NAMES = ("resnet20", "resnet32", "resnet44", "resnet56", "resnet110",
          "resnet50", "vgg16", "alexnet", "mnistnet", "lstm", "lstman4",
-         "transformer", "transformer_lm", "mellum2")
+         "transformer", "transformer_lm", "mellum2", "joyai_flash")

@@ -38,9 +38,13 @@ blocks a mask leaves empty, so a window layer's work goes with S x window;
 elsewhere (the CPU tests) blocks of queries against the keys their mask can
 reach.
 
-Not in the published config and so not built: QK-norm, a shared expert, an
-auxiliary load-balance loss, a multi-token-prediction head (the benchmark's
-configuration file lists these under `assumed`).
+Not in this model's published config and so not used by it: QK-norm, an
+auxiliary load-balance loss; nor, though the pieces below offer them to the
+models that share them (`models/joyai_flash.py`): a shared expert, sigmoid
+scores with a selection bias and a scale (`Experts`, `GatedMLP`), adjacent-pair rotary
+(`apply_rope`), values of a head size of their own and a key/value head for
+every query head (`plain_attention`, `splash_attention`); the benchmark's
+configuration file lists what is assumed under `assumed`.
 
 Device scopes (`jax.named_scope`, read by `benchmarks/model_scopes.py`):
 `attn_window`, `attn_full`, `moe_router`, `moe_experts`, `lm_head`.
@@ -109,15 +113,22 @@ def yarn_inv_freq(head_dim: int, theta: float, factor: float,
     return plain / factor * ramp + plain * (1.0 - ramp)
 
 
-def apply_rope(x, inv_freq: np.ndarray, scale: float = 1.0):
+def apply_rope(x, inv_freq: np.ndarray, scale: float = 1.0,
+               interleave: bool = False):
     """Rotate `x` [B, S, H, D] by its position: pair i is (x[i], x[i +
-    D/2]); cos and sin times `scale` (yarn's `attention_factor`). In
+    D/2]), or with `interleave` the adjacent (x[2i], x[2i + 1]), turned in
+    place; cos and sin times `scale` (yarn's `attention_factor`). In
     float32, returned in float32."""
     pos = jnp.arange(x.shape[1], dtype=jnp.float32)
     ang = pos[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
     cos = (jnp.cos(ang) * scale)[None, :, None, :]
     sin = (jnp.sin(ang) * scale)[None, :, None, :]
     x = x.astype(jnp.float32)
+    if interleave:
+        pairs = x.reshape(*x.shape[:-1], -1, 2)
+        a, b = pairs[..., 0], pairs[..., 1]
+        return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                         axis=-1).reshape(x.shape)
     a, b = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
@@ -134,9 +145,10 @@ def allowed(q_pos, k_pos, window: Optional[int]):
 
 def plain_attention(q, k, v, window: Optional[int], block: int = _PLAIN_BLOCK):
     """softmax(q k^T + mask) v in blocks of queries, no kernel. q [B, S,
-    Hkv, G, D] (scaled), k and v [B, S, Hkv, D]. A block of a window layer
-    takes the `block + window` keys its mask can reach, a block of a full
-    layer all S: scores are `[block, keys]`, never `[S, S]`."""
+    Hkv, G, D] (scaled), k [B, S, Hkv, D], v [B, S, Hkv, Dv] (a head size
+    of its own). A block of a window layer takes the `block + window` keys
+    its mask can reach, a block of a full layer all S: scores are `[block,
+    keys]`, never `[S, S]`."""
     b, s, hkv, g, d = q.shape
     block = min(block, s)
     if s % block:
@@ -160,14 +172,17 @@ def plain_attention(q, k, v, window: Optional[int], block: int = _PLAIN_BLOCK):
         return jnp.einsum("bhgqk,bkhd->bqhgd", p, v_i)
 
     out = lax.map(jax.checkpoint(one), jnp.arange(nblk))
-    return jnp.moveaxis(out, 0, 1).reshape(b, s, hkv, g, d)
+    return jnp.moveaxis(out, 0, 1).reshape(b, s, hkv, g, v.shape[-1])
 
 
 def splash_attention(q, k, v, window: Optional[int]):
-    """The same by the Pallas splash-attention kernel: one call a sequence
-    and key/value head, its 8 query heads against the one key/value head
-    (`make_splash_mqa`), forward and backward; the blocks a mask leaves
-    empty are never visited."""
+    """The same by the Pallas splash-attention kernel, forward and backward;
+    the blocks a mask leaves empty are never visited. Where a key/value
+    head serves G > 1 query heads: one call a sequence and key/value head,
+    its G query heads against the one key/value head (`make_splash_mqa`).
+    Where every query head has a key/value head of its own (G = 1): one
+    call a sequence over all heads (`make_splash_mha`). The kernel takes
+    the values' head size from `v`."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel, splash_attention_mask as masks)
     b, s, hkv, g, d = q.shape
@@ -178,6 +193,15 @@ def splash_attention(q, k, v, window: Optional[int]):
         block_q=blk, block_kv=blk, block_kv_compute=blk, block_q_dkv=blk,
         block_kv_dkv=blk, block_kv_dkv_compute=blk, block_q_dq=blk,
         block_kv_dq=blk)
+    if g == 1:
+        call = kernel.make_splash_mha_single_device(
+            masks.MultiHeadMask([mask] * hkv), block_sizes=sizes,
+            residual_checkpoint_name=_SAVED)
+        out = jax.vmap(call)(
+            jnp.transpose(q[:, :, :, 0], (0, 2, 1, 3)),
+            jnp.transpose(k, (0, 2, 1, 3)),
+            jnp.transpose(v, (0, 2, 1, 3)))             # [B, H, S, Dv]
+        return jnp.transpose(out, (0, 2, 1, 3))[:, :, :, None]
     call = kernel.make_splash_mqa_single_device(
         masks.MultiHeadMask([mask] * g), block_sizes=sizes,
         residual_checkpoint_name=_SAVED)
@@ -429,17 +453,27 @@ def _expert_terms_bwd(enough, top, res, g):
 expert_terms.defvjp(_expert_terms_fwd, _expert_terms_bwd)
 
 
-def route(probs, top: int, first: int, held: int):
-    """From the router's probabilities [T, E]: each token's `top` largest,
-    renormalised to sum 1; which rows of the `T * top` assignments go to
-    which of the `held` experts from `first` on.
+def route(probs, top: int, first: int, held: int, choose_by=None,
+          scale: float = 1.0):
+    """From the router's scores [T, E] (softmax probabilities, or a sigmoid
+    of each logit): each token's `top` largest by `choose_by` [T, E] where
+    that is given (the scores plus a bias that only selects) and by the
+    scores themselves where not; their scores renormalised to sum 1, times
+    `scale`; which rows of the `T * top` assignments go to which of the
+    `held` experts from `first` on.
 
     Returns (weights [T, top]; `order` [T * top], the assignments sorted by
     held expert, those of absent experts last; its inverse; `sizes`
     [held], the rows each held expert got; `served` [T], whether any of a
     token's experts is held)."""
-    weights, experts = lax.top_k(probs, top)
+    if choose_by is None:
+        weights, experts = lax.top_k(probs, top)
+    else:
+        _, experts = lax.top_k(choose_by, top)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
     weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scale != 1.0:
+        weights = weights * scale
     local = experts - first
     mine = (local >= 0) & (local < held)
     group = jnp.where(mine, local, held).reshape(-1)
@@ -451,14 +485,40 @@ def route(probs, top: int, first: int, held: int):
     return weights, order, inverse, sizes, jnp.any(mine, axis=-1)
 
 
+class GatedMLP(nn.Module):
+    """`W2 (silu(W1 x) * W3 x)` on the last axis, under the device scope
+    `device_scope`: float32 parameters multiplied as `x.dtype`."""
+    width: int
+    device_scope: str
+
+    @nn.compact
+    def __call__(self, x):
+        wide = (x.shape[-1], self.width)
+        w1 = self.param("w1", _INIT, wide, jnp.float32).astype(x.dtype)
+        w3 = self.param("w3", _INIT, wide, jnp.float32).astype(x.dtype)
+        w2 = self.param("w2", _INIT, wide[::-1], jnp.float32).astype(x.dtype)
+        with jax.named_scope(self.device_scope):
+            return jnp.dot(jax.nn.silu(jnp.dot(x, w1)) * jnp.dot(x, w3), w2)
+
+
 class Experts(nn.Module):
-    """The router over all `num_experts` and the gated experts held here."""
+    """The router over all `num_experts` and the gated experts held here.
+    `scoring` `softmax`: probabilities over all experts; `sigmoid`: each
+    logit's own. With `select_bias` a leaf `router_bias` is added to the
+    scores where the `experts_per_token` are CHOSEN and nowhere else (its
+    gradient is exactly zero: a selection has none). The chosen scores are
+    renormalised and multiplied by `scale`. `shared_width` > 0: a gated
+    expert of that width that every token passes, added by every share."""
     num_experts: int
     experts_per_token: int
     width: int
     share: int
     shares: int
     dtype: Any
+    scoring: str = "softmax"
+    select_bias: bool = False
+    scale: float = 1.0
+    shared_width: int = 0
 
     @nn.compact
     def __call__(self, x):
@@ -469,6 +529,8 @@ class Experts(nn.Module):
             raise ValueError(
                 f"share {self.share} of {self.shares} does not divide "
                 f"{self.num_experts} experts")
+        if self.scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {self.scoring!r}")
         held = self.num_experts // self.shares
         x = x.reshape(tokens, hidden)
         with jax.named_scope("moe_router"):
@@ -477,9 +539,16 @@ class Experts(nn.Module):
                                 jnp.float32)
             logits = jnp.dot(x.astype(jnp.float32), router,
                              precision=lax.Precision.HIGHEST)
+            scores = (jax.nn.softmax(logits, axis=-1)
+                      if self.scoring == "softmax"
+                      else jax.nn.sigmoid(logits))
+            choose_by = None
+            if self.select_bias:
+                choose_by = scores + self.param(
+                    "router_bias", nn.initializers.zeros,
+                    (self.num_experts,), jnp.float32)
             weights, order, inverse, sizes, served = route(
-                jax.nn.softmax(logits, axis=-1), top, self.share * held,
-                held)
+                scores, top, self.share * held, held, choose_by, self.scale)
         shape = (held, hidden, self.width)
         w1 = self.param("w1", _INIT, shape, jnp.float32)
         w3 = self.param("w3", _INIT, shape, jnp.float32)
@@ -494,6 +563,9 @@ class Experts(nn.Module):
             enough = min(full, -(-2 * full // self.shares // 8) * 8)
             y = expert_terms(enough, top, x, weights, order, inverse, sizes,
                              w1, w3, w2)
+        if self.shared_width:
+            y = y + GatedMLP(self.shared_width, "moe_shared",
+                             name="shared")(x).astype(jnp.float32)
         load = sizes.astype(jnp.float32)
         counters = {
             "moe_held_assignments": jnp.sum(load),
@@ -501,6 +573,25 @@ class Experts(nn.Module):
                 jnp.mean(load), 1.0),
             "moe_tokens_unserved": 1.0 - jnp.mean(served.astype(jnp.float32))}
         return y.astype(self.dtype).reshape(b, s, hidden), counters
+
+
+def own_fields(module: nn.Module) -> types.SimpleNamespace:
+    """A model's own fields as a namespace for its layers: a module may not
+    be another's field, so the layers get the numbers."""
+    return types.SimpleNamespace(**{
+        f.name: getattr(module, f.name) for f in dataclasses.fields(module)
+        if f.name not in ("parent", "name")})
+
+
+def model_counters(per_layer):
+    """The model's counters from its expert layers': the assignments held
+    summed, the load of the worst layer, the unserved share's mean."""
+    stacked = jax.tree.map(lambda *v: jnp.stack(v), *per_layer)
+    return {
+        "moe_held_assignments": jnp.sum(stacked["moe_held_assignments"]),
+        "moe_load_max_over_mean": jnp.max(
+            stacked["moe_load_max_over_mean"]),
+        "moe_tokens_unserved": jnp.mean(stacked["moe_tokens_unserved"])}
 
 
 class Layer(nn.Module):
@@ -570,10 +661,7 @@ class Mellum2(nn.Module):
         # a layer is recomputed in its backward pass, but for `_SAVED`
         layer = nn.remat(Layer, policy=jax.checkpoint_policies
                          .save_only_these_names(_SAVED))
-        # a module may not be another's field: the layers get the numbers
-        widths = types.SimpleNamespace(**{
-            f.name: getattr(self, f.name) for f in dataclasses.fields(self)
-            if f.name not in ("parent", "name")})
+        widths = own_fields(self)
         per_layer = []
         for i, kind in enumerate(kinds):
             x, counters = layer(
@@ -589,10 +677,4 @@ class Mellum2(nn.Module):
                              preferred_element_type=jnp.float32)
         if not return_counters:
             return logits
-        stacked = jax.tree.map(lambda *v: jnp.stack(v), *per_layer)
-        return logits, {
-            "moe_held_assignments": jnp.sum(
-                stacked["moe_held_assignments"]),
-            "moe_load_max_over_mean": jnp.max(
-                stacked["moe_load_max_over_mean"]),
-            "moe_tokens_unserved": jnp.mean(stacked["moe_tokens_unserved"])}
+        return logits, model_counters(per_layer)

@@ -571,12 +571,34 @@ def _select_candidates_topk(vals: jax.Array, idxs: jax.Array, k: int,
     return sent_idx, val
 
 
+# the share of Newton's step that the controller takes where the sent
+# magnitudes lie close together (see _controller_update)
+_SPREAD_DAMPING = 0.7
+
+
 def _controller_update(state: jax.Array, count: jax.Array, val: jax.Array,
                        valid: jax.Array, k: int, gain: float) -> jax.Array:
     """Next carried threshold (shared by the flat and batched fused forms).
 
-    Warm (state > 0): multiplicative nudge toward count == k, clipped to
-    [1/4, 4] per step — same controller as gaussian_warm_compress.
+    Warm (state > 0): a multiplicative step toward count == k, clipped to
+    [1/4, 4]: ``(count / k) ** g``. Where the sent magnitudes lie apart,
+    g is ``gain`` — the controller of gaussian_warm_compress. Where they
+    lie close together, a step of that size overshoots: with the sent
+    magnitudes a mean share ``spread`` above the smallest of them, the
+    count falls by about ``1 / spread`` in the logarithm for every unit the
+    threshold rises, so Newton's step to count == k is ``(count / k) **
+    spread``, and ``gain`` is many times that where one large population of
+    like entries sits just under the threshold under error feedback (96 M
+    of `joyai_llm_flash`'s 414 M: 19 k selected, the threshold times 1.7,
+    NOTHING selected, the threshold times 1/4, 120 k selected, and round
+    again; PERF.md section 6, PR 33). So g is the smaller of ``gain`` and
+    ``_SPREAD_DAMPING * spread``, and, in the measure that g falls short
+    of ``gain`` and where all k slots were filled, the step starts from the
+    smallest SENT magnitude — the threshold that this step's candidates
+    would have met exactly — and not from the carried one. ``spread`` is
+    read above that magnitude where the slots were filled and above the
+    carried threshold where not (one outlier alone above the threshold
+    has no spread of its own, and the threshold still has to come down).
     Cold (state <= 0): adopt the smallest SENT magnitude — the k-th
     largest candidate, a free near-ideal threshold estimate (see
     gaussian_fused_compress docstring). An all-invalid selection (dead
@@ -585,9 +607,22 @@ def _controller_update(state: jax.Array, count: jax.Array, val: jax.Array,
     """
     with jax.named_scope("ef_select"):
         ratio = (count.astype(jnp.float32) + 1.0) / float(k + 1)
-        t_warm = state * jnp.clip(ratio ** gain, 0.25, 4.0)
-        mags = jnp.where(valid, jnp.abs(val.astype(jnp.float32)), jnp.inf)
-        kth = jnp.min(mags, axis=-1)
+        mag = jnp.abs(val.astype(jnp.float32))
+        kth = jnp.min(jnp.where(valid, mag, jnp.inf), axis=-1)
+        sent = jnp.sum(valid.astype(jnp.float32), axis=-1)
+        mean = jnp.sum(jnp.where(valid, mag, 0.0), axis=-1) / jnp.maximum(
+            sent, 1.0)
+        warm = jnp.where(state > 0, state, 1.0)
+        # what the sent magnitudes lie above: the k-th of them where all
+        # slots were filled, else the threshold that let them through
+        filled = jnp.all(valid, axis=-1)
+        base = jnp.where(filled, kth, warm)
+        # nothing sent: nothing to read a spread from, the plain step
+        spread = jnp.where(sent > 0, jnp.maximum(mean / base - 1.0, 0.0),
+                           jnp.inf)
+        g = jnp.minimum(gain, _SPREAD_DAMPING * spread)
+        t_warm = (warm * jnp.clip(ratio ** g, 0.25, 4.0)
+                  * (base / warm) ** (1.0 - g / gain))
         bootstrap = jnp.where(jnp.isfinite(kth), kth, jnp.float32(1e-8))
         return jnp.where(state > 0, t_warm, bootstrap).astype(state.dtype)
 

@@ -91,16 +91,27 @@ def make_loss_fn(spec: ModelSpec, label_smoothing: float = 0.0,
             # a model with counters of its own (`ModelSpec.counters`) hands
             # them out beside its logits; they ride the auxiliary output
             # into the `train` record
-            counters = {}
+            counters, extra = {}, {}
             if spec.counters:
-                (logits, counters), mstate = _apply(
-                    spec, params, mstate, rng, x, return_counters=True)
-            else:
-                logits, mstate = _apply(spec, params, mstate, rng, x)
-            loss = optax.softmax_cross_entropy_with_integer_labels(
+                extra["return_counters"] = True
+            if spec.mtp_lambda:
+                extra["next_tokens"] = y
+            out, mstate = _apply(spec, params, mstate, rng, x, **extra)
+            if spec.counters:
+                out, counters = out
+            logits, ahead = out if spec.mtp_lambda else (out, None)
+            loss = ce = optax.softmax_cross_entropy_with_integer_labels(
                 logits, y).mean()
-            # perplexity = exp(loss); report loss, exp on host
-            return loss, (mstate, {"ce_per_token": loss, **counters})
+            if spec.mtp_lambda:
+                # a multi-token-prediction module: at position t it saw
+                # y_t's embedding and predicts y_{t+1}; the last position
+                # has no such target
+                ce_mtp = optax.softmax_cross_entropy_with_integer_labels(
+                    ahead[:, :-1], y[:, 1:]).mean()
+                loss = ce + spec.mtp_lambda * ce_mtp
+                counters = {"ce_mtp_per_token": ce_mtp, **counters}
+            # perplexity = exp(ce); report it, exp on host
+            return loss, (mstate, {"ce_per_token": ce, **counters})
         return loss_fn
 
     if task == "ctc":
