@@ -15,7 +15,8 @@ from typing import Optional, Tuple
 import numpy as np
 
 from .cifar import make_cifar, make_mnist
-from .loader import ArrayDataset, BucketedDataset, EpochStream, prefetch
+from .loader import (ArrayDataset, BucketedDataset, EpochStream,
+                     batch_buffers, prefetch)
 from .ptb import PTBDataset, make_ptb
 from .synthetic import (flip_labels, synthetic_images, synthetic_images_u8,
                         synthetic_seq2seq, synthetic_spectrograms,

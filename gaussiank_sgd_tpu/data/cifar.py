@@ -15,7 +15,7 @@ from typing import Optional, Tuple
 
 import numpy as np
 
-from .loader import ArrayDataset, fill_sliced
+from .loader import ArrayDataset, batch_buffers, fill_sliced
 from .synthetic import flip_labels, synthetic_images
 
 # standard CIFAR-10 channel stats
@@ -54,51 +54,77 @@ def _normalize(x_u8: np.ndarray) -> np.ndarray:
 
 _PAD = 4
 
-
-def _reflect(p: np.ndarray, n: int) -> np.ndarray:
-    """Where position ``p`` of an axis of length ``n`` reads from once the
-    axis is padded as ``np.pad(mode="reflect")`` pads it (mirrored about
-    the edge element, the edge not repeated); ``-n < p < 2n - 1``."""
-    p = np.abs(p)
-    return np.where(p >= n, 2 * (n - 1) - p, p)
+# Rows gathered by one indexed read (256 images' worth): the read makes an
+# array of its own before it is copied into the batch, so it is kept to a
+# few megabytes, and long enough that numpy lets go of the GIL for it and
+# the slices' threads do not queue for the interpreter between reads.
+_ROWS_AT_ONCE = 256 * 32
 
 
-def _take_crops(pixels: np.ndarray, out: np.ndarray, sel: np.ndarray,
-                rows: np.ndarray, cols: np.ndarray, lo: int, hi: int):
-    """Fill ``out[lo:hi]``: image i's pixel (r, c) is pixel
-    (rows[i, r], cols[i, c]) of image ``sel[i]``, read from the data set's
-    ``(N*h*w, c)`` pixel view in one indexed copy."""
-    _, h, w, c = out.shape
-    src = ((sel[lo:hi, None] * h + rows[lo:hi])[:, :, None] * w
-           + cols[lo:hi, None, :])
-    # mode="clip": the indices are in range by construction, and "raise"
-    # would fill a buffer and copy it into ``out`` afterwards
-    np.take(pixels, src.reshape(-1), axis=0,
-            out=out[lo:hi].reshape(-1, c), mode="clip")
+def _padded_both_ways(x: np.ndarray) -> np.ndarray:
+    """``x`` (``[N, h, w, c]``) padded by ``_PAD`` as
+    ``np.pad(mode="reflect")`` pads it, and behind it the same images
+    mirrored left to right: ``[2N, h + 2*_PAD, w + 2*_PAD, c]``, contiguous.
+    In it every row of every crop, flipped or not, is one run of ``w``
+    pixels."""
+    n = len(x)
+    both = np.empty((2 * n, x.shape[1] + 2 * _PAD, x.shape[2] + 2 * _PAD,
+                     x.shape[3]), x.dtype)
+
+    def fill(lo: int, hi: int) -> None:
+        both[lo:hi] = np.pad(
+            x[lo:hi], ((0, 0), (_PAD, _PAD), (_PAD, _PAD), (0, 0)),
+            mode="reflect")
+        both[n + lo:n + hi] = both[lo:hi, :, ::-1]
+    fill_sliced(fill, n)
+    return both
 
 
-def _augment(rng: np.random.Generator):
-    """Pad-4 reflect random crop and horizontal flip as ``ArrayDataset``'s
-    ``augment``. The three draws are made for the whole batch first; crop,
-    border and flip then are index arithmetic on ``b x 32`` integers, and
-    the pixels are copied once, from the data set's array into the batch,
-    in slices (``loader.fill_sliced``; ``slices`` is for the tests)."""
+def _take_rows(runs: np.ndarray, rows: np.ndarray, src: np.ndarray,
+               lo: int, hi: int) -> None:
+    """Fill ``rows[lo:hi]``: row k of the batch is run ``src[k]``."""
+    for a in range(lo, hi, _ROWS_AT_ONCE):
+        z = min(a + _ROWS_AT_ONCE, hi)
+        rows[a:z] = runs[src[a:z]]
+
+
+def _augment(rng: np.random.Generator, x: np.ndarray):
+    """Pad-4 reflect random crop and horizontal flip over the images ``x``
+    as ``ArrayDataset``'s ``augment`` (whose ``arrays`` are ``x`` and the
+    labels). Border and flip are paid once, here: the images are kept
+    padded and in both orientations (:func:`_padded_both_ways`, 6.25 times
+    the bytes of ``x``), so a batch is the three draws, made for the whole
+    batch first, ``b x 32`` integers of arithmetic (where each row of each
+    crop starts), and one copy of each row, ``w`` pixels in one piece, from
+    that array into a recycled buffer (``loader.batch_buffers``), in slices
+    (``loader.fill_sliced``; ``slices`` is for the tests)."""
+    n, h, w, c = x.shape
+    hp, wp = h + 2 * _PAD, w + 2 * _PAD
+    both = _padded_both_ways(x)
+    # every run of w pixels in ``both``, run p the one that starts at pixel
+    # p, as the elements of a 1-D array: numpy gathers those with one
+    # memcpy a row, where a window of a 2-D view costs it an iterator a row
+    run = np.dtype((np.void, w * c * x.itemsize))
+    runs = np.ndarray((both.size // c - w + 1,), run, buffer=both,
+                      strides=(c * x.itemsize,))
+    down = np.arange(h)
+
     def fn(arrays, sel: np.ndarray, slices: Optional[int] = None):
-        x, y = arrays
         b = len(sel)
-        _, h, w, c = x.shape
         oy = rng.integers(0, 2 * _PAD + 1, size=b)
         ox = rng.integers(0, 2 * _PAD + 1, size=b)
         flip = rng.random(b) < 0.5
-        rows = _reflect(oy[:, None] + np.arange(h) - _PAD, h)
-        across = np.arange(w)
-        cols = _reflect(ox[:, None] - _PAD
-                        + np.where(flip[:, None], w - 1 - across, across), w)
-        pixels = x.reshape(-1, c)       # a view: make_cifar keeps x contiguous
-        out = np.empty((b, h, w, c), x.dtype)
-        fill_sliced(lambda lo, hi: _take_crops(pixels, out, sel, rows, cols,
-                                               lo, hi), b, slices)
-        return out, y[sel]
+        # a flipped crop that starts ox from the left of the image starts
+        # 2*_PAD - ox from the left of its mirror image
+        image = sel + n * flip
+        left = np.where(flip, 2 * _PAD - ox, ox)
+        src = (((image * hp + oy)[:, None] + down) * wp
+               + left[:, None]).reshape(-1)
+        out = batch_buffers.empty((b, h, w, c), x.dtype)
+        rows = out.reshape(-1).view(run)
+        fill_sliced(lambda lo, hi: _take_rows(runs, rows, src,
+                                              lo * h, hi * h), b, slices)
+        return out, arrays[1][sel]
     return fn
 
 
@@ -174,9 +200,10 @@ def make_cifar(dataset: str = "cifar10", data_dir: Optional[str] = None,
         x, y = synthetic_images(synthetic_examples, (32, 32, 3), num_classes,
                                 seed=0 if train else 1)
         y = flip_labels(y, num_classes, label_noise, seed=0 if train else 1)
-    aug = _augment(np.random.default_rng(seed)) if (train and augment) else None
-    ds = ArrayDataset((np.ascontiguousarray(x), y), batch_size,
-                      shuffle=train, seed=seed,
+    x = np.ascontiguousarray(x)
+    aug = (_augment(np.random.default_rng(seed), x) if train and augment
+           else None)
+    ds = ArrayDataset((x, y), batch_size, shuffle=train, seed=seed,
                       augment=aug)
     return ds, num_classes
 

@@ -4,12 +4,22 @@ Reference parity: the torch ``DataLoader`` worker pool the reference leans on
 (SURVEY.md §3.2 "io timer ← host dataloader workers"). One prefetch thread
 per stream pulls whole batches into a bounded queue; the optional C++
 pipeline (native/) slots in behind the same iterator protocol. The host
-work is not small beside the accelerator's step: a batch of 5120 augmented
-CIFAR images is 63 MB of float32, and while one thread cropped it image by
-image the chip sat idle three quarters of the time, the queue empty 99
-times in 100 (PERF.md, PR 24's ledger lines). So a large batch is assembled
-in contiguous slices on a few worker threads (:func:`fill_sliced`); numpy
-releases the GIL inside an indexed copy, so the slices run side by side.
+work is not small beside the accelerator's step, and what it costs is
+memory traffic: a batch of 20 480 augmented CIFAR images is 252 MB of
+float32, which four chips consume in 90 ms. So a large batch is assembled
+in contiguous slices on a few worker threads (:func:`fill_sliced`; numpy
+releases the GIL inside an indexed copy, so the slices run side by side),
+and into a buffer that an earlier batch has left (:class:`BufferPool`): a
+block that large comes from the allocator as fresh pages every time, which
+the kernel has to find and zero while eight threads fault them in, and on
+the chip's host that alone took as long as the copy (PERF.md, PR 35).
+
+What the train loop can read of all this is on its ``data_wait`` span
+(docs/OBSERVABILITY.md): ``ready``, the batches waiting in the queue when
+the loop asked for one (:meth:`Prefetcher.ready`); ``assemble_ms``, what the
+producer thread took for the newest batch it has pulled
+(:attr:`Prefetcher.assemble_s`); ``fresh``, the buffers the pool has had to
+allocate so far (:attr:`BufferPool.fresh`).
 
 ``ArrayDataset`` serves in-memory numpy arrays — both real files (CIFAR/PTB
 fit comfortably in host RAM, as in the reference) and synthetic data.
@@ -17,11 +27,15 @@ fit comfortably in host RAM, as in the reference) and synthetic data.
 
 from __future__ import annotations
 
+import collections
+import math
 import os
 import queue
 import threading
 import time
-from typing import Callable, Iterator, Optional, Sequence, Tuple
+import weakref
+from typing import (Callable, Deque, Dict, Iterator, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -100,6 +114,59 @@ def fill_sliced(fill: Callable[[int, int], None], n: int,
     for e in errors:
         if e is not None:
             raise e
+
+
+# Free buffers of one size that the pool keeps; one that returns to a full
+# list pushes the oldest out to the allocator.
+_KEEP_FREE = 8
+
+
+class BufferPool:
+    """Output arrays for batches, recycled: the memory of a batch that
+    nothing can read any more is the memory of a later one.
+
+    :meth:`empty` hands out an array as ``np.empty`` does. Its storage is
+    bytes that ``np.empty`` gave (untouched: whoever fills them first pays
+    for the pages, on as many threads as it fills them with), and it is a
+    view of ``np.frombuffer`` over a ``memoryview`` of those: behind
+    something that is no array, numpy lets the ``base`` of every further
+    view, slice and reshape collapse to THAT array and no further, so a
+    finalizer on it puts the storage back. So a buffer
+    comes back when the batch, every view of it and whatever else holds one
+    (``jax.device_put`` until its copy has ended; on the CPU backend the
+    device array, which aliases it, for its life) have gone, and not
+    before: no depth of any queue is assumed. The finalizer runs wherever
+    the last reference dies, so it only appends to a deque; nothing here
+    takes a lock that it could find held.
+    """
+
+    def __init__(self) -> None:
+        self._free: Dict[int, Deque[np.ndarray]] = {}
+        self._count = threading.Lock()
+        self.fresh = 0      # buffers allocated so far: flat once they recycle
+
+    def empty(self, shape: Sequence[int], dtype) -> np.ndarray:
+        dtype = np.dtype(dtype)
+        nbytes = math.prod(shape) * dtype.itemsize
+        free = self._free.get(nbytes)
+        if free is None:
+            free = self._free.setdefault(
+                nbytes, collections.deque(maxlen=_KEEP_FREE))
+        try:
+            raw = free.pop()
+        except IndexError:
+            raw = np.empty(nbytes, np.uint8)
+            with self._count:
+                self.fresh += 1
+        flat = np.frombuffer(memoryview(raw), dtype)
+        # nothing is left to recycle for at interpreter exit
+        weakref.finalize(flat, free.append, raw).atexit = False
+        return flat.reshape(shape)
+
+
+# The process's one pool, as the slice workers are the process's: two
+# trainers that take turns (the benchmark's arms) share what either left.
+batch_buffers = BufferPool()
 
 
 class ArrayDataset:
@@ -246,14 +313,20 @@ class EpochStream:
 
 class Prefetcher:
     """The iterator :func:`prefetch` returns: the batches of its source,
-    pulled by a daemon thread, and ``ready()``, how many of them wait in
-    its queue right now (0 to ``depth``) — the train loop's one input
-    counter (telemetry span ``data_wait``, field ``ready``)."""
+    pulled by a daemon thread; ``ready()``, how many of them wait in its
+    queue right now (0 to ``depth``); and ``assemble_s``, the seconds the
+    thread spent in the pull of the newest batch it has pulled, retries and
+    whatever the source itself waited for included (None before the
+    first). The train loop records both on its ``data_wait`` span (fields
+    ``ready`` and ``assemble_ms``): a queue that runs empty says THAT the
+    loop waits for this thread, ``assemble_s`` against the step's time says
+    by how much the source would have to be faster."""
 
-    def __init__(self, batches: Iterator, q: "queue.Queue", depth: int):
-        self._batches = batches
-        self._q = q
+    def __init__(self, it: Iterator, depth: int, *retry):
+        self._q: "queue.Queue" = queue.Queue(maxsize=depth)
         self.depth = depth
+        self.assemble_s: Optional[float] = None
+        self._batches = _prefetched(it, self, *retry)
 
     def __iter__(self) -> "Prefetcher":
         return self
@@ -294,17 +367,16 @@ def prefetch(it: Iterator, depth: int = 2, max_retries: int = 0,
     schema/seq envelope); ``on_event`` runs on the prefetch thread, so
     the sink must be thread-safe (telemetry.EventBus.publish is).
     """
-    q: "queue.Queue" = queue.Queue(maxsize=depth)
-    return Prefetcher(
-        _prefetched(it, q, max_retries, backoff_s, max_backoff_s, on_event),
-        q, depth)
+    return Prefetcher(it, depth, max_retries, backoff_s, max_backoff_s,
+                      on_event)
 
 
-def _prefetched(it: Iterator, q: "queue.Queue", max_retries: int,
+def _prefetched(it: Iterator, out: Prefetcher, max_retries: int,
                 backoff_s: float, max_backoff_s: float,
                 on_event: Optional[Callable[[dict], None]]) -> Iterator:
     """The generator behind :func:`prefetch`: starts the producer thread
     at its first pull and hands on what the thread queued."""
+    q = out._q
     _END = object()
     _ERR = object()
 
@@ -340,11 +412,13 @@ def _prefetched(it: Iterator, q: "queue.Queue", max_retries: int,
         try:
             src = iter(it)
             while True:
+                t0 = time.perf_counter()
                 try:
                     item = pull(src)
                 except StopIteration:
                     q.put(_END)
                     return
+                out.assemble_s = time.perf_counter() - t0
                 q.put(item)
         except BaseException as e:  # noqa: BLE001 — re-raised in consumer
             q.put((_ERR, e))

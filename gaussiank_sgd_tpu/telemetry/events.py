@@ -196,8 +196,11 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
     # reading; a "B" record also carries ``wall_ns``, the time_ns reading
     # taken with its ``t0_ns``, which maps the others to wall time. "X"
     # records are published when the trainer drains them (log steps,
-    # close), so their ``ts`` is not their time. ``ready`` is the one
-    # counter: batches in the prefetch queue when ``data_wait`` opened.
+    # close), so their ``ts`` is not their time. ``data_wait`` carries the
+    # input path's counters as they stood when it opened: ``ready``,
+    # batches in the prefetch queue; ``assemble_ms``, what the producer
+    # thread took for its newest batch; ``fresh``, batch buffers allocated
+    # so far (data/loader.py).
     # ``span_id``/``parent_span`` form the span tree; validate_stream
     # checks its health as WARNINGS only (orphans/unclosed are suspicious,
     # not illegal — a crashed run ends mid-span by design).
@@ -206,6 +209,7 @@ EVENT_SCHEMAS: Dict[str, EventSchema] = {
         optional={"parent_span": STRING, "trace_id": STRING,
                   "cat": STRING, "t0_ns": NUMBER, "dur_ns": NUMBER,
                   "wall_ns": NUMBER, "ready": NUMBER,
+                  "assemble_ms": NUMBER, "fresh": NUMBER,
                   "step": NUMBER, "reason": STRING, "knob": STRING,
                   "path": STRING},
     ),

@@ -808,11 +808,20 @@ class Trainer:
         return (self.profiler is not None
                 and self.profiler.transition_due(done))
 
-    def _input_ready(self) -> Optional[int]:
-        """Batches waiting in the trainer's own prefetch queue (whoever
-        pulls from it); None when it has none running."""
+    def _input_fields(self) -> Dict[str, Any]:
+        """What the ``data_wait`` span records of the input path as the
+        loop asks for a batch: ``ready``, the batches waiting in the
+        trainer's own prefetch queue (whoever pulls from it);
+        ``assemble_ms``, what its producer thread took for the newest
+        batch it pulled; ``fresh``, the batch buffers the data package has
+        allocated so far. The first two only while that queue runs."""
+        fields: Dict[str, Any] = {"fresh": data_lib.batch_buffers.fresh}
         it = getattr(self, "_iter", None)
-        return it.ready() if it is not None else None
+        if it is not None:
+            fields["ready"] = it.ready()
+            if it.assemble_s is not None:
+                fields["assemble_ms"] = round(it.assemble_s * 1e3, 3)
+        return fields
 
     def _dispatch(self, step: int, data_iter) -> _Flight:
         """The first half of the optimizer step from global step ``step``:
@@ -824,7 +833,7 @@ class Trainer:
         # cached iterator, and the rebuilt one must be picked up here
         it = data_iter if data_iter is not None else self._train_iter()
         t_io = time.perf_counter()
-        with (self.trace.span("data_wait", ready=self._input_ready())
+        with (self.trace.span("data_wait", **self._input_fields())
               if self.trace is not None else _NO_SPAN):
             batch = next(it)
         with self._span("h2d"):

@@ -172,17 +172,18 @@ def _assert_batches_equal(got, want):
 
 @pytest.mark.parametrize("slices", [1, 3, 8])
 @pytest.mark.parametrize("name", ["cifar10", "cifar100"])
-@pytest.mark.parametrize("batch", [16, 128, 1031, 5120])
+@pytest.mark.parametrize("batch", [16, 128, 300, 1031, 5120])
 def test_cifar_assembly_equals_the_old_loop_bit_for_bit(
         cifar_arrays, batch, name, slices):
     """Same generator state in, same batch out, whatever the slice count
-    (1031 is prime: no slice count divides it)."""
+    (1031 is prime: no slice count divides it; 300 images are one indexed
+    read of ``_ROWS_AT_ONCE`` rows and a shorter one)."""
     from gaussiank_sgd_tpu.data.cifar import _augment
 
     x, y = cifar_arrays(name)
     sel = np.random.default_rng(batch).permutation(len(x))[:batch]
     new_rng, old_rng = np.random.default_rng(11), np.random.default_rng(11)
-    got = _augment(new_rng)((x, y), sel, slices=slices)
+    got = _augment(new_rng, x)((x, y), sel, slices=slices)
     want = _old_augment(old_rng)(x[sel], y[sel])
     _assert_batches_equal(got, want)
     assert got[0].dtype == np.float32 and got[0].flags.c_contiguous
@@ -259,13 +260,13 @@ def test_slice_failure_reaches_the_consumer_through_prefetch(monkeypatch):
     from gaussiank_sgd_tpu.data import EpochStream, cifar
     from gaussiank_sgd_tpu.data.loader import fill_sliced
 
-    take = cifar._take_crops
+    take = cifar._take_rows
 
-    def failing(pixels, out, sel, rows, cols, lo, hi):
+    def failing(runs, rows, src, lo, hi):
         if lo > 0:
             raise ValueError(f"slice {lo}:{hi} broke")
-        take(pixels, out, sel, rows, cols, lo, hi)
-    monkeypatch.setattr(cifar, "_take_crops", failing)
+        take(runs, rows, src, lo, hi)
+    monkeypatch.setattr(cifar, "_take_rows", failing)
     # three slices whatever this machine's CPUs would allow a batch of 64
     monkeypatch.setattr(cifar, "fill_sliced",
                         lambda fill, n, slices=None: fill_sliced(fill, n, 3))
@@ -315,9 +316,9 @@ def test_two_datasets_on_two_threads_give_what_each_gives_alone():
 
 def test_cifar_files_without_the_native_library_assemble_as_before(tmp_path):
     """Real records come channel-first, and ``_normalize`` keeps that
-    memory order under its NHWC shape: the assembly reads the pixels of the
-    data set's array by linear index, so make_cifar has to lay them out
-    first. The batch is the old path's on the same records."""
+    memory order under its NHWC shape: the assembly reads rows of pixels by
+    their place in memory, so make_cifar has to lay the images out first.
+    The batch is the old path's on the same records."""
     from gaussiank_sgd_tpu.data import cifar
 
     rng = np.random.default_rng(3)
@@ -332,3 +333,126 @@ def test_cifar_files_without_the_native_library_assemble_as_before(tmp_path):
     assert not x.flags.c_contiguous and ds.arrays[0].flags.c_contiguous
     for _, got, exp in zip(range(3), ds, _old_dataset((x, y), 50, seed=4)):
         _assert_batches_equal(got, exp)
+
+
+# ---- recycled batch buffers (data/loader.BufferPool)
+
+@pytest.fixture
+def pool(monkeypatch):
+    """A pool of this test's own in place of the process's."""
+    from gaussiank_sgd_tpu.data import cifar, loader
+
+    own = loader.BufferPool()
+    monkeypatch.setattr(cifar, "batch_buffers", own)
+    return own
+
+
+def _cifar_and_oracle(batch_size, seed, examples):
+    ds, _ = make_dataset("cifar10", batch_size=batch_size, seed=seed,
+                         synthetic_examples=examples)
+    return ds, _old_dataset(ds.arrays, batch_size, seed=seed)
+
+
+@pytest.mark.parametrize("shape,dtype", [((7, 32, 32, 3), np.float32),
+                                         ((5, 3), np.int64), ((4,), "u1")])
+def test_pool_hands_out_what_np_empty_hands_out(shape, dtype):
+    from gaussiank_sgd_tpu.data.loader import BufferPool
+
+    a = BufferPool().empty(shape, dtype)
+    assert a.shape == shape and a.dtype == np.dtype(dtype)
+    assert a.flags.c_contiguous and a.flags.writeable and a.flags.aligned
+    a[...] = 3
+    assert (a == 3).all()
+
+
+def test_pool_recycles_by_size_and_keeps_a_bounded_list():
+    from gaussiank_sgd_tpu.data import loader
+
+    own = loader.BufferPool()
+    a = own.empty((6, 4), np.float32)
+    at = a.ctypes.data
+    small = own.empty((5, 4), np.float32)      # another size: another list
+    assert own.fresh == 2
+    del a
+    assert own.empty((2, 12), np.float32).ctypes.data == at     # 96 bytes
+    assert own.fresh == 2
+    del small
+    # more buffers come back than the list keeps: the oldest go
+    held = [own.empty((3,), np.float64) for _ in range(loader._KEEP_FREE + 3)]
+    assert own.fresh == 2 + len(held)
+    del held
+    again = [own.empty((3,), np.float64) for _ in range(loader._KEEP_FREE + 3)]
+    assert own.fresh == 2 + len(again) + 3
+
+
+def test_a_dropped_batch_is_the_next_batchs_memory(pool):
+    """The consumer holds one batch at a time: two buffers are all the
+    pool ever allocates (the generator makes the next before the last is
+    let go), and they take turns."""
+    ds, old = _cifar_and_oracle(64, seed=3, examples=256)
+    seen = []
+    for _, got, want in zip(range(12), ds, old):
+        _assert_batches_equal(got, want)
+        seen.append(got[0].ctypes.data)
+        del got
+    assert pool.fresh == 2
+    assert len(set(seen)) == 2 and seen[2:] == seen[:-2]
+
+
+@pytest.mark.parametrize("hold", ["batch", "slice", "reshape_of_slice"])
+def test_what_is_held_keeps_its_values_while_the_pool_recycles(pool, hold):
+    """A buffer comes back when the batch AND every view of it are gone:
+    a slice, and a reshape of a slice (whose ``base`` is not the batch),
+    read the same after ten further batches."""
+    ds, old = _cifar_and_oracle(48, seed=6, examples=200)
+    it, old_it = iter(ds), iter(old)
+    x, want = next(it)[0], next(old_it)[0]
+    if hold != "batch":
+        x, want = x[5:29], want[5:29]
+    if hold == "reshape_of_slice":
+        x, want = x.reshape(24, -1), want.reshape(24, -1)
+    for _, got, exp in zip(range(10), it, old_it):
+        _assert_batches_equal(got, exp)
+        del got
+    np.testing.assert_array_equal(x, want)
+    assert pool.fresh == 3          # the held one, and two that take turns
+
+
+def test_a_list_of_an_epoch_is_distinct_batches(pool):
+    ds, old = _cifar_and_oracle(40, seed=8, examples=200)
+    assert ds.steps_per_epoch == 5
+    got = list(ds.epoch(epoch_seed=4))
+    want = list(old.epoch(epoch_seed=4))
+    assert len({g[0].ctypes.data for g in got}) == 5 and pool.fresh == 5
+    for g, w in zip(got, want, strict=True):
+        _assert_batches_equal(g, w)
+
+
+def test_device_put_and_drop_reads_the_oracle_on_the_device(pool):
+    """The train loop's use: each batch is placed and the host array let
+    go at once. A placed array (on the CPU backend it may alias the host's)
+    holds what the oracle holds, now and after twenty further batches."""
+    import jax
+
+    ds, old = _cifar_and_oracle(96, seed=12, examples=400)
+    placed, wanted = [], []
+    for _, got, want in zip(range(20), ds, old):
+        placed.append(jax.device_put(got[0]))
+        wanted.append(want[0])
+        del got
+        np.testing.assert_array_equal(np.asarray(placed[-1]), wanted[-1])
+    for dev, want in zip(placed, wanted, strict=True):
+        np.testing.assert_array_equal(np.asarray(dev), want)
+
+
+def test_prefetch_times_the_pull_of_its_newest_batch():
+    import time
+
+    def slow():
+        for i in range(3):
+            time.sleep(0.02)
+            yield i
+    it = prefetch(slow())
+    assert it.assemble_s is None        # the thread starts at the first pull
+    assert list(it) == [0, 1, 2]
+    assert 0.02 <= it.assemble_s < 2.0

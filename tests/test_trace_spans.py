@@ -139,6 +139,25 @@ def test_one_iteration_per_step_with_its_children_in_order(traced_run):
     assert not {s.span_id for s in leaves} & {s.parent for s in spans}
 
 
+def test_data_wait_carries_the_input_paths_counters(traced_run):
+    """``fresh`` on every ``data_wait``; ``assemble_ms`` once the producer
+    thread has pulled a batch (by the second iteration it has: the loop
+    was handed the first). Both reach the JSONL stream."""
+    rec, events = traced_run
+    waits = sorted((s for s in rec.spans if s.name == "data_wait"),
+                   key=lambda s: s.t0_ns)
+    assert len(waits) == 3
+    for s in waits:
+        assert isinstance(s.fields["fresh"], int) and s.fields["fresh"] >= 0
+    for s in waits[1:]:
+        assert 0 < s.fields["assemble_ms"] < 60_000
+    written = [e for e in events
+               if e.get("event") == "span" and e.get("name") == "data_wait"]
+    assert len(written) == 3
+    assert all("fresh" in e for e in written)
+    assert all("assemble_ms" in e for e in written[1:])
+
+
 @pytest.mark.parametrize("kw,n,ahead", [
     # all steps of a call but its last
     (dict(), 5, [1, 1, 1, 1, 0]),
