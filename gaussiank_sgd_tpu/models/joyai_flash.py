@@ -45,8 +45,13 @@ elsewhere), RMSNorm, the rotary helper and rematerialisation are
 `models/mellum2.py`'s, imported.
 
 Device scopes: `attn_mla` (softmax(q k^T) v and its backward), `mla_proj`
-(the five products, the two inner norms, the rotary), `moe_router`,
-`moe_experts`, `moe_shared`, `dense_mlp`, `lm_head`, `mtp`. Counters as
+(the five products, the two inner norms, the rotary) and inside it `mla_q`,
+`mla_kv`, `mla_out` (the products and norms of each path) and `mla_assemble`
+(q and k put together, `mellum2.apply_rope`'s `rope` inside it),
+`moe_router`, `moe_experts` (with `mellum2.py`'s scopes inside both),
+`moe_shared`, `dense_mlp`, `lm_head`, `mtp`, `embed`, `rms_norm` from
+`mellum2.RMSNorm`, and `layer_scan` around the scanned layers (what lies
+directly under it is the loop's own slicing and stacking). Counters as
 `mellum2`'s: `moe_held_assignments`, `moe_load_max_over_mean`,
 `moe_tokens_unserved`.
 """
@@ -80,31 +85,36 @@ class LatentAttention(nn.Module):
                                    name=name)
 
         with jax.named_scope("mla_proj"):
-            cq = RMSNorm(m.rms_norm_eps, m.dtype, name="q_a_norm")(
-                proj("q_a_proj", m.q_lora_rank)(x))
-            q = proj("q_b_proj", (heads, nope + rot))(cq)
-            kva = proj("kv_a_proj", rank + rot)(x)
-            ckv = RMSNorm(m.rms_norm_eps, m.dtype, name="kv_a_norm")(
-                kva[..., :rank])
-            kv = proj("kv_b_proj", (heads, nope + dv))(ckv)
-            inv_freq = rope_inv_freq(rot, m.rope_theta)
-            q_rot = apply_rope(q[..., nope:], inv_freq,
-                               interleave=m.rope_interleave)
-            k_rot = apply_rope(kva[:, :, None, rank:], inv_freq,
-                               interleave=m.rope_interleave)
-            q = jnp.concatenate([q[..., :nope].astype(jnp.float32), q_rot],
-                                axis=-1) * (nope + rot) ** -0.5
-            q = q.astype(m.dtype).reshape(b, s, heads, 1, nope + rot)
-            k = jnp.concatenate(
-                [kv[..., :nope], jnp.broadcast_to(
-                    k_rot.astype(m.dtype), (b, s, heads, rot))], axis=-1)
-            v = kv[..., nope:]
+            with jax.named_scope("mla_q"):
+                cq = RMSNorm(m.rms_norm_eps, m.dtype, name="q_a_norm")(
+                    proj("q_a_proj", m.q_lora_rank)(x))
+                q = proj("q_b_proj", (heads, nope + rot))(cq)
+            with jax.named_scope("mla_kv"):
+                kva = proj("kv_a_proj", rank + rot)(x)
+                ckv = RMSNorm(m.rms_norm_eps, m.dtype, name="kv_a_norm")(
+                    kva[..., :rank])
+                kv = proj("kv_b_proj", (heads, nope + dv))(ckv)
+            # q and k put together, the rotary turn (`rope`) inside it
+            with jax.named_scope("mla_assemble"):
+                inv_freq = rope_inv_freq(rot, m.rope_theta)
+                q_rot = apply_rope(q[..., nope:], inv_freq,
+                                   interleave=m.rope_interleave)
+                k_rot = apply_rope(kva[:, :, None, rank:], inv_freq,
+                                   interleave=m.rope_interleave)
+                q = jnp.concatenate(
+                    [q[..., :nope].astype(jnp.float32), q_rot],
+                    axis=-1) * (nope + rot) ** -0.5
+                q = q.astype(m.dtype).reshape(b, s, heads, 1, nope + rot)
+                k = jnp.concatenate(
+                    [kv[..., :nope], jnp.broadcast_to(
+                        k_rot.astype(m.dtype), (b, s, heads, rot))], axis=-1)
+                v = kv[..., nope:]
         with jax.named_scope("attn_mla"):
             if use_kernels(m.kernels):
                 out = splash_attention(q, k, v, None)
             else:
                 out = plain_attention(q, k, v, None)
-        with jax.named_scope("mla_proj"):
+        with jax.named_scope("mla_proj"), jax.named_scope("mla_out"):
             return proj("o_proj", hidden, axis=(-2, -1))(
                 out.reshape(b, s, heads, dv))
 
@@ -179,7 +189,8 @@ class JoyAIFlash(nn.Module):
                 return jnp.dot(x, head.astype(self.dtype),
                                preferred_element_type=jnp.float32)
 
-        x = embed(tokens)
+        with jax.named_scope("embed"):
+            x = embed(tokens)
         per_layer = []
         dense = min(self.first_k_dense_replace, self.num_layers)
         for i in range(dense):
@@ -187,11 +198,15 @@ class JoyAIFlash(nn.Module):
         if self.num_layers > dense:
             # the expert layers are equal: ONE body, run as a loop of the
             # program over weights stacked along a leading axis (a layer
-            # compiles once, not once a layer)
-            x, counters = nn.scan(
-                layer, variable_axes={"params": 0},
-                split_rngs={"params": True}, length=self.num_layers - dense)(
-                    widths, False, name="expert_layers")(x)
+            # compiles once, not once a layer). Directly under the scope
+            # `layer_scan` is what the loop itself costs: the slices of the
+            # stacked weights, the writes into the stacked gradient
+            with jax.named_scope("layer_scan"):
+                x, counters = nn.scan(
+                    layer, variable_axes={"params": 0},
+                    split_rngs={"params": True},
+                    length=self.num_layers - dense)(
+                        widths, False, name="expert_layers")(x)
             per_layer += [jax.tree.map(lambda v, i=i: v[i], counters)
                           for i in range(self.num_layers - dense)]
         out = logits_of(RMSNorm(self.rms_norm_eps, self.dtype,

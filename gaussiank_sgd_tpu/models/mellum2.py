@@ -46,8 +46,14 @@ scores with a selection bias and a scale (`Experts`, `GatedMLP`), adjacent-pair 
 every query head (`plain_attention`, `splash_attention`); the benchmark's
 configuration file lists what is assumed under `assumed`.
 
-Device scopes (`jax.named_scope`, read by `benchmarks/model_scopes.py`):
-`attn_window`, `attn_full`, `moe_router`, `moe_experts`, `lm_head`.
+Device scopes (`jax.named_scope`; `benchmarks/model_scopes.py` reads the
+first five, `benchmarks/scope_tree.py` the whole path): `attn_window`,
+`attn_full`, `moe_router`, `moe_experts`, `lm_head`; inside `moe_experts`
+`moe_to_rows`, `moe_to_tokens`, `moe_gate`, `moe_product_glue`; inside
+`moe_router` `moe_route_sort`; `attn_proj` (the four projections, not around
+attention proper) with `rope` inside it; `rms_norm` (every instance);
+`embed`. A new scope goes INSIDE the one a metric reads
+(docs/OBSERVABILITY.md, "Device scopes").
 Counters (returned with `return_counters=True`, logged through the loss
 function's auxiliary output): `moe_held_assignments`,
 `moe_load_max_over_mean`, `moe_tokens_unserved`.
@@ -119,18 +125,20 @@ def apply_rope(x, inv_freq: np.ndarray, scale: float = 1.0,
     D/2]), or with `interleave` the adjacent (x[2i], x[2i + 1]), turned in
     place; cos and sin times `scale` (yarn's `attention_factor`). In
     float32, returned in float32."""
-    pos = jnp.arange(x.shape[1], dtype=jnp.float32)
-    ang = pos[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
-    cos = (jnp.cos(ang) * scale)[None, :, None, :]
-    sin = (jnp.sin(ang) * scale)[None, :, None, :]
-    x = x.astype(jnp.float32)
-    if interleave:
-        pairs = x.reshape(*x.shape[:-1], -1, 2)
-        a, b = pairs[..., 0], pairs[..., 1]
-        return jnp.stack([a * cos - b * sin, b * cos + a * sin],
-                         axis=-1).reshape(x.shape)
-    a, b = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+    with jax.named_scope("rope"):
+        pos = jnp.arange(x.shape[1], dtype=jnp.float32)
+        ang = pos[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
+        cos = (jnp.cos(ang) * scale)[None, :, None, :]
+        sin = (jnp.sin(ang) * scale)[None, :, None, :]
+        x = x.astype(jnp.float32)
+        if interleave:
+            pairs = x.reshape(*x.shape[:-1], -1, 2)
+            a, b = pairs[..., 0], pairs[..., 1]
+            return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                             axis=-1).reshape(x.shape)
+        a, b = jnp.split(x, 2, axis=-1)
+        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                               axis=-1)
 
 
 # --------------------------------------------------------------- attention
@@ -225,9 +233,11 @@ class RMSNorm(nn.Module):
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            jnp.float32)
-        x = x.astype(jnp.float32)
-        x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + self.eps)
-        return (x * scale).astype(self.dtype)
+        with jax.named_scope("rms_norm"):
+            x = x.astype(jnp.float32)
+            x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
+                              + self.eps)
+            return (x * scale).astype(self.dtype)
 
 
 class Attention(nn.Module):
@@ -251,19 +261,22 @@ class Attention(nn.Module):
                                    name=name)(x)
 
         inv_freq = np.asarray(self.inv_freq)
-        q = apply_rope(proj("q_proj", hq), inv_freq, self.rope_scale)
-        k = apply_rope(proj("k_proj", hkv), inv_freq, self.rope_scale)
-        v = proj("v_proj", hkv)
-        q = (q * d ** -0.5).astype(self.dtype).reshape(b, s, hkv, hq // hkv, d)
-        k = k.astype(self.dtype)
+        with jax.named_scope("attn_proj"):
+            q = apply_rope(proj("q_proj", hq), inv_freq, self.rope_scale)
+            k = apply_rope(proj("k_proj", hkv), inv_freq, self.rope_scale)
+            v = proj("v_proj", hkv)
+            q = (q * d ** -0.5).astype(self.dtype).reshape(
+                b, s, hkv, hq // hkv, d)
+            k = k.astype(self.dtype)
         with jax.named_scope("attn_window" if self.window else "attn_full"):
             if use_kernels(self.kernels):
                 out = splash_attention(q, k, v, self.window)
             else:
                 out = plain_attention(q, k, v, self.window)
-        return nn.DenseGeneral(hidden, axis=(-2, -1), use_bias=False,
-                               dtype=self.dtype, kernel_init=_INIT,
-                               name="o_proj")(out.reshape(b, s, hq, d))
+        with jax.named_scope("attn_proj"):
+            return nn.DenseGeneral(hidden, axis=(-2, -1), use_bias=False,
+                                   dtype=self.dtype, kernel_init=_INIT,
+                                   name="o_proj")(out.reshape(b, s, hq, d))
 
 
 # ----------------------------------------------------------------- experts
@@ -272,27 +285,35 @@ class Attention(nn.Module):
 def to_rows(x, first, inverse, live, top: int):
     """The token's row for each of the sorted assignments `first` [rows]
     (assignment a is token `a // top`): `x[first // top]`. Its cotangent
-    comes back by `to_tokens`: gathered, not scattered."""
-    return x[first // top]
+    comes back by `_summed`, `to_tokens`' sum: gathered, not scattered
+    (and under this function's scope, `moe_to_rows`)."""
+    with jax.named_scope("moe_to_rows"):
+        return x[first // top]
 
 
 def _to_rows_fwd(x, first, inverse, live, top):
-    return x[first // top], (first, inverse, live)
+    return to_rows(x, first, inverse, live, top), (first, inverse, live)
 
 
 def _to_rows_bwd(top, res, g):
     first, inverse, live = res
-    ones = jnp.ones(inverse.shape, g.dtype)
-    return (to_tokens(g, ones, first, inverse, live, top).astype(g.dtype),
-            None, None, None)
+    with jax.named_scope("moe_to_rows"):
+        ones = jnp.ones(inverse.shape, g.dtype)
+        return (_summed(g, ones, inverse, live, top).astype(g.dtype),
+                None, None, None)
 
 
 to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
 
 
-def _picked(r, inverse, live):
-    return jnp.where(live[:, None], r[jnp.minimum(inverse, r.shape[0] - 1)],
-                     jnp.zeros((), r.dtype))
+def _summed(r, scale, inverse, live, top: int):
+    """`to_tokens`' sum, under the scope of whoever calls it."""
+    picked = jnp.where(live[:, None],
+                       r[jnp.minimum(inverse, r.shape[0] - 1)],
+                       jnp.zeros((), r.dtype))
+    return jnp.einsum("tkh,tk->th", picked.reshape(-1, top, r.shape[-1]),
+                      scale.reshape(-1, top),
+                      preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -301,9 +322,8 @@ def to_tokens(r, scale, first, inverse, live, top: int):
     times the row `r[inverse[a]]` that assignment a was sorted to, over the
     `live` assignments (those of held experts, sorted before `r`'s end),
     in float32."""
-    rows = _picked(r, inverse, live).reshape(-1, top, r.shape[-1])
-    return jnp.einsum("tkh,tk->th", rows, scale.reshape(-1, top),
-                      preferred_element_type=jnp.float32)
+    with jax.named_scope("moe_to_tokens"):
+        return _summed(r, scale, inverse, live, top)
 
 
 def _to_tokens_fwd(r, scale, first, inverse, live, top):
@@ -313,14 +333,16 @@ def _to_tokens_fwd(r, scale, first, inverse, live, top):
 
 def _to_tokens_bwd(top, res, g):
     r, scale, first, inverse, live = res
-    # in the sorted rows' order: every live row has one assignment
-    sorted_live = (jnp.arange(first.shape[0]) < jnp.sum(live))[:, None]
-    g_rows = jnp.where(sorted_live, g[first // top], 0.0)
-    d_r = (g_rows * scale[first][:, None].astype(g.dtype)).astype(r.dtype)
-    d_sorted = jnp.sum(g_rows * r.astype(g.dtype), axis=-1)
-    d_scale = jnp.where(
-        live, d_sorted[jnp.minimum(inverse, first.shape[0] - 1)], 0.0)
-    return d_r, d_scale.astype(scale.dtype), None, None, None
+    with jax.named_scope("moe_to_tokens"):
+        # in the sorted rows' order: every live row has one assignment
+        sorted_live = (jnp.arange(first.shape[0]) < jnp.sum(live))[:, None]
+        g_rows = jnp.where(sorted_live, g[first // top], 0.0)
+        d_r = (g_rows * scale[first][:, None].astype(g.dtype)
+               ).astype(r.dtype)
+        d_sorted = jnp.sum(g_rows * r.astype(g.dtype), axis=-1)
+        d_scale = jnp.where(
+            live, d_sorted[jnp.minimum(inverse, first.shape[0] - 1)], 0.0)
+        return d_r, d_scale.astype(scale.dtype), None, None, None
 
 
 to_tokens.defvjp(_to_tokens_fwd, _to_tokens_bwd)
@@ -347,8 +369,11 @@ def grouped_product(x, w, sizes):
     the last group's, forward and backward: what the product leaves there
     must not reach a sum, and 0 times it is no 0. The weights' cotangent
     leaves the product in float32 (a bfloat16 one would round every
-    gradient of an expert to 8 bits before it is accumulated)."""
-    return _live_rows(lax.ragged_dot(x, w.astype(x.dtype), sizes), sizes)
+    gradient of an expert to 8 bits before it is accumulated). Under the
+    scope `moe_product_glue` is what is NOT the TPU compiler's kernel, which
+    carries no name: the casts, the transposition, the zeroing."""
+    with jax.named_scope("moe_product_glue"):
+        return _live_rows(lax.ragged_dot(x, w.astype(x.dtype), sizes), sizes)
 
 
 def _grouped_fwd(x, w, sizes):
@@ -357,11 +382,12 @@ def _grouped_fwd(x, w, sizes):
 
 def _grouped_bwd(res, g):
     x, w, sizes = res
-    g = _live_rows(g, sizes)
-    dx = lax.ragged_dot(g, jnp.swapaxes(w.astype(x.dtype), 1, 2), sizes)
-    dw = lax.ragged_dot_general(x, g, sizes, _BY_GROUP,
-                                preferred_element_type=jnp.float32)
-    return _live_rows(dx, sizes), dw.astype(w.dtype), None
+    with jax.named_scope("moe_product_glue"):
+        g = _live_rows(g, sizes)
+        dx = lax.ragged_dot(g, jnp.swapaxes(w.astype(x.dtype), 1, 2), sizes)
+        dw = lax.ragged_dot_general(x, g, sizes, _BY_GROUP,
+                                    preferred_element_type=jnp.float32)
+        return _live_rows(dx, sizes), dw.astype(w.dtype), None
 
 
 grouped_product.defvjp(_grouped_fwd, _grouped_bwd)
@@ -374,8 +400,11 @@ def _terms(cap: int, top: int, x, weights, order, inverse, sizes, w1, w3,
     first = order[:cap]
     live = inverse < jnp.sum(sizes)
     rows = to_rows(x, first, inverse, live, top)
-    gate = jax.nn.silu(grouped_product(rows, w1, sizes))
-    out = grouped_product(gate * grouped_product(rows, w3, sizes), w2, sizes)
+    gate = grouped_product(rows, w1, sizes)
+    up = grouped_product(rows, w3, sizes)
+    with jax.named_scope("moe_gate"):
+        gated = jax.nn.silu(gate) * up
+    out = grouped_product(gated, w2, sizes)
     return to_tokens(out, weights.reshape(-1), first, inverse, live, top)
 
 
@@ -477,11 +506,12 @@ def route(probs, top: int, first: int, held: int, choose_by=None,
     local = experts - first
     mine = (local >= 0) & (local < held)
     group = jnp.where(mine, local, held).reshape(-1)
-    order = jnp.argsort(group, stable=True)
-    inverse = jnp.zeros_like(order).at[order].set(
-        jnp.arange(order.shape[0], dtype=order.dtype))
-    sizes = jnp.sum(group[:, None] == jnp.arange(held)[None, :], axis=0,
-                    dtype=jnp.int32)
+    with jax.named_scope("moe_route_sort"):
+        order = jnp.argsort(group, stable=True)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        sizes = jnp.sum(group[:, None] == jnp.arange(held)[None, :], axis=0,
+                        dtype=jnp.int32)
     return weights, order, inverse, sizes, jnp.any(mine, axis=-1)
 
 
@@ -655,9 +685,10 @@ class Mellum2(nn.Module):
         # unit embeddings: at the products' 0.02 a layer's output swamps
         # them at random weights, every token's router input then shares
         # one direction and the experts' load collapses onto a few
-        x = nn.Embed(self.vocab_size, self.hidden_size, dtype=self.dtype,
-                     embedding_init=nn.initializers.normal(1.0),
-                     name="embed")(tokens)
+        with jax.named_scope("embed"):
+            x = nn.Embed(self.vocab_size, self.hidden_size, dtype=self.dtype,
+                         embedding_init=nn.initializers.normal(1.0),
+                         name="embed")(tokens)
         # a layer is recomputed in its backward pass, but for `_SAVED`
         layer = nn.remat(Layer, policy=jax.checkpoint_policies
                          .save_only_these_names(_SAVED))

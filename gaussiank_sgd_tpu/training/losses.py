@@ -100,14 +100,18 @@ def make_loss_fn(spec: ModelSpec, label_smoothing: float = 0.0,
             if spec.counters:
                 out, counters = out
             logits, ahead = out if spec.mtp_lambda else (out, None)
-            loss = ce = optax.softmax_cross_entropy_with_integer_labels(
-                logits, y).mean()
+            # device scope `loss`: the cross-entropy over the float32
+            # logits and its backward pass (docs/OBSERVABILITY.md)
+            with jax.named_scope("loss"):
+                loss = ce = optax.softmax_cross_entropy_with_integer_labels(
+                    logits, y).mean()
             if spec.mtp_lambda:
                 # a multi-token-prediction module: at position t it saw
                 # y_t's embedding and predicts y_{t+1}; the last position
                 # has no such target
-                ce_mtp = optax.softmax_cross_entropy_with_integer_labels(
-                    ahead[:, :-1], y[:, 1:]).mean()
+                with jax.named_scope("loss"):
+                    ce_mtp = optax.softmax_cross_entropy_with_integer_labels(
+                        ahead[:, :-1], y[:, 1:]).mean()
                 loss = ce + spec.mtp_lambda * ce_mtp
                 counters = {"ce_mtp_per_token": ce_mtp, **counters}
             # perplexity = exp(ce); report it, exp on host

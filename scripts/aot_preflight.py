@@ -9,8 +9,11 @@ count, the collectives left in the optimized HLO, XLA's FLOP count, its
 memory analysis, and what the program does AFTER the gradient as a count of
 work: the bytes that the optimized HLO's operations read and write under each
 of the step's own scopes, in passes over one n-vector (``passes_by_scope``;
-ROADMAP S10's list is sized from this). The optimizer is the cells' (momentum
-0.9 and a weight decay), so the program compiled is the one a cell runs.
+ROADMAP S10's list is sized from this), and for a model that names its parts
+which of its scopes the compiled program carries on forward, recomputed and
+backward instructions (``instructions_by_scope_and_pass``). The optimizer is
+the cells' (momentum 0.9 and a weight decay), so the program compiled is the
+one a cell runs.
 
 It proves COMPILATION ONLY. It runs nothing: not start-up, not placement, not
 numerics, not time. Those are chip_smoke.py's, on the chip.
@@ -46,6 +49,7 @@ import numpy as np
 from jax.experimental import topologies
 from jax.sharding import Mesh
 
+from benchmarks import scope_tree
 from benchmarks.span_reduce import scope_of
 from gaussiank_sgd_tpu.compressors import get_compressor
 from gaussiank_sgd_tpu.models import get_model
@@ -256,6 +260,34 @@ def print_passes(hlo: str, n: int) -> None:
         print(f"      {label:<31}{r:6.2f} + {w:5.2f} = {r + w:6.2f}")
 
 
+def instructions_by_scope_and_pass(hlo: str) -> dict:
+    """{a model's scope: [forward, recomputed, backward]}: the optimized
+    HLO's instructions whose ``op_name`` ends in that scope (its innermost
+    known name, ``benchmarks/scope_tree.parse``), by the pass the path
+    says; ``""`` stands for ``fwd_bwd`` and no name of a model's. A count of
+    instructions: what a trace of this program CAN name, never a time."""
+    out: dict = {}
+    for name in _OP_NAME.findall(hlo):
+        chain, which, _ = scope_tree.parse(name)
+        if chain[:1] == ("fwd_bwd",):
+            row = out.setdefault(chain[-1] if len(chain) > 1 else "",
+                                 [0, 0, 0])
+            row[scope_tree.PASSES.index(which)] += 1
+    return out
+
+
+def print_model_scopes(hlo: str) -> None:
+    counts = instructions_by_scope_and_pass(hlo)
+    if set(counts) <= {""}:
+        return
+    print("    instructions under fwd_bwd by the model's innermost scope: "
+          "forward / recomputed / backward")
+    for scope in sorted(counts, key=lambda k: (k == "", k)):
+        f, r, b = counts[scope]
+        print(f"      {scope or 'no name of the model':<31}{f:6d} /{r:6d} /"
+              f"{b:6d}")
+
+
 def _batch_shapes(spec, batch_size: int):
     """The (x, y) shapes and dtypes of one batch of the model task."""
     def ints(*shape):
@@ -332,6 +364,7 @@ def main(argv=None) -> None:
                 print("   ", line.strip()[:200])
         print("   ", compiled.memory_analysis())
         print_passes(hlo, plan.total_numel)
+        print_model_scopes(hlo)
 
 
 if __name__ == "__main__":
