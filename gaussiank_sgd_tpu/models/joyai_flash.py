@@ -47,7 +47,11 @@ elsewhere), RMSNorm, the rotary helper and rematerialisation are
 Device scopes: `attn_mla` (softmax(q k^T) v and its backward), `mla_proj`
 (the five products, the two inner norms, the rotary) and inside it `mla_q`,
 `mla_kv`, `mla_out` (the products and norms of each path) and `mla_assemble`
-(q and k put together, `mellum2.apply_rope`'s `rope` inside it),
+(q and k put together, `mellum2.apply_rope`'s `rope` inside it: one fused
+pass over the whole 192-wide q, whose first 128 lanes pass through, with the
+scale and the one rounding, and one over the shared key part, against one
+set of angles that `mellum2.rope_table` lays out once on the host, at q's
+192 lanes and at the key part's 64),
 `moe_router`, `moe_experts` (with `mellum2.py`'s scopes inside both),
 `moe_shared`, `dense_mlp`, `lm_head`, `mtp`, `embed`, `rms_norm` from
 `mellum2.RMSNorm`, and `layer_scan` around the scanned layers (what lies
@@ -94,20 +98,20 @@ class LatentAttention(nn.Module):
                 ckv = RMSNorm(m.rms_norm_eps, m.dtype, name="kv_a_norm")(
                     kva[..., :rank])
                 kv = proj("kv_b_proj", (heads, nope + dv))(ckv)
-            # q and k put together, the rotary turn (`rope`) inside it
+            # q and k put together, the rotary turn (`rope`) inside it:
+            # q whole in one pass (its first `nope` lanes pass through)
             with jax.named_scope("mla_assemble"):
                 inv_freq = rope_inv_freq(rot, m.rope_theta)
-                q_rot = apply_rope(q[..., nope:], inv_freq,
-                                   interleave=m.rope_interleave)
+                q = apply_rope(q, inv_freq, interleave=m.rope_interleave,
+                               out_scale=(nope + rot) ** -0.5,
+                               dtype=m.dtype).reshape(
+                                   b, s, heads, 1, nope + rot)
                 k_rot = apply_rope(kva[:, :, None, rank:], inv_freq,
-                                   interleave=m.rope_interleave)
-                q = jnp.concatenate(
-                    [q[..., :nope].astype(jnp.float32), q_rot],
-                    axis=-1) * (nope + rot) ** -0.5
-                q = q.astype(m.dtype).reshape(b, s, heads, 1, nope + rot)
+                                   interleave=m.rope_interleave,
+                                   dtype=m.dtype)
                 k = jnp.concatenate(
-                    [kv[..., :nope], jnp.broadcast_to(
-                        k_rot.astype(m.dtype), (b, s, heads, rot))], axis=-1)
+                    [kv[..., :nope],
+                     jnp.broadcast_to(k_rot, (b, s, heads, rot))], axis=-1)
                 v = kv[..., nope:]
         with jax.named_scope("attn_mla"):
             if use_kernels(m.kernels):

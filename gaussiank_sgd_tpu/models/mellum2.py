@@ -52,8 +52,12 @@ first five, `benchmarks/scope_tree.py` the whole path): `attn_window`,
 `moe_to_rows`, `moe_to_tokens`, `moe_gate`, `moe_product_glue`; inside
 `moe_router` `moe_route_sort`; `attn_proj` (the four projections, not around
 attention proper) with `rope` inside it; `rms_norm` (every instance);
-`embed`. A new scope goes INSIDE the one a metric reads
-(docs/OBSERVABILITY.md, "Device scopes").
+`embed`. `rope` holds the rotary turn whole: one fused pass over q and one
+over k (the pairs' exchange by a 0/1 product, the turn in float32, the
+attention's scale, the one rounding) against cos and sin tables that
+`rope_table` makes once on the host, two for this model (default and yarn).
+A new scope goes INSIDE the one a metric reads (docs/OBSERVABILITY.md,
+"Device scopes").
 Counters (returned with `return_counters=True`, logged through the loss
 function's auxiliary output): `moe_held_assignments`,
 `moe_load_max_over_mean`, `moe_tokens_unserved`.
@@ -65,7 +69,7 @@ import dataclasses
 import functools
 import math
 import types
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -119,26 +123,110 @@ def yarn_inv_freq(head_dim: int, theta: float, factor: float,
     return plain / factor * ramp + plain * (1.0 - ramp)
 
 
-def apply_rope(x, inv_freq: np.ndarray, scale: float = 1.0,
-               interleave: bool = False):
-    """Rotate `x` [B, S, H, D] by its position: pair i is (x[i], x[i +
-    D/2]), or with `interleave` the adjacent (x[2i], x[2i + 1]), turned in
-    place; cos and sin times `scale` (yarn's `attention_factor`). In
-    float32, returned in float32."""
+@functools.lru_cache(maxsize=None)
+def rope_table(positions: int, inv_freq: Tuple[float, ...], scale: float,
+               interleave: bool, width: int):
+    """cos and signed sin times `scale`, float32 [positions, width], laid
+    out at the width of the axis they turn: made ONCE for each (positions,
+    frequencies, scale, layout) a process meets, on the host, and constants
+    of every program that uses them (never computed on the device, so never
+    inside a fusion that visits every head). The angle is float32 position
+    times float32 frequency. Half-split `[cos | cos]` and `[-sin | sin]`;
+    adjacent pairs each value twice, the sine's sign alternating; 1 and 0
+    in the lanes before the turned part."""
+    ang = (np.arange(positions, dtype=np.float32)[:, None]
+           * np.asarray(inv_freq, np.float32)[None, :]).astype(np.float64)
+    cos, sin = np.cos(ang) * scale, np.sin(ang) * scale
+    if interleave:
+        cos = np.repeat(cos, 2, axis=-1)
+        sin = np.stack([-sin, sin], axis=-1).reshape(positions, -1)
+    else:
+        cos, sin = np.tile(cos, 2), np.concatenate([-sin, sin], axis=-1)
+    still = ((0, 0), (width - cos.shape[-1], 0))
+    return (np.pad(cos, still, constant_values=1.0).astype(np.float32),
+            np.pad(sin, still).astype(np.float32))
+
+
+def _partner_matrix(width: int, rot: int, interleave: bool) -> np.ndarray:
+    """0/1 [width, width]: `x @ m` holds at every lane of the turned part
+    (the last `rot`) the other entry of that lane's pair, and 0 before it."""
+    place = np.arange(rot)
+    other = place ^ 1 if interleave else (place + rot // 2) % rot
+    m = np.zeros((width, width), np.float32)
+    m[width - rot + other, width - rot + place] = 1.0
+    return m
+
+
+class _Turn(NamedTuple):
+    """How `_turned` turns: the width of the turned part, the pairs'
+    layout, whether it turns BACK (the sine's sign: the cotangent's turn),
+    the factor and dtype of what it hands out, the dtype of its cotangent."""
+    rot: int
+    interleave: bool
+    back: bool
+    out_scale: float
+    dtype: Any
+    cotangent_dtype: Any
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _turned(how: _Turn, x, cos, sin):
+    """`(x * cos + partner(x) * sin) * out_scale` in float32, rounded once
+    to `how.dtype`; the lanes before the turned part pass through (a
+    `where`, no product with 0 and 1). `partner` is a product with a 0/1
+    matrix: every output is ONE input times 1, exact in any dtype, and the
+    compiler runs it on the matrix unit inside the fusion that reads `x`
+    and writes the result, in whatever layout the consumer wants: one pass
+    at full lane width, no split, stack or concatenation. The cotangent is
+    the same function with the sine's sign turned, on the cotangent as it
+    arrives (so the product sees the compute dtype there too)."""
+    width = x.shape[-1]
+    # bfloat16 times 1 is exact in one pass; anything wider needs them all
+    exact = None if x.dtype == jnp.bfloat16 else lax.Precision.HIGHEST
+    partner = jnp.einsum(
+        "...i,ij->...j", x,
+        jnp.asarray(_partner_matrix(width, how.rot, how.interleave), x.dtype),
+        precision=exact, preferred_element_type=jnp.float32)
+    x = x.astype(jnp.float32)
+    straight = x * cos[None, :, None, :]
+    across = partner * sin[None, :, None, :]
+    y = straight - across if how.back else straight + across
+    if width > how.rot:
+        lane = lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+        y = jnp.where(lane >= width - how.rot, y, x)
+    return (y * how.out_scale).astype(how.dtype)
+
+
+def _turned_fwd(how, x, cos, sin):
+    return _turned(how, x, cos, sin), (cos, sin)
+
+
+def _turned_bwd(how, tables, g):
+    back = how._replace(back=not how.back, dtype=how.cotangent_dtype,
+                        cotangent_dtype=how.dtype)
+    return _turned(back, g, *tables), None, None
+
+
+_turned.defvjp(_turned_fwd, _turned_bwd)
+
+
+def apply_rope(x, inv_freq, scale: float = 1.0, interleave: bool = False,
+               out_scale: float = 1.0, dtype: Any = jnp.float32):
+    """Rotate the last `2 * len(inv_freq)` entries of `x` [B, S, H, W] by
+    their position (`inv_freq`: the pairs' frequencies, any sequence); what
+    lies before them passes through. Pair i is (x[i],
+    x[i + D/2]), or with `interleave` the adjacent (x[2i], x[2i + 1]),
+    turned in place; cos and sin times `scale` (yarn's `attention_factor`).
+    In float32; the result times `out_scale` (the attention's 1 / sqrt(d)),
+    rounded once to `dtype`. One pass over `x` against `rope_table`'s
+    constants (`_turned`)."""
     with jax.named_scope("rope"):
-        pos = jnp.arange(x.shape[1], dtype=jnp.float32)
-        ang = pos[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
-        cos = (jnp.cos(ang) * scale)[None, :, None, :]
-        sin = (jnp.sin(ang) * scale)[None, :, None, :]
-        x = x.astype(jnp.float32)
-        if interleave:
-            pairs = x.reshape(*x.shape[:-1], -1, 2)
-            a, b = pairs[..., 0], pairs[..., 1]
-            return jnp.stack([a * cos - b * sin, b * cos + a * sin],
-                             axis=-1).reshape(x.shape)
-        a, b = jnp.split(x, 2, axis=-1)
-        return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                               axis=-1)
+        cos, sin = rope_table(
+            x.shape[1], tuple(np.asarray(inv_freq, np.float64).tolist()),
+            float(scale), bool(interleave), x.shape[-1])
+        how = _Turn(2 * len(inv_freq), bool(interleave), False,
+                    float(out_scale), jnp.dtype(dtype), x.dtype)
+        return _turned(how, x, cos, sin)
 
 
 # --------------------------------------------------------------- attention
@@ -260,14 +348,13 @@ class Attention(nn.Module):
                                    dtype=self.dtype, kernel_init=_INIT,
                                    name=name)(x)
 
-        inv_freq = np.asarray(self.inv_freq)
         with jax.named_scope("attn_proj"):
-            q = apply_rope(proj("q_proj", hq), inv_freq, self.rope_scale)
-            k = apply_rope(proj("k_proj", hkv), inv_freq, self.rope_scale)
+            q = apply_rope(proj("q_proj", hq), self.inv_freq,
+                           self.rope_scale, out_scale=d ** -0.5,
+                           dtype=self.dtype).reshape(b, s, hkv, hq // hkv, d)
+            k = apply_rope(proj("k_proj", hkv), self.inv_freq,
+                           self.rope_scale, dtype=self.dtype)
             v = proj("v_proj", hkv)
-            q = (q * d ** -0.5).astype(self.dtype).reshape(
-                b, s, hkv, hq // hkv, d)
-            k = k.astype(self.dtype)
         with jax.named_scope("attn_window" if self.window else "attn_full"):
             if use_kernels(self.kernels):
                 out = splash_attention(q, k, v, self.window)

@@ -299,6 +299,42 @@ def test_adjacent_pair_rotary_against_the_direct_formula():
                                (halves[0] * halves[1]).sum(-1), atol=1e-5)
 
 
+@pytest.mark.parametrize("shape", [(2, 8192, 32, 192), (2, 8192, 1, 64)])
+def test_the_cells_adjacent_pair_turn_is_one_pass_at_full_width(shape):
+    """`joyai_mla_dp1`'s q, whole (its last 64 turned), and the one shared
+    key part: no cosine per head, and nothing of the input's size is
+    concatenated, split or reshaped to a minor axis of 2."""
+    from test_mellum2 import assert_one_pass_turn
+    assert_one_pass_turn(shape, 64, True)
+
+
+def test_a_whole_head_turned_is_its_rotary_part_turned():
+    """What `LatentAttention` asks for, the turn over a head of 16 + 8 with
+    the scale and the one rounding inside, is what it used to put together:
+    the first 16 in float32 beside the last 8 turned, times the scale,
+    rounded; and the cotangent likewise."""
+    rng = np.random.default_rng(3)
+    inv = mellum2.rope_inv_freq(8, 32e6)
+    x = jnp.asarray(rng.normal(size=(2, POSITIONS, 4, 24)), jnp.bfloat16)
+    g = jnp.asarray(rng.normal(size=x.shape), jnp.bfloat16)
+
+    def whole(x):
+        return mellum2.apply_rope(x, inv, interleave=True,
+                                  out_scale=24 ** -0.5, dtype=jnp.bfloat16)
+
+    def parts(x):
+        turned = mellum2.apply_rope(x[..., 16:], inv, interleave=True)
+        return (jnp.concatenate([x[..., :16].astype(jnp.float32), turned],
+                                axis=-1) * 24 ** -0.5).astype(jnp.bfloat16)
+
+    (got, back), (want, want_back) = jax.vjp(whole, x), jax.vjp(parts, x)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    np.testing.assert_allclose(
+        np.asarray(back(g)[0], np.float32),
+        np.asarray(want_back(g)[0], np.float32), atol=2.0 ** -8)
+
+
 def test_latent_attention_against_the_direct_formula():
     """One `[S, S]` softmax a head at S = 64, from the layer's own weights:
     rank bottlenecks with their norms, one rotary key for all heads, query

@@ -234,6 +234,103 @@ def test_yarn_rotary_against_the_direct_formula():
         np.full(d, scale), rtol=1e-6)
 
 
+def _turn_by_pairs(x, inv, scale, interleave, rot):
+    """The rotary turn by its definition, pair by pair, on the last `rot`
+    entries of the last axis; float32."""
+    x = x.astype(jnp.float32)
+    off = x.shape[-1] - rot
+    ang = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * jnp.asarray(
+        inv, jnp.float32)[None, :]
+    cos = (jnp.cos(ang) * scale)[None, :, None, :]
+    sin = (jnp.sin(ang) * scale)[None, :, None, :]
+    i = np.arange(rot // 2)
+    first, second = ((off + 2 * i, off + 2 * i + 1) if interleave
+                     else (off + i, off + i + rot // 2))
+    a, b = x[..., first], x[..., second]
+    return x.at[..., first].set(a * cos - b * sin).at[..., second].set(
+        b * cos + a * sin)
+
+
+@pytest.mark.parametrize("scale", [1.0, 1.2772588722239782])
+@pytest.mark.parametrize("width,rot", [(128, 128), (64, 64), (192, 64)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("interleave", [False, True])
+def test_the_turn_and_its_cotangent_against_the_pairs_formula(
+        interleave, dtype, width, rot, scale):
+    """Both layouts, at a head size of a lane row, of half of one, and on
+    the last 64 of a 192-wide head (the rest passes through): the result in
+    float32 and the cotangent against the per-pair formula's."""
+    rng = np.random.default_rng(width + rot)
+    inv = mellum2.rope_inv_freq(rot, 500000.0)
+    x = jnp.asarray(rng.normal(size=(2, 12, 3, width)), dtype)
+    g = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
+    got, back = jax.vjp(
+        lambda x: mellum2.apply_rope(x, inv, scale, interleave), x)
+    want, want_back = jax.vjp(
+        lambda x: _turn_by_pairs(x, inv, scale, interleave, rot), x)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    (dx,), (want_dx,) = back(g), want_back(g)
+    assert dx.dtype == dtype
+    # a bfloat16 cotangent is the float32 one rounded once
+    np.testing.assert_allclose(
+        np.asarray(dx, np.float32), np.asarray(want_dx, np.float32),
+        atol=1e-5 if dtype == jnp.float32 else 2.0 ** -6)
+    # the factor and the one rounding that the attention layers ask for
+    scaled = mellum2.apply_rope(x, inv, scale, interleave, out_scale=0.25,
+                                dtype=dtype)
+    np.testing.assert_array_equal(
+        np.asarray(scaled, np.float32),
+        np.asarray((got * 0.25).astype(dtype), np.float32))
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for inner in (value if isinstance(value, (tuple, list))
+                          else (value,)):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def assert_one_pass_turn(shape, rot, interleave, dtype=jnp.bfloat16):
+    """On the jaxpr of `apply_rope` and of its cotangent at `shape` (abstract
+    values: nothing is compiled): no cos or sin over more than the S x D/2
+    angles, and on nothing of the input's size a concatenation, a split or
+    a reshape to a minor axis of 2."""
+    inv = mellum2.rope_inv_freq(rot, 500000.0)
+    x = jax.ShapeDtypeStruct(shape, dtype)
+
+    def both(x, g):
+        y, back = jax.vjp(lambda x: mellum2.apply_rope(
+            x, inv, 1.25, interleave, out_scale=0.5, dtype=dtype), x)
+        return y, back(g)
+
+    size, angles, seen = math.prod(shape), shape[1] * rot // 2, set()
+    for eqn in _equations(jax.make_jaxpr(both)(x, x).jaxpr):
+        name = eqn.primitive.name
+        seen.add(name)
+        sizes = [v.aval.size for v in (*eqn.invars, *eqn.outvars)
+                 if hasattr(v.aval, "size")]
+        if name in ("cos", "sin"):
+            assert max(sizes) <= angles, eqn
+        if name in ("concatenate", "split"):
+            assert max(sizes) < size, eqn
+        if name == "reshape" and max(sizes) >= size:
+            assert eqn.outvars[0].aval.shape[-1] != 2, eqn
+    assert "dot_general" in seen and "mul" in seen  # it did look inside
+
+
+@pytest.mark.parametrize("shape", [(2, 8192, 32, 128), (2, 8192, 4, 128)])
+def test_the_cells_half_split_turn_is_one_pass_at_full_width(shape):
+    """`mellum2_moe_dp1`'s q and k: the per-head cosines and the half-lane
+    layouts do not come back."""
+    assert_one_pass_turn(shape, 128, False)
+
+
 def _expert_layer(share, shares, experts=8, top=2):
     return mellum2.Experts(num_experts=experts, experts_per_token=top,
                            width=32, share=share, shares=shares,
