@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from .alexnet import AlexNet
 from .joyai_flash import JoyAIFlash
+from .lfm2_moe import LFM2MoE
 from .lstm import LSTMLM
 from .mellum2 import Mellum2
 from .mnistnet import MnistNet
@@ -121,9 +122,26 @@ def get_model(dnn: str, dataset: Optional[str] = None, *,
                          "lm", counters=True, mtp_lambda=(
                              mtp_lambda if m.num_nextn_predict_layers
                              else 0.0))
+    if dnn == "lfm2_moe":
+        # gated short convolutions and grouped-query attention with normed
+        # heads three to one, a sigmoid router, the embedding for a head
+        # (models/lfm2_moe.py); `vocab_size` is the rows held
+        vocab = kw.pop("vocab_size", 65536)
+        seq_len = kw.pop("seq_len", 128)
+        if kw.get("layer_types") is not None:
+            kw["layer_types"] = tuple(kw["layer_types"])
+        m = LFM2MoE(vocab_size=vocab, dtype=dtype, **kw)
+        return ModelSpec("lfm2_moe", m, (seq_len,), jnp.int32, vocab, "lm",
+                         counters=True)
     raise ValueError(f"unknown dnn {dnn!r}; known: {', '.join(NAMES)}")
 
 
 NAMES = ("resnet20", "resnet32", "resnet44", "resnet56", "resnet110",
          "resnet50", "vgg16", "alexnet", "mnistnet", "lstm", "lstman4",
-         "transformer", "transformer_lm", "mellum2", "joyai_flash")
+         "transformer", "transformer_lm", "mellum2", "joyai_flash",
+         "lfm2_moe")
+# the names `get_model` takes (aliases included) whose head is a vocabulary:
+# the trainer hands them the data set's cardinality as `vocab_size`
+TOKEN_MODELS = frozenset(("lstm", "transformer", "transformer_lm",
+                          "transformerlm", "mellum2", "joyai_flash",
+                          "lfm2_moe"))

@@ -38,13 +38,16 @@ blocks a mask leaves empty, so a window layer's work goes with S x window;
 elsewhere (the CPU tests) blocks of queries against the keys their mask can
 reach.
 
-Not in this model's published config and so not used by it: QK-norm, an
-auxiliary load-balance loss; nor, though the pieces below offer them to the
-models that share them (`models/joyai_flash.py`): a shared expert, sigmoid
-scores with a selection bias and a scale (`Experts`, `GatedMLP`), adjacent-pair rotary
-(`apply_rope`), values of a head size of their own and a key/value head for
-every query head (`plain_attention`, `splash_attention`); the benchmark's
-configuration file lists what is assumed under `assumed`.
+Not in this model's published config and so not used by it: an auxiliary
+load-balance loss; nor, though the pieces below offer them to the models
+that share them (`models/joyai_flash.py`, `models/lfm2_moe.py`): a shared
+expert, sigmoid scores with a selection bias, a scale and a constant in the
+weights' sum (`Experts`, `GatedMLP`), adjacent-pair rotary (`apply_rope`),
+values of a head size of their own and a key/value head for every query
+head (`plain_attention`, `splash_attention`), a norm over each q and k head
+before the turn (`Attention(qk_norm=True)`, under the scope `qk_norm` inside
+`attn_proj`); the benchmark's configuration file lists what is assumed
+under `assumed`.
 
 Device scopes (`jax.named_scope`; `benchmarks/model_scopes.py` reads the
 first five, `benchmarks/scope_tree.py` the whole path): `attn_window`,
@@ -313,6 +316,13 @@ def use_kernels(kernels: Optional[bool]) -> bool:
     return jax.default_backend() == "tpu" if kernels is None else kernels
 
 
+def rms_normed(x, scale, eps: float, dtype):
+    """`x / rms(x) * scale` over the last axis in float32, rounded once."""
+    x = x.astype(jnp.float32)
+    x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return (x * scale).astype(dtype)
+
+
 class RMSNorm(nn.Module):
     eps: float
     dtype: Any
@@ -322,10 +332,7 @@ class RMSNorm(nn.Module):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            jnp.float32)
         with jax.named_scope("rms_norm"):
-            x = x.astype(jnp.float32)
-            x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True)
-                              + self.eps)
-            return (x * scale).astype(self.dtype)
+            return rms_normed(x, scale, self.eps, self.dtype)
 
 
 class Attention(nn.Module):
@@ -337,6 +344,8 @@ class Attention(nn.Module):
     rope_scale: float
     kernels: Optional[bool]
     dtype: Any
+    qk_norm: bool = False           # RMSNorm over each q and k head, one
+    qk_norm_eps: float = 1e-6       # learned scale each, BEFORE the turn
 
     @nn.compact
     def __call__(self, x):
@@ -348,11 +357,20 @@ class Attention(nn.Module):
                                    dtype=self.dtype, kernel_init=_INIT,
                                    name=name)(x)
 
+        def normed(name, heads):
+            y = proj(name + "_proj", heads)
+            if not self.qk_norm:
+                return y
+            scale = self.param(name + "_layernorm", nn.initializers.ones,
+                               (d,), jnp.float32)
+            with jax.named_scope("qk_norm"):
+                return rms_normed(y, scale, self.qk_norm_eps, self.dtype)
+
         with jax.named_scope("attn_proj"):
-            q = apply_rope(proj("q_proj", hq), self.inv_freq,
+            q = apply_rope(normed("q", hq), self.inv_freq,
                            self.rope_scale, out_scale=d ** -0.5,
                            dtype=self.dtype).reshape(b, s, hkv, hq // hkv, d)
-            k = apply_rope(proj("k_proj", hkv), self.inv_freq,
+            k = apply_rope(normed("k", hkv), self.inv_freq,
                            self.rope_scale, dtype=self.dtype)
             v = proj("v_proj", hkv)
         with jax.named_scope("attn_window" if self.window else "attn_full"):
@@ -570,13 +588,13 @@ expert_terms.defvjp(_expert_terms_fwd, _expert_terms_bwd)
 
 
 def route(probs, top: int, first: int, held: int, choose_by=None,
-          scale: float = 1.0):
+          scale: float = 1.0, sum_eps: float = 0.0):
     """From the router's scores [T, E] (softmax probabilities, or a sigmoid
     of each logit): each token's `top` largest by `choose_by` [T, E] where
     that is given (the scores plus a bias that only selects) and by the
-    scores themselves where not; their scores renormalised to sum 1, times
-    `scale`; which rows of the `T * top` assignments go to which of the
-    `held` experts from `first` on.
+    scores themselves where not; their scores renormalised to sum 1 (divided
+    by their sum plus `sum_eps`), times `scale`; which rows of the `T * top`
+    assignments go to which of the `held` experts from `first` on.
 
     Returns (weights [T, top]; `order` [T * top], the assignments sorted by
     held expert, those of absent experts last; its inverse; `sizes`
@@ -587,7 +605,8 @@ def route(probs, top: int, first: int, held: int, choose_by=None,
     else:
         _, experts = lax.top_k(choose_by, top)
         weights = jnp.take_along_axis(probs, experts, axis=-1)
-    weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    total = jnp.sum(weights, axis=-1, keepdims=True)
+    weights = weights / (total + sum_eps if sum_eps else total)
     if scale != 1.0:
         weights = weights * scale
     local = experts - first
@@ -624,8 +643,9 @@ class Experts(nn.Module):
     logit's own. With `select_bias` a leaf `router_bias` is added to the
     scores where the `experts_per_token` are CHOSEN and nowhere else (its
     gradient is exactly zero: a selection has none). The chosen scores are
-    renormalised and multiplied by `scale`. `shared_width` > 0: a gated
-    expert of that width that every token passes, added by every share."""
+    renormalised (their sum plus `sum_eps` the divisor) and multiplied by
+    `scale`. `shared_width` > 0: a gated expert of that width that every
+    token passes, added by every share."""
     num_experts: int
     experts_per_token: int
     width: int
@@ -636,6 +656,7 @@ class Experts(nn.Module):
     select_bias: bool = False
     scale: float = 1.0
     shared_width: int = 0
+    sum_eps: float = 0.0
 
     @nn.compact
     def __call__(self, x):
@@ -665,7 +686,8 @@ class Experts(nn.Module):
                     "router_bias", nn.initializers.zeros,
                     (self.num_experts,), jnp.float32)
             weights, order, inverse, sizes, served = route(
-                scores, top, self.share * held, held, choose_by, self.scale)
+                scores, top, self.share * held, held, choose_by, self.scale,
+                self.sum_eps)
         shape = (held, hidden, self.width)
         w1 = self.param("w1", _INIT, shape, jnp.float32)
         w3 = self.param("w3", _INIT, shape, jnp.float32)

@@ -322,8 +322,7 @@ class Trainer:
         # key like num_classes/dtype overrides instead of raising a
         # duplicate-keyword TypeError) ----
         model_kw = {"num_classes": cfg.num_classes or card, "dtype": dtype}
-        if cfg.dnn.lower() in ("lstm", "transformer", "transformer_lm",
-                               "transformerlm", "mellum2", "joyai_flash"):
+        if cfg.dnn.lower() in models_lib.TOKEN_MODELS:
             model_kw["vocab_size"] = cfg.num_classes or card
         elif cfg.dnn.lower() == "lstman4":
             model_kw["num_labels"] = cfg.num_classes or card

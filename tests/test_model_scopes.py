@@ -38,7 +38,15 @@ MODELS = {
         dense_width=96, num_heads=4, q_lora_rank=48, kv_lora_rank=32,
         qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
         num_experts=8, experts_per_token=2, expert_width=32, expert_share=0,
-        expert_shares=2, num_nextn_predict_layers=1)}
+        expert_shares=2, num_nextn_predict_layers=1),
+    # its own scopes are the configuration's to name, not `scope_tree`'s:
+    # `tests/test_lfm2_moe.py` reads this one's compiled step
+    "lfm2_moe": dict(
+        hidden_size=64, num_layers=4,
+        layer_types=("conv", "full_attention", "conv", "conv"),
+        num_dense_layers=1, dense_width=96, num_heads=4, num_kv_heads=2,
+        head_dim=16, num_experts=8, experts_per_token=2, expert_width=32,
+        expert_share=0, expert_shares=2)}
 
 F, R, B = scope_tree.PASSES
 ALL = (F, R, B)
