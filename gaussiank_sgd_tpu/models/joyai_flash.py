@@ -56,8 +56,8 @@ set of angles that `mellum2.rope_table` lays out once on the host, at q's
 `moe_shared`, `dense_mlp`, `lm_head`, `mtp`, `embed`, `rms_norm` from
 `mellum2.RMSNorm`, and `layer_scan` around the scanned layers (what lies
 directly under it is the loop's own slicing and stacking). Counters as
-`mellum2`'s: `moe_held_assignments`, `moe_load_max_over_mean`,
-`moe_tokens_unserved`.
+`mellum2`'s: `moe_held_assignments`, `moe_room_used`,
+`moe_load_max_over_mean`, `moe_tokens_unserved`.
 """
 
 from __future__ import annotations

@@ -51,7 +51,7 @@ Device scopes: `short_conv` around the mixer with `conv_in_proj`,
 `mellum2.Attention`; `moe_router`, `moe_experts` (with `mellum2.py`'s
 scopes inside both), `dense_mlp`, `lm_head`, `embed`, `rms_norm` from
 `mellum2.RMSNorm`. Counters as `mellum2`'s: `moe_held_assignments`,
-`moe_load_max_over_mean`, `moe_tokens_unserved`.
+`moe_room_used`, `moe_load_max_over_mean`, `moe_tokens_unserved`.
 """
 
 from __future__ import annotations

@@ -62,7 +62,7 @@ attention's scale, the one rounding) against cos and sin tables that
 A new scope goes INSIDE the one a metric reads (docs/OBSERVABILITY.md,
 "Device scopes").
 Counters (returned with `return_counters=True`, logged through the loss
-function's auxiliary output): `moe_held_assignments`,
+function's auxiliary output): `moe_held_assignments`, `moe_room_used`,
 `moe_load_max_over_mean`, `moe_tokens_unserved`.
 """
 
@@ -92,6 +92,8 @@ _SPLASH_BLOCK = 512
 # size), so that kernel does not run a second time. The experts' products do
 # (their backward pass keeps more than their output).
 _SAVED = "attn_kernel_out"
+# the slots that a block of tokens has for its live rows in `_summed`
+_ROOM = 512
 
 
 # ------------------------------------------------------------------ rotary
@@ -390,8 +392,8 @@ class Attention(nn.Module):
 def to_rows(x, first, inverse, live, top: int):
     """The token's row for each of the sorted assignments `first` [rows]
     (assignment a is token `a // top`): `x[first // top]`. Its cotangent
-    comes back by `_summed`, `to_tokens`' sum: gathered, not scattered
-    (and under this function's scope, `moe_to_rows`)."""
+    comes back by `_summed`, `to_tokens`' sum with every weight 1 (under
+    this function's scope, `moe_to_rows`)."""
     with jax.named_scope("moe_to_rows"):
         return x[first // top]
 
@@ -403,22 +405,126 @@ def _to_rows_fwd(x, first, inverse, live, top):
 def _to_rows_bwd(top, res, g):
     first, inverse, live = res
     with jax.named_scope("moe_to_rows"):
-        ones = jnp.ones(inverse.shape, g.dtype)
-        return (_summed(g, ones, inverse, live, top).astype(g.dtype),
+        return (_summed(g, None, inverse, live, top).astype(g.dtype),
                 None, None, None)
 
 
 to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
 
 
+def _blocks(tokens: int, cap: int) -> Tuple[int, int, int]:
+    """How `_summed` cuts `tokens` tokens over `cap` sorted rows: (blocks,
+    tokens a block, the slots a block has for its live rows). `_ROOM` slots
+    a block, and as many blocks as make `cap` slots in all: a block has
+    twice an even load's rows, as `cap` has."""
+    room = min(_ROOM, cap)
+    blocks = max(1, cap // room)
+    return blocks, -(-tokens // blocks), room
+
+
+def _by_block(a, top: int, cap: int):
+    """`a` [tokens * top] by `_blocks`' block of tokens: [blocks, tokens a
+    block * top], zeros after the last token."""
+    blocks, per, _ = _blocks(a.shape[0] // top, cap)
+    return jnp.pad(a, (0, blocks * per * top - a.shape[0])).reshape(
+        blocks, per * top)
+
+
+def _fullest(live, top: int, cap: int):
+    """The live assignments of the block of tokens that has most."""
+    return jnp.max(jnp.sum(_by_block(live, top, cap), axis=1,
+                           dtype=jnp.int32))
+
+
+def room_used(cap: int, top: int, inverse, sizes):
+    """The share of its room that the layer's load takes: the live rows
+    over `cap` or, where `cap` is not room for all, the fullest block of
+    tokens' live rows over its slots (`_blocks`) if that is more. Up to 1.0
+    the `cap` rows hold every live one and `_summed` reads `cap` rows; over
+    it the sum, or with more live rows than `cap` the whole layer
+    (`_by_rows`), goes by a row for every assignment."""
+    held = jnp.sum(sizes)
+    used = held / cap
+    if cap < inverse.shape[0]:
+        room = _blocks(inverse.shape[0] // top, cap)[2]
+        used = jnp.maximum(used, _fullest(inverse < held, top, cap) / room)
+    return used.astype(jnp.float32)
+
+
 def _summed(r, scale, inverse, live, top: int):
-    """`to_tokens`' sum, under the scope of whoever calls it."""
+    """`to_tokens`' sum, under the scope of whoever calls it; `scale` None:
+    every weight 1. float32 [T, h].
+
+    Where `r` has a row for every assignment (`cap == T * top`: all experts
+    held, or the `large` side of `_by_rows`) every row may be live, and
+    each assignment's row is gathered (`_gathered`). Where it has fewer,
+    that gather reads `T * top` rows to zero most (the assignments of
+    absent experts: 3 in 4 at `T * top / cap` 4, 15 in 16 at 16), and the
+    sum reads `cap` rows, the number that is there (`_banded`), wherever
+    every block of tokens' live rows fit the block's slots: which the step
+    can see, and the gather is there for the step where they do not (a run
+    of tokens that choose held experts: 2 of 102 logged steps of
+    `mellum2_moe_dp1` read `room_used` 1.06 and 1.09). One path for every ratio
+    `T * top / cap`; on the chip, ms a call with weights / with none, T
+    16 384 (`PERF.md` section 6, PR 39, has every form tried): 1.9-2.1 /
+    1.75-1.85 at top 8, cap 32 768, h 2304 (ratio 4) where the gather
+    alone takes 7.5 and 4.75 fused into the step; 0.5-0.8 / 0.35-0.45 for
+    1.8 at cap 8 192, h 2048 (ratio 16); 1.7-1.9 / 1.6 for 4.1 at top 4,
+    cap 32 768, h 2048 (ratio 2)."""
+    cap, full = r.shape[0], inverse.shape[0]
+    if cap == full:
+        return _gathered(scale is None, top, r, scale, inverse, live)
+    return lax.cond(_fullest(live, top, cap) <= _blocks(full // top, cap)[2],
+                    functools.partial(_banded, scale is None, top),
+                    functools.partial(_gathered, scale is None, top),
+                    r, scale, inverse, live)
+
+
+def _gathered(ones: bool, top: int, r, scale, inverse, live):
+    """`_summed` by a gathered row for every assignment."""
     picked = jnp.where(live[:, None],
                        r[jnp.minimum(inverse, r.shape[0] - 1)],
                        jnp.zeros((), r.dtype))
+    scale = jnp.ones(inverse.shape, r.dtype) if ones else scale
     return jnp.einsum("tkh,tk->th", picked.reshape(-1, top, r.shape[-1]),
                       scale.reshape(-1, top),
                       preferred_element_type=jnp.float32)
+
+
+def _banded(ones: bool, top: int, r, scale, inverse, live):
+    """`_summed` over `r`'s `cap` rows, where every block's live rows fit
+    its slots. The live assignments are in token order along `a = t * top +
+    j` already, so a live assignment's place among its block of tokens'
+    live ones is a prefix count; the block's rows are gathered into its
+    slots by those places, and the block's sum is its [tokens, slots]
+    matrix of weights (0 where a slot is not that token's) times its rows:
+    float32 weights times the rows widened to float32 at `highest`, which
+    the matrix unit computes exactly (a weight split into bfloat16 parts by
+    casts is NOT kept apart on the TPU, whose compiler drops a rounding to
+    bfloat16 and back, and the product of such parts was the slower one)."""
+    (cap, h), tokens = r.shape, inverse.shape[0] // top
+    blocks, per, room = _blocks(tokens, cap)
+    live = _by_block(live, top, cap)
+    lives = live.astype(jnp.int32)
+    counts = jnp.sum(lives, axis=1)
+    # a live assignment's slot: its place among its block's live ones
+    slot = jnp.where(live, jnp.cumsum(lives, axis=1) - lives, room)
+    hit = slot.reshape(blocks, per, top, 1) == jnp.arange(room)
+    # the sorted row in each slot (one assignment hits a slot, or none)
+    window = jnp.sum(jnp.where(hit, _by_block(inverse, top, cap).reshape(
+        blocks, per, top, 1), 0), axis=(1, 2))          # [blocks, room]
+    mine = jnp.arange(room)[None, :] < counts[:, None]
+    rows = jnp.where(mine[:, :, None], r[window], jnp.zeros((), r.dtype))
+    if ones:
+        weights = jnp.any(hit, axis=2).astype(r.dtype)
+    else:
+        scale = _by_block(scale.astype(jnp.float32), top, cap).reshape(
+            blocks, per, top)
+        weights = jnp.sum(jnp.where(hit, scale[..., None], 0.0), axis=2)
+    y = jnp.einsum("btc,bch->bth", weights, rows.astype(weights.dtype),
+                   precision=lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
+    return y.reshape(blocks * per, h)[:tokens]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
@@ -708,6 +814,7 @@ class Experts(nn.Module):
         load = sizes.astype(jnp.float32)
         counters = {
             "moe_held_assignments": jnp.sum(load),
+            "moe_room_used": room_used(enough, top, inverse, sizes),
             "moe_load_max_over_mean": jnp.max(load) / jnp.maximum(
                 jnp.mean(load), 1.0),
             "moe_tokens_unserved": 1.0 - jnp.mean(served.astype(jnp.float32))}
@@ -724,10 +831,12 @@ def own_fields(module: nn.Module) -> types.SimpleNamespace:
 
 def model_counters(per_layer):
     """The model's counters from its expert layers': the assignments held
-    summed, the load of the worst layer, the unserved share's mean."""
+    summed, the room used and the load of the worst layer, the unserved
+    share's mean."""
     stacked = jax.tree.map(lambda *v: jnp.stack(v), *per_layer)
     return {
         "moe_held_assignments": jnp.sum(stacked["moe_held_assignments"]),
+        "moe_room_used": jnp.max(stacked["moe_room_used"]),
         "moe_load_max_over_mean": jnp.max(
             stacked["moe_load_max_over_mean"]),
         "moe_tokens_unserved": jnp.mean(stacked["moe_tokens_unserved"])}

@@ -454,6 +454,190 @@ def test_no_token_is_dropped_when_every_token_goes_to_one_expert(
     assert (float(sizes.sum()) > enough) == (len(forced) == 3)
 
 
+def _sorted_by_group(group, held):
+    """`route`'s sort of the assignments `group` [T, top] (a held expert's
+    number, or `held` for an absent one)."""
+    group = jnp.asarray(group, jnp.int32).reshape(-1)
+    order = jnp.argsort(group, stable=True)
+    inverse = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    sizes = jnp.sum(group[:, None] == jnp.arange(held)[None, :], axis=0,
+                    dtype=jnp.int32)
+    return order, inverse, sizes
+
+
+# two blocks of tokens, each with `_ROOM` slots for its live rows, wherever
+# the sorted rows' room is SUM_CAP: twice an even load's, as the cells'
+SUM_TOP, SUM_HELD = 8, 8
+SUM_TOKENS, SUM_CAP = mellum2._ROOM, 2 * mellum2._ROOM
+
+
+def _routing(case):
+    """[T, top] held-expert numbers (8: absent) and the sorted rows' room."""
+    rng = np.random.default_rng(29)
+    tokens, top, held = SUM_TOKENS, SUM_TOP, SUM_HELD
+    per, room = tokens // 2, mellum2._ROOM
+    assert mellum2._blocks(tokens, SUM_CAP) == (2, per, room)
+    # each token's experts are distinct, as a top-k's are: 8 of 64
+    group = np.stack([rng.permutation(64)[:top] for _ in range(tokens)])
+    group = np.where(group < held, group, held)
+    cap = SUM_CAP
+    if case == "all_of_a_token_and_none":
+        group[0] = np.arange(top)               # every assignment live
+        group[1] = held                         # none
+        group[tokens - 1] = np.arange(top)[::-1]    # the last token too
+    elif case == "neighbours_in_a_group":
+        # token 5's two rows are neighbours in the sorted order (the end of
+        # expert 2's rows, the start of expert 3's: tokens 0-4 get neither)
+        group[:5] = held
+        group[5] = [2, 3] + [held] * (top - 2)
+        # and one expert twice: what no top-k gives, and a sum all the same
+        group[9] = [4, 4] + [held] * (top - 2)
+    elif case == "live_rows_exactly_cap":
+        group[:] = held
+        group[:, 2] = rng.integers(0, held, tokens)     # two a token:
+        group[:, 6] = (group[:, 2] + 3) % held      # every slot of both blocks
+    elif case == "cap_is_every_assignment":
+        cap = tokens * top
+    elif case == "most_of_the_load_in_one_block":
+        group[per:] = held
+        group[per:per + room // top - 4] = np.arange(top)   # all but 32 slots
+    elif case == "tokens_no_multiple_of_the_blocks":
+        group = group[:tokens - 5]      # the last block is a token short
+    elif case == "a_block_fuller_than_its_slots":
+        group[:] = held
+        group[:room // top + 8] = np.arange(top)    # 64 rows more than slots
+    else:
+        assert case == "even_load"
+    return group, cap
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", [
+    "even_load", "all_of_a_token_and_none", "neighbours_in_a_group",
+    "live_rows_exactly_cap", "cap_is_every_assignment",
+    "most_of_the_load_in_one_block", "tokens_no_multiple_of_the_blocks",
+    "a_block_fuller_than_its_slots"])
+def test_the_sum_back_to_tokens_against_the_dense_formula(case, dtype):
+    """`to_tokens` and the cotangent of `to_rows` against a dense [T, cap]
+    matrix of weights times the rows in float64: forward, and both
+    cotangents (`d_r`, `d_scale` through `to_tokens`; `d_x` through
+    `to_rows`), whichever way `_summed` goes for `cap` and for the blocks'
+    load: a block with more live rows than slots loses none (the sum takes
+    a row for every assignment that step)."""
+    top, held, h = SUM_TOP, SUM_HELD, 24
+    group, cap = _routing(case)
+    tokens = group.shape[0]
+    order, inverse, sizes = _sorted_by_group(group, held)
+    first, live = order[:cap], inverse < jnp.sum(sizes)
+    n_live = int(sizes.sum())
+    assert n_live <= cap
+    used = float(mellum2.room_used(cap, top, inverse, sizes))
+    if case == "a_block_fuller_than_its_slots":
+        assert used == (mellum2._ROOM + 64) / mellum2._ROOM
+    else:
+        assert used <= 1.0
+    if case == "live_rows_exactly_cap":
+        assert n_live == cap and used == 1.0
+    rng = np.random.default_rng(31)
+    r = jnp.asarray(rng.normal(size=(cap, h)), dtype)
+    scale = jnp.asarray(rng.uniform(0.05, 1.0, size=tokens * top),
+                        jnp.float32)
+    g = jnp.asarray(rng.normal(size=(tokens, h)), jnp.float32)
+    # the dense matrices: weights[t, i] of sorted row i in token t's sum
+    weights = np.zeros((tokens, cap))
+    ones = np.zeros((tokens, cap))
+    for a in np.flatnonzero(np.asarray(live)):
+        weights[a // top, int(inverse[a])] += float(scale[a])
+        ones[a // top, int(inverse[a])] += 1.0
+    r64 = np.asarray(r.astype(jnp.float32), np.float64)
+    one_rounding = 2.0 ** -8 if dtype == jnp.bfloat16 else 1e-6
+
+    y, back = jax.vjp(lambda r, scale: mellum2.to_tokens(
+        r, scale, first, inverse, live, top), r, scale)
+    assert y.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(y, np.float64), weights @ r64,
+                               atol=2e-6, rtol=2e-6)
+    d_r, d_scale = back(g)
+    assert d_r.dtype == dtype
+    g64 = np.asarray(g, np.float64)
+    np.testing.assert_allclose(
+        np.asarray(d_r.astype(jnp.float32), np.float64), weights.T @ g64,
+        atol=one_rounding, rtol=one_rounding)
+    want = np.zeros(tokens * top)
+    for a in np.flatnonzero(np.asarray(live)):
+        want[a] = g64[a // top] @ r64[int(inverse[a])]
+    np.testing.assert_allclose(np.asarray(d_scale, np.float64), want,
+                               atol=2e-5, rtol=2e-5)
+
+    x = jnp.asarray(rng.normal(size=(tokens, h)), dtype)
+    rows, back = jax.vjp(lambda x: mellum2.to_rows(
+        x, first, inverse, live, top), x)
+    np.testing.assert_array_equal(
+        np.asarray(rows.astype(jnp.float32)),
+        np.asarray(x.astype(jnp.float32))[np.asarray(first) // top])
+    g_rows = jnp.asarray(rng.normal(size=(cap, h)), dtype)
+    d_x, = back(g_rows)
+    assert d_x.dtype == dtype
+    np.testing.assert_allclose(
+        np.asarray(d_x.astype(jnp.float32), np.float64),
+        ones @ np.asarray(g_rows.astype(jnp.float32), np.float64),
+        atol=4 * one_rounding, rtol=one_rounding)
+    # a token none of whose experts is held gets exactly nothing
+    if case == "all_of_a_token_and_none":
+        assert not np.asarray(y[1]).any() and not np.asarray(d_x[1]).any()
+        assert np.asarray(y[0]).any() and np.asarray(y[tokens - 1]).any()
+
+
+def _rows_of_every_assignment(jaxpr, full, h):
+    """The primitives in `jaxpr` with an operand or a result of `full` rows
+    of width `h` or more, and every primitive it holds."""
+    big, seen = [], set()
+    for eqn in _equations(jaxpr):
+        seen.add(eqn.primitive.name)
+        if any(getattr(v.aval, "size", 0) >= full * h
+               for v in (*eqn.invars, *eqn.outvars)):
+            big.append(eqn.primitive.name)
+    return big, seen
+
+
+@pytest.mark.parametrize("which", ["to_tokens", "to_rows_cotangent"])
+def test_the_cells_sum_reads_the_rows_that_are_there(which):
+    """`mellum2_moe_dp1`'s expert layer (T 16 384, top 8, h 2304, room for
+    32 768 sorted rows): the sum is a `cond` whose side for blocks that fit
+    holds no gather, nor anything else, of a row for every one of the
+    131 072 assignments, and nothing outside the `cond` does; its other
+    side is the gather that was there (the count looks where the rows
+    are)."""
+    tokens, top, h, cap = 16384, 8, 2304, 32768
+    full = tokens * top
+    shape = jax.ShapeDtypeStruct
+    index = (shape((cap,), jnp.int32), shape((full,), jnp.int32),
+             shape((full,), jnp.bool_))
+    rows = shape((cap, h), jnp.bfloat16)
+    if which == "to_tokens":
+        jaxpr = jax.make_jaxpr(
+            lambda r, scale, first, inverse, live: mellum2.to_tokens(
+                r, scale, first, inverse, live, top))(
+            rows, shape((full,), jnp.float32), *index).jaxpr
+    else:
+        jaxpr = jax.make_jaxpr(
+            lambda x, g, first, inverse, live: jax.vjp(
+                lambda x: mellum2.to_rows(x, first, inverse, live, top),
+                x)[1](g))(shape((tokens, h), jnp.bfloat16), rows,
+                          *index).jaxpr
+    cond, = [e for e in _equations(jaxpr) if e.primitive.name == "cond"]
+    gathered, banded = (b.jaxpr for b in cond.params["branches"])
+    big, seen = _rows_of_every_assignment(banded, full, h)
+    assert not big and {"gather", "dot_general", "cumsum"} <= seen
+    big, seen = _rows_of_every_assignment(gathered, full, h)
+    assert "gather" in big and "cumsum" not in seen
+    outside = [e for e in _equations(jaxpr)
+               if e not in set(_equations(gathered))]
+    assert not any(getattr(v.aval, "size", 0) >= full * h for e in outside
+                   for v in (*e.invars, *e.outvars))
+
+
 def test_through_the_trainer_for_a_few_sparse_steps(tmp_path):
     """`--dnn mellum2 --dataset ptb` builds through `make_trainer` like
     every other model, trains sparse steps on two workers, and its `train`

@@ -95,9 +95,9 @@ def no_compile_cache():
         compilation_cache.reset_cache()
 
 
-def compiled_op_names(model: str):
+def compiled_op_names(model: str, **changed):
     spec = get_model(model, "ptb", vocab_size=50, dtype=jnp.float32,
-                     seq_len=POSITIONS, **MODELS[model])
+                     seq_len=POSITIONS, **{**MODELS[model], **changed})
     tokens = jax.ShapeDtypeStruct((2, POSITIONS), jnp.int32)
     params = jax.eval_shape(
         lambda t: spec.module.init({"params": jax.random.PRNGKey(0)}, t,
@@ -185,3 +185,38 @@ def test_little_of_fwd_bwd_has_no_name_of_the_models(parsed, model):
     # the three passes are all of it
     assert {w for _, c, w in parsed(model) if c[:1] == ("fwd_bwd",)} == {
         F, R, B}
+
+
+@pytest.fixture(scope="module")
+def cut_room():
+    """`mellum2`'s step with 2 of its 8 experts held: room for 64 sorted
+    rows of the 128 assignments, so the sum back to tokens goes over the
+    rows that are there (`mellum2._summed`) on the small side of the layer's
+    `cond` and over a row for every assignment on the other."""
+    return [(n, *scope_tree.parse(n)[:2])
+            for n in compiled_op_names("mellum2", expert_shares=4)]
+
+
+@pytest.mark.parametrize("scope", ["moe_to_rows", "moe_to_tokens"])
+def test_the_sum_over_the_rows_that_are_there_is_under_the_same_scopes(
+        cut_room, scope):
+    """Its slots' comparison and its product are `moe_to_tokens`' forward
+    and `moe_to_rows`' backward, inside `moe_experts`: the two metrics read
+    what they read."""
+    mine = [(n, chain, which) for n, chain, which in cut_room
+            if scope in chain]
+    assert {which for _, _, which in mine} == set(SHARED[scope])
+    for name, chain, _ in mine:
+        assert chain.index("moe_experts") < chain.index(scope), name
+        assert model_scopes.scope_of(name, OLD_SCOPES["mellum2"]) == (
+            "moe_experts"), name
+    # the new sum is what was compiled, in the pass it belongs to
+    its_pass = F if scope == "moe_to_tokens" else B
+    for piece in ("eq", "dot_general"):
+        assert any(n.rsplit("/", 1)[-1].startswith(piece)
+                   for n, _, which in mine if which == its_pass), piece
+    # and nothing of it is `moe_experts`' alone
+    loose = [n for n, chain, _ in cut_room
+             if n.rsplit("/", 1)[-1].startswith(("eq", "dot_general"))
+             and chain[-1:] == ("moe_experts",)]
+    assert not loose, loose
