@@ -13,6 +13,7 @@ from typing import Any, NamedTuple, Optional, Tuple
 
 import jax.numpy as jnp
 
+from .afmoe import Afmoe
 from .alexnet import AlexNet
 from .joyai_flash import JoyAIFlash
 from .lfm2_moe import LFM2MoE
@@ -133,15 +134,27 @@ def get_model(dnn: str, dataset: Optional[str] = None, *,
         m = LFM2MoE(vocab_size=vocab, dtype=dtype, **kw)
         return ModelSpec("lfm2_moe", m, (seq_len,), jnp.int32, vocab, "lm",
                          counters=True)
+    if dnn == "afmoe":
+        # window attention under rotary positions and full attention under
+        # none three to one, a sigmoid gate on the attention's output, four
+        # norms a layer, a sigmoid router with a shared expert, leading
+        # dense layers (models/afmoe.py); `vocab_size` is the rows held
+        vocab = kw.pop("vocab_size", 200192)
+        seq_len = kw.pop("seq_len", 128)
+        if kw.get("layer_types") is not None:
+            kw["layer_types"] = tuple(kw["layer_types"])
+        m = Afmoe(vocab_size=vocab, dtype=dtype, **kw)
+        return ModelSpec("afmoe", m, (seq_len,), jnp.int32, vocab, "lm",
+                         counters=True)
     raise ValueError(f"unknown dnn {dnn!r}; known: {', '.join(NAMES)}")
 
 
 NAMES = ("resnet20", "resnet32", "resnet44", "resnet56", "resnet110",
          "resnet50", "vgg16", "alexnet", "mnistnet", "lstm", "lstman4",
          "transformer", "transformer_lm", "mellum2", "joyai_flash",
-         "lfm2_moe")
+         "lfm2_moe", "afmoe")
 # the names `get_model` takes (aliases included) whose head is a vocabulary:
 # the trainer hands them the data set's cardinality as `vocab_size`
 TOKEN_MODELS = frozenset(("lstm", "transformer", "transformer_lm",
                           "transformerlm", "mellum2", "joyai_flash",
-                          "lfm2_moe"))
+                          "lfm2_moe", "afmoe"))

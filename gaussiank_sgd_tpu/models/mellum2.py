@@ -40,14 +40,28 @@ reach.
 
 Not in this model's published config and so not used by it: an auxiliary
 load-balance loss; nor, though the pieces below offer them to the models
-that share them (`models/joyai_flash.py`, `models/lfm2_moe.py`): a shared
-expert, sigmoid scores with a selection bias, a scale and a constant in the
-weights' sum (`Experts`, `GatedMLP`), adjacent-pair rotary (`apply_rope`),
-values of a head size of their own and a key/value head for every query
-head (`plain_attention`, `splash_attention`), a norm over each q and k head
-before the turn (`Attention(qk_norm=True)`, under the scope `qk_norm` inside
-`attn_proj`); the benchmark's configuration file lists what is assumed
-under `assumed`.
+that share them: a shared expert, sigmoid scores with a selection bias, a
+scale and a constant in the weights' sum (`Experts`, `GatedMLP`),
+adjacent-pair rotary (`apply_rope`), values of a head size of their own and
+a key/value head for every query head (`plain_attention`,
+`splash_attention`), a norm over each q and k head before the turn
+(`Attention(qk_norm=True)`, under the scope `qk_norm` inside `attn_proj`),
+a layer without positions (`Attention(positions=False)`: q and k are not
+turned, only scaled, and the layer opens no `rope` scope), a sigmoid gate
+on the attention's output (`Attention(gate=True)`, `gated_output`, under
+the scope `attn_gate` inside `attn_proj`); the benchmark's configuration
+file lists what is assumed under `assumed`. Which model sets which field:
+
+    field                              mellum2  joyai_flash  lfm2_moe  afmoe
+    Attention  qk_norm                 -        (own MLA)    yes       yes
+               positions=False         -        (own MLA)    -         full layers
+               gate                    -        (own MLA)    -         yes
+    Experts    scoring                 softmax  sigmoid      sigmoid   sigmoid
+               select_bias             -        yes          yes       yes
+               scale                   1        2.5          1         2.826
+               sum_eps                 0        0            1e-6      1e-20
+               shared_width            0        768          0         1024
+    GatedMLP   leading dense layers    -        1            2         2
 
 Device scopes (`jax.named_scope`; `benchmarks/model_scopes.py` reads the
 first five, `benchmarks/scope_tree.py` the whole path): `attn_window`,
@@ -63,7 +77,8 @@ A new scope goes INSIDE the one a metric reads (docs/OBSERVABILITY.md,
 "Device scopes").
 Counters (returned with `return_counters=True`, logged through the loss
 function's auxiliary output): `moe_held_assignments`, `moe_room_used`,
-`moe_load_max_over_mean`, `moe_tokens_unserved`.
+`moe_load_max_over_mean`, `moe_tokens_unserved`; a model with gated
+attention adds `attn_gate_mean` (`models/afmoe.py`).
 """
 
 from __future__ import annotations
@@ -82,7 +97,18 @@ from jax import lax
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 _PERIOD = (SLIDING, SLIDING, SLIDING, FULL)
-_INIT = nn.initializers.normal(0.02)
+
+
+def _INIT(key, shape, dtype=jnp.float32):
+    """normal(0, 0.02), drawn flat and folded to `shape`: entry for entry
+    the draw of `shape` itself (the generator counts entries, not rows),
+    from a program the TPU compiler is done with in 0.7 s where a draw of
+    three axes takes it 3.3 (`[2048, 32, 128]`) to 9.3 (`[8, 2048, 1024]`);
+    a model's init program is mostly such draws."""
+    return nn.initializers.normal(0.02)(key, (math.prod(shape),),
+                                        dtype).reshape(shape)
+
+
 # query rows a block of the plain attention path takes at a time
 _PLAIN_BLOCK = 128
 # splash attention's tiles on a v5e (queries x keys, forward and backward)
@@ -337,6 +363,40 @@ class RMSNorm(nn.Module):
             return rms_normed(x, scale, self.eps, self.dtype)
 
 
+@jax.custom_vjp
+def gated_output(out, gate):
+    """`out * sigmoid(gate)` entry by entry in float32, rounded once to
+    `out.dtype`: the gate on the attention's output. ONE pass of its own
+    forward (reads both, writes the product) and one backward (reads both
+    and the cotangent, writes two cotangents), held apart from the kernel
+    before it and the products around it by `optimization_barrier`s. Left to
+    itself XLA writes the kernel's output out again in float32, runs the
+    forward multiply inside `gate_proj`'s product and the backward pass
+    inside `o_proj`'s, under their names. The backward pass is written out
+    so that both cotangents leave one fusion; it keeps `out` and `gate` and
+    computes the sigmoid again."""
+    out, gate = lax.optimization_barrier((out, gate))
+    share = jax.nn.sigmoid(gate.astype(jnp.float32))
+    return lax.optimization_barrier(
+        (out.astype(jnp.float32) * share).astype(out.dtype))
+
+
+def _gated_output_fwd(out, gate):
+    return gated_output(out, gate), (out, gate)
+
+
+def _gated_output_bwd(res, g):
+    out, gate, g = lax.optimization_barrier((*res, g))
+    g = g.astype(jnp.float32)
+    share = jax.nn.sigmoid(gate.astype(jnp.float32))
+    d_gate = g * out.astype(jnp.float32) * (share * (1.0 - share))
+    return lax.optimization_barrier(
+        ((g * share).astype(out.dtype), d_gate.astype(gate.dtype)))
+
+
+gated_output.defvjp(_gated_output_fwd, _gated_output_bwd)
+
+
 class Attention(nn.Module):
     num_heads: int
     num_kv_heads: int
@@ -348,6 +408,10 @@ class Attention(nn.Module):
     dtype: Any
     qk_norm: bool = False           # RMSNorm over each q and k head, one
     qk_norm_eps: float = 1e-6       # learned scale each, BEFORE the turn
+    positions: bool = True          # False: q and k are NOT turned
+    gate: bool = False              # `gate_proj`, hidden -> heads x head_dim:
+    # its sigmoid times the attention's output, entry by entry, before
+    # `o_proj`; the module then answers (output, the sigmoid's mean)
 
     @nn.compact
     def __call__(self, x):
@@ -359,31 +423,43 @@ class Attention(nn.Module):
                                    dtype=self.dtype, kernel_init=_INIT,
                                    name=name)(x)
 
-        def normed(name, heads):
+        def placed(name, heads, out_scale=1.0):
+            """The projection, normed where heads are, turned by its
+            position where positions are, times `out_scale`: float32 from
+            the last of them, rounded once."""
             y = proj(name + "_proj", heads)
-            if not self.qk_norm:
-                return y
-            scale = self.param(name + "_layernorm", nn.initializers.ones,
-                               (d,), jnp.float32)
-            with jax.named_scope("qk_norm"):
-                return rms_normed(y, scale, self.qk_norm_eps, self.dtype)
+            last = self.dtype if self.positions else jnp.float32
+            if self.qk_norm:
+                scale = self.param(name + "_layernorm", nn.initializers.ones,
+                                   (d,), jnp.float32)
+                with jax.named_scope("qk_norm"):
+                    y = rms_normed(y, scale, self.qk_norm_eps, last)
+            if self.positions:
+                return apply_rope(y, self.inv_freq, self.rope_scale,
+                                  out_scale=out_scale, dtype=self.dtype)
+            return (y.astype(jnp.float32) * out_scale).astype(self.dtype)
 
         with jax.named_scope("attn_proj"):
-            q = apply_rope(normed("q", hq), self.inv_freq,
-                           self.rope_scale, out_scale=d ** -0.5,
-                           dtype=self.dtype).reshape(b, s, hkv, hq // hkv, d)
-            k = apply_rope(normed("k", hkv), self.inv_freq,
-                           self.rope_scale, dtype=self.dtype)
+            q = placed("q", hq, d ** -0.5).reshape(b, s, hkv, hq // hkv, d)
+            k = placed("k", hkv)
             v = proj("v_proj", hkv)
+            gate = proj("gate_proj", hq) if self.gate else None
         with jax.named_scope("attn_window" if self.window else "attn_full"):
             if use_kernels(self.kernels):
                 out = splash_attention(q, k, v, self.window)
             else:
                 out = plain_attention(q, k, v, self.window)
         with jax.named_scope("attn_proj"):
-            return nn.DenseGeneral(hidden, axis=(-2, -1), use_bias=False,
-                                   dtype=self.dtype, kernel_init=_INIT,
-                                   name="o_proj")(out.reshape(b, s, hq, d))
+            out, share = out.reshape(b, s, hq, d), None
+            if self.gate:
+                with jax.named_scope("attn_gate"):
+                    out = gated_output(out, gate)
+                    share = jnp.mean(jax.nn.sigmoid(
+                        lax.stop_gradient(gate).astype(jnp.float32)))
+            y = nn.DenseGeneral(hidden, axis=(-2, -1), use_bias=False,
+                                dtype=self.dtype, kernel_init=_INIT,
+                                name="o_proj")(out)
+        return (y, share) if self.gate else y
 
 
 # ----------------------------------------------------------------- experts

@@ -46,7 +46,16 @@ MODELS = {
         layer_types=("conv", "full_attention", "conv", "conv"),
         num_dense_layers=1, dense_width=96, num_heads=4, num_kv_heads=2,
         head_dim=16, num_experts=8, experts_per_token=2, expert_width=32,
-        expert_share=0, expert_shares=2)}
+        expert_share=0, expert_shares=2),
+    # window layers under rotary positions, a full layer under none, a
+    # gate on every layer's attention output, four norms a layer
+    "afmoe": dict(
+        hidden_size=64, num_layers=4,
+        layer_types=("sliding_attention", "sliding_attention",
+                     "full_attention", "sliding_attention"),
+        num_dense_layers=1, dense_width=96, num_heads=4, num_kv_heads=2,
+        head_dim=16, sliding_window=8, num_experts=8, experts_per_token=2,
+        expert_width=32, expert_share=0, expert_shares=2)}
 
 F, R, B = scope_tree.PASSES
 ALL = (F, R, B)
@@ -60,7 +69,10 @@ SHARED = {"moe_to_rows": ALL, "moe_to_tokens": (F, B), "moe_gate": ALL,
 PASSES_OF = {
     "mellum2": dict(SHARED, attn_proj=ALL),
     "joyai_flash": dict(SHARED, mla_q=ALL, mla_kv=ALL, mla_out=ALL,
-                        mla_assemble=ALL, layer_scan=ALL)}
+                        mla_assemble=ALL, layer_scan=ALL),
+    # the norm a branch leaves through needs the branch's output again in
+    # the backward pass: here `moe_to_tokens`' recomputed sum is no dead code
+    "afmoe": dict(SHARED, attn_proj=ALL, moe_to_tokens=ALL)}
 # new scope: the older scope that has to enclose it wherever it appears
 ENCLOSED_BY = {"moe_to_rows": "moe_experts", "moe_to_tokens": "moe_experts",
                "moe_gate": "moe_experts", "moe_product_glue": "moe_experts",
@@ -72,12 +84,14 @@ OLD_SCOPES = {
     "mellum2": ("attn_window", "attn_full", "moe_router", "moe_experts",
                 "lm_head"),
     "joyai_flash": ("attn_mla", "mla_proj", "moe_router", "moe_experts",
-                    "moe_shared", "dense_mlp", "lm_head")}
+                    "moe_shared", "dense_mlp", "lm_head"),
+    "afmoe": ("attn_window", "attn_full", "attn_gate", "moe_router",
+              "moe_experts", "moe_shared", "dense_mlp", "lm_head")}
 # share of the instructions under `fwd_bwd` whose path holds no name of a
 # model's: the residual additions, the counters, `jax.checkpoint`'s own
 # barriers (3.4 % and 0.7 % at these sizes; 31 % and 5 % of the time on the
 # chip before PR 36, PERF.md section 6)
-UNNAMED_SHARE = {"mellum2": 0.06, "joyai_flash": 0.03}
+UNNAMED_SHARE = {"mellum2": 0.06, "joyai_flash": 0.03, "afmoe": 0.06}
 
 
 @contextlib.contextmanager
@@ -158,7 +172,7 @@ def test_the_older_scope_stays_the_outer_one(parsed, model, scope):
 
 @pytest.mark.parametrize("model", sorted(PASSES_OF))
 def test_the_rotary_turn_is_inside_the_projections(parsed, model):
-    outer = "attn_proj" if model == "mellum2" else "mla_assemble"
+    outer = "mla_assemble" if model == "joyai_flash" else "attn_proj"
     chains = {chain for _, chain, _ in parsed(model) if "rope" in chain}
     assert chains and all(outer in c[:c.index("rope")] for c in chains)
 
@@ -185,6 +199,31 @@ def test_little_of_fwd_bwd_has_no_name_of_the_models(parsed, model):
     # the three passes are all of it
     assert {w for _, c, w in parsed(model) if c[:1] == ("fwd_bwd",)} == {
         F, R, B}
+
+
+def test_the_gate_on_the_attentions_output_is_inside_the_projections(parsed):
+    """`attn_gate` (`mellum2.gated_output`) lies inside `attn_proj` on
+    forward, recomputed and backward operations of every layer: the
+    configuration lists it, so `model_scopes.scope_of` reads it as its own
+    (`attn_gate_ms`), and `scope_tree`, which does not know the name, reads
+    it with the scope around it (`attn_proj_ms`; `fwd_bwd_unnamed_ms` stays
+    what it was)."""
+    mine = [(n, chain, which) for n, chain, which in parsed("afmoe")
+            if "/attn_gate/" in n]
+    assert {which for _, _, which in mine} == {F, R, B}
+    for i in range(4):
+        assert {which for n, _, which in mine
+                if f"/layers_{i}/" in n} == {F, R, B}, i
+    for name, chain, _ in mine:
+        parts = name.split("/")
+        assert parts.index("attn_proj") < parts.index("attn_gate"), name
+        assert chain == ("fwd_bwd", "attn_proj"), name
+        assert model_scopes.scope_of(name, OLD_SCOPES["afmoe"]) == (
+            "attn_gate"), name
+    # the sigmoid and both multiplies, forward and backward
+    for which, piece in ((F, "exp"), (F, "mul"), (R, "mul"), (B, "mul")):
+        assert any(n.rsplit("/", 1)[-1].startswith(piece)
+                   for n, _, w in mine if w == which), (which, piece)
 
 
 @pytest.fixture(scope="module")
