@@ -95,7 +95,7 @@ class Layer(nn.Module):
                 m.expert_share, m.expert_shares, m.dtype, scoring="sigmoid",
                 select_bias=True, scale=m.route_scale,
                 shared_width=m.num_shared_experts * m.expert_width,
-                sum_eps=_SUM_EPS, name="moe")(h)
+                sum_eps=_SUM_EPS, kernels=m.kernels, name="moe")(h)
         return x + norm("post_mlp_norm")(f), (counters, gate_mean)
 
 

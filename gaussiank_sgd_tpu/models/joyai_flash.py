@@ -139,7 +139,8 @@ class Layer(nn.Module):
             m.num_experts, m.experts_per_token, m.expert_width,
             m.expert_share, m.expert_shares, m.dtype, scoring="sigmoid",
             select_bias=True, scale=m.routed_scaling_factor,
-            shared_width=m.shared_experts * m.expert_width, name="moe")(h)
+            shared_width=m.shared_experts * m.expert_width,
+            kernels=m.kernels, name="moe")(h)
         return x + y, counters
 
 

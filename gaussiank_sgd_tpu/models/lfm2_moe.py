@@ -192,7 +192,7 @@ class Layer(nn.Module):
             m.num_experts, m.experts_per_token, m.expert_width,
             m.expert_share, m.expert_shares, m.dtype, scoring="sigmoid",
             select_bias=True, scale=m.routed_scaling_factor,
-            sum_eps=_SUM_EPS, name="moe")(h)
+            sum_eps=_SUM_EPS, kernels=m.kernels, name="moe")(h)
         return x + y, counters
 
 

@@ -25,12 +25,18 @@ vocabulary is a smaller vocabulary.
 
 No token is dropped: the (token, expert) assignments are sorted by held
 expert, each one's row gathered, and the experts' three products run as
-grouped products over the rows each expert got (`lax.ragged_dot`, which
-the TPU compiler serves with its own grouped-product kernel and visits only
-the tiles that hold rows). Shapes are static, so there is room for twice
-an even load's rows where the step sees that they suffice and for every
-assignment (all of a token's experts held) where not: a `lax.cond`, not a
-capacity.
+grouped products over the rows each expert got (`grouped_product`). With
+`kernels` (the default on a TPU) a product is a Pallas kernel of
+`ops/grouped_matmul.py` on tiles computed from its shape: an expert's whole
+matrix as one block, which stays in VMEM for all of the expert's row tiles,
+and only the row tiles that hold rows visited; elsewhere (the CPU tests),
+and for a room that no row tile divides, `lax.ragged_dot` (which the TPU
+compiler serves with a kernel of its own that takes no tiles from its
+caller: 23 % of the matrix unit at 2304 x 896 where the tiled kernel reads
+70 %, `PERF.md` section 6, PR 41). Shapes are static, so there is room for
+twice an even load's rows where the step sees that they suffice and for
+every assignment (all of a token's experts held) where not: a `lax.cond`,
+not a capacity.
 
 Attention never builds `[S, S]`. With `kernels` (the default on a TPU) it is
 the Pallas splash-attention kernel of `jax.experimental`, which skips the
@@ -66,7 +72,10 @@ file lists what is assumed under `assumed`. Which model sets which field:
 Device scopes (`jax.named_scope`; `benchmarks/model_scopes.py` reads the
 first five, `benchmarks/scope_tree.py` the whole path): `attn_window`,
 `attn_full`, `moe_router`, `moe_experts`, `lm_head`; inside `moe_experts`
-`moe_to_rows`, `moe_to_tokens`, `moe_gate`, `moe_product_glue`; inside
+`moe_to_rows`, `moe_to_tokens`, `moe_gate`, `moe_product_glue` and, around
+the products' kernels `grouped_fwd`, `grouped_dx`, `grouped_dw` and their
+schedule, `moe_product` (a name no reader lists: its time is `moe_experts`'
+own); inside
 `moe_router` `moe_route_sort`; `attn_proj` (the four projections, not around
 attention proper) with `rope` inside it; `rms_norm` (every instance);
 `embed`. `rope` holds the rotary turn whole: one fused pass over q and one
@@ -94,6 +103,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from ..ops import grouped_matmul
 
 SLIDING, FULL = "sliding_attention", "full_attention"
 _PERIOD = (SLIDING, SLIDING, SLIDING, FULL)
@@ -649,49 +660,83 @@ _BY_GROUP = lax.RaggedDotDimensionNumbers(
     lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
 
 
-@jax.custom_vjp
-def grouped_product(x, w, sizes):
-    """`x[rows of group e] @ w[e]` for every group (`lax.ragged_dot`), `w`
-    the float32 parameter, multiplied as `x.dtype`. Zero in the rows past
-    the last group's, forward and backward: what the product leaves there
-    must not reach a sum, and 0 times it is no 0. The weights' cotangent
-    leaves the product in float32 (a bfloat16 one would round every
-    gradient of an expert to 8 bits before it is accumulated). Under the
-    scope `moe_product_glue` is what is NOT the TPU compiler's kernel, which
-    carries no name: the casts, the transposition, the zeroing."""
+def _grouped(kernels: bool, name: str, a, b, sizes, transposed=False):
+    """One grouped product over the rows `a`, float32 accumulated. `b` [E,
+    k, n]: `a[rows of e] @ b[e]`, or `@ b[e].T` if `transposed`, as
+    `a.dtype`; `b` rows [m, n]: `a[rows of e].T @ b[rows of e]` for every
+    group, float32. With `kernels`, `ops/grouped_matmul.py`'s kernel `name`
+    on the tiles it computes from the shape, under the scope `moe_product`;
+    without, or for a shape it has no tiles for, `lax.ragged_dot` under
+    `moe_product_glue` (on a TPU that is the compiler's own kernel, which
+    carries no name at all)."""
+    by_group = b.ndim == 2
+    tiles = (grouped_matmul.tiles_by_group if by_group
+             else grouped_matmul.tiles)
+    if kernels and tiles(*a.shape, b.shape[1 if by_group or transposed
+                                           else 2]):
+        with jax.named_scope("moe_product"):
+            if by_group:
+                return grouped_matmul.grouped_by_group(a, b, sizes, name=name)
+            return grouped_matmul.grouped(a, b, sizes, transposed=transposed,
+                                          name=name)
     with jax.named_scope("moe_product_glue"):
-        return _live_rows(lax.ragged_dot(x, w.astype(x.dtype), sizes), sizes)
+        if by_group:
+            return lax.ragged_dot_general(
+                a, b, sizes, _BY_GROUP, preferred_element_type=jnp.float32)
+        return lax.ragged_dot(a, jnp.swapaxes(b, 1, 2) if transposed else b,
+                              sizes)
 
 
-def _grouped_fwd(x, w, sizes):
-    return grouped_product(x, w, sizes), (x, w, sizes)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def grouped_product(x, w, sizes, kernels: bool = False):
+    """`x[rows of group e] @ w[e]` for every group, `w` the float32
+    parameter, multiplied as `x.dtype` and accumulated in float32. Zero in
+    the rows past the last group's, forward and backward: neither kernel
+    writes them, what is there must not reach a sum, and 0 times it is no
+    0. The weights' cotangent leaves the product in float32 (a bfloat16 one
+    would round every gradient of an expert to 8 bits before it is
+    accumulated). `kernels`: the three products (forward `grouped_fwd`, the
+    rows' cotangent `grouped_dx` against the matrices as they lie, the
+    weights' `grouped_dw`) run through `ops/grouped_matmul.py` (`_grouped`).
+    Under the scope `moe_product_glue` is what is NOT a product's kernel:
+    the casts, the zeroing and, without `kernels`, the transposed copy."""
+    with jax.named_scope("moe_product_glue"):
+        w = w.astype(x.dtype)
+    y = _grouped(kernels, "grouped_fwd", x, w, sizes)
+    with jax.named_scope("moe_product_glue"):
+        return _live_rows(y, sizes)
 
 
-def _grouped_bwd(res, g):
+def _grouped_fwd(x, w, sizes, kernels):
+    return grouped_product(x, w, sizes, kernels), (x, w, sizes)
+
+
+def _grouped_bwd(kernels, res, g):
     x, w, sizes = res
     with jax.named_scope("moe_product_glue"):
         g = _live_rows(g, sizes)
-        dx = lax.ragged_dot(g, jnp.swapaxes(w.astype(x.dtype), 1, 2), sizes)
-        dw = lax.ragged_dot_general(x, g, sizes, _BY_GROUP,
-                                    preferred_element_type=jnp.float32)
+        wx = w.astype(x.dtype)
+    dx = _grouped(kernels, "grouped_dx", g, wx, sizes, transposed=True)
+    dw = _grouped(kernels, "grouped_dw", x, g, sizes)
+    with jax.named_scope("moe_product_glue"):
         return _live_rows(dx, sizes), dw.astype(w.dtype), None
 
 
 grouped_product.defvjp(_grouped_fwd, _grouped_bwd)
 
 
-def _terms(cap: int, top: int, x, weights, order, inverse, sizes, w1, w3,
-           w2):
+def _terms(cap: int, top: int, kernels: bool, x, weights, order, inverse,
+           sizes, w1, w3, w2):
     """The held experts' part of the layer's output, float32 [T, h], over
     the first `cap` sorted assignments, which hold every live one."""
     first = order[:cap]
     live = inverse < jnp.sum(sizes)
     rows = to_rows(x, first, inverse, live, top)
-    gate = grouped_product(rows, w1, sizes)
-    up = grouped_product(rows, w3, sizes)
+    gate = grouped_product(rows, w1, sizes, kernels)
+    up = grouped_product(rows, w3, sizes, kernels)
     with jax.named_scope("moe_gate"):
         gated = jax.nn.silu(gate) * up
-    out = grouped_product(gated, w2, sizes)
+    out = grouped_product(gated, w2, sizes, kernels)
     return to_tokens(out, weights.reshape(-1), first, inverse, live, top)
 
 
@@ -700,37 +745,38 @@ def _by_rows(enough: int, sizes, small, large, *args):
     return lax.cond(jnp.sum(sizes) <= enough, small, large, *args)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def expert_terms(enough: int, top: int, x, weights, order, inverse, sizes,
-                 w1, w3, w2):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def expert_terms(enough: int, top: int, kernels: bool, x, weights, order,
+                 inverse, sizes, w1, w3, w2):
     """`_terms` with room for `enough` rows where the live ones fit and for
     all `T * top` where not. A `lax.cond` that is differentiated through
     keeps BOTH sides' residuals (7 GB more at the benchmark's size), so the
     choice is made again in the backward pass: the small side keeps what
     its backward pass needs, the large side (an uneven load, seldom taken)
     keeps nothing and is recomputed from the arguments."""
-    return _expert_terms_fwd(enough, top, x, weights, order, inverse, sizes,
-                             w1, w3, w2)[0]
+    return _expert_terms_fwd(enough, top, kernels, x, weights, order,
+                             inverse, sizes, w1, w3, w2)[0]
 
 
-def _floats_of(cap, top, order, inverse, sizes):
+def _floats_of(cap, top, kernels, order, inverse, sizes):
     """`_terms` as a function of what it is differentiated by."""
     return lambda x, weights, w1, w3, w2: _terms(
-        cap, top, x, weights, order, inverse, sizes, w1, w3, w2)
+        cap, top, kernels, x, weights, order, inverse, sizes, w1, w3, w2)
 
 
-def _expert_terms_fwd(enough, top, *args):
+def _expert_terms_fwd(enough, top, kernels, *args):
     x, weights, order, inverse, sizes, w1, w3, w2 = args
     floats, full = (x, weights, w1, w3, w2), order.shape[0]
     if enough >= full:
-        y, back = jax.vjp(_floats_of(full, top, order, inverse, sizes),
-                          *floats)
+        y, back = jax.vjp(
+            _floats_of(full, top, kernels, order, inverse, sizes), *floats)
         return y, (back, args)
 
     def small(*args):
         x, weights, order, inverse, sizes, w1, w3, w2 = args
-        return jax.vjp(_floats_of(enough, top, order, inverse, sizes),
-                       x, weights, w1, w3, w2)
+        return jax.vjp(
+            _floats_of(enough, top, kernels, order, inverse, sizes),
+            x, weights, w1, w3, w2)
 
     # the backward function is a pytree: its leaves are what it keeps, and
     # the two sides of a `cond` have to hand out the same leaves
@@ -741,21 +787,22 @@ def _expert_terms_fwd(enough, top, *args):
         return y, jax.tree.leaves(back)
 
     def large_kept(*args):
-        return (_terms(full, top, *args),
+        return (_terms(full, top, kernels, *args),
                 [jnp.zeros(r.shape, r.dtype) for r in kept])
 
     y, leaves = _by_rows(enough, sizes, small_kept, large_kept, *args)
     return y, (jax.tree.unflatten(function, leaves), args)
 
 
-def _expert_terms_bwd(enough, top, res, g):
+def _expert_terms_bwd(enough, top, kernels, res, g):
     back, args = res
     full, sizes = args[2].shape[0], args[4]
 
     def recomputed(back, g, *args):
         x, weights, order, inverse, sizes, w1, w3, w2 = args
-        return jax.vjp(_floats_of(full, top, order, inverse, sizes),
-                       x, weights, w1, w3, w2)[1](g)
+        return jax.vjp(
+            _floats_of(full, top, kernels, order, inverse, sizes),
+            x, weights, w1, w3, w2)[1](g)
 
     if enough >= full:
         dx, dweights, dw1, dw3, dw2 = back(g)
@@ -839,6 +886,7 @@ class Experts(nn.Module):
     scale: float = 1.0
     shared_width: int = 0
     sum_eps: float = 0.0
+    kernels: Optional[bool] = None  # None: where the backend is a TPU
 
     @nn.compact
     def __call__(self, x):
@@ -882,8 +930,8 @@ class Experts(nn.Module):
             # token is dropped on either side.
             full = tokens * top
             enough = min(full, -(-2 * full // self.shares // 8) * 8)
-            y = expert_terms(enough, top, x, weights, order, inverse, sizes,
-                             w1, w3, w2)
+            y = expert_terms(enough, top, use_kernels(self.kernels), x,
+                             weights, order, inverse, sizes, w1, w3, w2)
         if self.shared_width:
             y = y + GatedMLP(self.shared_width, "moe_shared",
                              name="shared")(x).astype(jnp.float32)
@@ -939,7 +987,8 @@ class Layer(nn.Module):
         h = RMSNorm(m.rms_norm_eps, m.dtype, name="post_attn_norm")(x)
         y, counters = Experts(m.num_experts, m.experts_per_token,
                               m.expert_width, m.expert_share,
-                              m.expert_shares, m.dtype, name="moe")(h)
+                              m.expert_shares, m.dtype, kernels=m.kernels,
+                              name="moe")(h)
         return x + y, counters
 
 

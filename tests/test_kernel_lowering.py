@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 
 from gaussiank_sgd_tpu.compressors import get_compressor
+from gaussiank_sgd_tpu.ops import grouped_matmul
 from gaussiank_sgd_tpu.ops.pallas_pack import (
     _chunk_geometry, ef_padded_chunk, fused_ef_select_candidates_chunked,
     fused_select_candidates_chunked, gaussian_fused_compress_batched,
@@ -91,6 +92,33 @@ def test_fused_ef_kernel_lowers_or_gate_says_no(n_chunks, chunk, density):
                           density=density, interpret=False),
         _f32(n_chunks, cp), _f32(n_chunks, cp), _f32(),
         state=_f32(n_chunks))
+
+
+# (rows of room, hidden, width) of the experts in the four transformer
+# cells, and `expert_terms`' large side in the first (every assignment)
+EXPERT_SHAPES = [(32768, 2304, 896), (32768, 2048, 1792), (16384, 2048, 1024),
+                 (8192, 2048, 768), (131072, 2304, 896)]
+
+
+@pytest.mark.parametrize("rows,hidden,width", EXPERT_SHAPES)
+def test_grouped_products_lower_at_the_cells_shapes(rows, hidden, width):
+    """The three kernels of `ops/grouped_matmul.py` on the tiles they
+    choose, for both products of an expert (hidden to width and back),
+    each call under the name of its pass."""
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32)
+    for k, n in ((hidden, width), (width, hidden)):
+        x, g, w = (jax.ShapeDtypeStruct(s, jnp.bfloat16)
+                   for s in ((rows, k), (rows, n), (8, k, n)))
+        for fn, args, name in (
+                (grouped_matmul.grouped, (x, w, sizes), "grouped_fwd"),
+                (functools.partial(grouped_matmul.grouped, transposed=True,
+                                   name="grouped_dx"), (g, w, sizes),
+                 "grouped_dx"),
+                (grouped_matmul.grouped_by_group, (x, g, sizes),
+                 "grouped_dw")):
+            text = jax.jit(fn).trace(*args).lower(
+                lowering_platforms=("tpu",)).as_text()
+            assert "tpu_custom_call" in text and name in text
 
 
 def _loc_names(txt):
