@@ -40,7 +40,9 @@ not a capacity.
 
 Attention never builds `[S, S]`. With `kernels` (the default on a TPU) it is
 the Pallas splash-attention kernel of `jax.experimental`, which skips the
-blocks a mask leaves empty, so a window layer's work goes with S x window;
+blocks a mask leaves empty, so a window layer's work goes with S x window,
+on tiles and in the form that `splash_sizes` computes from the call's shape
+(a full layer's backward pass is ONE kernel call, a window layer's two);
 elsewhere (the CPU tests) blocks of queries against the keys their mask can
 reach.
 
@@ -122,8 +124,16 @@ def _INIT(key, shape, dtype=jnp.float32):
 
 # query rows a block of the plain attention path takes at a time
 _PLAIN_BLOCK = 128
-# splash attention's tiles on a v5e (queries x keys, forward and backward)
+# splash attention's compute tile on a v5e (queries x keys, forward and
+# backward): what one pass of the softmax's vector work covers
 _SPLASH_BLOCK = 512
+# The fused backward kernel hands dq out as one bfloat16 partial sum for each
+# memory block of keys: at most this many (each is rounded before their sum)
+_DQ_PARTS = 4
+# and no more bytes of them than this, a sixteenth of a v5e's 16 GiB
+_DQ_PARTS_BYTES = 2 ** 30
+# compute tiles of keys to a memory block of a full layer's forward kernel
+_KV_TILES = 4
 # What a recomputed layer keeps from its first forward pass: the attention
 # kernel's output and row statistics (0.4 GB a layer at the benchmark's
 # size), so that kernel does not run a second time. The experts' products do
@@ -313,6 +323,55 @@ def plain_attention(q, k, v, window: Optional[int], block: int = _PLAIN_BLOCK):
     return jnp.moveaxis(out, 0, 1).reshape(b, s, hkv, g, v.shape[-1])
 
 
+def splash_sizes(b: int, s: int, heads: int, d: int,
+                 window: Optional[int]):
+    """The kernels' `BlockSizes` from what a call shows: `b` sequences of `s`
+    positions, `heads` query heads of q/k size `d`, a window or none
+    (`PERF.md` section 6, PR 42, has each form's time alone on the chip).
+
+    A window layer: the dq and the dkv kernel, every block `_SPLASH_BLOCK`
+    square (or `s`, where that is less). Their grids shrink to the blocks
+    the window leaves, and a wider block of keys widens the span a block
+    of queries visits: no other size beat this one at windows of 1024 and
+    2048 of 8192.
+
+    A full layer: the ONE fused backward kernel (dq's product in the dkv
+    kernel: the scores, the softmax's vector work and `do v^T` once, not
+    twice), whose grid does not shrink, which costs a causal mask nothing.
+    It hands dq out as `s // block_kv_dkv` partial sums, each rounded to
+    q's dtype, so `block_kv_dkv` is the smallest memory block of up to
+    `_KV_TILES` compute tiles that leaves `_DQ_PARTS` of them or fewer in
+    `_DQ_PARTS_BYTES` or less (the compute tiles inside a visited memory
+    block are never skipped, so a smaller block wastes less of the
+    diagonal; a larger one does not fit the kernel's VMEM at every head
+    size); where there is none (64 k positions), the window layer's form.
+    The forward kernel's memory block is `_KV_TILES` compute tiles of keys
+    too: a grid step's own cost is then paid a quarter as often (13-18 %
+    of the kernel at heads of 64 to 192). A head wider than 128 lanes
+    (192) takes two lane tiles in every block the kernel holds in VMEM,
+    and the fused kernel over 512 x 512 tiles then asks for 16.07 of the
+    16 MiB it may have: such a head computes on tiles of half as many
+    keys, which costs 1-2 %."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as kernel)
+    blk = min(_SPLASH_BLOCK, s)
+    wide = [m for m in range(blk, _KV_TILES * blk + 1, blk) if s % m == 0]
+    fused = [] if window else [
+        m for m in wide if s // m <= _DQ_PARTS
+        and b * (s // m) * heads * s * d * 2 <= _DQ_PARTS_BYTES]
+    if not fused:
+        return kernel.BlockSizes(
+            block_q=blk, block_kv=blk, block_kv_compute=blk, block_q_dkv=blk,
+            block_kv_dkv=blk, block_kv_dkv_compute=blk, block_q_dq=blk,
+            block_kv_dq=blk)
+    return kernel.BlockSizes(
+        block_q=blk, block_kv=wide[-1], block_kv_compute=blk,
+        block_q_dkv=blk, block_kv_dkv=fused[0],
+        block_kv_dkv_compute=(blk // 2 if d > 128 and blk == _SPLASH_BLOCK
+                              else blk),
+        use_fused_bwd_kernel=True)
+
+
 def splash_attention(q, k, v, window: Optional[int]):
     """The same by the Pallas splash-attention kernel, forward and backward;
     the blocks a mask leaves empty are never visited. Where a key/value
@@ -320,17 +379,14 @@ def splash_attention(q, k, v, window: Optional[int]):
     its G query heads against the one key/value head (`make_splash_mqa`).
     Where every query head has a key/value head of its own (G = 1): one
     call a sequence over all heads (`make_splash_mha`). The kernel takes
-    the values' head size from `v`."""
+    the values' head size from `v`, its tiles and the form of its backward
+    pass from `splash_sizes`."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel, splash_attention_mask as masks)
     b, s, hkv, g, d = q.shape
-    blk = min(_SPLASH_BLOCK, s)
     mask = (masks.LocalMask((s, s), (window - 1, 0), 0) if window
             else masks.CausalMask((s, s)))
-    sizes = kernel.BlockSizes(
-        block_q=blk, block_kv=blk, block_kv_compute=blk, block_q_dkv=blk,
-        block_kv_dkv=blk, block_kv_dkv_compute=blk, block_q_dq=blk,
-        block_kv_dq=blk)
+    sizes = splash_sizes(b, s, hkv * g, d, window)
     if g == 1:
         call = kernel.make_splash_mha_single_device(
             masks.MultiHeadMask([mask] * hkv), block_sizes=sizes,

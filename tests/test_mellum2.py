@@ -4,9 +4,12 @@ key/value heads of 16, 8 experts top-2, window 8, 32 positions, 4 layers
 (`benchmarks/reference/mellum2_12b_a2p5b.py`, which imports nothing of the
 program) and against direct formulas."""
 
+import contextlib
+import functools
 import json
 import math
 import os
+import signal
 
 import jax
 import jax.numpy as jnp
@@ -182,6 +185,132 @@ def test_blocked_attention_is_softmax_over_the_masked_scores(window):
     p = jax.nn.softmax(jnp.where(ok, scores, -jnp.inf), axis=-1)
     want = jnp.einsum("bhgqk,bkhd->bqhgd", p, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+# (sequences, positions, query heads, q/k head size, window): the four
+# transformer cells' attention layers, then the shapes the CPU tests lower
+SPLASH_SHAPES = {
+    "joyai_mla_dp1": (2, 8192, 32, 192, None),
+    "mellum2_moe_dp1.full": (2, 8192, 32, 128, None),
+    "mellum2_moe_dp1.window": (2, 8192, 32, 128, 1024),
+    "trinity_gated_dp1.window": (2, 8192, 32, 128, 2048),
+    "lfm2_conv_dp1": (2, 8192, 32, 64, None),
+    "lowered.full": (2, 1024, 8, 128, None),
+    "lowered.window": (2, 1024, 8, 128, 512),
+    "lowered.mha": (2, 1024, 4, 192, None),
+    "short.full": (2, 256, 4, 64, None),
+    "short.window": (2, 64, 4, 16, 32),
+    "many_sequences": (64, 8192, 32, 192, None),
+    "long": (1, 65536, 32, 128, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SPLASH_SHAPES))
+def test_the_attention_kernels_sizes_follow_the_calls_shape(case):
+    """Every block divides the positions and every compute block its memory
+    block; a window layer keeps the two backward kernels over tiles no
+    wider than 512 keys; a full layer takes the fused one with four partial
+    sums of dq or fewer, in no more than a sixteenth of the chip's memory,
+    out of memory blocks of four compute tiles or fewer, and the two
+    kernels where no such block leaves that few."""
+    b, s, heads, d, window = SPLASH_SHAPES[case]
+    sizes = mellum2.splash_sizes(b, s, heads, d, window)
+    memory = {"block_q": sizes.block_q, "block_kv": sizes.block_kv,
+              "block_q_dkv": sizes.block_q_dkv,
+              "block_kv_dkv": sizes.block_kv_dkv}
+    tile = min(512, s)
+
+    def held(block_kv_dkv):
+        return b * (s // block_kv_dkv) * heads * s * d * 2
+
+    if sizes.use_fused_bwd_kernel:
+        assert window is None
+        assert sizes.block_q_dq is None and sizes.block_kv_dq is None
+        assert s // sizes.block_kv_dkv <= 4
+        assert held(sizes.block_kv_dkv) <= 16 * 2 ** 30 // 16
+        assert max(sizes.block_kv, sizes.block_kv_dkv) <= 4 * tile
+        # a head of two lane tiles computes on half as many keys
+        assert sizes.block_kv_dkv_compute == (
+            256 if d > 128 and s >= 512 else tile)
+    else:
+        assert window or all(
+            s // m > 4 or held(m) > 2 ** 30
+            for m in range(tile, 4 * tile + 1, tile) if s % m == 0)
+        memory.update(block_q_dq=sizes.block_q_dq,
+                      block_kv_dq=sizes.block_kv_dq)
+        assert set(memory.values()) == {tile}
+        assert sizes.block_kv_dkv_compute == tile
+    assert sizes.use_fused_bwd_kernel == (case not in (
+        "mellum2_moe_dp1.window", "trinity_gated_dp1.window",
+        "lowered.window", "short.window", "many_sequences", "long"))
+    if s == 8192 and sizes.use_fused_bwd_kernel:      # the cells' full layers
+        assert (sizes.block_kv, sizes.block_kv_dkv) == (2048, 2048)
+    assert sizes.has_backward_blocks and sizes.block_kv_compute == tile
+    for name, block in memory.items():
+        assert 0 < block <= s and s % block == 0, (name, block)
+    assert sizes.block_kv % sizes.block_kv_compute == 0
+    assert sizes.block_kv_dkv % sizes.block_kv_dkv_compute == 0
+
+
+@contextlib.contextmanager
+def time_limit(seconds):
+    """The body raises `TimeoutError` after `seconds` of wall time."""
+    def late(*_):
+        raise TimeoutError(f"over its limit of {seconds} s")
+    before = signal.signal(signal.SIGALRM, late)
+    signal.setitimer(signal.ITIMER_REAL, seconds)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, before)
+
+
+@pytest.mark.parametrize("kv_heads,group,d,dv", [
+    (2, 1, 192, 128), (1, 4, 128, 128)], ids=["mha_192_128", "mqa_128"])
+def test_the_fused_backward_kernel_is_as_near_the_float32_gradients(
+        kv_heads, group, d, dv, monkeypatch):
+    """512 positions in tiles of 128, so that a full layer's fused kernel
+    hands dq out as FOUR rounded partial sums, under the library's
+    interpreter: dq, dk and dv lie no further from `plain_attention`'s
+    float32 gradients than 1.5 times the two kernels' distance over the
+    same tiles (dk and dv are the same arithmetic in both)."""
+    from jax.experimental.pallas import tpu as pltpu
+    monkeypatch.setattr(mellum2, "_SPLASH_BLOCK", 128)
+    s = 512
+    fused = mellum2.splash_sizes(1, s, kv_heads * group, d, None)
+    assert fused.use_fused_bwd_kernel and s // fused.block_kv_dkv == 4
+    two = mellum2.splash_sizes(1, s, kv_heads * group, d, s)  # a window's
+    assert not two.use_fused_bwd_kernel and two.block_kv_dq == 128
+    key = jax.random.key(42)
+    q, k, v, do = (jax.random.normal(jax.random.fold_in(key, i), shape)
+                   for i, shape in enumerate((
+                       (1, s, kv_heads, group, d), (1, s, kv_heads, d),
+                       (1, s, kv_heads, dv), (1, s, kv_heads, group, dv))))
+    q = q * d ** -0.5
+
+    def gradients(attention, *args):
+        out, back = jax.vjp(lambda *qkv: attention(*qkv, None), *args[:3])
+        return back(args[3].astype(out.dtype))
+
+    with jax.default_matmul_precision("highest"):
+        want = gradients(mellum2.plain_attention, q, k, v, do)
+    low = [x.astype(jnp.bfloat16) for x in (q, k, v, do)]
+
+    def distances(sizes):
+        monkeypatch.setattr(mellum2, "splash_sizes", lambda *_: sizes)
+        got = jax.jit(functools.partial(
+            gradients, mellum2.splash_attention))(*low)
+        return [float(jnp.linalg.norm((a.astype(jnp.float32) - b).ravel())
+                      / jnp.linalg.norm(b.ravel()))
+                for a, b in zip(got, want)]
+
+    with time_limit(120), pltpu.force_tpu_interpret_mode():
+        one_kernel, two_kernels = distances(fused), distances(two)
+    for name, a, b in zip(("dq", "dk", "dv"), one_kernel, two_kernels):
+        assert 0 < b < 0.01, (name, b)     # bfloat16 products, no more
+        assert a <= 1.5 * b, (name, a, b)
+    assert one_kernel[1:] == two_kernels[1:]
 
 
 def test_default_rotary_against_the_direct_formula():

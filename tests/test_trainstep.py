@@ -445,24 +445,31 @@ def test_kernel_mode_comes_from_the_mesh_not_the_default_backend(monkeypatch):
     assert build("topk")[0].kernel_mode == "none"      # no kernel at all
 
 
-def test_tpu_mesh_never_gets_an_interpreted_kernel(monkeypatch):
-    """The converse, on a device-less TPU topology (libtpu compiles for a
-    chip this host does not have): default backend CPU, mesh TPU — the step
-    reports ``mosaic`` and lowers to exactly one Mosaic call. Before PR 21
-    the interpreted kernel was inlined into the TPU program, silently."""
+@pytest.fixture
+def v5e(monkeypatch):
+    """A device-less v5e:2x2 (libtpu compiles for a chip this host does not
+    have); the tests that compile for it live in this ONE file."""
     pytest.importorskip("libtpu")
     from jax.experimental import topologies
-    from jax.sharding import Mesh
 
     # no metadata server to ask, and no chip to guard with libtpu's
     # one-process lockfile: nothing here creates a TPU client
     monkeypatch.setenv("TPU_SKIP_MDS_QUERY", "1")
     monkeypatch.setenv("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
     try:
-        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+        return topologies.get_topology_desc("v5e:2x2", "tpu")
     except jax.errors.JaxRuntimeError as e:
         pytest.skip(f"no device-less TPU topology on this host: {e}")
-    mesh = Mesh(np.array(topo.devices[:2]), ("dp",))
+
+
+def test_tpu_mesh_never_gets_an_interpreted_kernel(v5e):
+    """The converse, on a device-less TPU topology: default backend CPU,
+    mesh TPU — the step reports ``mosaic`` and lowers to exactly one Mosaic
+    call. Before PR 21 the interpreted kernel was inlined into the TPU
+    program, silently."""
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(v5e.devices[:2]), ("dp",))
     assert jax.default_backend() == "cpu"
     params, loss_fn, make_batch = make_problem()
     ts = build_dp_train_step(
@@ -475,6 +482,37 @@ def test_tpu_mesh_never_gets_an_interpreted_kernel(monkeypatch):
     batch = jax.eval_shape(lambda: make_batch(64))
     text = ts.sparse_step.lower(state, batch).as_text()
     assert text.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("kv_heads,group,d,dv,window", [
+    (32, 1, 192, 128, None), (4, 8, 128, 128, None), (8, 4, 64, 64, None),
+    (4, 8, 128, 128, 1024), (4, 8, 128, 128, 2048)],
+    ids=["joyai_mla_dp1", "full_128", "lfm2_conv_dp1", "window_1024",
+         "window_2048"])
+def test_the_attention_kernels_compile_at_the_cells_shapes(
+        v5e, kv_heads, group, d, dv, window):
+    """The tiles that `models/mellum2.splash_sizes` computes fit the
+    kernels' 16 MiB of VMEM at the four transformer cells' shapes, forward
+    and backward: Mosaic's own verdict, which lowering does not ask for
+    (a full layer's fused backward kernel at 512 query rows, 2048 keys and
+    heads of 192 was refused by 76 KiB)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from gaussiank_sgd_tpu.models import mellum2
+
+    def both(q, k, v, do):
+        out, back = jax.vjp(
+            lambda *qkv: mellum2.splash_attention(*qkv, window), q, k, v)
+        return out, back(do)
+
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    b, s = 2, 8192
+    avals = [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+             for shape in ((b, s, kv_heads, group, d), (b, s, kv_heads, d),
+                           (b, s, kv_heads, dv), (b, s, kv_heads, group, dv))]
+    text = jax.jit(both).lower(*avals).compile().as_text()
+    assert ("dq_no_residuals" in text) == bool(window)
+    assert "dkv_no_residuals" in text and "fwd_residuals" in text
 
 
 def test_init_state_is_created_under_the_steps_shardings():
