@@ -16,7 +16,7 @@ Layer map (TPU-native; compare SURVEY.md §1.1):
     compression                 -> gaussiank_sgd_tpu.compressors
     comms backend               -> XLA collectives over the ICI/DCN device mesh
                                    (gaussiank_sgd_tpu.parallel.{mesh,collectives})
-    hot select kernel           -> gaussiank_sgd_tpu.ops.pallas_select
+    hot select kernel           -> gaussiank_sgd_tpu.ops.pallas_pack
 """
 
 __version__ = "0.1.0"

@@ -7,7 +7,7 @@ error-feedback-accumulated gradient as N(mu, sigma^2), derive the selection
 threshold from the inverse Gaussian tail CDF so that P(|x| > t) ~= density,
 then refine with a bounded number of adjustment iterations. Cost is O(n)
 reductions + a mask — no sort — which is exactly what the TPU VPU wants; the
-fused single-pass version lives in ops/pallas_select.py.
+fused single-pass version lives in ops/pallas_pack.py.
 """
 
 from __future__ import annotations

@@ -158,13 +158,6 @@ def get_compressor(name: str, *, density: float = 0.001,
         return CompressorSpec("gaussian_fused", fn, False, True,
                               lambda k: k, stateful=True, batched_fn=bfn,
                               fused_ef_fn=effn, ef_pad=epad, pallas=True)
-    if name in ("gaussian_pallas", "gaussianp"):
-        # same selection contract as 'gaussian', threshold found by the
-        # 3-pass Pallas kernel estimator (ops/pallas_select.py, SURVEY §7
-        # stage 6) instead of the ~13-pass XLA mean/std+bisection composite
-        from ..ops.pallas_select import pallas_gaussian_compress
-        return CompressorSpec("gaussian_pallas", pallas_gaussian_compress,
-                              False, True, lambda k: k, pallas=True)
     if name == "randomk":
         return CompressorSpec("randomk", randomk_compress, True, False,
                               lambda k: k)
@@ -184,8 +177,8 @@ def get_compressor(name: str, *, density: float = 0.001,
 
 
 NAMES = ("none", "topk", "approxtopk", "approxtopk16", "gaussian",
-         "gaussian_warm", "gaussian_fused", "gaussian_pallas", "randomk",
-         "randomkec", "dgcsampling", "redsync", "redsynctrim")
+         "gaussian_warm", "gaussian_fused", "randomk", "randomkec",
+         "dgcsampling", "redsync", "redsynctrim")
 
 
 # --- THE ex-ante default selector policy (VERDICT r3 item 2) -------------

@@ -48,35 +48,72 @@ _IMAGENET = (224, 224, 3)
 _MNIST = (28, 28, 1)
 
 
+# images: name -> (class, one example's shape, the classes where none are
+# given); `resnet<depth>` is built from its name
+_IMAGE_MODELS = {
+    "resnet50": (ResNet50, _IMAGENET, 1000),
+    "vgg16": (VGG16, _CIFAR, 10),
+    "alexnet": (AlexNet, _CIFAR, 10),
+    "mnistnet": (MnistNet, _MNIST, 10),
+}
+_CIFAR_DEPTHS = (20, 32, 44, 56, 110)       # the reference's `resnet<depth>`
+
+
+# the language models built from `blocks/`: name -> (class, the published
+# vocabulary's rows, the weight of its multi-token-prediction module's loss
+# or, where the model has no such module and takes no `mtp_lambda`, None).
+# `vocab_size` is the rows held, `seq_len` only sizes the init input
+_BLOCK_MODELS = {
+    # sparse experts, window and full attention mixed (models/mellum2.py)
+    "mellum2": (Mellum2, 98304, None),
+    # latent attention, a sigmoid router with a shared expert, a leading
+    # dense layer, a multi-token-prediction module (models/joyai_flash.py).
+    # DeepSeek-V3 (arXiv:2412.19437, whose keys the config carries)
+    # weighs the module's loss 0.3; the config itself gives no weight
+    "joyai_flash": (JoyAIFlash, 129280, 0.3),
+    # gated short convolutions and grouped-query attention with normed
+    # heads three to one, a sigmoid router, the embedding for a head
+    # (models/lfm2_moe.py)
+    "lfm2_moe": (LFM2MoE, 65536, None),
+    # window attention under rotary positions and full attention under
+    # none three to one, a sigmoid gate on the attention's output, four
+    # norms a layer, a sigmoid router with a shared expert, leading
+    # dense layers (models/afmoe.py)
+    "afmoe": (Afmoe, 200192, None),
+}
+_ALIASES = {"mnist": "mnistnet", "transformerlm": "transformer_lm"}
+
+
+def _block_model(name: str, dtype, kw) -> ModelSpec:
+    cls, vocab, mtp_lambda = _BLOCK_MODELS[name]
+    vocab = kw.pop("vocab_size", vocab)
+    seq_len = kw.pop("seq_len", 128)
+    if mtp_lambda is not None:
+        mtp_lambda = kw.pop("mtp_lambda", mtp_lambda)
+    if kw.get("layer_types") is not None:
+        kw["layer_types"] = tuple(kw["layer_types"])
+    m = cls(vocab_size=vocab, dtype=dtype, **kw)
+    if mtp_lambda is None or not m.num_nextn_predict_layers:
+        mtp_lambda = 0.0
+    return ModelSpec(name, m, (seq_len,), jnp.int32, vocab, "lm",
+                     counters=True, mtp_lambda=mtp_lambda)
+
+
 def get_model(dnn: str, dataset: Optional[str] = None, *,
               num_classes: Optional[int] = None,
               dtype=jnp.float32, **kw) -> ModelSpec:
-    dnn = dnn.lower()
+    dnn = _ALIASES.get(dnn.lower(), dnn.lower())
     # **kw forwards to every module ctor (e.g. width/dropout overrides via
     # TrainConfig.model_kwargs) — never silently dropped
+    image = _IMAGE_MODELS.get(dnn)
     if dnn.startswith("resnet") and dnn != "resnet50":
-        depth = int(dnn[len("resnet"):])
-        nc = num_classes or (100 if dataset == "cifar100" else 10)
-        kw.setdefault("depth", depth)
-        return ModelSpec(dnn, CifarResNet(num_classes=nc, dtype=dtype, **kw),
-                         _CIFAR, jnp.float32, nc, "classify")
-    if dnn == "resnet50":
-        nc = num_classes or 1000
-        return ModelSpec(dnn, ResNet50(num_classes=nc, dtype=dtype, **kw),
-                         _IMAGENET, jnp.float32, nc, "classify")
-    if dnn == "vgg16":
-        nc = num_classes or 10
-        return ModelSpec(dnn, VGG16(num_classes=nc, dtype=dtype, **kw),
-                         _CIFAR, jnp.float32, nc, "classify")
-    if dnn == "alexnet":
-        nc = num_classes or 10
-        return ModelSpec(dnn, AlexNet(num_classes=nc, dtype=dtype, **kw),
-                         _CIFAR, jnp.float32, nc, "classify")
-    if dnn in ("mnistnet", "mnist"):
-        nc = num_classes or 10
-        return ModelSpec("mnistnet", MnistNet(num_classes=nc, dtype=dtype,
-                                              **kw),
-                         _MNIST, jnp.float32, nc, "classify")
+        kw.setdefault("depth", int(dnn[len("resnet"):]))
+        image = (CifarResNet, _CIFAR, 100 if dataset == "cifar100" else 10)
+    if image:
+        cls, shape, nc = image
+        nc = num_classes or nc
+        return ModelSpec(dnn, cls(num_classes=nc, dtype=dtype, **kw), shape,
+                         jnp.float32, nc, "classify")
     if dnn == "lstm":  # PTB language model (SURVEY.md §2 C8)
         vocab = kw.pop("vocab_size", 10000)
         m = LSTMLM(vocab_size=vocab, dtype=dtype, **kw)
@@ -91,7 +128,7 @@ def get_model(dnn: str, dataset: Optional[str] = None, *,
         m = Transformer(vocab_size=vocab, dtype=dtype, **kw)
         return ModelSpec("transformer", m, (seq_len,), jnp.int32, vocab,
                          "seq2seq")
-    if dnn in ("transformer_lm", "transformerlm"):
+    if dnn == "transformer_lm":
         # decoder-only LM with optional ring-attention sequence parallelism
         # (long-context path; models/transformer_lm.py)
         vocab = kw.pop("vocab_size", 32000)
@@ -99,62 +136,14 @@ def get_model(dnn: str, dataset: Optional[str] = None, *,
         m = TransformerLM(vocab_size=vocab, dtype=dtype, **kw)
         return ModelSpec("transformer_lm", m, (seq_len,), jnp.int32, vocab,
                          "lm")
-    if dnn == "mellum2":
-        # sparse experts, window and full attention mixed (models/mellum2.py);
-        # `vocab_size` is the rows held, `seq_len` only sizes the init input
-        vocab = kw.pop("vocab_size", 98304)
-        seq_len = kw.pop("seq_len", 128)
-        if kw.get("layer_types") is not None:
-            kw["layer_types"] = tuple(kw["layer_types"])
-        m = Mellum2(vocab_size=vocab, dtype=dtype, **kw)
-        return ModelSpec("mellum2", m, (seq_len,), jnp.int32, vocab, "lm",
-                         counters=True)
-    if dnn == "joyai_flash":
-        # latent attention, a sigmoid router with a shared expert, a leading
-        # dense layer, a multi-token-prediction module
-        # (models/joyai_flash.py); `vocab_size` is the rows held
-        vocab = kw.pop("vocab_size", 129280)
-        seq_len = kw.pop("seq_len", 128)
-        # DeepSeek-V3 (arXiv:2412.19437, whose keys the config carries)
-        # weighs the module's loss 0.3; the config itself gives no weight
-        mtp_lambda = kw.pop("mtp_lambda", 0.3)
-        m = JoyAIFlash(vocab_size=vocab, dtype=dtype, **kw)
-        return ModelSpec("joyai_flash", m, (seq_len,), jnp.int32, vocab,
-                         "lm", counters=True, mtp_lambda=(
-                             mtp_lambda if m.num_nextn_predict_layers
-                             else 0.0))
-    if dnn == "lfm2_moe":
-        # gated short convolutions and grouped-query attention with normed
-        # heads three to one, a sigmoid router, the embedding for a head
-        # (models/lfm2_moe.py); `vocab_size` is the rows held
-        vocab = kw.pop("vocab_size", 65536)
-        seq_len = kw.pop("seq_len", 128)
-        if kw.get("layer_types") is not None:
-            kw["layer_types"] = tuple(kw["layer_types"])
-        m = LFM2MoE(vocab_size=vocab, dtype=dtype, **kw)
-        return ModelSpec("lfm2_moe", m, (seq_len,), jnp.int32, vocab, "lm",
-                         counters=True)
-    if dnn == "afmoe":
-        # window attention under rotary positions and full attention under
-        # none three to one, a sigmoid gate on the attention's output, four
-        # norms a layer, a sigmoid router with a shared expert, leading
-        # dense layers (models/afmoe.py); `vocab_size` is the rows held
-        vocab = kw.pop("vocab_size", 200192)
-        seq_len = kw.pop("seq_len", 128)
-        if kw.get("layer_types") is not None:
-            kw["layer_types"] = tuple(kw["layer_types"])
-        m = Afmoe(vocab_size=vocab, dtype=dtype, **kw)
-        return ModelSpec("afmoe", m, (seq_len,), jnp.int32, vocab, "lm",
-                         counters=True)
+    if dnn in _BLOCK_MODELS:
+        return _block_model(dnn, dtype, kw)
     raise ValueError(f"unknown dnn {dnn!r}; known: {', '.join(NAMES)}")
 
 
-NAMES = ("resnet20", "resnet32", "resnet44", "resnet56", "resnet110",
-         "resnet50", "vgg16", "alexnet", "mnistnet", "lstm", "lstman4",
-         "transformer", "transformer_lm", "mellum2", "joyai_flash",
-         "lfm2_moe", "afmoe")
+NAMES = (*(f"resnet{d}" for d in _CIFAR_DEPTHS), *_IMAGE_MODELS, "lstm",
+         "lstman4", "transformer", "transformer_lm", *_BLOCK_MODELS)
 # the names `get_model` takes (aliases included) whose head is a vocabulary:
 # the trainer hands them the data set's cardinality as `vocab_size`
 TOKEN_MODELS = frozenset(("lstm", "transformer", "transformer_lm",
-                          "transformerlm", "mellum2", "joyai_flash",
-                          "lfm2_moe", "afmoe"))
+                          "transformerlm", *_BLOCK_MODELS))

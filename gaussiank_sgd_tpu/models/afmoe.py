@@ -38,14 +38,14 @@ a RMSNorm and the head.
 `layer_types` names the layers held here, in order (a pipeline stage holds
 some of the published 32), the first `num_dense_layers` of them with the
 dense MLP. One chip's share, attention (`Attention(gate=True,
-positions=...)`), the experts' layer, RMSNorm and rematerialisation are
-`models/mellum2.py`'s, imported.
+positions=...)`), the experts' layer, RMSNorm, the head and
+rematerialisation are `models/blocks/`'s, imported.
 
 Device scopes: `attn_window`, `attn_full`, `attn_proj` (with `qk_norm`,
 `attn_gate` and, in a window layer only, `rope` inside it), `moe_router`,
 `moe_experts`, `moe_shared`, `dense_mlp`, `lm_head`, `embed` (the scale
 inside it), `rms_norm` (all four norms, the final one too). Counters as
-`mellum2`'s and `attn_gate_mean`: the mean of the gate's sigmoid over
+`blocks/experts.py`'s and `attn_gate_mean`: the mean of the gate's sigmoid over
 tokens, heads and layers (0.5 at seeded weights; a gate that saturates
 shows there before it shows in the loss).
 """
@@ -59,9 +59,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from .mellum2 import (_INIT, _PERIOD, _SAVED, FULL, SLIDING, Attention,
-                      Experts, GatedMLP, RMSNorm, model_counters, own_fields,
-                      rope_inv_freq)
+from .blocks.attention import FULL, PERIOD, SLIDING, Attention, recomputed
+from .blocks.common import RMSNorm, own_fields, untied_head
+from .blocks.experts import Experts, GatedMLP, model_counters
+from .blocks.rope import rope_inv_freq
 
 _SUM_EPS = 1e-20        # in the chosen scores' sum (`route_norm`)
 
@@ -127,7 +128,7 @@ class Afmoe(nn.Module):
     def __call__(self, tokens, train: bool = True,
                  return_counters: bool = False):
         # tokens int32 [B, S] -> logits float32 [B, S, vocab_size]
-        kinds = tuple(self.layer_types or _PERIOD * (self.num_layers // 4 + 1)
+        kinds = tuple(self.layer_types or PERIOD * (self.num_layers // 4 + 1)
                       )[:self.num_layers]
         if len(kinds) != self.num_layers or set(kinds) - {SLIDING, FULL}:
             raise ValueError(f"{self.num_layers} layers, layer_types "
@@ -144,8 +145,7 @@ class Afmoe(nn.Module):
             if self.mup_enabled:
                 x = x * math.sqrt(self.hidden_size)
             x = x.astype(self.dtype)
-        layer = nn.remat(Layer, policy=jax.checkpoint_policies
-                         .save_only_these_names(_SAVED))
+        layer = recomputed(Layer)
         widths = own_fields(self)
         per_layer, gate_means = [], []
         for i, kind in enumerate(kinds):
@@ -157,12 +157,7 @@ class Afmoe(nn.Module):
             if not dense:
                 per_layer.append(counters)
         x = RMSNorm(self.rms_norm_eps, self.dtype, name="norm")(x)
-        with jax.named_scope("lm_head"):
-            head = self.param("lm_head", _INIT,
-                              (self.hidden_size, self.vocab_size),
-                              jnp.float32)
-            logits = jnp.dot(x, head.astype(self.dtype),
-                             preferred_element_type=jnp.float32)
+        logits = untied_head(self, x)
         if not return_counters:
             return logits
         counters = model_counters(per_layer) if per_layer else {}

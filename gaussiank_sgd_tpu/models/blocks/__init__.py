@@ -1,0 +1,46 @@
+"""What the decoder-only language models of `models/` are built from, one
+module a decision: `rope` (how positions turn q and k), `attention` (how
+attention is tiled and masked, what a recomputed layer keeps), `experts`
+(how tokens reach their experts and come back), `common` (what all of them
+share). A model file imports blocks and never another model; a block
+imports no model (`tests/test_layering.py`).
+
+Beyond what Mellum 2's published config uses, the blocks offer the models
+that share them: a shared expert, sigmoid scores with a selection bias, a
+scale and a constant in the weights' sum (`Experts`, `GatedMLP`),
+adjacent-pair rotary (`apply_rope`), values of a head size of their own and
+a key/value head for every query head (`plain_attention`,
+`splash_attention`), a norm over each q and k head before the turn
+(`Attention(qk_norm=True)`, under the scope `qk_norm` inside `attn_proj`),
+a layer without positions (`Attention(positions=False)`: q and k are not
+turned, only scaled, and the layer opens no `rope` scope), a sigmoid gate
+on the attention's output (`Attention(gate=True)`, `gated_output`, under
+the scope `attn_gate` inside `attn_proj`). Which model sets which field:
+
+    field                              mellum2  joyai_flash  lfm2_moe  afmoe
+    Attention  qk_norm                 -        (own MLA)    yes       yes
+               positions=False         -        (own MLA)    -         full layers
+               gate                    -        (own MLA)    -         yes
+    Experts    scoring                 softmax  sigmoid      sigmoid   sigmoid
+               select_bias             -        yes          yes       yes
+               scale                   1        2.5          1         2.826
+               sum_eps                 0        0            1e-6      1e-20
+               shared_width            0        768          0         1024
+    GatedMLP   leading dense layers    -        1            2         2
+
+Device scopes (`jax.named_scope`; `benchmarks/model_scopes.py` reads the
+first five, `benchmarks/scope_tree.py` the whole path): `attn_window`,
+`attn_full`, `moe_router`, `moe_experts`, `lm_head`; inside `moe_experts`
+`moe_to_rows`, `moe_to_tokens`, `moe_gate`, `moe_product_glue` and, around
+the products' kernels `grouped_fwd`, `grouped_dx`, `grouped_dw` and their
+schedule, `moe_product` (a name no reader lists: its time is `moe_experts`'
+own); inside `moe_router` `moe_route_sort`; `attn_proj` (the four
+projections, not around attention proper) with `rope` inside it (the rotary
+turn whole, `rope.py`); `rms_norm` (every instance); the models' own `embed`.
+A new scope goes INSIDE the one a metric reads (docs/OBSERVABILITY.md,
+"Device scopes").
+Counters (returned with `return_counters=True`, logged through the loss
+function's auxiliary output): `moe_held_assignments`, `moe_room_used`,
+`moe_load_max_over_mean`, `moe_tokens_unserved`; a model with gated
+attention adds `attn_gate_mean` (`models/afmoe.py`).
+"""

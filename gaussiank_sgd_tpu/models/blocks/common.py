@@ -1,0 +1,69 @@
+"""What every block and every model built from them shares: the weights'
+initialiser, the RMSNorm, whether the Pallas kernels run, a model's fields
+handed to its layers, the untied head."""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+import types
+from typing import Any, Optional
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+
+def INIT(key, shape, dtype=jnp.float32):
+    """normal(0, 0.02), drawn flat and folded to `shape`: entry for entry
+    the draw of `shape` itself (the generator counts entries, not rows),
+    from a program the TPU compiler is done with in 0.7 s where a draw of
+    three axes takes it 3.3 (`[2048, 32, 128]`) to 9.3 (`[8, 2048, 1024]`);
+    a model's init program is mostly such draws."""
+    return nn.initializers.normal(0.02)(key, (math.prod(shape),),
+                                        dtype).reshape(shape)
+
+
+def use_kernels(kernels: Optional[bool]) -> bool:
+    """`kernels` where it is given; else whether the process's default
+    backend is a TPU."""
+    return jax.default_backend() == "tpu" if kernels is None else kernels
+
+
+def rms_normed(x, scale, eps: float, dtype):
+    """`x / rms(x) * scale` over the last axis in float32, rounded once."""
+    x = x.astype(jnp.float32)
+    x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+    return (x * scale).astype(dtype)
+
+
+class RMSNorm(nn.Module):
+    eps: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        with jax.named_scope("rms_norm"):
+            return rms_normed(x, scale, self.eps, self.dtype)
+
+
+def own_fields(module: nn.Module) -> types.SimpleNamespace:
+    """A model's own fields as a namespace for its layers: a module may not
+    be another's field, so the layers get the numbers."""
+    return types.SimpleNamespace(**{
+        f.name: getattr(module, f.name) for f in dataclasses.fields(module)
+        if f.name not in ("parent", "name")})
+
+
+def untied_head(model: nn.Module, x):
+    """`x @ lm_head`, float32 logits under the device scope `lm_head`: the
+    leaf `lm_head` [hidden_size, vocab_size] of `model` (float32, multiplied
+    as `model.dtype`). For a model that takes its head once."""
+    with jax.named_scope("lm_head"):
+        head = model.param("lm_head", INIT,
+                           (model.hidden_size, model.vocab_size), jnp.float32)
+        return jnp.dot(x, head.astype(model.dtype),
+                       preferred_element_type=jnp.float32)

@@ -41,22 +41,22 @@ the step program compiles one such layer, not one a layer (PERF.md section
 
 One chip's share, the experts' layer, its grouped products without dropped
 tokens, the attention paths (splash attention on a TPU, blocks of queries
-elsewhere), RMSNorm, the rotary helper and rematerialisation are
-`models/mellum2.py`'s, imported.
+elsewhere), RMSNorm, the rotary helper, the head and rematerialisation are
+`models/blocks/`'s, imported.
 
 Device scopes: `attn_mla` (softmax(q k^T) v and its backward), `mla_proj`
 (the five products, the two inner norms, the rotary) and inside it `mla_q`,
 `mla_kv`, `mla_out` (the products and norms of each path) and `mla_assemble`
-(q and k put together, `mellum2.apply_rope`'s `rope` inside it: one fused
+(q and k put together, `rope.apply_rope`'s `rope` inside it: one fused
 pass over the whole 192-wide q, whose first 128 lanes pass through, with the
 scale and the one rounding, and one over the shared key part, against one
-set of angles that `mellum2.rope_table` lays out once on the host, at q's
+set of angles that `rope.rope_table` lays out once on the host, at q's
 192 lanes and at the key part's 64),
-`moe_router`, `moe_experts` (with `mellum2.py`'s scopes inside both),
+`moe_router`, `moe_experts` (with `blocks/experts.py`'s scopes inside both),
 `moe_shared`, `dense_mlp`, `lm_head`, `mtp`, `embed`, `rms_norm` from
-`mellum2.RMSNorm`, and `layer_scan` around the scanned layers (what lies
+`common.RMSNorm`, and `layer_scan` around the scanned layers (what lies
 directly under it is the loop's own slicing and stacking). Counters as
-`mellum2`'s: `moe_held_assignments`, `moe_room_used`,
+`blocks/experts.py`'s: `moe_held_assignments`, `moe_room_used`,
 `moe_load_max_over_mean`, `moe_tokens_unserved`.
 """
 
@@ -68,9 +68,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from .mellum2 import (_INIT, _SAVED, Experts, GatedMLP, RMSNorm, apply_rope,
-                      model_counters, own_fields, plain_attention,
-                      rope_inv_freq, splash_attention, use_kernels)
+from .blocks.attention import plain_attention, recomputed, splash_attention
+from .blocks.common import INIT, RMSNorm, own_fields, use_kernels
+from .blocks.experts import Experts, GatedMLP, model_counters
+from .blocks.rope import apply_rope, rope_inv_freq
 
 
 class LatentAttention(nn.Module):
@@ -85,7 +86,7 @@ class LatentAttention(nn.Module):
 
         def proj(name, features, axis=-1):
             return nn.DenseGeneral(features, axis=axis, use_bias=False,
-                                   dtype=m.dtype, kernel_init=_INIT,
+                                   dtype=m.dtype, kernel_init=INIT,
                                    name=name)
 
         with jax.named_scope("mla_proj"):
@@ -183,10 +184,9 @@ class JoyAIFlash(nn.Module):
         embed = nn.Embed(self.vocab_size, self.hidden_size, dtype=self.dtype,
                          embedding_init=nn.initializers.normal(1.0),
                          name="embed")
-        layer = nn.remat(Layer, policy=jax.checkpoint_policies
-                         .save_only_these_names(_SAVED))
+        layer = recomputed(Layer)
         widths = own_fields(self)
-        head = self.param("lm_head", _INIT,
+        head = self.param("lm_head", INIT,
                           (self.hidden_size, self.vocab_size), jnp.float32)
 
         def logits_of(x):
@@ -226,7 +226,7 @@ class JoyAIFlash(nn.Module):
                     RMSNorm(self.rms_norm_eps, self.dtype,
                             name="mtp_hidden_norm")(x)], axis=-1)
                 u = nn.Dense(self.hidden_size, use_bias=False,
-                             dtype=self.dtype, kernel_init=_INIT,
+                             dtype=self.dtype, kernel_init=INIT,
                              name="mtp_proj")(both)
                 u, counters = layer(widths, False, name="mtp_block")(u)
                 per_layer.append(counters)

@@ -38,7 +38,7 @@ gradient by both paths.
 some of the published 24), the first `num_dense_layers` of them with the
 dense MLP. One chip's share, the experts' layer, attention (splash
 attention on a TPU, blocks of queries elsewhere), RMSNorm, the rotary
-helper and rematerialisation are `models/mellum2.py`'s, imported.
+helper and rematerialisation are `models/blocks/`'s, imported.
 
 The taps are shifted multiply-adds, not a convolution primitive and not a
 kernel: XLA fuses them with both gates into one pass over `[tokens, 2048]`
@@ -48,9 +48,9 @@ is so that every shift is of an input).
 Device scopes: `short_conv` around the mixer with `conv_in_proj`,
 `conv_gate` (both gates and the taps) and `conv_out_proj` beneath it;
 `attn_full` and `attn_proj` (`qk_norm` and `rope` inside it) from
-`mellum2.Attention`; `moe_router`, `moe_experts` (with `mellum2.py`'s
+`attention.Attention`; `moe_router`, `moe_experts` (with `blocks/experts.py`'s
 scopes inside both), `dense_mlp`, `lm_head`, `embed`, `rms_norm` from
-`mellum2.RMSNorm`. Counters as `mellum2`'s: `moe_held_assignments`,
+`common.RMSNorm`. Counters as `blocks/experts.py`'s: `moe_held_assignments`,
 `moe_room_used`, `moe_load_max_over_mean`, `moe_tokens_unserved`.
 """
 
@@ -63,10 +63,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .mellum2 import (_INIT, _SAVED, Attention, Experts, GatedMLP, RMSNorm,
-                      model_counters, own_fields, rope_inv_freq)
+from .blocks.attention import FULL, Attention, recomputed
+from .blocks.common import INIT, RMSNorm, own_fields
+from .blocks.experts import Experts, GatedMLP, model_counters
+from .blocks.rope import rope_inv_freq
 
-CONV, FULL = "conv", "full_attention"
+CONV = "conv"
 _SUM_EPS = 1e-6         # in the chosen scores' sum (`norm_topk_prob`)
 # the published pattern: an attention layer after every two or three
 # convolution layers, six of them in 24
@@ -156,16 +158,16 @@ class ShortConv(nn.Module):
         with jax.named_scope("short_conv"):
             with jax.named_scope("conv_in_proj"):
                 bcx = nn.Dense(3 * hidden, use_bias=False, dtype=m.dtype,
-                               kernel_init=_INIT, name="in_proj")(x)
+                               kernel_init=INIT, name="in_proj")(x)
             with jax.named_scope("conv_gate"):
-                kernel = self.param("taps", _INIT, (hidden, m.conv_taps),
+                kernel = self.param("taps", INIT, (hidden, m.conv_taps),
                                     jnp.float32)
                 # a pass of its own: left to itself XLA runs it inside
                 # `out_proj`'s product, under that product's name
                 y = lax.optimization_barrier(gated_taps(bcx, kernel))
             with jax.named_scope("conv_out_proj"):
                 return nn.Dense(hidden, use_bias=False, dtype=m.dtype,
-                                kernel_init=_INIT, name="out_proj")(y)
+                                kernel_init=INIT, name="out_proj")(y)
 
 
 class Layer(nn.Module):
@@ -234,8 +236,7 @@ class LFM2MoE(nn.Module):
                          name="embed")
         with jax.named_scope("embed"):
             x = embed(tokens)
-        layer = nn.remat(Layer, policy=jax.checkpoint_policies
-                         .save_only_these_names(_SAVED))
+        layer = recomputed(Layer)
         widths = own_fields(self)
         per_layer = []
         for i, kind in enumerate(kinds):

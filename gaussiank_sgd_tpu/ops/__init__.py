@@ -1,8 +1,3 @@
-"""TPU kernels (Pallas) for the hot compression ops (SURVEY.md §7 stage 6)."""
-
-from .pallas_select import (fused_stats, multi_threshold_counts,
-                            pallas_gaussian_compress,
-                            pallas_threshold_estimate)
-
-__all__ = ["fused_stats", "multi_threshold_counts",
-           "pallas_gaussian_compress", "pallas_threshold_estimate"]
+"""TPU kernels (Pallas): the fused select+pack of the hot compression path
+(`pallas_pack`, SURVEY.md §7 stage 6) and the experts' grouped products
+(`grouped_matmul`)."""

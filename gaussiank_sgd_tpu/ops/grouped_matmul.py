@@ -1,5 +1,5 @@
 """Grouped matrix products as Pallas kernels whose tiles are computed from
-the operands' shapes: the experts' three products of `models/mellum2.py`
+the operands' shapes: the experts' three products of `models/blocks/experts.py`
 on the TPU (PR 41), where XLA's own kernel for `lax.ragged_dot` takes no
 tile sizes from its caller and cuts an expert of 2304 x 896 badly.
 
@@ -11,7 +11,7 @@ The rows of group e are the `sizes[e]` rows after those of the groups
 before it. The schedule is that of megablox (`jax.experimental.pallas.ops.
 tpu.megablox`): a row tile is visited once for every group that has rows in
 it and no tile past the last group's end is visited at all, so the rows
-past it are NOT written (the caller zeroes them: `mellum2._live_rows`).
+past it are NOT written (the caller zeroes them: `experts._live_rows`).
 What differs from megablox's `gmm` and `tgmm`: the schedule is a dozen
 operations on arrays of `row tiles + groups` entries (`_schedule`); the
 tiles come from `tiles` / `tiles_by_group` (whole contraction and whole

@@ -10,10 +10,10 @@ memory analysis, and what the program does AFTER the gradient as a count of
 work: the bytes that the optimized HLO's operations read and write under each
 of the step's own scopes, in passes over one n-vector (``passes_by_scope``;
 ROADMAP S10's list is sized from this), and for a model that names its parts
-which of its scopes the compiled program carries on forward, recomputed and
-backward instructions (``instructions_by_scope_and_pass``). The optimizer is
-the cells' (momentum 0.9 and a weight decay), so the program compiled is the
-one a cell runs.
+(the language models built from ``models/blocks/``) which of its scopes the
+compiled program carries on forward, recomputed and backward instructions
+(``instructions_by_scope_and_pass``). The optimizer is the cells' (momentum
+0.9 and a weight decay), so the program compiled is the one a cell runs.
 
 It proves COMPILATION ONLY. It runs nothing: not start-up, not placement, not
 numerics, not time. Those are chip_smoke.py's, on the chip.

@@ -18,7 +18,9 @@ import pytest
 
 from benchmarks.reference import trinity_mini as ref
 from gaussiank_sgd_tpu import models
-from gaussiank_sgd_tpu.models import afmoe, get_model, mellum2
+from gaussiank_sgd_tpu.models import afmoe, get_model
+from gaussiank_sgd_tpu.models.blocks import attention, common, rope
+from gaussiank_sgd_tpu.models.blocks import experts as moe
 from gaussiank_sgd_tpu.training.losses import make_loss_fn
 from test_joyai_flash import as_tree, by_path, shapes_of
 
@@ -227,8 +229,8 @@ def test_the_stream_starts_at_the_scaled_embedding(batch):
 
 
 def _attention(window, positions, **kw):
-    inv = mellum2.rope_inv_freq(16, 10000.0)
-    return mellum2.Attention(4, 2, 16, window, tuple(inv.tolist()), 1.0,
+    inv = rope.rope_inv_freq(16, 10000.0)
+    return attention.Attention(4, 2, 16, window, tuple(inv.tolist()), 1.0,
                              False, jnp.float32, qk_norm=True,
                              qk_norm_eps=1e-5, positions=positions, **kw)
 
@@ -352,7 +354,7 @@ def test_the_accepted_models_keep_their_parameters(model, count, paths):
 
 def _layer(share, shares, experts, top, kind, dense=False):
     model = tiny(share, shares, experts=experts, top=top)[0].module
-    return afmoe.Layer(mellum2.own_fields(model),
+    return afmoe.Layer(common.own_fields(model),
                        WINDOW if kind == SLIDING else None, dense)
 
 
@@ -431,7 +433,7 @@ def test_the_chosen_scores_sum_with_the_constant_and_the_scale():
            "route_norm": True, "score_func": "sigmoid"}
     chosen, gates = ref.gates(x, router, bias, cfg)
     scores = jax.nn.sigmoid(x @ router)
-    mine = mellum2.route(scores, 8, 0, 128, scores + bias, 2.826, 1e-20)
+    mine = moe.route(scores, 8, 0, 128, scores + bias, 2.826, 1e-20)
     group = np.empty(64 * 8, np.int64)
     group[np.asarray(mine[1])] = np.repeat(np.arange(128),
                                            np.asarray(mine[3]))
@@ -560,7 +562,7 @@ def test_the_attention_kernels_lower_for_the_tpu_without_positions():
         (2, s, kv_heads, 128))]
     for window in (None, 512):
         def loss(q, k, v):
-            return jnp.sum(mellum2.splash_attention(q, k, v, window)
+            return jnp.sum(attention.splash_attention(q, k, v, window)
                            .astype(jnp.float32))
         text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(
             *avals).lower(lowering_platforms=("tpu",)).as_text()

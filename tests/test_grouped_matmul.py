@@ -1,9 +1,9 @@
 """`ops/grouped_matmul.py` (PR 41): the experts' three grouped products as
 Pallas kernels on tiles computed from the shape, here under the Pallas
 interpreter, against `lax.ragged_dot` and against a plain product a group
-at a time in float32; `models/mellum2.grouped_product` and the expert layer
-with the kernels against the same without; the tile chooser at the four
-transformer cells' shapes."""
+at a time in float32; `models/blocks/experts.grouped_product` and the expert
+layer with the kernels against the same without; the tile chooser at the
+four transformer cells' shapes."""
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +12,7 @@ import pytest
 from jax import lax
 from jax.experimental.pallas import tpu as pltpu
 
-from gaussiank_sgd_tpu.models import mellum2
+from gaussiank_sgd_tpu.models.blocks import experts as moe
 from gaussiank_sgd_tpu.ops import grouped_matmul as gm
 
 # name: (rows of room, contraction, width, the groups' rows, tiles or None
@@ -83,7 +83,7 @@ def test_a_product_against_ragged_dot_and_the_plain_formula(case, which):
         want = _plain(g, w, sizes, transposed=True)
     else:
         got = gm.grouped_by_group(x, g, sizes, tiling=tiling, interpret=True)
-        same = lax.ragged_dot_general(x, g, sizes, mellum2._BY_GROUP,
+        same = lax.ragged_dot_general(x, g, sizes, moe._BY_GROUP,
                                       preferred_element_type=jnp.float32)
         want = _plain_by_group(x, g, sizes)
         assert got.dtype == jnp.float32 and got.shape == w.shape
@@ -107,7 +107,7 @@ def test_grouped_product_with_the_kernels_is_the_one_without(case):
 
     def both(kernels):
         def loss(x, w):
-            y = mellum2.grouped_product(x, w, sizes, kernels)
+            y = moe.grouped_product(x, w, sizes, kernels)
             return jnp.sum((y * g).astype(jnp.float32)), y
         (_, y), (dx, dw) = jax.value_and_grad(
             loss, argnums=(0, 1), has_aux=True)(x, w)
@@ -115,7 +115,7 @@ def test_grouped_product_with_the_kernels_is_the_one_without(case):
 
     with pltpu.force_tpu_interpret_mode():
         assert "grouped_dw" in str(jax.make_jaxpr(
-            lambda x, w: jax.grad(lambda *a: jnp.sum(mellum2.grouped_product(
+            lambda x, w: jax.grad(lambda *a: jnp.sum(moe.grouped_product(
                 *a, sizes, True).astype(jnp.float32)), argnums=1)(x, w))(x, w))
         got = both(True)
     want = both(False)
@@ -148,7 +148,7 @@ def test_the_expert_layer_with_the_kernels_on_either_side_of_its_room(
         params[name] = jnp.asarray(0.1 * rng.normal(size=shape), jnp.float32)
 
     def run(kernels):
-        layer = mellum2.Experts(experts, top, width, 0, shares, jnp.float32,
+        layer = moe.Experts(experts, top, width, 0, shares, jnp.float32,
                                 kernels=kernels)
 
         def loss(p):

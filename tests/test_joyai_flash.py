@@ -16,7 +16,9 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import joyai_llm_flash as ref
-from gaussiank_sgd_tpu.models import NAMES, get_model, joyai_flash, mellum2
+from gaussiank_sgd_tpu.models import NAMES, get_model, joyai_flash
+from gaussiank_sgd_tpu.models.blocks import attention, common, rope
+from gaussiank_sgd_tpu.models.blocks import experts as moe
 from gaussiank_sgd_tpu.training.losses import make_loss_fn
 
 VOCAB, POSITIONS, MTP_LAMBDA = 50, 32, 0.3
@@ -240,7 +242,7 @@ def test_selection_follows_score_plus_bias_and_weights_the_scores_alone():
                                         jnp.float32))
     bias = jnp.asarray(0.5 * rng.normal(size=(16,)), jnp.float32)
     top, scale = 4, 2.5
-    weights, order, inverse, sizes, served = mellum2.route(
+    weights, order, inverse, sizes, served = moe.route(
         scores, top, 0, 16, scores + bias, scale)
     want = np.argsort(-np.asarray(scores + bias), axis=-1)[:, :top]
     plain = np.argsort(-np.asarray(scores), axis=-1)[:, :top]
@@ -262,7 +264,7 @@ def test_selection_follows_score_plus_bias_and_weights_the_scores_alone():
     router = jnp.asarray(rng.normal(size=(8, 16)), jnp.float32)
     chosen, gates = ref.gates(x, router, bias, {
         "num_experts_per_tok": top, "routed_scaling_factor": scale})
-    mine = mellum2.route(jax.nn.sigmoid(x @ router), top, 0, 16,
+    mine = moe.route(jax.nn.sigmoid(x @ router), top, 0, 16,
                          jax.nn.sigmoid(x @ router) + bias, scale)
     group[np.asarray(mine[1])] = np.repeat(np.arange(16),
                                            np.asarray(mine[3]))
@@ -273,9 +275,9 @@ def test_selection_follows_score_plus_bias_and_weights_the_scores_alone():
 
 def test_adjacent_pair_rotary_against_the_direct_formula():
     d, theta = 8, 32e6
-    inv = mellum2.rope_inv_freq(d, theta)
+    inv = rope.rope_inv_freq(d, theta)
     x = np.random.default_rng(0).normal(size=(1, 6, 2, d)).astype(np.float32)
-    got = np.asarray(mellum2.apply_rope(jnp.asarray(x), inv,
+    got = np.asarray(rope.apply_rope(jnp.asarray(x), inv,
                                         interleave=True))
     for s in range(6):
         for j in range(d // 2):
@@ -291,9 +293,9 @@ def test_adjacent_pair_rotary_against_the_direct_formula():
         got, np.asarray(ref.rotate_adjacent(jnp.asarray(x), theta)),
         atol=1e-6)
     y = np.random.default_rng(1).normal(size=x.shape).astype(np.float32)
-    halves = [np.asarray(mellum2.apply_rope(jnp.asarray(np.concatenate(
+    halves = [np.asarray(rope.apply_rope(jnp.asarray(np.concatenate(
         [t[..., 0::2], t[..., 1::2]], -1)), inv)) for t in (x, y)]
-    got_y = np.asarray(mellum2.apply_rope(jnp.asarray(y), inv,
+    got_y = np.asarray(rope.apply_rope(jnp.asarray(y), inv,
                                           interleave=True))
     np.testing.assert_allclose((got * got_y).sum(-1),
                                (halves[0] * halves[1]).sum(-1), atol=1e-5)
@@ -314,16 +316,16 @@ def test_a_whole_head_turned_is_its_rotary_part_turned():
     the first 16 in float32 beside the last 8 turned, times the scale,
     rounded; and the cotangent likewise."""
     rng = np.random.default_rng(3)
-    inv = mellum2.rope_inv_freq(8, 32e6)
+    inv = rope.rope_inv_freq(8, 32e6)
     x = jnp.asarray(rng.normal(size=(2, POSITIONS, 4, 24)), jnp.bfloat16)
     g = jnp.asarray(rng.normal(size=x.shape), jnp.bfloat16)
 
     def whole(x):
-        return mellum2.apply_rope(x, inv, interleave=True,
+        return rope.apply_rope(x, inv, interleave=True,
                                   out_scale=24 ** -0.5, dtype=jnp.bfloat16)
 
     def parts(x):
-        turned = mellum2.apply_rope(x[..., 16:], inv, interleave=True)
+        turned = rope.apply_rope(x[..., 16:], inv, interleave=True)
         return (jnp.concatenate([x[..., :16].astype(jnp.float32), turned],
                                 axis=-1) * 24 ** -0.5).astype(jnp.bfloat16)
 
@@ -393,9 +395,9 @@ def test_blocked_attention_takes_values_of_their_own_head_size():
     q = jnp.asarray(rng.normal(size=(2, POSITIONS, 4, 1, 24)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(2, POSITIONS, 4, 24)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(2, POSITIONS, 4, 16)), jnp.float32)
-    got = mellum2.plain_attention(q, k, v, None, block=8)
+    got = attention.plain_attention(q, k, v, None, block=8)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k)
-    ok = mellum2.allowed(jnp.arange(POSITIONS), jnp.arange(POSITIONS), None)
+    ok = attention.allowed(jnp.arange(POSITIONS), jnp.arange(POSITIONS), None)
     want = jnp.einsum("bhgqk,bkhd->bqhgd", jax.nn.softmax(
         jnp.where(ok, scores, -jnp.inf), axis=-1), v)
     assert got.shape == (2, POSITIONS, 4, 1, 16)
@@ -404,7 +406,7 @@ def test_blocked_attention_takes_values_of_their_own_head_size():
 
 def _layer(share, shares, experts, top):
     model = tiny(share, shares, experts=experts, top=top)[0].module
-    return joyai_flash.Layer(mellum2.own_fields(model), False)
+    return joyai_flash.Layer(common.own_fields(model), False)
 
 
 @pytest.mark.parametrize("experts,top,shares", [
@@ -528,13 +530,13 @@ def test_the_attention_kernels_lower_for_the_tpu_at_the_published_head_sizes():
     `tests/test_kernel_lowering.py` does; the numbers are the chip's to
     prove, by the cell's `correct`)."""
     def loss(q, k, v):
-        return jnp.sum(mellum2.splash_attention(q, k, v, None)
+        return jnp.sum(attention.splash_attention(q, k, v, None)
                        .astype(jnp.float32))
 
     s, heads = 1024, 4
     avals = [jax.ShapeDtypeStruct(shape, jnp.bfloat16) for shape in (
         (2, s, heads, 1, 192), (2, s, heads, 192), (2, s, heads, 128))]
-    out = jax.eval_shape(mellum2.splash_attention, *avals, None)
+    out = jax.eval_shape(attention.splash_attention, *avals, None)
     assert out.shape == (2, s, heads, 1, 128)
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(*avals).lower(
         lowering_platforms=("tpu",)).as_text()

@@ -193,11 +193,3 @@ def test_above_the_density_ceiling_the_gate_refuses_before_lowering():
     # ... and the registry builds (and names) the XLA selector instead
     spec = get_compressor("gaussian_fused", density=0.0625)
     assert spec.name == "gaussian_fused(warm-fallback)" and not spec.pallas
-
-
-def test_threshold_estimator_kernels_lower():
-    from gaussiank_sgd_tpu.ops.pallas_select import pallas_gaussian_compress
-
-    assert _lowers_with_kernel(
-        functools.partial(pallas_gaussian_compress, k=270, interpret=False),
-        _f32(269_722))

@@ -56,7 +56,8 @@ KNOWN_BACK_EDGES = {
 
 
 def _imports(path, rel):
-    """(absolute dotted module, line) of every import in ``path``."""
+    """(absolute dotted module, line, the names taken from it) of every
+    import in ``path``."""
     with open(path, encoding="utf-8") as fh:
         tree = ast.parse(fh.read(), filename=path)
     # the package a relative import starts from
@@ -64,17 +65,18 @@ def _imports(path, rel):
     for node in ast.walk(tree):
         if isinstance(node, ast.Import):
             for a in node.names:
-                yield a.name, node.lineno
+                yield a.name, node.lineno, []
         elif isinstance(node, ast.ImportFrom):
+            names = [a.name for a in node.names]
             if not node.level:
-                yield node.module, node.lineno
+                yield node.module, node.lineno, names
                 continue
             base = here[:len(here) - (node.level - 1)]
             if node.module:
-                yield ".".join(base + [node.module]), node.lineno
+                yield ".".join(base + [node.module]), node.lineno, names
             else:
-                for a in node.names:
-                    yield ".".join(base + [a.name]), node.lineno
+                for name in names:
+                    yield ".".join(base + [name]), node.lineno, []
 
 
 def _scan(sub):
@@ -84,7 +86,7 @@ def _scan(sub):
             if name.endswith(".py"):
                 path = os.path.join(root, name)
                 rel = os.path.relpath(path, PKG_DIR).replace(os.sep, "/")
-                for module, line in _imports(path, rel):
+                for module, line, _ in _imports(path, rel):
                     yield rel, line, module.split(".")
 
 
@@ -107,3 +109,40 @@ def test_a_subpackage_imports_only_along_the_arrows(sub):
     # a known debt that was repaired leaves the list
     assert back_edges == {e for e in KNOWN_BACK_EDGES
                           if e[0].startswith(sub + "/")}
+
+
+# ---- inside `models/`: blocks below, models above, and no private names
+MODELS_DIR = os.path.join(PKG_DIR, "models")
+MODEL_FILES = sorted(n for n in os.listdir(MODELS_DIR)
+                     if n.endswith(".py") and n != "__init__.py")
+BLOCK_FILES = sorted("blocks/" + n
+                     for n in os.listdir(os.path.join(MODELS_DIR, "blocks"))
+                     if n.endswith(".py"))
+# a debt, not design: the seed's decoder-only LM takes `MLP` and
+# `sinusoidal_positions` from the seed's transformer
+KNOWN_MODEL_IMPORTS = {("transformer_lm.py", "transformer")}
+
+
+@pytest.mark.parametrize("rel", MODEL_FILES + BLOCK_FILES)
+def test_a_model_file_imports_blocks_and_never_another_model(rel):
+    """Under `models/` a block imports blocks, a model file imports blocks
+    (`__init__.py` alone imports the models), and a name that crosses a
+    file is public: no `from ... import _name`."""
+    models = {n[:-len(".py")] for n in MODEL_FILES}
+    assert "mellum2" in models and "blocks/attention.py" in BLOCK_FILES
+    imports_a_model, private, known = [], [], set()
+    for module, line, names in _imports(os.path.join(MODELS_DIR, rel),
+                                        "models/" + rel):
+        parts = module.split(".")
+        if parts[:2] != [PKG, "models"] or len(parts) < 3:
+            continue
+        target = "/".join(parts[2:])
+        if (rel, target) in KNOWN_MODEL_IMPORTS:
+            known.add((rel, target))
+        elif parts[2] in models:
+            imports_a_model.append(f"models/{rel}:{line} imports {target}")
+        private += [f"models/{rel}:{line} takes {n} from {target}"
+                    for n in names if n.startswith("_")]
+    assert not imports_a_model, imports_a_model
+    assert not private, private
+    assert known == {e for e in KNOWN_MODEL_IMPORTS if e[0] == rel}

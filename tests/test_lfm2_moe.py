@@ -18,7 +18,9 @@ import pytest
 
 from benchmarks.reference import lfm2_8b_a1b as ref
 from gaussiank_sgd_tpu import models
-from gaussiank_sgd_tpu.models import get_model, lfm2_moe, mellum2
+from gaussiank_sgd_tpu.models import get_model, lfm2_moe
+from gaussiank_sgd_tpu.models.blocks import attention, common, rope
+from gaussiank_sgd_tpu.models.blocks import experts as moe
 from gaussiank_sgd_tpu.training.losses import make_loss_fn
 from test_joyai_flash import as_tree, by_path, shapes_of
 
@@ -247,12 +249,12 @@ def _logits_from_rows(spec, tree, rows):
     """The model's logits with the embedding's rows given: its layers, its
     final norm and its head applied by hand from the model's own modules."""
     m = spec.module
-    widths = mellum2.own_fields(m)
+    widths = common.own_fields(m)
     x = rows
     for i, kind in enumerate(m.layer_types):
         x, _ = lfm2_moe.Layer(widths, kind, i < m.num_dense_layers).apply(
             {"params": tree[f"layers_{i}"]}, x)
-    x = mellum2.RMSNorm(m.rms_norm_eps, m.dtype).apply(
+    x = common.RMSNorm(m.rms_norm_eps, m.dtype).apply(
         {"params": tree["embedding_norm"]}, x)
     return jnp.einsum("bsh,vh->bsv", x, tree["embed"]["embedding"])
 
@@ -328,8 +330,8 @@ def test_normed_heads_against_the_direct_formula():
          if p.startswith("attn/")}
     w["q_layernorm"] = jnp.asarray(rng.uniform(0.5, 1.5, d), jnp.float32)
     w["k_layernorm"] = jnp.asarray(rng.uniform(0.5, 1.5, d), jnp.float32)
-    inv = mellum2.rope_inv_freq(d, 1e6)
-    got = mellum2.Attention(
+    inv = rope.rope_inv_freq(d, 1e6)
+    got = attention.Attention(
         heads, kv_heads, d, None, tuple(inv.tolist()), 1.0, False,
         jnp.float32, qk_norm=True, qk_norm_eps=eps).apply(
             {"params": as_tree(w)}, x)
@@ -337,7 +339,7 @@ def test_normed_heads_against_the_direct_formula():
     def normed(v, scale):       # [S, d]
         return v / np.sqrt((v * v).mean(-1, keepdims=True) + eps) * scale
 
-    def rope(v):                # [S, d]: pairs (j, j + d/2)
+    def turn(v):                # [S, d]: pairs (j, j + d/2)
         out = np.array(v)
         for j in range(d // 2):
             ang = np.arange(s) * 1e6 ** (-2 * j / d)
@@ -352,8 +354,8 @@ def test_normed_heads_against_the_direct_formula():
         xb = np.asarray(x[b], np.float64)
         for i in range(heads):
             j = i // (heads // kv_heads)
-            q = rope(normed(xb @ w["q_proj/kernel"][:, i], w["q_layernorm"]))
-            k = rope(normed(xb @ w["k_proj/kernel"][:, j], w["k_layernorm"]))
+            q = turn(normed(xb @ w["q_proj/kernel"][:, i], w["q_layernorm"]))
+            k = turn(normed(xb @ w["k_proj/kernel"][:, j], w["k_layernorm"]))
             scores = q @ k.T / math.sqrt(d)
             scores[np.triu_indices(s, 1)] = -np.inf
             p = np.exp(scores - scores.max(-1, keepdims=True))
@@ -362,7 +364,7 @@ def test_normed_heads_against_the_direct_formula():
                 @ w["o_proj/kernel"][i]
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5)
     # the norm bites: without it the layer answers otherwise
-    plain = mellum2.Attention(
+    plain = attention.Attention(
         heads, kv_heads, d, None, tuple(inv.tolist()), 1.0, False,
         jnp.float32).apply({"params": as_tree({
             p: v for p, v in w.items() if "layernorm" not in p})}, x)
@@ -371,7 +373,7 @@ def test_normed_heads_against_the_direct_formula():
 
 def test_without_normed_heads_the_attention_has_its_old_parameters():
     shapes = jax.eval_shape(
-        lambda x: mellum2.Attention(4, 2, 16, None, (1.0,) * 8, 1.0, False,
+        lambda x: attention.Attention(4, 2, 16, None, (1.0,) * 8, 1.0, False,
                                     jnp.float32).init(
                                         jax.random.PRNGKey(0), x),
         jnp.zeros((1, 8, 64)))["params"]
@@ -384,7 +386,7 @@ def test_selection_follows_score_plus_bias_and_weights_the_scores_alone():
                                         jnp.float32))
     bias = jnp.asarray(0.5 * rng.normal(size=(32,)), jnp.float32)
     top = 4
-    weights, order, inverse, sizes, served = mellum2.route(
+    weights, order, inverse, sizes, served = moe.route(
         scores, top, 0, 32, scores + bias, 1.0, 1e-6)
     want = np.argsort(-np.asarray(scores + bias), axis=-1)[:, :top]
     plain = np.argsort(-np.asarray(scores), axis=-1)[:, :top]
@@ -410,7 +412,7 @@ def test_selection_follows_score_plus_bias_and_weights_the_scores_alone():
     router = jnp.asarray(rng.normal(size=(8, 32)), jnp.float32)
     chosen, gates = ref.gates(x, router, bias, {
         "num_experts_per_tok": top, "routed_scaling_factor": 1})
-    mine = mellum2.route(jax.nn.sigmoid(x @ router), top, 0, 32,
+    mine = moe.route(jax.nn.sigmoid(x @ router), top, 0, 32,
                          jax.nn.sigmoid(x @ router) + bias, 1.0, 1e-6)
     group[np.asarray(mine[1])] = np.repeat(np.arange(32),
                                            np.asarray(mine[3]))
@@ -421,7 +423,7 @@ def test_selection_follows_score_plus_bias_and_weights_the_scores_alone():
 
 def _layer(share, shares, experts, top, kind="conv"):
     model = tiny(share, shares, experts=experts, top=top)[0].module
-    return lfm2_moe.Layer(mellum2.own_fields(model), kind, False)
+    return lfm2_moe.Layer(common.own_fields(model), kind, False)
 
 
 @pytest.mark.parametrize("experts,top,shares,kind", [
@@ -587,14 +589,14 @@ def test_the_attention_kernels_lower_for_the_tpu_at_heads_of_64():
     without a chip, as `tests/test_kernel_lowering.py` does; the numbers
     are the chip's to prove, by the cell's `correct`)."""
     def loss(q, k, v):
-        return jnp.sum(mellum2.splash_attention(q, k, v, None)
+        return jnp.sum(attention.splash_attention(q, k, v, None)
                        .astype(jnp.float32))
 
     s, kv_heads, group = 1024, 2, 4
     avals = [jax.ShapeDtypeStruct(shape, jnp.bfloat16) for shape in (
         (2, s, kv_heads, group, 64), (2, s, kv_heads, 64),
         (2, s, kv_heads, 64))]
-    out = jax.eval_shape(mellum2.splash_attention, *avals, None)
+    out = jax.eval_shape(attention.splash_attention, *avals, None)
     assert out.shape == (2, s, kv_heads, group, 64)
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(*avals).lower(
         lowering_platforms=("tpu",)).as_text()

@@ -17,7 +17,9 @@ import numpy as np
 import pytest
 
 from benchmarks.reference import mellum2_12b_a2p5b as ref
-from gaussiank_sgd_tpu.models import get_model, mellum2
+from gaussiank_sgd_tpu.models import get_model
+from gaussiank_sgd_tpu.models.blocks import attention, rope
+from gaussiank_sgd_tpu.models.blocks import experts as moe
 from gaussiank_sgd_tpu.training.losses import make_loss_fn
 
 VOCAB, POSITIONS, WINDOW = 50, 32, 8
@@ -166,7 +168,7 @@ def test_the_float8_control_is_further_from_the_program_than_float32(batch):
 
 @pytest.mark.parametrize("window", [None, WINDOW, 1, POSITIONS])
 def test_mask_against_the_direct_formula(window):
-    got = np.asarray(mellum2.allowed(jnp.arange(POSITIONS),
+    got = np.asarray(attention.allowed(jnp.arange(POSITIONS),
                                      jnp.arange(POSITIONS), window))
     want = np.array([[0 <= i - j and (window is None or i - j < window)
                       for j in range(POSITIONS)] for i in range(POSITIONS)])
@@ -179,9 +181,10 @@ def test_blocked_attention_is_softmax_over_the_masked_scores(window):
     q = jnp.asarray(rng.normal(size=(2, POSITIONS, 2, 2, 16)), jnp.float32)
     k = jnp.asarray(rng.normal(size=(2, POSITIONS, 2, 16)), jnp.float32)
     v = jnp.asarray(rng.normal(size=(2, POSITIONS, 2, 16)), jnp.float32)
-    got = mellum2.plain_attention(q, k, v, window, block=8)
+    got = attention.plain_attention(q, k, v, window, block=8)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k)
-    ok = mellum2.allowed(jnp.arange(POSITIONS), jnp.arange(POSITIONS), window)
+    ok = attention.allowed(jnp.arange(POSITIONS), jnp.arange(POSITIONS),
+                           window)
     p = jax.nn.softmax(jnp.where(ok, scores, -jnp.inf), axis=-1)
     want = jnp.einsum("bhgqk,bkhd->bqhgd", p, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
@@ -214,7 +217,7 @@ def test_the_attention_kernels_sizes_follow_the_calls_shape(case):
     out of memory blocks of four compute tiles or fewer, and the two
     kernels where no such block leaves that few."""
     b, s, heads, d, window = SPLASH_SHAPES[case]
-    sizes = mellum2.splash_sizes(b, s, heads, d, window)
+    sizes = attention.splash_sizes(b, s, heads, d, window)
     memory = {"block_q": sizes.block_q, "block_kv": sizes.block_kv,
               "block_q_dkv": sizes.block_q_dkv,
               "block_kv_dkv": sizes.block_kv_dkv}
@@ -276,11 +279,11 @@ def test_the_fused_backward_kernel_is_as_near_the_float32_gradients(
     float32 gradients than 1.5 times the two kernels' distance over the
     same tiles (dk and dv are the same arithmetic in both)."""
     from jax.experimental.pallas import tpu as pltpu
-    monkeypatch.setattr(mellum2, "_SPLASH_BLOCK", 128)
+    monkeypatch.setattr(attention, "_SPLASH_BLOCK", 128)
     s = 512
-    fused = mellum2.splash_sizes(1, s, kv_heads * group, d, None)
+    fused = attention.splash_sizes(1, s, kv_heads * group, d, None)
     assert fused.use_fused_bwd_kernel and s // fused.block_kv_dkv == 4
-    two = mellum2.splash_sizes(1, s, kv_heads * group, d, s)  # a window's
+    two = attention.splash_sizes(1, s, kv_heads * group, d, s)  # a window's
     assert not two.use_fused_bwd_kernel and two.block_kv_dq == 128
     key = jax.random.key(42)
     q, k, v, do = (jax.random.normal(jax.random.fold_in(key, i), shape)
@@ -289,18 +292,18 @@ def test_the_fused_backward_kernel_is_as_near_the_float32_gradients(
                        (1, s, kv_heads, dv), (1, s, kv_heads, group, dv))))
     q = q * d ** -0.5
 
-    def gradients(attention, *args):
-        out, back = jax.vjp(lambda *qkv: attention(*qkv, None), *args[:3])
+    def gradients(attend, *args):
+        out, back = jax.vjp(lambda *qkv: attend(*qkv, None), *args[:3])
         return back(args[3].astype(out.dtype))
 
     with jax.default_matmul_precision("highest"):
-        want = gradients(mellum2.plain_attention, q, k, v, do)
+        want = gradients(attention.plain_attention, q, k, v, do)
     low = [x.astype(jnp.bfloat16) for x in (q, k, v, do)]
 
     def distances(sizes):
-        monkeypatch.setattr(mellum2, "splash_sizes", lambda *_: sizes)
+        monkeypatch.setattr(attention, "splash_sizes", lambda *_: sizes)
         got = jax.jit(functools.partial(
-            gradients, mellum2.splash_attention))(*low)
+            gradients, attention.splash_attention))(*low)
         return [float(jnp.linalg.norm((a.astype(jnp.float32) - b).ravel())
                       / jnp.linalg.norm(b.ravel()))
                 for a, b in zip(got, want)]
@@ -315,11 +318,11 @@ def test_the_fused_backward_kernel_is_as_near_the_float32_gradients(
 
 def test_default_rotary_against_the_direct_formula():
     d, theta = 16, 500000.0
-    inv = mellum2.rope_inv_freq(d, theta)
+    inv = rope.rope_inv_freq(d, theta)
     np.testing.assert_allclose(
         inv, [theta ** (-2 * i / d) for i in range(d // 2)], rtol=1e-12)
     x = np.random.default_rng(0).normal(size=(1, 6, 1, d)).astype(np.float32)
-    got = np.asarray(mellum2.apply_rope(jnp.asarray(x), inv))
+    got = np.asarray(rope.apply_rope(jnp.asarray(x), inv))
     for s in range(6):
         for i in range(d // 2):
             a, b = x[0, s, 0, i], x[0, s, 0, i + d // 2]
@@ -335,7 +338,7 @@ def test_yarn_rotary_against_the_direct_formula():
     one turn are slowed 16 times, a linear ramp between (truncated ends);
     cos and sin carry the published attention factor."""
     d, theta, factor, orig = 128, 500000.0, 16.0, 8192
-    got = mellum2.yarn_inv_freq(d, theta, factor, orig, 32.0, 1.0)
+    got = rope.yarn_inv_freq(d, theta, factor, orig, 32.0, 1.0)
 
     def pair_of(turns):
         return d * math.log(orig / (turns * 2 * math.pi)) / (
@@ -359,7 +362,7 @@ def test_yarn_rotary_against_the_direct_formula():
     assert scale == pytest.approx(0.1 * math.log(factor) + 1.0)
     x = jnp.ones((1, 3, 1, d), jnp.float32)
     np.testing.assert_allclose(
-        np.asarray(mellum2.apply_rope(x, got, scale))[0, 0, 0],
+        np.asarray(rope.apply_rope(x, got, scale))[0, 0, 0],
         np.full(d, scale), rtol=1e-6)
 
 
@@ -390,11 +393,11 @@ def test_the_turn_and_its_cotangent_against_the_pairs_formula(
     the last 64 of a 192-wide head (the rest passes through): the result in
     float32 and the cotangent against the per-pair formula's."""
     rng = np.random.default_rng(width + rot)
-    inv = mellum2.rope_inv_freq(rot, 500000.0)
+    inv = rope.rope_inv_freq(rot, 500000.0)
     x = jnp.asarray(rng.normal(size=(2, 12, 3, width)), dtype)
     g = jnp.asarray(rng.normal(size=x.shape), jnp.float32)
     got, back = jax.vjp(
-        lambda x: mellum2.apply_rope(x, inv, scale, interleave), x)
+        lambda x: rope.apply_rope(x, inv, scale, interleave), x)
     want, want_back = jax.vjp(
         lambda x: _turn_by_pairs(x, inv, scale, interleave, rot), x)
     assert got.dtype == jnp.float32
@@ -406,7 +409,7 @@ def test_the_turn_and_its_cotangent_against_the_pairs_formula(
         np.asarray(dx, np.float32), np.asarray(want_dx, np.float32),
         atol=1e-5 if dtype == jnp.float32 else 2.0 ** -6)
     # the factor and the one rounding that the attention layers ask for
-    scaled = mellum2.apply_rope(x, inv, scale, interleave, out_scale=0.25,
+    scaled = rope.apply_rope(x, inv, scale, interleave, out_scale=0.25,
                                 dtype=dtype)
     np.testing.assert_array_equal(
         np.asarray(scaled, np.float32),
@@ -430,11 +433,11 @@ def assert_one_pass_turn(shape, rot, interleave, dtype=jnp.bfloat16):
     values: nothing is compiled): no cos or sin over more than the S x D/2
     angles, and on nothing of the input's size a concatenation, a split or
     a reshape to a minor axis of 2."""
-    inv = mellum2.rope_inv_freq(rot, 500000.0)
+    inv = rope.rope_inv_freq(rot, 500000.0)
     x = jax.ShapeDtypeStruct(shape, dtype)
 
     def both(x, g):
-        y, back = jax.vjp(lambda x: mellum2.apply_rope(
+        y, back = jax.vjp(lambda x: rope.apply_rope(
             x, inv, 1.25, interleave, out_scale=0.5, dtype=dtype), x)
         return y, back(g)
 
@@ -461,7 +464,7 @@ def test_the_cells_half_split_turn_is_one_pass_at_full_width(shape):
 
 
 def _expert_layer(share, shares, experts=8, top=2):
-    return mellum2.Experts(num_experts=experts, experts_per_token=top,
+    return moe.Experts(num_experts=experts, experts_per_token=top,
                            width=32, share=share, shares=shares,
                            dtype=jnp.float32)
 
@@ -545,7 +548,7 @@ def test_no_token_is_dropped_when_every_token_goes_to_one_expert(
     router[0, list(forced)] = 10.0
     router = jnp.asarray(router, jnp.float32)
     probs = jax.nn.softmax(x.reshape(tokens, 64) @ router, axis=-1)
-    _, _, _, sizes, served = mellum2.route(probs, top, 0, held)
+    _, _, _, sizes, served = moe.route(probs, top, 0, held)
     assert [int(sizes[e]) for e in forced] == [tokens] * len(forced)
     assert bool(served.all())
     layer = _expert_layer(0, shares, experts, top)
@@ -598,15 +601,15 @@ def _sorted_by_group(group, held):
 # two blocks of tokens, each with `_ROOM` slots for its live rows, wherever
 # the sorted rows' room is SUM_CAP: twice an even load's, as the cells'
 SUM_TOP, SUM_HELD = 8, 8
-SUM_TOKENS, SUM_CAP = mellum2._ROOM, 2 * mellum2._ROOM
+SUM_TOKENS, SUM_CAP = moe._ROOM, 2 * moe._ROOM
 
 
 def _routing(case):
     """[T, top] held-expert numbers (8: absent) and the sorted rows' room."""
     rng = np.random.default_rng(29)
     tokens, top, held = SUM_TOKENS, SUM_TOP, SUM_HELD
-    per, room = tokens // 2, mellum2._ROOM
-    assert mellum2._blocks(tokens, SUM_CAP) == (2, per, room)
+    per, room = tokens // 2, moe._ROOM
+    assert moe._blocks(tokens, SUM_CAP) == (2, per, room)
     # each token's experts are distinct, as a top-k's are: 8 of 64
     group = np.stack([rng.permutation(64)[:top] for _ in range(tokens)])
     group = np.where(group < held, group, held)
@@ -661,9 +664,9 @@ def test_the_sum_back_to_tokens_against_the_dense_formula(case, dtype):
     first, live = order[:cap], inverse < jnp.sum(sizes)
     n_live = int(sizes.sum())
     assert n_live <= cap
-    used = float(mellum2.room_used(cap, top, inverse, sizes))
+    used = float(moe.room_used(cap, top, inverse, sizes))
     if case == "a_block_fuller_than_its_slots":
-        assert used == (mellum2._ROOM + 64) / mellum2._ROOM
+        assert used == (moe._ROOM + 64) / moe._ROOM
     else:
         assert used <= 1.0
     if case == "live_rows_exactly_cap":
@@ -682,7 +685,7 @@ def test_the_sum_back_to_tokens_against_the_dense_formula(case, dtype):
     r64 = np.asarray(r.astype(jnp.float32), np.float64)
     one_rounding = 2.0 ** -8 if dtype == jnp.bfloat16 else 1e-6
 
-    y, back = jax.vjp(lambda r, scale: mellum2.to_tokens(
+    y, back = jax.vjp(lambda r, scale: moe.to_tokens(
         r, scale, first, inverse, live, top), r, scale)
     assert y.dtype == jnp.float32
     np.testing.assert_allclose(np.asarray(y, np.float64), weights @ r64,
@@ -700,7 +703,7 @@ def test_the_sum_back_to_tokens_against_the_dense_formula(case, dtype):
                                atol=2e-5, rtol=2e-5)
 
     x = jnp.asarray(rng.normal(size=(tokens, h)), dtype)
-    rows, back = jax.vjp(lambda x: mellum2.to_rows(
+    rows, back = jax.vjp(lambda x: moe.to_rows(
         x, first, inverse, live, top), x)
     np.testing.assert_array_equal(
         np.asarray(rows.astype(jnp.float32)),
@@ -746,13 +749,13 @@ def test_the_cells_sum_reads_the_rows_that_are_there(which):
     rows = shape((cap, h), jnp.bfloat16)
     if which == "to_tokens":
         jaxpr = jax.make_jaxpr(
-            lambda r, scale, first, inverse, live: mellum2.to_tokens(
+            lambda r, scale, first, inverse, live: moe.to_tokens(
                 r, scale, first, inverse, live, top))(
             rows, shape((full,), jnp.float32), *index).jaxpr
     else:
         jaxpr = jax.make_jaxpr(
             lambda x, g, first, inverse, live: jax.vjp(
-                lambda x: mellum2.to_rows(x, first, inverse, live, top),
+                lambda x: moe.to_rows(x, first, inverse, live, top),
                 x)[1](g))(shape((tokens, h), jnp.bfloat16), rows,
                           *index).jaxpr
     cond, = [e for e in _equations(jaxpr) if e.primitive.name == "cond"]

@@ -202,7 +202,7 @@ def test_little_of_fwd_bwd_has_no_name_of_the_models(parsed, model):
 
 
 def test_the_gate_on_the_attentions_output_is_inside_the_projections(parsed):
-    """`attn_gate` (`mellum2.gated_output`) lies inside `attn_proj` on
+    """`attn_gate` (`attention.gated_output`) lies inside `attn_proj` on
     forward, recomputed and backward operations of every layer: the
     configuration lists it, so `model_scopes.scope_of` reads it as its own
     (`attn_gate_ms`), and `scope_tree`, which does not know the name, reads
@@ -230,7 +230,7 @@ def test_the_gate_on_the_attentions_output_is_inside_the_projections(parsed):
 def cut_room():
     """`mellum2`'s step with 2 of its 8 experts held: room for 64 sorted
     rows of the 128 assignments, so the sum back to tokens goes over the
-    rows that are there (`mellum2._summed`) on the small side of the layer's
+    rows that are there (`experts._summed`) on the small side of the layer's
     `cond` and over a row for every assignment on the other."""
     return [(n, *scope_tree.parse(n)[:2])
             for n in compiled_op_names("mellum2", expert_shares=4)]

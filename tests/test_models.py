@@ -100,8 +100,68 @@ def test_transformer_shapes():
 
 
 def test_unknown_model_raises():
-    with pytest.raises(ValueError):
+    """An unknown name's error lists `NAMES`, and `TOKEN_MODELS` holds only
+    names that `get_model` takes (an alias builds its model's spec)."""
+    with pytest.raises(ValueError) as err:
         models.get_model("resnext9000")
+    assert all(name in str(err.value) for name in models.NAMES)
+    assert len(set(models.NAMES)) == len(models.NAMES)
+    for name in models.TOKEN_MODELS:
+        assert models.get_model(name).name in models.NAMES
+
+
+# keywords that cut each family's widths to a size the CPU builds at once
+TINY = {
+    "transformer": dict(dim=32, heads=4, enc_layers=1, dec_layers=1, ffn=64,
+                        max_len=64),
+    "transformer_lm": dict(dim=32, heads=4, num_layers=1, ffn=64),
+    "lstm": dict(embed_dim=32, hidden_dim=32, num_layers=1),
+    "mellum2": dict(hidden_size=32, num_layers=1, num_heads=2,
+                    num_kv_heads=1, head_dim=16, num_experts=4,
+                    experts_per_token=2, expert_width=16,
+                    layer_types=["sliding_attention"]),
+    "joyai_flash": dict(hidden_size=32, num_layers=2, num_heads=2,
+                        q_lora_rank=16, kv_lora_rank=16, qk_nope_head_dim=8,
+                        qk_rope_head_dim=8, v_head_dim=8, dense_width=32,
+                        num_experts=4, experts_per_token=2, expert_width=16),
+    "lfm2_moe": dict(hidden_size=32, num_layers=2, num_heads=2,
+                     num_kv_heads=1, head_dim=16, dense_width=32,
+                     num_dense_layers=1, num_experts=4, experts_per_token=2,
+                     expert_width=16, layer_types=["conv", "full_attention"]),
+    "afmoe": dict(hidden_size=32, num_layers=2, num_heads=2, num_kv_heads=1,
+                  head_dim=16, dense_width=32, num_dense_layers=1,
+                  num_experts=4, experts_per_token=2, expert_width=16,
+                  layer_types=["sliding_attention", "full_attention"]),
+}
+
+
+@pytest.mark.parametrize("name", models.NAMES)
+def test_every_name_builds_its_spec(name):
+    """One row of `get_model`'s tables a case: the name builds a `ModelSpec`
+    under that name whose module initialises on one example's shape (tiny
+    widths where the family has keywords for them), a token model takes
+    `vocab_size` and hands it on as its head's rows, and a list of
+    `layer_types` reaches the module as a tuple."""
+    kw = dict(TINY.get(name, {}))
+    if name in models.TOKEN_MODELS:
+        kw["vocab_size"] = 48
+        if name != "lstm":      # its 35 positions are fixed
+            kw["seq_len"] = 16
+    spec = models.get_model(name, **kw)
+    assert spec.name == name
+    assert (spec.num_classes == 48) == (name in models.TOKEN_MODELS)
+    assert (spec.task in ("lm", "seq2seq")) == (name in models.TOKEN_MODELS)
+    if "layer_types" in kw:
+        assert spec.module.layer_types == tuple(kw["layer_types"])
+    assert spec.counters == (name in ("mellum2", "joyai_flash", "lfm2_moe",
+                                      "afmoe"))
+    assert spec.mtp_lambda == (0.3 if name == "joyai_flash" else 0.0)
+    x = jnp.zeros((2,) + spec.input_shape, spec.input_dtype)
+    inputs = (x, x) if spec.task == "seq2seq" else (x,)
+    shapes = jax.eval_shape(
+        lambda *a: spec.module.init({"params": jax.random.PRNGKey(0)}, *a,
+                                    train=False), *inputs)
+    assert _param_count(shapes) > 0
 
 
 def test_batchnorm_model_trains_with_compression():
