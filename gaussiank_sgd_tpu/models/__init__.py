@@ -20,6 +20,7 @@ from .lfm2_moe import LFM2MoE
 from .lstm import LSTMLM
 from .mellum2 import Mellum2
 from .mnistnet import MnistNet
+from .qwen3_next import Qwen3Next
 from .resnet import CifarResNet, ResNet50
 from .speech import LSTMAN4
 from .transformer import Transformer
@@ -80,6 +81,10 @@ _BLOCK_MODELS = {
     # norms a layer, a sigmoid router with a shared expert, leading
     # dense layers (models/afmoe.py)
     "afmoe": (Afmoe, 200192, None),
+    # a gated delta rule (linear attention with a carried state) and gated
+    # attention at heads of 256 three to one, zero-centred norms, a softmax
+    # router over 512 with a gated shared expert (models/qwen3_next.py)
+    "qwen3_next": (Qwen3Next, 151936, None),
 }
 _ALIASES = {"mnist": "mnistnet", "transformerlm": "transformer_lm"}
 

@@ -1,8 +1,9 @@
 """What the decoder-only language models of `models/` are built from, one
 module a decision: `rope` (how positions turn q and k), `attention` (how
-attention is tiled and masked, what a recomputed layer keeps), `experts`
-(how tokens reach their experts and come back), `common` (what all of them
-share). A model file imports blocks and never another model; a block
+attention is tiled and masked, what a recomputed layer keeps), `delta` (how
+a mixer carries a state along the sequence: the gated delta rule in chunks),
+`experts` (how tokens reach their experts and come back), `common` (what all
+of them share). A model file imports blocks and never another model; a block
 imports no model (`tests/test_layering.py`).
 
 Beyond what Mellum 2's published config uses, the blocks offer the models
@@ -15,18 +16,27 @@ a key/value head for every query head (`plain_attention`,
 a layer without positions (`Attention(positions=False)`: q and k are not
 turned, only scaled, and the layer opens no `rope` scope), a sigmoid gate
 on the attention's output (`Attention(gate=True)`, `gated_output`, under
-the scope `attn_gate` inside `attn_proj`). Which model sets which field:
+the scope `attn_gate` inside `attn_proj`), a turn of a head's FIRST entries
+(`Attention(rope_lead=True)`, `apply_rope(lead=True)`: a
+`partial_rotary_factor`), zero-centred norms (`RMSNorm(zero_centred=True)`,
+`Attention(qk_norm_zero_centred=True)`: times `1 + scale`, the scale from
+zero), a sigmoid gate on the shared expert (`Experts(shared_gate=True)`).
+Which model sets which field:
 
-    field                              mellum2  joyai_flash  lfm2_moe  afmoe
-    Attention  qk_norm                 -        (own MLA)    yes       yes
-               positions=False         -        (own MLA)    -         full layers
-               gate                    -        (own MLA)    -         yes
-    Experts    scoring                 softmax  sigmoid      sigmoid   sigmoid
-               select_bias             -        yes          yes       yes
-               scale                   1        2.5          1         2.826
-               sum_eps                 0        0            1e-6      1e-20
-               shared_width            0        768          0         1024
-    GatedMLP   leading dense layers    -        1            2         2
+    field                        mellum2  joyai_flash  lfm2_moe  afmoe        qwen3_next
+    Attention  qk_norm           -        (own MLA)    yes       yes          yes, zero-centred
+               positions=False   -        (own MLA)    -         full layers  -
+               rope_lead         -        (own MLA)    -         -            yes (64 of 256)
+               gate              -        (own MLA)    -         yes          yes
+    RMSNorm    zero_centred      -        -            -         -            yes
+    Experts    scoring           softmax  sigmoid      sigmoid   sigmoid      softmax
+               select_bias       -        yes          yes       yes          -
+               scale             1        2.5          1         2.826        1
+               sum_eps           0        0            1e-6      1e-20        0
+               shared_width      0        768          0         1024         512
+               shared_gate       -        -            -         -            yes
+    GatedMLP   leading dense     -        1            2         2            -
+    delta      GatedDeltaNet     -        -            -         -            3 layers in 4
 
 Device scopes (`jax.named_scope`; `benchmarks/model_scopes.py` reads the
 first five, `benchmarks/scope_tree.py` the whole path): `attn_window`,
@@ -42,5 +52,8 @@ A new scope goes INSIDE the one a metric reads (docs/OBSERVABILITY.md,
 Counters (returned with `return_counters=True`, logged through the loss
 function's auxiliary output): `moe_held_assignments`, `moe_room_used`,
 `moe_load_max_over_mean`, `moe_tokens_unserved`; a model with gated
-attention adds `attn_gate_mean` (`models/afmoe.py`).
+attention adds `attn_gate_mean` (`models/afmoe.py`), a gated shared expert
+`moe_shared_gate_mean`, the linear mixer `gdn_decay_mean`, `gdn_beta_mean`
+and `gdn_state_rms` (`delta.py`, which also has its scopes: `linear_attn`
+and the five inside it).
 """

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .common import INIT, rms_normed, use_kernels
+from .common import INIT, norm_scale_init, rms_normed, use_kernels
 from .rope import apply_rope
 
 # the two kinds of attention layer, as a config's `layer_types` names them
@@ -219,7 +219,9 @@ class Attention(nn.Module):
     dtype: Any
     qk_norm: bool = False           # RMSNorm over each q and k head, one
     qk_norm_eps: float = 1e-6       # learned scale each, BEFORE the turn
+    qk_norm_zero_centred: bool = False  # that norm times `1 + scale`
     positions: bool = True          # False: q and k are NOT turned
+    rope_lead: bool = False         # the turn takes a head's FIRST entries
     gate: bool = False              # `gate_proj`, hidden -> heads x head_dim:
     # its sigmoid times the attention's output, entry by entry, before
     # `o_proj`; the module then answers (output, the sigmoid's mean)
@@ -241,13 +243,17 @@ class Attention(nn.Module):
             y = proj(name + "_proj", heads)
             last = self.dtype if self.positions else jnp.float32
             if self.qk_norm:
-                scale = self.param(name + "_layernorm", nn.initializers.ones,
-                                   (d,), jnp.float32)
+                scale = self.param(
+                    name + "_layernorm",
+                    norm_scale_init(self.qk_norm_zero_centred), (d,),
+                    jnp.float32)
                 with jax.named_scope("qk_norm"):
-                    y = rms_normed(y, scale, self.qk_norm_eps, last)
+                    y = rms_normed(y, scale, self.qk_norm_eps, last,
+                                   self.qk_norm_zero_centred)
             if self.positions:
                 return apply_rope(y, self.inv_freq, self.rope_scale,
-                                  out_scale=out_scale, dtype=self.dtype)
+                                  out_scale=out_scale, dtype=self.dtype,
+                                  lead=self.rope_lead)
             return (y.astype(jnp.float32) * out_scale).astype(self.dtype)
 
         with jax.named_scope("attn_proj"):

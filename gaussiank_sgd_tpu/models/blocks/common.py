@@ -1,6 +1,7 @@
 """What every block and every model built from them shares: the weights'
-initialiser, the RMSNorm, whether the Pallas kernels run, a model's fields
-handed to its layers, the untied head."""
+initialiser, the RMSNorm (plain or zero-centred), a sequence seen some
+positions back, whether the Pallas kernels run, a model's fields handed to
+its layers, the untied head."""
 
 from __future__ import annotations
 
@@ -31,23 +32,44 @@ def use_kernels(kernels: Optional[bool]) -> bool:
     return jax.default_backend() == "tpu" if kernels is None else kernels
 
 
-def rms_normed(x, scale, eps: float, dtype):
-    """`x / rms(x) * scale` over the last axis in float32, rounded once."""
+def rms_normed(x, scale, eps: float, dtype, zero_centred: bool = False):
+    """`x / rms(x) * scale` over the last axis in float32, rounded once;
+    `zero_centred`: times `1 + scale`."""
     x = x.astype(jnp.float32)
     x = x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-    return (x * scale).astype(dtype)
+    return (x * (1.0 + scale if zero_centred else scale)).astype(dtype)
+
+
+def norm_scale_init(zero_centred: bool):
+    """A norm's scale at init: ones, or zeros where the norm multiplies by
+    `1 + scale` (weight decay then pulls the factor to 1 and not to 0)."""
+    return nn.initializers.zeros if zero_centred else nn.initializers.ones
 
 
 class RMSNorm(nn.Module):
     eps: float
     dtype: Any
+    zero_centred: bool = False      # `x / rms(x) * (1 + scale)`, scale from 0
 
     @nn.compact
     def __call__(self, x):
-        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                           jnp.float32)
+        scale = self.param("scale", norm_scale_init(self.zero_centred),
+                           (x.shape[-1],), jnp.float32)
         with jax.named_scope("rms_norm"):
-            return rms_normed(x, scale, self.eps, self.dtype)
+            return rms_normed(x, scale, self.eps, self.dtype,
+                              self.zero_centred)
+
+
+def shifted(a, back: int):
+    """`a` [B, S, w] as seen `back` positions back: entry t holds
+    `a[t - back]`, zeros before the sequence starts (after its end where
+    `back` is negative)."""
+    if back == 0:
+        return a
+    s = a.shape[1]
+    if back > 0:
+        return jnp.pad(a, ((0, 0), (back, 0), (0, 0)))[:, :s]
+    return jnp.pad(a, ((0, 0), (0, -back), (0, 0)))[:, -back:]
 
 
 def own_fields(module: nn.Module) -> types.SimpleNamespace:

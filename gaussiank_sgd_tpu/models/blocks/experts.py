@@ -437,7 +437,10 @@ class Experts(nn.Module):
     gradient is exactly zero: a selection has none). The chosen scores are
     renormalised (their sum plus `sum_eps` the divisor) and multiplied by
     `scale`. `shared_width` > 0: a gated expert of that width that every
-    token passes, added by every share."""
+    token passes, added by every share; with `shared_gate` times
+    `sigmoid(x w_g)`, one number a token (`w_g` the leaf `shared_gate`
+    [hidden], float32; under the scope `moe_shared`), whose mean is the
+    counter `moe_shared_gate_mean`."""
     num_experts: int
     experts_per_token: int
     width: int
@@ -450,6 +453,7 @@ class Experts(nn.Module):
     shared_width: int = 0
     sum_eps: float = 0.0
     kernels: Optional[bool] = None  # None: where the backend is a TPU
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -495,25 +499,40 @@ class Experts(nn.Module):
             enough = min(full, -(-2 * full // self.shares // 8) * 8)
             y = expert_terms(enough, top, use_kernels(self.kernels), x,
                              weights, order, inverse, sizes, w1, w3, w2)
+        shared_counters = {}
         if self.shared_width:
-            y = y + GatedMLP(self.shared_width, "moe_shared",
-                             name="shared")(x).astype(jnp.float32)
+            shared = GatedMLP(self.shared_width, "moe_shared",
+                              name="shared")(x).astype(jnp.float32)
+            if self.shared_gate:
+                with jax.named_scope("moe_shared"):
+                    w_g = self.param("shared_gate", INIT, (hidden,),
+                                     jnp.float32)
+                    share = jax.nn.sigmoid(jnp.sum(
+                        x.astype(jnp.float32) * w_g, axis=-1, keepdims=True))
+                    shared = shared * share
+                shared_counters["moe_shared_gate_mean"] = jnp.mean(
+                    lax.stop_gradient(share))
+            y = y + shared
         load = sizes.astype(jnp.float32)
         counters = {
             "moe_held_assignments": jnp.sum(load),
             "moe_room_used": room_used(enough, top, inverse, sizes),
             "moe_load_max_over_mean": jnp.max(load) / jnp.maximum(
                 jnp.mean(load), 1.0),
-            "moe_tokens_unserved": 1.0 - jnp.mean(served.astype(jnp.float32))}
+            "moe_tokens_unserved": 1.0 - jnp.mean(served.astype(jnp.float32)),
+            **shared_counters}
         return y.astype(self.dtype).reshape(b, s, hidden), counters
 
 
 def model_counters(per_layer):
     """The model's counters from its expert layers': the assignments held
     summed, the room used and the load of the worst layer, the unserved
-    share's mean."""
+    share's mean and, where the shared expert is gated, its gate's."""
     stacked = jax.tree.map(lambda *v: jnp.stack(v), *per_layer)
+    gate = ({"moe_shared_gate_mean": jnp.mean(stacked["moe_shared_gate_mean"])}
+            if "moe_shared_gate_mean" in stacked else {})
     return {
+        **gate,
         "moe_held_assignments": jnp.sum(stacked["moe_held_assignments"]),
         "moe_room_used": jnp.max(stacked["moe_room_used"]),
         "moe_load_max_over_mean": jnp.max(

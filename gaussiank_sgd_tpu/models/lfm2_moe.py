@@ -64,7 +64,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from .blocks.attention import FULL, Attention, recomputed
-from .blocks.common import INIT, RMSNorm, own_fields
+from .blocks.common import INIT, RMSNorm, own_fields, shifted
 from .blocks.experts import Experts, GatedMLP, model_counters
 from .blocks.rope import rope_inv_freq
 
@@ -76,18 +76,6 @@ _PUBLISHED = tuple(
     FULL if i in (2, 6, 10, 14, 18, 21) else CONV for i in range(24))
 
 
-def _at(a, back: int):
-    """`a` [B, S, w] as seen `back` positions back: entry t holds
-    `a[t - back]`, zeros before the sequence starts (after its end where
-    `back` is negative)."""
-    if back == 0:
-        return a
-    s = a.shape[1]
-    if back > 0:
-        return jnp.pad(a, ((0, 0), (back, 0), (0, 0)))[:, :s]
-    return jnp.pad(a, ((0, 0), (0, -back), (0, 0)))[:, -back:]
-
-
 def _gate_parts(bcx, back: int = 0):
     """`B`, `C`, `x` in float32 from `bcx = [B | C | x]`, as seen `back`
     positions back. The shift is of `bcx` ITSELF, before anything is
@@ -95,7 +83,7 @@ def _gate_parts(bcx, back: int = 0):
     it, and writes a shifted intermediate (a converted part, the product
     `B * x`) out in float32 first."""
     return (part.astype(jnp.float32)
-            for part in jnp.split(_at(bcx, back), 3, axis=-1))
+            for part in jnp.split(shifted(bcx, back), 3, axis=-1))
 
 
 def _seen(bcx, back: int):
@@ -136,7 +124,7 @@ def _gated_taps_bwd(res, g):
     for d in range(taps):
         _, later_out, _ = _gate_parts(bcx, -d)
         dv = dv + kernel[:, taps - 1 - d] * (
-            _at(g, -d).astype(jnp.float32) * later_out)
+            shifted(g, -d).astype(jnp.float32) * later_out)
     g = g.astype(jnp.float32)
     dc = g * gate_out
     dkernel = jnp.stack([jnp.sum(dc * seen[taps - 1 - j], axis=(0, 1))

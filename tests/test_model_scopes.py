@@ -55,7 +55,15 @@ MODELS = {
                      "full_attention", "sliding_attention"),
         num_dense_layers=1, dense_width=96, num_heads=4, num_kv_heads=2,
         head_dim=16, sliding_window=8, num_experts=8, experts_per_token=2,
-        expert_width=32, expert_share=0, expert_shares=2)}
+        expert_width=32, expert_share=0, expert_shares=2),
+    # its mixer's scopes are the configuration's to name too:
+    # `tests/test_qwen3_next.py` reads this one's compiled step
+    "qwen3_next": dict(
+        hidden_size=64, num_layers=4, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=16,
+        linear_value_head_dim=16, num_heads=4, num_kv_heads=2, head_dim=32,
+        num_experts=8, experts_per_token=2, expert_width=32,
+        shared_expert_width=32, expert_share=0, expert_shares=2)}
 
 F, R, B = scope_tree.PASSES
 ALL = (F, R, B)

@@ -132,6 +132,12 @@ TINY = {
                   head_dim=16, dense_width=32, num_dense_layers=1,
                   num_experts=4, experts_per_token=2, expert_width=16,
                   layer_types=["sliding_attention", "full_attention"]),
+    "qwen3_next": dict(hidden_size=32, num_layers=2, linear_num_key_heads=1,
+                       linear_num_value_heads=2, linear_key_head_dim=8,
+                       linear_value_head_dim=8, num_heads=2, num_kv_heads=1,
+                       head_dim=16, num_experts=4, experts_per_token=2,
+                       expert_width=16, shared_expert_width=16,
+                       layer_types=["linear_attention", "full_attention"]),
 }
 
 
@@ -154,7 +160,7 @@ def test_every_name_builds_its_spec(name):
     if "layer_types" in kw:
         assert spec.module.layer_types == tuple(kw["layer_types"])
     assert spec.counters == (name in ("mellum2", "joyai_flash", "lfm2_moe",
-                                      "afmoe"))
+                                      "afmoe", "qwen3_next"))
     assert spec.mtp_lambda == (0.3 if name == "joyai_flash" else 0.0)
     x = jnp.zeros((2,) + spec.input_shape, spec.input_dtype)
     inputs = (x, x) if spec.task == "seq2seq" else (x,)

@@ -486,9 +486,9 @@ def test_tpu_mesh_never_gets_an_interpreted_kernel(v5e):
 
 @pytest.mark.parametrize("kv_heads,group,d,dv,window", [
     (32, 1, 192, 128, None), (4, 8, 128, 128, None), (8, 4, 64, 64, None),
-    (4, 8, 128, 128, 1024), (4, 8, 128, 128, 2048)],
+    (4, 8, 128, 128, 1024), (4, 8, 128, 128, 2048), (2, 8, 256, 256, None)],
     ids=["joyai_mla_dp1", "full_128", "lfm2_conv_dp1", "window_1024",
-         "window_2048"])
+         "window_2048", "qwen3next_gdn_dp1"])
 def test_the_attention_kernels_compile_at_the_cells_shapes(
         v5e, kv_heads, group, d, dv, window):
     """The tiles that `models/blocks/attention.splash_sizes` computes fit the
