@@ -1,7 +1,9 @@
 """What the decoder-only language models of `models/` are built from, one
 module a decision: `rope` (how positions turn q and k), `attention` (how
 attention is tiled and masked, what a recomputed layer keeps), `delta` (how
-a mixer carries a state along the sequence: the gated delta rule in chunks),
+a mixer carries a state along the sequence: the gated delta rule in chunks,
+by the kernels of `ops/delta_rule.py` on a TPU at heads of 128 lanes and
+whole chunks, by `chunked_rule` in XLA's own operations elsewhere),
 `experts` (how tokens reach their experts and come back), `common` (what all
 of them share). A model file imports blocks and never another model; a block
 imports no model (`tests/test_layering.py`).
@@ -44,7 +46,8 @@ first five, `benchmarks/scope_tree.py` the whole path): `attn_window`,
 `moe_to_rows`, `moe_to_tokens`, `moe_gate`, `moe_product_glue` and, around
 the products' kernels `grouped_fwd`, `grouped_dx`, `grouped_dw` and their
 schedule, `moe_product` (a name no reader lists: its time is `moe_experts`'
-own); inside `moe_router` `moe_route_sort`; `attn_proj` (the four
+own); inside `gdn_rule` the rule's kernels `gdn_fwd`, `gdn_fwd_kept`,
+`gdn_bwd` (they carry the path, so the scope's reader counts them); inside `moe_router` `moe_route_sort`; `attn_proj` (the four
 projections, not around attention proper) with `rope` inside it (the rotary
 turn whole, `rope.py`); `rms_norm` (every instance); the models' own `embed`.
 A new scope goes INSIDE the one a metric reads (docs/OBSERVABILITY.md,

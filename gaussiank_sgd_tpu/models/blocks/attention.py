@@ -120,7 +120,11 @@ def splash_sizes(b: int, s: int, heads: int, d: int,
     (192) takes two lane tiles in every block the kernel holds in VMEM,
     and the fused kernel over 512 x 512 tiles then asks for 16.07 of the
     16 MiB it may have: such a head computes on tiles of half as many
-    keys, which costs 1-2 %."""
+    keys, which costs 1-2 %. A head over 192 lanes (256) takes the fused
+    kernel's queries in blocks of half as many rows too: at 512 the kernel
+    alone compiles, but in a step whose neighbours leave the compiler
+    operands to place in fast memory it is refused its stack (PERF.md
+    section 7, PR 44 open (3); met by PR 45's step)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel)
     blk = min(_SPLASH_BLOCK, s)
@@ -135,7 +139,8 @@ def splash_sizes(b: int, s: int, heads: int, d: int,
             block_kv_dq=blk)
     return kernel.BlockSizes(
         block_q=blk, block_kv=wide[-1], block_kv_compute=blk,
-        block_q_dkv=blk, block_kv_dkv=fused[0],
+        block_q_dkv=blk // 2 if d > 192 and blk == _SPLASH_BLOCK else blk,
+        block_kv_dkv=fused[0],
         block_kv_dkv_compute=(blk // 2 if d > 128 and blk == _SPLASH_BLOCK
                               else blk),
         use_fused_bwd_kernel=True)

@@ -15,6 +15,17 @@ v_t^T`). `recurrent_rule` is that sentence, token by token: the rule's own
 statement, what the CPU tests hold everything else to, and not what a chip
 should run (8192 dependent steps of a few vector operations each).
 
+**What runs where.** On a TPU (`GatedDeltaNet.kernels`: None means "where
+the backend is a TPU", as `Attention` and `Experts` take it), at key and
+value heads of whole 128-lane blocks and a sequence of whole chunks, the
+rule is `ops/delta_rule.gated_delta_rule` (PR 45): the chunk algebra below
+in three Pallas kernels, `gdn_fwd`, `gdn_fwd_kept` and `gdn_bwd`, with the
+state in VMEM from a sequence's first chunk to its last, q, k, v, g and beta
+read once a pass where the mixer has them, and the inverse computed in the
+kernel. Any other shape, and every CPU run, takes `chunked_rule`: the same
+algebra in XLA's own operations, the statement the kernels are tested
+against beside `recurrent_rule` (`tests/test_delta_kernels.py`).
+
 `chunked_rule` computes the same in chunks of `CHUNK` tokens, with the state
 carried between chunks and never a `[S, S]` matrix. Within a chunk, with `G`
 the running sum of `g` and `decay[i, j] = exp(G_i - G_j)` for `j <= i` (only
@@ -53,7 +64,9 @@ SiLU over `[q | k | v]` (`conv_silu`, shifted multiply-adds as
 output gated by `silu(z)` (`normed_gate`), the output product.
 
 Device scopes: `linear_attn` around the mixer; inside it `gdn_in_proj`,
-`gdn_conv`, `gdn_rule` (gates, L2 norms, the chunks' solve and the scan),
+`gdn_conv`, `gdn_rule` (gates, L2 norms and the rule: the three kernels,
+whose `op_name` ends in `gdn_rule/<kernel>/pallas_call`, or the chunks'
+solve and the scan),
 `gdn_norm_gate`, `gdn_out_proj`. `gdn_conv` and `gdn_norm_gate` are passes
 of their own (`optimization_barrier`): left alone XLA runs them inside the
 products beside them, under those products' names. Counters (the module's
@@ -64,16 +77,17 @@ second output): `gdn_decay_mean` (mean of `exp(g)`), `gdn_beta_mean`,
 from __future__ import annotations
 
 import functools
-from typing import Any
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .common import INIT, shifted
+from ...ops import delta_rule
+from .common import INIT, shifted, use_kernels
 
-CHUNK = 64              # tokens to a chunk of `chunked_rule`
+CHUNK = delta_rule.CHUNK    # tokens to a chunk, of the kernels' and here (64)
 _SUBSTITUTED = 16       # the diagonal blocks that are inverted row by row
 _L2_EPS = 1e-6          # in the L2 norm of q and k (beside the squares' sum)
 _HIGHEST = lax.Precision.HIGHEST
@@ -359,6 +373,7 @@ class GatedDeltaNet(nn.Module):
     conv_taps: int
     eps: float
     dtype: Any
+    kernels: Optional[bool] = None  # None: where the backend is a TPU
 
     @nn.compact
     def __call__(self, x):
@@ -394,9 +409,13 @@ class GatedDeltaNet(nn.Module):
                      * dk ** -0.5).astype(self.dtype)
                 k = l2_normed(qkv[..., keys:2 * keys].reshape(b, s, hk, dk)
                               ).astype(self.dtype)
-                o, state = chunked_rule(
-                    q, k, qkv[..., 2 * keys:].reshape(b, s, hv, dv), g, beta,
-                    CHUNK, self.dtype)
+                v = qkv[..., 2 * keys:].reshape(b, s, hv, dv)
+                if (use_kernels(self.kernels)
+                        and delta_rule.takes(q.shape, v.shape)):
+                    o, state = delta_rule.gated_delta_rule(q, k, v, g, beta)
+                else:
+                    o, state = chunked_rule(q, k, v, g, beta, CHUNK,
+                                            self.dtype)
                 g, beta, state = (lax.stop_gradient(a)
                                   for a in (g, beta, state))
                 counters = {

@@ -113,7 +113,7 @@ class Mixed(nn.Module):
                 m.linear_num_key_heads, m.linear_num_value_heads,
                 m.linear_key_head_dim, m.linear_value_head_dim,
                 m.linear_conv_kernel_dim, m.rms_norm_eps, m.dtype,
-                name="linear_attn")(h)
+                kernels=m.kernels, name="linear_attn")(h)
         else:
             turned = int(m.head_dim * m.partial_rotary_factor)
             a, gate_mean = Attention(

@@ -11,6 +11,7 @@ formulas."""
 import json
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -571,9 +572,45 @@ def op_names():
     return compiled_op_names("qwen3_next")
 
 
+@pytest.fixture(scope="module")
+def kernel_paths():
+    """The name stack of every Mosaic call of one linear layer's gradient,
+    lowered for the TPU at heads of 128 and one chunk of positions with
+    `kernels` (the CPU's compiled step above runs `chunked_rule`)."""
+    from test_model_scopes import MODELS
+    spec = get_model("qwen3_next", "ptb", vocab_size=VOCAB, seq_len=64, **{
+        **MODELS["qwen3_next"], "num_layers": 1, "linear_num_key_heads": 1,
+        "linear_num_value_heads": 2, "linear_key_head_dim": 128,
+        "linear_value_head_dim": 128, "kernels": True})
+    tokens = jax.ShapeDtypeStruct((2, 64), jnp.int32)
+    params = jax.eval_shape(
+        lambda t: spec.module.init({"params": jax.random.PRNGKey(0)}, t,
+                                   train=False), tokens)["params"]
+    def loss(p, t):
+        with jax.named_scope("fwd_bwd"):        # as the step names it
+            return jnp.sum(spec.module.apply({"params": p}, t))
+    text = jax.jit(jax.grad(loss)).trace(params, tokens).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    return re.findall(r'"([^"]*/pallas_call)"', text)
+
+
 def test_the_scopes_the_cells_readers_take_are_on_the_compiled_step(
-        op_names):
+        op_names, kernel_paths):
     from benchmarks import gdn_ops, model_scopes, scope_tree
+    # the rule's kernels carry the whole path, so `gdn_rule_ms` reads them
+    # by `op_name` like any fusion: both forward passes keep the states
+    # (`jax.checkpoint` runs the forward RULE in the first too), the
+    # backward pass is the one kernel
+    rule = [n for n in kernel_paths if "/gdn_" in n]
+    assert all(re.search(r"/linear_attn/gdn_rule/gdn_\w+/pallas_call$", n)
+               for n in rule), rule
+    with open(CONFIG) as f:
+        assert model_scopes.scope_of(
+            rule[0], json.load(f)["model_scopes"]) == "gdn_rule"
+    assert sorted((scope_tree.parse(n)[1], n.split("/")[-2])
+                  for n in rule) == [("backward", "gdn_bwd"),
+                                     ("forward", "gdn_fwd_kept"),
+                                     ("recomputed", "gdn_fwd_kept")]
     with open(CONFIG) as f:
         listed = json.load(f)["model_scopes"]
     by_scope = {}

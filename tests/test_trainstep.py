@@ -515,6 +515,41 @@ def test_the_attention_kernels_compile_at_the_cells_shapes(
     assert "dkv_no_residuals" in text and "fwd_residuals" in text
 
 
+def test_the_delta_rule_kernels_compile_at_the_cells_shape(v5e):
+    """`ops/delta_rule.py`'s three kernels at `qwen3next_gdn_dp1`'s shape (2
+    x 8192 tokens, 16 key and 32 value heads of 128, bfloat16), sixteen
+    chunks a grid step in rounds of eight: Mosaic's own verdict on the
+    permutation of lanes that the inverse's substitution makes
+    (`take_along_axis`), on the joins' two chunks side by side along the
+    lanes and on the VMEM the calls ask for (`vmem_limit_bytes` from the
+    blocks and a round's spills, under a core's 128 MiB), which neither
+    lowering nor the interpreter gives."""
+    from jax.sharding import SingleDeviceSharding
+
+    from gaussiank_sgd_tpu.ops import delta_rule
+
+    def both(q, k, v, g, beta, do, dstate):
+        out, back = jax.vjp(delta_rule.gated_delta_rule, q, k, v, g, beta)
+        return delta_rule.gated_delta_rule(q, k, v, g, beta), out, back(
+            (do, dstate))
+
+    one_chip = SingleDeviceSharding(v5e.devices[0])
+    b, s, hk, h, d = 2, 8192, 16, 32, 128
+    assert delta_rule.chunks_a_step(s // delta_rule.CHUNK) == 16
+    for backward in (False, True):
+        assert delta_rule.vmem_bytes(16, h // hk, d, d, 2,
+                                     backward) < 48 * 2 ** 20
+    avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for shape, dtype in (
+                 ((b, s, hk, d), jnp.bfloat16), ((b, s, hk, d), jnp.bfloat16),
+                 ((b, s, h, d), jnp.bfloat16), ((b, s, h), jnp.float32),
+                 ((b, s, h), jnp.float32), ((b, s, h, d), jnp.bfloat16),
+                 ((b, h, d, d), jnp.float32))]
+    text = jax.jit(both).lower(*avals).compile().as_text()
+    for name in ("gdn_fwd", "gdn_fwd_kept", "gdn_bwd"):
+        assert len(re.findall(rf"%\S*{name}[_.\d]* = ", text)) == 1, name
+
+
 def test_init_state_is_created_under_the_steps_shardings():
     """Replicated leaves on every device of the mesh, per-worker leaves one
     shard per worker — so the first step neither re-lays the state out nor
