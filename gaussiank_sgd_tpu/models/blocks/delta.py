@@ -18,13 +18,29 @@ should run (8192 dependent steps of a few vector operations each).
 **What runs where.** On a TPU (`GatedDeltaNet.kernels`: None means "where
 the backend is a TPU", as `Attention` and `Experts` take it), at key and
 value heads of whole 128-lane blocks and a sequence of whole chunks, the
-rule is `ops/delta_rule.gated_delta_rule` (PR 45): the chunk algebra below
-in three Pallas kernels, `gdn_fwd`, `gdn_fwd_kept` and `gdn_bwd`, with the
-state in VMEM from a sequence's first chunk to its last, q, k, v, g and beta
-read once a pass where the mixer has them, and the inverse computed in the
-kernel. Any other shape, and every CPU run, takes `chunked_rule`: the same
-algebra in XLA's own operations, the statement the kernels are tested
-against beside `recurrent_rule` (`tests/test_delta_kernels.py`).
+mixer between its two products is five Pallas calls a layer and step:
+
+  - what lies between `in_proj_qkvz` and the rule,
+    `ops/delta_prologue.conv_norm` (PR 47): `gdn_conv_fwd` reads the columns
+    of `[q | k | v]` where they lie in `qkvz`, computes the taps' sum once,
+    `silu`, and the L2 norms of q's and k's heads on what it holds, and
+    writes q, k and v as the rule's kernels read them (`[B, S, heads x
+    128]`); `gdn_conv_bwd` computes the same again from `qkvz` and the taps,
+    ALL it keeps (no float32 copy of q or k), and writes `qkvz`'s cotangent
+    and the taps';
+  - the rule, `ops/delta_rule.gated_delta_rule` (PR 45): the chunk algebra
+    below in three kernels, `gdn_fwd`, `gdn_fwd_kept` and `gdn_bwd`, with
+    the state in VMEM from a sequence's first chunk to its last, q, k, v, g
+    and beta read once a pass, and the inverse computed in the kernel.
+
+A differentiated layer under `jax.checkpoint` runs `gdn_conv_fwd` and
+`gdn_fwd_kept` in its forward pass and again in the recomputed one, then
+`gdn_bwd` and `gdn_conv_bwd`. Any other shape, and every CPU run, takes
+`conv_silu`, `l2_normed` and `chunked_rule`: the same arithmetic with the
+same roundings in XLA's own operations, the statements the kernels are tested
+against (`tests/test_delta_prologue.py`, `tests/test_delta_kernels.py`,
+beside `recurrent_rule`). One choice by shape for both families: a shape
+that either refuses runs no kernel.
 
 `chunked_rule` computes the same in chunks of `CHUNK` tokens, with the state
 carried between chunks and never a `[S, S]` matrix. Within a chunk, with `G`
@@ -64,12 +80,15 @@ SiLU over `[q | k | v]` (`conv_silu`, shifted multiply-adds as
 output gated by `silu(z)` (`normed_gate`), the output product.
 
 Device scopes: `linear_attn` around the mixer; inside it `gdn_in_proj`,
-`gdn_conv`, `gdn_rule` (gates, L2 norms and the rule: the three kernels,
-whose `op_name` ends in `gdn_rule/<kernel>/pallas_call`, or the chunks'
-solve and the scan),
-`gdn_norm_gate`, `gdn_out_proj`. `gdn_conv` and `gdn_norm_gate` are passes
-of their own (`optimization_barrier`): left alone XLA runs them inside the
-products beside them, under those products' names. Counters (the module's
+`gdn_conv` (the convolution with SiLU; with the kernels also the L2 norms
+and the scale of q and k: `gdn_conv_fwd` and `gdn_conv_bwd`, whose `op_name`
+ends in `gdn_conv/<kernel>/pallas_call`), `gdn_rule` (the gates and the rule:
+the three kernels, whose `op_name` ends in `gdn_rule/<kernel>/pallas_call`;
+without the kernels the L2 norms, the chunks' solve and the scan),
+`gdn_norm_gate`, `gdn_out_proj`. Without the kernels `gdn_conv`, and either
+way `gdn_norm_gate`, are passes of their own (`optimization_barrier`): left
+alone XLA runs them inside the products beside them, under those products'
+names. Counters (the module's
 second output): `gdn_decay_mean` (mean of `exp(g)`), `gdn_beta_mean`,
 `gdn_state_rms` (root mean square of the state after the last token).
 """
@@ -84,12 +103,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ...ops import delta_rule
+from ...ops import delta_prologue, delta_rule
 from .common import INIT, shifted, use_kernels
 
 CHUNK = delta_rule.CHUNK    # tokens to a chunk, of the kernels' and here (64)
 _SUBSTITUTED = 16       # the diagonal blocks that are inverted row by row
-_L2_EPS = 1e-6          # in the L2 norm of q and k (beside the squares' sum)
+_L2_EPS = delta_prologue.L2_EPS   # in the L2 norm of q and k (1e-6)
 _HIGHEST = lax.Precision.HIGHEST
 
 
@@ -365,7 +384,10 @@ class GatedDeltaNet(nn.Module):
     """x [B, S, hidden] -> (the mixer's output [B, S, hidden], its
     counters). `num_k_heads` key heads of `head_k_dim`, each serving
     `num_v_heads / num_k_heads` value heads of `head_v_dim` (neighbours
-    together)."""
+    together). `kernels`: whether the Pallas kernels run where the shape
+    lets them (`delta_rule.takes` and `delta_prologue.takes`: heads of whole
+    128-lane blocks, whole chunks), the prologue's and the rule's together
+    or neither; the module's head says which pass runs and keeps what."""
     num_k_heads: int
     num_v_heads: int
     head_k_dim: int
@@ -390,13 +412,19 @@ class GatedDeltaNet(nn.Module):
             with jax.named_scope("gdn_in_proj"):
                 qkvz = dense("in_proj_qkvz", 2 * keys + 2 * values)(x)
                 ba = dense("in_proj_ba", 2 * hv)(x).astype(jnp.float32)
+            taps = self.param("conv_taps", INIT,
+                              (2 * keys + values, self.conv_taps),
+                              jnp.float32)
+            kernels = (use_kernels(self.kernels)
+                       and delta_rule.takes((b, s, hk, dk), (b, s, hv, dv))
+                       and delta_prologue.takes(s, dk, dv, self.conv_taps))
             with jax.named_scope("gdn_conv"):
-                taps = self.param("conv_taps", INIT,
-                                  (2 * keys + values, self.conv_taps),
-                                  jnp.float32)
-                qkv = lax.optimization_barrier(conv_silu(
-                    lax.optimization_barrier(qkvz[..., :2 * keys + values]),
-                    taps))
+                if kernels:
+                    q, k, v = delta_prologue.conv_norm(qkvz, taps, keys, dk)
+                else:
+                    qkv = lax.optimization_barrier(conv_silu(
+                        lax.optimization_barrier(
+                            qkvz[..., :2 * keys + values]), taps))
             z = qkvz[..., 2 * keys + values:].reshape(b, s, hv, dv)
             with jax.named_scope("gdn_rule"):
                 a_log = self.param("A_log", log_uniform(16.0), (hv,),
@@ -405,15 +433,16 @@ class GatedDeltaNet(nn.Module):
                                      jnp.float32)
                 beta = jax.nn.sigmoid(ba[..., :hv])
                 g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., hv:] + dt_bias)
-                q = (l2_normed(qkv[..., :keys].reshape(b, s, hk, dk))
-                     * dk ** -0.5).astype(self.dtype)
-                k = l2_normed(qkv[..., keys:2 * keys].reshape(b, s, hk, dk)
-                              ).astype(self.dtype)
-                v = qkv[..., 2 * keys:].reshape(b, s, hv, dv)
-                if (use_kernels(self.kernels)
-                        and delta_rule.takes(q.shape, v.shape)):
-                    o, state = delta_rule.gated_delta_rule(q, k, v, g, beta)
+                if kernels:
+                    o, state = delta_rule.gated_delta_rule(q, k, v, g, beta,
+                                                         dk)
+                    o = o.reshape(b, s, hv, dv)
                 else:
+                    q = (l2_normed(qkv[..., :keys].reshape(b, s, hk, dk))
+                         * dk ** -0.5).astype(self.dtype)
+                    k = l2_normed(qkv[..., keys:2 * keys].reshape(
+                        b, s, hk, dk)).astype(self.dtype)
+                    v = qkv[..., 2 * keys:].reshape(b, s, hv, dv)
                     o, state = chunked_rule(q, k, v, g, beta, CHUNK,
                                             self.dtype)
                 g, beta, state = (lax.stop_gradient(a)

@@ -2,16 +2,20 @@
 `models/blocks/delta.chunked_rule` states, forward and backward, with the
 state in VMEM from a sequence's first chunk to its last.
 
-    gated_delta_rule(q, k, v, g, beta)  ->  (o, the state after the last token)
+    gated_delta_rule(q, k, v, g, beta, dk)  ->  (o, the last token's state)
 
-`q`, `k` [B, T, Hk, dk] and `v` [B, T, H, dv] in the products' dtype (each key
-head serves `H / Hk` value heads, neighbours together), `g` and `beta`
-[B, T, H] float32. The algebra of a chunk is `blocks/delta.py`'s head; here
-ALL of it happens in VMEM: the running sum of `g` and the decay mask, `K K^T`
-and `Q K^T`, the float32 inverse of `I + A`, `W`, `U`, `V' = U - W S`, `O` and
-the state's update. The products take their operands in `q.dtype` and sum in
-float32 (float32 operands: at `highest`); the state, the decays and the
-inverse are float32; only differences that are <= 0 are exponentiated.
+`q`, `k` [B, T, Hk x dk] and `v` [B, T, H x dv] in the products' dtype, heads
+side by side along the last axis as the kernels' blocks cut them and as
+`ops/delta_prologue.py`'s kernels write them (since PR 47: no four-dimensional
+array, no relayout between the two; each key head serves `H / Hk` value
+heads, neighbours together), `g` and `beta` [B, T, H] float32, `dk` a key
+head's width; o comes back `[B, T, H x dv]`. The algebra of a chunk is
+`blocks/delta.py`'s head; here ALL of it happens in VMEM: the running sum of
+`g` and the decay mask, `K K^T` and `Q K^T`, the float32 inverse of `I + A`,
+`W`, `U`, `V' = U - W S`, `O` and the state's update. The products take their
+operands in `q.dtype` and sum in float32 (float32 operands: at `highest`);
+the state, the decays and the inverse are float32; only differences that are
+<= 0 are exponentiated.
 
 **The grid** runs over (batch, key head, blocks of chunks), the last
 sequential: a step reads `(chunks x 64, 128)` of q and k and `(chunks x 64,
@@ -537,17 +541,18 @@ def _params(need: int):
         vmem_limit_bytes=need)
 
 
-def _layout(q, k, v, g, beta):
-    """The arguments as the kernels' blocks cut them, and the sizes."""
-    (b, t, hk, dk), (_, _, h, dv) = q.shape, v.shape
+def _layout(q, k, v, g, beta, dk: int):
+    """The arguments as the kernels' blocks cut them (q, k and v as they
+    come: `[B, T, heads x width]`), and the sizes."""
+    (b, t, keys), h = q.shape, g.shape[-1]
+    hk, dv = keys // dk, v.shape[-1] // h
     n = t // CHUNK
     nb = chunks_a_step(n)
 
     def gates(a):       # [B, T, H] -> [B, H, blocks, chunks, 64]
         return jnp.moveaxis(a.astype(_F32), 1, 2).reshape(
             b, h, n // nb, nb, CHUNK)
-    return ((q.reshape(b, t, hk * dk), k.reshape(b, t, hk * dk),
-             v.reshape(b, t, h * dv), gates(g), gates(beta)),
+    return ((q, k, v, gates(g), gates(beta)),
             (b, t, hk, h, dk, dv, n, nb))
 
 
@@ -572,8 +577,8 @@ def _specs(sizes, reverse: bool):
                      lambda b, key, block: (b, key, 0, 0, 0)))
 
 
-def _forward(q, k, v, g, beta, keep: bool, interpret: bool):
-    args, sizes = _layout(q, k, v, g, beta)
+def _forward(q, k, v, g, beta, dk: int, keep: bool, interpret: bool):
+    args, sizes = _layout(q, k, v, g, beta, dk)
     b, t, hk, h, dk, dv, n, nb = sizes
     rep = h // hk
     keys, values, gates, kept, states = _specs(sizes, False)
@@ -595,8 +600,7 @@ def _forward(q, k, v, g, beta, keep: bool, interpret: bool):
         compiler_params=_params(vmem_bytes(
             nb, rep, dk, dv, q.dtype.itemsize, False)),
         cost_estimate=_cost(sizes, q.dtype.itemsize, 1))(*args)
-    o, final = out[0].reshape(b, t, h, dv), out[1].reshape(b, h, dk, dv)
-    return (o, final) + tuple(out[2:])
+    return (out[0], out[1].reshape(b, h, dk, dv)) + tuple(out[2:])
 
 
 def _cost(sizes, itemsize: int, passes: int):
@@ -611,8 +615,8 @@ def _cost(sizes, itemsize: int, passes: int):
             itemsize * (2 * hk * dk + 2 * h * dv) + 8 * h))
 
 
-def _backward(q, k, v, g, beta, kept, do, dfinal, interpret: bool):
-    args, sizes = _layout(q, k, v, g, beta)
+def _backward(q, k, v, g, beta, kept, do, dfinal, dk: int, interpret: bool):
+    args, sizes = _layout(q, k, v, g, beta, dk)
     b, t, hk, h, dk, dv, n, nb = sizes
     rep = h // hk
     keys, values, gates, kept_spec, states = _specs(sizes, True)
@@ -629,33 +633,32 @@ def _backward(q, k, v, g, beta, kept, do, dfinal, interpret: bool):
         compiler_params=_params(vmem_bytes(
             nb, rep, dk, dv, q.dtype.itemsize, True)),
         cost_estimate=_cost(sizes, q.dtype.itemsize, 3))(
-            *args, kept, do.reshape(b, t, h * dv),
+            *args, kept, do,
             dfinal.astype(_F32).reshape(b, hk, rep, dk, dv))
 
     def gates_back(a):  # [B, H, blocks, chunks, 64] -> [B, T, H]
         return jnp.moveaxis(a.reshape(b, h, t), 1, 2)
-    return (dq.reshape(q.shape), dk_.reshape(k.shape), dv_.reshape(v.shape),
-            gates_back(dg).astype(g.dtype),
+    return (dq, dk_, dv_, gates_back(dg).astype(g.dtype),
             gates_back(dbeta).astype(beta.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def gated_delta_rule(q, k, v, g, beta, interpret: bool = False):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def gated_delta_rule(q, k, v, g, beta, dk: int, interpret: bool = False):
     """The gated delta rule over a sequence in chunks of 64 (the module's
-    head has the shapes). Returns (o [B, T, H, dv] as `v.dtype`, the state
+    head has the shapes). Returns (o [B, T, H x dv] as `v.dtype`, the state
     after the last token [B, H, dk, dv] float32). `interpret`: under
     Pallas' interpreter (the CPU tests)."""
-    return _forward(q, k, v, g, beta, False, interpret)
+    return _forward(q, k, v, g, beta, dk, False, interpret)
 
 
-def _rule_fwd(q, k, v, g, beta, interpret):
-    o, final, kept = _forward(q, k, v, g, beta, True, interpret)
+def _rule_fwd(q, k, v, g, beta, dk, interpret):
+    o, final, kept = _forward(q, k, v, g, beta, dk, True, interpret)
     return (o, final), (q, k, v, g, beta, kept)
 
 
-def _rule_bwd(interpret, res, cotangents):
+def _rule_bwd(dk, interpret, res, cotangents):
     do, dfinal = cotangents
-    return _backward(*res, do, dfinal, interpret)
+    return _backward(*res, do, dfinal, dk, interpret)
 
 
 gated_delta_rule.defvjp(_rule_fwd, _rule_bwd)

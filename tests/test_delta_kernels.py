@@ -1,4 +1,5 @@
-"""The gated delta rule's Pallas kernels (`ops/delta_rule.py`, PR 45) under
+"""The gated delta rule's Pallas kernels (`ops/delta_rule.py`, PR 45; the
+prologue's, PR 47, are `tests/test_delta_prologue.py`'s) under
 the interpreter on the CPU: against the rule token by token in float32
 (output, final state, all five cotangents), near the chunked rule with
 bfloat16 products, and the mixer's choice between them by shape. What Mosaic
@@ -19,8 +20,14 @@ from test_qwen3_next import _rule_gradients, _rule_inputs
 WIDE = dict(dk=128, dv=128, b=1)
 
 
-def _kernels(*args):
-    return delta_rule.gated_delta_rule(*args, True)
+def _kernels(q, k, v, g, beta):
+    """The kernels on `recurrent_rule`'s arguments: heads side by side along
+    the last axis, as the kernels take and give them."""
+    (b, t, _, dk), (_, _, h, dv) = q.shape, v.shape
+    o, state = delta_rule.gated_delta_rule(
+        q.reshape(b, t, -1), k.reshape(b, t, -1), v.reshape(b, t, -1), g,
+        beta, dk, True)
+    return o.reshape(b, t, h, dv), state
 
 
 @pytest.mark.parametrize("hk,h", [(1, 2), (2, 2)],
@@ -161,16 +168,22 @@ def test_the_mixer_takes_the_kernels_by_shape(width, positions, taken):
 
 
 def test_the_mixers_backward_pass_is_two_kernel_calls():
-    """Differentiated, the mixer calls `gdn_fwd_kept` and `gdn_bwd`; the
-    forward pass alone `gdn_fwd`, which writes no states."""
+    """Differentiated, the mixer calls `gdn_fwd_kept` and `gdn_bwd`, and
+    around them the prologue's two (PR 47: `gdn_conv_fwd` before the rule,
+    `gdn_conv_bwd` after its backward kernel): two calls a way, four in all;
+    the forward pass alone `gdn_conv_fwd` and `gdn_fwd`, which writes no
+    states."""
     mixer, params, x = _mixer(True, 128, 128)
 
     def lowered(f):
         return jax.jit(f).trace(params, x).lower(
             lowering_platforms=("tpu",)).as_text()
     forward = lowered(lambda p, x: mixer.apply(p, x)[0])
-    assert forward.count("tpu_custom_call") == 1 and "gdn_fwd" in forward
+    assert forward.count("tpu_custom_call") == 2
+    assert "gdn_fwd" in forward and "gdn_conv_fwd" in forward
     assert "gdn_fwd_kept" not in forward and "gdn_bwd" not in forward
+    assert "gdn_conv_bwd" not in forward
     both = lowered(jax.grad(lambda p, x: jnp.sum(mixer.apply(p, x)[0])))
-    assert both.count("tpu_custom_call") == 2
-    assert "gdn_fwd_kept" in both and "gdn_bwd" in both
+    assert both.count("tpu_custom_call") == 4
+    for name in ("gdn_conv_fwd", "gdn_fwd_kept", "gdn_bwd", "gdn_conv_bwd"):
+        assert name in both, name

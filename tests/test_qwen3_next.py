@@ -597,22 +597,26 @@ def kernel_paths():
 def test_the_scopes_the_cells_readers_take_are_on_the_compiled_step(
         op_names, kernel_paths):
     from benchmarks import gdn_ops, model_scopes, scope_tree
-    # the rule's kernels carry the whole path, so `gdn_rule_ms` reads them
-    # by `op_name` like any fusion: both forward passes keep the states
-    # (`jax.checkpoint` runs the forward RULE in the first too), the
-    # backward pass is the one kernel
-    rule = [n for n in kernel_paths if "/gdn_" in n]
-    assert all(re.search(r"/linear_attn/gdn_rule/gdn_\w+/pallas_call$", n)
-               for n in rule), rule
-    with open(CONFIG) as f:
-        assert model_scopes.scope_of(
-            rule[0], json.load(f)["model_scopes"]) == "gdn_rule"
-    assert sorted((scope_tree.parse(n)[1], n.split("/")[-2])
-                  for n in rule) == [("backward", "gdn_bwd"),
-                                     ("forward", "gdn_fwd_kept"),
-                                     ("recomputed", "gdn_fwd_kept")]
+    # the mixer's kernels carry the whole path, so `gdn_rule_ms` and
+    # `gdn_conv_ms` read them by `op_name` like any fusion: both forward
+    # passes are the prologue's forward kernel and the rule's that keeps the
+    # states (`jax.checkpoint` runs the forward RULE in the first too), the
+    # backward pass is one kernel of each
+    mine = [n for n in kernel_paths if "/gdn_" in n]
     with open(CONFIG) as f:
         listed = json.load(f)["model_scopes"]
+    for n in mine:
+        under = re.search(
+            r"/linear_attn/(gdn_rule|gdn_conv)/gdn_\w+/pallas_call$", n)
+        assert under and model_scopes.scope_of(n, listed) == under.group(1), n
+    assert sorted((scope_tree.parse(n)[1], n.split("/")[-3], n.split("/")[-2])
+                  for n in mine) == [
+        ("backward", "gdn_conv", "gdn_conv_bwd"),
+        ("backward", "gdn_rule", "gdn_bwd"),
+        ("forward", "gdn_conv", "gdn_conv_fwd"),
+        ("forward", "gdn_rule", "gdn_fwd_kept"),
+        ("recomputed", "gdn_conv", "gdn_conv_fwd"),
+        ("recomputed", "gdn_rule", "gdn_fwd_kept")]
     by_scope = {}
     for name in op_names:
         scope = model_scopes.scope_of(name, listed)

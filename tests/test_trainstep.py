@@ -515,6 +515,19 @@ def test_the_attention_kernels_compile_at_the_cells_shapes(
     assert "dkv_no_residuals" in text and "fwd_residuals" in text
 
 
+def _each_kernel_compiles_once(fn, shapes, device, names):
+    """`fn` compiled for `device` at `shapes` ((shape, dtype), ...): each of
+    `names` is one custom call of the executable."""
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(device)
+    avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+             for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*avals).compile().as_text()
+    for name in names:
+        assert len(re.findall(rf"%\S*{name}[_.\d]* = ", text)) == 1, name
+
+
 def test_the_delta_rule_kernels_compile_at_the_cells_shape(v5e):
     """`ops/delta_rule.py`'s three kernels at `qwen3next_gdn_dp1`'s shape (2
     x 8192 tokens, 16 key and 32 value heads of 128, bfloat16), sixteen
@@ -524,30 +537,55 @@ def test_the_delta_rule_kernels_compile_at_the_cells_shape(v5e):
     lanes and on the VMEM the calls ask for (`vmem_limit_bytes` from the
     blocks and a round's spills, under a core's 128 MiB), which neither
     lowering nor the interpreter gives."""
-    from jax.sharding import SingleDeviceSharding
-
     from gaussiank_sgd_tpu.ops import delta_rule
 
     def both(q, k, v, g, beta, do, dstate):
-        out, back = jax.vjp(delta_rule.gated_delta_rule, q, k, v, g, beta)
-        return delta_rule.gated_delta_rule(q, k, v, g, beta), out, back(
-            (do, dstate))
+        def rule(*args):
+            return delta_rule.gated_delta_rule(*args, d)
+        out, back = jax.vjp(rule, q, k, v, g, beta)
+        return rule(q, k, v, g, beta), out, back((do, dstate))
 
-    one_chip = SingleDeviceSharding(v5e.devices[0])
     b, s, hk, h, d = 2, 8192, 16, 32, 128
     assert delta_rule.chunks_a_step(s // delta_rule.CHUNK) == 16
     for backward in (False, True):
         assert delta_rule.vmem_bytes(16, h // hk, d, d, 2,
                                      backward) < 48 * 2 ** 20
-    avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-             for shape, dtype in (
-                 ((b, s, hk, d), jnp.bfloat16), ((b, s, hk, d), jnp.bfloat16),
-                 ((b, s, h, d), jnp.bfloat16), ((b, s, h), jnp.float32),
-                 ((b, s, h), jnp.float32), ((b, s, h, d), jnp.bfloat16),
-                 ((b, h, d, d), jnp.float32))]
-    text = jax.jit(both).lower(*avals).compile().as_text()
-    for name in ("gdn_fwd", "gdn_fwd_kept", "gdn_bwd"):
-        assert len(re.findall(rf"%\S*{name}[_.\d]* = ", text)) == 1, name
+    _each_kernel_compiles_once(
+        both, (((b, s, hk * d), jnp.bfloat16), ((b, s, hk * d), jnp.bfloat16),
+               ((b, s, h * d), jnp.bfloat16), ((b, s, h), jnp.float32),
+               ((b, s, h), jnp.float32), ((b, s, h * d), jnp.bfloat16),
+               ((b, h, d, d), jnp.float32)),
+        v5e.devices[0], ("gdn_fwd", "gdn_fwd_kept", "gdn_bwd"))
+
+
+def test_the_prologue_kernels_compile_at_the_cells_shape(v5e):
+    """`ops/delta_prologue.py`'s two kernels at `qwen3next_gdn_dp1`'s shape
+    (2 x 8192 positions, the first 8192 of `qkvz`'s 12288 columns where they
+    lie, 256 positions a grid step): Mosaic's own verdict on the loads at a
+    sublane offset that are the shifts along the sequence, on the dynamic
+    lane offset of a head in the loop over heads and on the VMEM the calls
+    ask for (`vmem_limit_bytes` from the blocks, under a core's 128 MiB),
+    which neither lowering nor the interpreter gives."""
+    from gaussiank_sgd_tpu.ops import delta_prologue
+
+    b, s, keys, values, dk, taps = 2, 8192, 2048, 4096, 128, 4
+    width = 2 * keys + values
+
+    def both(qkvz, taps, dq, dk_, dv):
+        out, back = jax.vjp(
+            lambda x, t: delta_prologue.conv_norm(x, t, keys, dk), qkvz, taps)
+        return out, back((dq, dk_, dv))
+
+    assert delta_prologue.takes(s, dk, 128, taps)
+    assert delta_prologue.rows_a_step(s) == 256
+    for backward in (False, True):
+        assert delta_prologue.vmem_bytes(256, width, 2, taps,
+                                         backward) < 48 * 2 ** 20
+    _each_kernel_compiles_once(
+        both, (((b, s, width + values), jnp.bfloat16),
+               ((width, taps), jnp.float32), ((b, s, keys), jnp.bfloat16),
+               ((b, s, keys), jnp.bfloat16), ((b, s, values), jnp.bfloat16)),
+        v5e.devices[0], ("gdn_conv_fwd", "gdn_conv_bwd"))
 
 
 def test_init_state_is_created_under_the_steps_shardings():
