@@ -20,6 +20,7 @@ from .lfm2_moe import LFM2MoE
 from .lstm import LSTMLM
 from .mellum2 import Mellum2
 from .mnistnet import MnistNet
+from .nemotron_h import NemotronH
 from .qwen3_next import Qwen3Next
 from .resnet import CifarResNet, ResNet50
 from .speech import LSTMAN4
@@ -85,6 +86,10 @@ _BLOCK_MODELS = {
     # attention at heads of 256 three to one, zero-centred norms, a softmax
     # router over 512 with a gated shared expert (models/qwen3_next.py)
     "qwen3_next": (Qwen3Next, 151936, None),
+    # blocks of ONE module under one norm by a pattern string: Mamba-2
+    # state-space mixers, squared-ReLU experts behind a sigmoid router with
+    # a shared expert, attention under no positions (models/nemotron_h.py)
+    "nemotron_h": (NemotronH, 131072, None),
 }
 _ALIASES = {"mnist": "mnistnet", "transformerlm": "transformer_lm"}
 

@@ -3,8 +3,9 @@ module a decision: `rope` (how positions turn q and k), `attention` (how
 attention is tiled and masked, what a recomputed layer keeps), `delta` (how
 a mixer carries a state along the sequence: the gated delta rule in chunks,
 by the kernels of `ops/delta_rule.py` on a TPU at heads of 128 lanes and
-whole chunks, by `chunked_rule` in XLA's own operations elsewhere),
-`experts` (how tokens reach their experts and come back), `common` (what all
+whole chunks, by `chunked_rule` in XLA's own operations elsewhere), `ssm` (a state-space
+mixer with a scalar decay a head, Mamba-2: the scan in chunks, a group of
+heads at a time, in XLA's own operations; a grouped gated norm), `experts` (how tokens reach their experts and come back), `common` (what all
 of them share). A model file imports blocks and never another model; a block
 imports no model (`tests/test_layering.py`).
 
@@ -22,23 +23,29 @@ the scope `attn_gate` inside `attn_proj`), a turn of a head's FIRST entries
 (`Attention(rope_lead=True)`, `apply_rope(lead=True)`: a
 `partial_rotary_factor`), zero-centred norms (`RMSNorm(zero_centred=True)`,
 `Attention(qk_norm_zero_centred=True)`: times `1 + scale`, the scale from
-zero), a sigmoid gate on the shared expert (`Experts(shared_gate=True)`).
+zero), a sigmoid gate on the shared expert (`Experts(shared_gate=True)`),
+squared-ReLU experts of two matrices (`Experts(form=RELU2)`,
+`GatedMLP(form=RELU2)`: `W2 relu(W1 x)^2`, no leaf `w3`), a bias on the
+mixers' convolution (`delta.conv_silu(x, taps, bias)`).
 Which model sets which field:
 
-    field                        mellum2  joyai_flash  lfm2_moe  afmoe        qwen3_next
-    Attention  qk_norm           -        (own MLA)    yes       yes          yes, zero-centred
-               positions=False   -        (own MLA)    -         full layers  -
-               rope_lead         -        (own MLA)    -         -            yes (64 of 256)
-               gate              -        (own MLA)    -         yes          yes
-    RMSNorm    zero_centred      -        -            -         -            yes
-    Experts    scoring           softmax  sigmoid      sigmoid   sigmoid      softmax
-               select_bias       -        yes          yes       yes          -
-               scale             1        2.5          1         2.826        1
-               sum_eps           0        0            1e-6      1e-20        0
-               shared_width      0        768          0         1024         512
-               shared_gate       -        -            -         -            yes
-    GatedMLP   leading dense     -        1            2         2            -
-    delta      GatedDeltaNet     -        -            -         -            3 layers in 4
+    field                        mellum2  joyai_flash  lfm2_moe  afmoe        qwen3_next         nemotron_h
+    Attention  qk_norm           -        (own MLA)    yes       yes          yes, zero-centred  -
+               positions=False   -        (own MLA)    -         full layers  -                  yes
+               rope_lead         -        (own MLA)    -         -            yes (64 of 256)    -
+               gate              -        (own MLA)    -         yes          yes                -
+    RMSNorm    zero_centred      -        -            -         -            yes                -
+    Experts    scoring           softmax  sigmoid      sigmoid   sigmoid      softmax            sigmoid
+               select_bias       -        yes          yes       yes          -                  yes
+               scale             1        2.5          1         2.826        1                  2.5
+               sum_eps           0        0            1e-6      1e-20        0                  0
+               shared_width      0        768          0         1024         512                3712
+               shared_gate       -        -            -         -            yes                -
+               form              gated    gated        gated     gated        gated              relu2
+    GatedMLP   leading dense     -        1            2         2            -                  -
+    delta      GatedDeltaNet     -        -            -         -            3 layers in 4      -
+               conv_silu bias    -        -            -         -            -                  yes
+    ssm        Mamba2Mixer       -        -            -         -            -                  `M` blocks
 
 Device scopes (`jax.named_scope`; `benchmarks/model_scopes.py` reads the
 first five, `benchmarks/scope_tree.py` the whole path): `attn_window`,
@@ -58,5 +65,7 @@ function's auxiliary output): `moe_held_assignments`, `moe_room_used`,
 attention adds `attn_gate_mean` (`models/afmoe.py`), a gated shared expert
 `moe_shared_gate_mean`, the linear mixer `gdn_decay_mean`, `gdn_beta_mean`
 and `gdn_state_rms` (`delta.py`, which also has its scopes: `linear_attn`
-and the five inside it).
+and the five inside it), the state-space mixer `ssm_dt_mean`,
+`ssm_decay_mean` and `ssm_state_rms` (`ssm.py`, with its scopes: `ssm` and
+the five inside it).
 """

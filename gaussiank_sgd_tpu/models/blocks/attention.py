@@ -19,7 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .common import INIT, norm_scale_init, rms_normed, use_kernels
+from .common import (INIT, norm_scale_init, rms_normed, scaled_init,
+                     use_kernels)
 from .rope import apply_rope
 
 # the two kinds of attention layer, as a config's `layer_types` names them
@@ -230,6 +231,7 @@ class Attention(nn.Module):
     gate: bool = False              # `gate_proj`, hidden -> heads x head_dim:
     # its sigmoid times the attention's output, entry by entry, before
     # `o_proj`; the module then answers (output, the sigmoid's mean)
+    out_init_scale: float = 1.0     # `o_proj`'s draw at init times this
 
     @nn.compact
     def __call__(self, x):
@@ -279,6 +281,7 @@ class Attention(nn.Module):
                     share = jnp.mean(jax.nn.sigmoid(
                         lax.stop_gradient(gate).astype(jnp.float32)))
             y = nn.DenseGeneral(hidden, axis=(-2, -1), use_bias=False,
-                                dtype=self.dtype, kernel_init=INIT,
+                                dtype=self.dtype,
+                                kernel_init=scaled_init(self.out_init_scale),
                                 name="o_proj")(out)
         return (y, share) if self.gate else y

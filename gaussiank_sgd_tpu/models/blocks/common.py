@@ -26,6 +26,12 @@ def INIT(key, shape, dtype=jnp.float32):
                                         dtype).reshape(shape)
 
 
+def scaled_init(by: float):
+    """`INIT`, its draw times `by`: a product that writes the residual
+    stream in a model whose config says `rescale_prenorm_residual`."""
+    return INIT if by == 1.0 else lambda *a: INIT(*a) * by
+
+
 def use_kernels(kernels: Optional[bool]) -> bool:
     """`kernels` where it is given; else whether the process's default
     backend is a TPU."""

@@ -288,20 +288,23 @@ def _taps_sum(x, taps):
 
 
 @jax.custom_vjp
-def conv_silu(x, taps):
+def conv_silu(x, taps, bias=None):
     """`silu` of the causal depthwise convolution of `x` [B, S, w] with
-    `taps` [w, L], in float32, rounded once to `x.dtype`: one pass over `x`
-    forward. The backward pass keeps `x` and the taps, computes the sum
-    again, and is one pass over `x` and the cotangent."""
-    return jax.nn.silu(_taps_sum(x, taps)).astype(x.dtype)
+    `taps` [w, L], plus `bias` [w] where one is given (`ssm.Mamba2Mixer`'s;
+    the gated delta rule's has none, and its program is the one without),
+    in float32, rounded once to `x.dtype`: one pass over `x` forward. The
+    backward pass keeps `x`, the taps and the bias, computes the sum again,
+    and is one pass over `x` and the cotangent."""
+    pre = _taps_sum(x, taps)
+    return jax.nn.silu(pre if bias is None else pre + bias).astype(x.dtype)
 
 
-def _conv_silu_fwd(x, taps):
-    return conv_silu(x, taps), (x, taps)
+def _conv_silu_fwd(x, taps, bias=None):
+    return conv_silu(x, taps, bias), (x, taps, bias)
 
 
 def _conv_silu_bwd(res, g):
-    x, taps = res
+    x, taps, bias = res
     length = taps.shape[1]
 
     def d_pre(ahead: int):
@@ -312,6 +315,8 @@ def _conv_silu_bwd(res, g):
         pre = sum(taps[:, length - 1 - d]
                   * shifted(x, d - ahead).astype(jnp.float32)
                   for d in range(length))
+        if bias is not None:
+            pre = pre + bias
         share = jax.nn.sigmoid(pre)
         return (shifted(g, -ahead).astype(jnp.float32) * share
                 * (1.0 + pre * (1.0 - share)))
@@ -322,7 +327,9 @@ def _conv_silu_bwd(res, g):
     d_taps = jnp.stack(
         [jnp.sum(ahead[0] * shifted(x, length - 1 - j).astype(jnp.float32),
                  axis=(0, 1)) for j in range(length)], axis=-1)
-    return dx.astype(x.dtype), d_taps.astype(taps.dtype)
+    d_bias = (None if bias is None
+              else jnp.sum(ahead[0], axis=(0, 1)).astype(bias.dtype))
+    return dx.astype(x.dtype), d_taps.astype(taps.dtype), d_bias
 
 
 conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)

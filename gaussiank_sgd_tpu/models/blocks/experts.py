@@ -19,7 +19,12 @@ caller: 23 % of the matrix unit at 2304 x 896 where the tiled kernel reads
 70 %, `PERF.md` section 6, PR 41). Shapes are static, so there is room for
 twice an even load's rows where the step sees that they suffice and for
 every assignment (all of a token's experts held) where not: a `lax.cond`,
-not a capacity."""
+not a capacity.
+
+**Two forms of an expert**, chosen by the model's code (`Experts.form`,
+`GatedMLP.form`): `GATED`, `W2 (silu(W1 x) * W3 x)`, three matrices an expert and
+three grouped products a layer; `RELU2`, `W2 relu(W1 x)^2`, two of each and
+no leaf `w3` (the routed experts and the shared one alike)."""
 
 from __future__ import annotations
 
@@ -32,10 +37,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from ...ops import grouped_matmul
-from .common import INIT, use_kernels
+from .common import INIT, scaled_init, use_kernels
 
 # the slots that a block of tokens has for its live rows in `_summed`
 _ROOM = 512
+GATED, RELU2 = "gated", "relu2"     # an expert's form: the module's head
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -291,14 +297,19 @@ grouped_product.defvjp(_grouped_fwd, _grouped_bwd)
 def _terms(cap: int, top: int, kernels: bool, x, weights, order, inverse,
            sizes, w1, w3, w2):
     """The held experts' part of the layer's output, float32 [T, h], over
-    the first `cap` sorted assignments, which hold every live one."""
+    the first `cap` sorted assignments, which hold every live one. `w3`
+    None: the experts are `RELU2` ones."""
     first = order[:cap]
     live = inverse < jnp.sum(sizes)
     rows = to_rows(x, first, inverse, live, top)
     gate = grouped_product(rows, w1, sizes, kernels)
-    up = grouped_product(rows, w3, sizes, kernels)
-    with jax.named_scope("moe_gate"):
-        gated = jax.nn.silu(gate) * up
+    if w3 is None:                  # `RELU2`: no second product going in
+        with jax.named_scope("moe_gate"):
+            gated = jnp.square(jax.nn.relu(gate))
+    else:
+        up = grouped_product(rows, w3, sizes, kernels)
+        with jax.named_scope("moe_gate"):
+            gated = jax.nn.silu(gate) * up
     out = grouped_product(gated, w2, sizes, kernels)
     return to_tokens(out, weights.reshape(-1), first, inverse, live, top)
 
@@ -414,23 +425,34 @@ def route(probs, top: int, first: int, held: int, choose_by=None,
 
 
 class GatedMLP(nn.Module):
-    """`W2 (silu(W1 x) * W3 x)` on the last axis, under the device scope
-    `device_scope`: float32 parameters multiplied as `x.dtype`."""
+    """`W2 (silu(W1 x) * W3 x)` on the last axis (`form` `RELU2`: `W2
+    relu(W1 x)^2`, no leaf `w3`), under the device scope `device_scope`:
+    float32 parameters multiplied as `x.dtype`."""
     width: int
     device_scope: str
+    form: str = GATED
+    out_init_scale: float = 1.0     # `w2`'s draw at init times this
 
     @nn.compact
     def __call__(self, x):
         wide = (x.shape[-1], self.width)
+        out_init = scaled_init(self.out_init_scale)
         w1 = self.param("w1", INIT, wide, jnp.float32).astype(x.dtype)
+        if self.form == RELU2:
+            w2 = self.param("w2", out_init, wide[::-1], jnp.float32
+                            ).astype(x.dtype)
+            with jax.named_scope(self.device_scope):
+                return jnp.dot(jnp.square(jax.nn.relu(jnp.dot(x, w1))), w2)
         w3 = self.param("w3", INIT, wide, jnp.float32).astype(x.dtype)
-        w2 = self.param("w2", INIT, wide[::-1], jnp.float32).astype(x.dtype)
+        w2 = self.param("w2", out_init, wide[::-1], jnp.float32
+                        ).astype(x.dtype)
         with jax.named_scope(self.device_scope):
             return jnp.dot(jax.nn.silu(jnp.dot(x, w1)) * jnp.dot(x, w3), w2)
 
 
 class Experts(nn.Module):
-    """The router over all `num_experts` and the gated experts held here.
+    """The router over all `num_experts` and the experts held here, of the
+    form `form` (the module's head; the shared expert's too).
     `scoring` `softmax`: probabilities over all experts; `sigmoid`: each
     logit's own. With `select_bias` a leaf `router_bias` is added to the
     scores where the `experts_per_token` are CHOSEN and nowhere else (its
@@ -454,6 +476,9 @@ class Experts(nn.Module):
     sum_eps: float = 0.0
     kernels: Optional[bool] = None  # None: where the backend is a TPU
     shared_gate: bool = False
+    form: str = GATED
+    out_init_scale: float = 1.0     # the draws of `w2`, the held experts'
+    # and the shared one's, at init times this
 
     @nn.compact
     def __call__(self, x):
@@ -466,6 +491,8 @@ class Experts(nn.Module):
                 f"{self.num_experts} experts")
         if self.scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring {self.scoring!r}")
+        if self.form not in (GATED, RELU2):
+            raise ValueError(f"form {self.form!r}")
         held = self.num_experts // self.shares
         x = x.reshape(tokens, hidden)
         with jax.named_scope("moe_router"):
@@ -487,8 +514,10 @@ class Experts(nn.Module):
                 self.sum_eps)
         shape = (held, hidden, self.width)
         w1 = self.param("w1", INIT, shape, jnp.float32)
-        w3 = self.param("w3", INIT, shape, jnp.float32)
-        w2 = self.param("w2", INIT, (held, self.width, hidden), jnp.float32)
+        w3 = (None if self.form == RELU2
+              else self.param("w3", INIT, shape, jnp.float32))
+        w2 = self.param("w2", scaled_init(self.out_init_scale),
+                        (held, self.width, hidden), jnp.float32)
         with jax.named_scope("moe_experts"):
             # Room for every assignment (all of a token's experts held)
             # costs gathers of `tokens * top` rows; an even load fills a
@@ -501,7 +530,8 @@ class Experts(nn.Module):
                              weights, order, inverse, sizes, w1, w3, w2)
         shared_counters = {}
         if self.shared_width:
-            shared = GatedMLP(self.shared_width, "moe_shared",
+            shared = GatedMLP(self.shared_width, "moe_shared", self.form,
+                              self.out_init_scale,
                               name="shared")(x).astype(jnp.float32)
             if self.shared_gate:
                 with jax.named_scope("moe_shared"):

@@ -63,7 +63,16 @@ MODELS = {
         linear_num_value_heads=4, linear_key_head_dim=16,
         linear_value_head_dim=16, num_heads=4, num_kv_heads=2, head_dim=32,
         num_experts=8, experts_per_token=2, expert_width=32,
-        shared_expert_width=32, expert_share=0, expert_shares=2)}
+        shared_expert_width=32, expert_share=0, expert_shares=2),
+    # blocks of one module; the state-space mixer's scopes are the
+    # configuration's to name: `tests/test_nemotron_h.py` reads this one's
+    # compiled step
+    "nemotron_h": dict(
+        hidden_size=64, pattern="EMEM*", mamba_num_heads=4, mamba_head_dim=16,
+        ssm_state_size=16, n_groups=2, chunk_size=8, num_heads=4,
+        num_kv_heads=2, head_dim=16, num_experts=8, experts_per_token=2,
+        expert_width=32, shared_expert_width=48, expert_share=0,
+        expert_shares=2)}
 
 F, R, B = scope_tree.PASSES
 ALL = (F, R, B)
@@ -80,7 +89,12 @@ PASSES_OF = {
                         mla_assemble=ALL, layer_scan=ALL),
     # the norm a branch leaves through needs the branch's output again in
     # the backward pass: here `moe_to_tokens`' recomputed sum is no dead code
-    "afmoe": dict(SHARED, attn_proj=ALL, moe_to_tokens=ALL)}
+    "afmoe": dict(SHARED, attn_proj=ALL, moe_to_tokens=ALL),
+    # no positions: its attention block opens no `rope`; the state-space
+    # mixer's five scopes are its configuration's to name
+    # (`tests/test_nemotron_h.py`)
+    "nemotron_h": dict({k: v for k, v in SHARED.items() if k != "rope"},
+                       attn_proj=ALL)}
 # new scope: the older scope that has to enclose it wherever it appears
 ENCLOSED_BY = {"moe_to_rows": "moe_experts", "moe_to_tokens": "moe_experts",
                "moe_gate": "moe_experts", "moe_product_glue": "moe_experts",
@@ -94,7 +108,10 @@ OLD_SCOPES = {
     "joyai_flash": ("attn_mla", "mla_proj", "moe_router", "moe_experts",
                     "moe_shared", "dense_mlp", "lm_head"),
     "afmoe": ("attn_window", "attn_full", "attn_gate", "moe_router",
-              "moe_experts", "moe_shared", "dense_mlp", "lm_head")}
+              "moe_experts", "moe_shared", "dense_mlp", "lm_head"),
+    "nemotron_h": ("ssm", "ssm_in_proj", "ssm_conv", "ssm_scan",
+                   "ssm_norm_gate", "ssm_out_proj", "attn_full",
+                   "moe_router", "moe_experts", "moe_shared", "lm_head")}
 # share of the instructions under `fwd_bwd` whose path holds no name of a
 # model's: the residual additions, the counters, `jax.checkpoint`'s own
 # barriers (3.4 % and 0.7 % at these sizes; 31 % and 5 % of the time on the
@@ -178,7 +195,8 @@ def test_the_older_scope_stays_the_outer_one(parsed, model, scope):
         assert model_scopes.scope_of(name, OLD_SCOPES[model]) == outer, name
 
 
-@pytest.mark.parametrize("model", sorted(PASSES_OF))
+@pytest.mark.parametrize("model", sorted(
+    m for m in PASSES_OF if "rope" in PASSES_OF[m]))
 def test_the_rotary_turn_is_inside_the_projections(parsed, model):
     outer = "mla_assemble" if model == "joyai_flash" else "attn_proj"
     chains = {chain for _, chain, _ in parsed(model) if "rope" in chain}
@@ -198,7 +216,7 @@ def test_a_norm_inside_latent_attention_counts_with_its_projection(parsed):
     assert {c[-2] for c in chains} >= {"mla_q", "mla_kv", "fwd_bwd", "mtp"}
 
 
-@pytest.mark.parametrize("model", sorted(PASSES_OF))
+@pytest.mark.parametrize("model", sorted(UNNAMED_SHARE))
 def test_little_of_fwd_bwd_has_no_name_of_the_models(parsed, model):
     under = [chain for _, chain, _ in parsed(model)
              if chain[:1] == ("fwd_bwd",)]

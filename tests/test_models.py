@@ -138,6 +138,11 @@ TINY = {
                        head_dim=16, num_experts=4, experts_per_token=2,
                        expert_width=16, shared_expert_width=16,
                        layer_types=["linear_attention", "full_attention"]),
+    "nemotron_h": dict(hidden_size=32, pattern="ME*", mamba_num_heads=2,
+                       mamba_head_dim=8, ssm_state_size=8, n_groups=1,
+                       chunk_size=8, num_heads=2, num_kv_heads=1,
+                       head_dim=16, num_experts=4, experts_per_token=2,
+                       expert_width=16, shared_expert_width=16),
 }
 
 
@@ -160,7 +165,7 @@ def test_every_name_builds_its_spec(name):
     if "layer_types" in kw:
         assert spec.module.layer_types == tuple(kw["layer_types"])
     assert spec.counters == (name in ("mellum2", "joyai_flash", "lfm2_moe",
-                                      "afmoe", "qwen3_next"))
+                                      "afmoe", "qwen3_next", "nemotron_h"))
     assert spec.mtp_lambda == (0.3 if name == "joyai_flash" else 0.0)
     x = jnp.zeros((2,) + spec.input_shape, spec.input_dtype)
     inputs = (x, x) if spec.task == "seq2seq" else (x,)
