@@ -34,12 +34,22 @@ are, shared by their group's heads. The products take their operands in
 `dtype` (the model's compute dtype) and accumulate in float32; running sums,
 decays and the state are float32.
 
-The backward pass is JAX's own transpose of that, a group of heads at a time:
+Its backward pass is JAX's own transpose of that, a group of heads at a time:
 `chunked_scan` is a `lax.map` over the groups of a `jax.checkpoint`ed body,
 so the backward pass holds one group's factors (`M` is `[B, chunks, heads a
 group, chunk, chunk]`) and of the forward pass only what the scan reads. All
 of it lives only while the one block that is being differentiated is
 recomputed (`attention.recomputed`).
+
+**Which form runs where.** On a TPU (`kernels` None) or with `kernels` true,
+at the published chunk of 128 and a shape `ops/ssd_scan.takes` admits (whole
+chunks; a group's heads x head size and the state size whole 128-lane tiles:
+8 heads of 64 and 128 here), the mixer's scan is that file's three Pallas
+kernels, `ssd_fwd`, `ssd_fwd_kept` and `ssd_bwd`: the same algebra with the
+same roundings, a group's states in VMEM from a sequence's first chunk to its
+last, `[x | B | C]` read where the convolution left it. Every other shape,
+every other chunk and every CPU run is `chunked_scan`, the statement the
+kernels are held to (`tests/test_ssd_kernels.py`, under Pallas' interpreter).
 
 `Mamba2Mixer` is the mixer whole: one product to `[z | x B C | dt]`, a
 depthwise causal convolution of a few taps WITH a bias and SiLU over `x B C`
@@ -52,7 +62,8 @@ first, norm second, all of a group's heads under one root mean square;
 product.
 
 Device scopes: `ssm` around the mixer; inside it `ssm_in_proj`, `ssm_conv`,
-`ssm_scan` (the softplus, the chunks and the state's scan), `ssm_norm_gate`,
+`ssm_scan` (the softplus, the chunks and the state's scan: the kernels'
+calls or `chunked_scan`'s loops, and the `D x` term), `ssm_norm_gate`,
 `ssm_out_proj`. `ssm_conv` and `ssm_norm_gate` are passes of their own
 (`optimization_barrier`): left alone XLA runs them inside the products beside
 them, under those products' names. Counters (the module's second output):
@@ -64,14 +75,15 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .common import INIT, scaled_init
+from ...ops import ssd_scan
+from .common import INIT, scaled_init, use_kernels
 from .delta import conv_silu
 
 CHUNK = 128             # tokens to a chunk (the published `chunk_size`)
@@ -252,7 +264,11 @@ class Mamba2Mixer(nn.Module):
     counters). `num_heads` heads of `head_dim` (the inner width is their
     product, whatever `hidden` is), a state of `state_size` a head, `B` and
     `C` in `groups` groups. `out_init_scale`: what the output product's
-    draw is multiplied by at init (`rescale_prenorm_residual`)."""
+    draw is multiplied by at init (`rescale_prenorm_residual`). `kernels`:
+    whether the scan runs as `ops/ssd_scan.py`'s Pallas kernels where the
+    shape lets them (`ssd_scan.takes`: whole chunks of 128, a group's heads
+    and the state size whole 128-lane tiles); everywhere else
+    `chunked_scan`."""
     num_heads: int
     head_dim: int
     state_size: int
@@ -262,6 +278,7 @@ class Mamba2Mixer(nn.Module):
     dtype: Any
     chunk: int = CHUNK
     out_init_scale: float = 1.0
+    kernels: Optional[bool] = None  # None: where the backend is a TPU
 
     @nn.compact
     def __call__(self, x):
@@ -293,14 +310,23 @@ class Mamba2Mixer(nn.Module):
                 dt = jax.nn.softplus(
                     zxbcdt[..., inner + mixed:].astype(jnp.float32) + dt_bias)
                 a = -jnp.exp(a_log)
-                x_in = xbc[..., :inner].reshape(b, s, h, p)
-                y, state = chunked_scan(
-                    x_in, dt, a,
-                    xbc[..., inner:inner + g * n].reshape(b, s, g, n),
-                    xbc[..., inner + g * n:].reshape(b, s, g, n),
-                    self.chunk, self.dtype)
-                y = (y.astype(jnp.float32) + skip[:, None]
-                     * x_in.astype(jnp.float32)).astype(self.dtype)
+                if (use_kernels(self.kernels) and self.chunk == ssd_scan.CHUNK
+                        and ssd_scan.takes((b, s, h, p), (b, s, g, n))):
+                    # heads side by side all the way: a head's 64 columns
+                    # as a minor axis of their own would be half a tile
+                    y, state = ssd_scan.ssd_scan(xbc, dt, a, g, n)
+                    y = (y.astype(jnp.float32) + jnp.repeat(skip, p)
+                         * xbc[..., :inner].astype(jnp.float32))
+                else:
+                    x_in = xbc[..., :inner].reshape(b, s, h, p)
+                    y, state = chunked_scan(
+                        x_in, dt, a,
+                        xbc[..., inner:inner + g * n].reshape(b, s, g, n),
+                        xbc[..., inner + g * n:].reshape(b, s, g, n),
+                        self.chunk, self.dtype)
+                    y = (y.astype(jnp.float32) + skip[:, None]
+                         * x_in.astype(jnp.float32))
+                y = y.astype(self.dtype).reshape(b, s, inner)
                 dt, state = lax.stop_gradient(dt), lax.stop_gradient(state)
                 counters = {
                     "ssm_dt_mean": jnp.mean(dt),
@@ -311,7 +337,7 @@ class Mamba2Mixer(nn.Module):
                 scale = self.param("norm_scale", nn.initializers.ones,
                                    (inner,), jnp.float32)
                 y = lax.optimization_barrier(gated_group_norm(
-                    *lax.optimization_barrier((y.reshape(b, s, inner), z)),
+                    *lax.optimization_barrier((y, z)),
                     scale, g, self.eps))
             with jax.named_scope("ssm_out_proj"):
                 out = nn.Dense(

@@ -103,7 +103,7 @@ class Block(nn.Module):
             y, counters = Mamba2Mixer(
                 m.mamba_num_heads, m.mamba_head_dim, m.ssm_state_size,
                 m.n_groups, m.conv_kernel, m.rms_norm_eps, m.dtype,
-                m.chunk_size, OUT_INIT_SCALE, name="mixer")(h)
+                m.chunk_size, OUT_INIT_SCALE, m.kernels, name="mixer")(h)
         elif self.kind == EXPERTS:
             y, counters = Experts(
                 m.num_experts, m.experts_per_token, m.expert_width,

@@ -639,6 +639,51 @@ def test_the_scopes_the_cells_readers_take_are_on_the_compiled_step(op_names):
     assert all(any(f"/blocks_{i}/" in n for n in norms) for i in range(5))
 
 
+def test_the_scans_kernels_carry_the_scans_scope_on_the_tpu_lowered_step():
+    """Where the mixer takes `ops/ssd_scan.py`'s kernels (lowered for the
+    TPU at a shape they take: chunks of 128, two heads of 64 a group, a
+    state of 128), the sparse step's three calls each carry `ssm/ssm_scan`
+    on their `op_name`, forward, recomputed and backward: `ssm_scan_ms`
+    cannot silently empty into `no_scope_ms`."""
+    import re
+    positions = 256
+    spec = get_model(
+        "nemotron_h", "ptb", vocab_size=VOCAB, dtype=jnp.float32,
+        seq_len=positions, kernels=True, **dict(
+            TINY, pattern="MM", mamba_head_dim=64, ssm_state_size=128,
+            chunk_size=128))
+    tokens = jax.ShapeDtypeStruct((2, positions), jnp.int32)
+    params = jax.eval_shape(
+        lambda t: spec.module.init({"params": jax.random.PRNGKey(0)}, t,
+                                   train=False), tokens)["params"]
+    ts = build_dp_train_step(
+        make_loss_fn(spec), None, get_compressor("auto", density=0.01),
+        plan_for_params(params, 0.01),
+        Mesh(np.array(jax.devices()[:1]), ("dp",)),
+        flat_opt=FlatSGDM(lr=0.1, momentum=0.9, weight_decay=1e-4))
+    state = jax.eval_shape(
+        lambda p: ts.init_state(p, jax.random.PRNGKey(2), model_state={},
+                                carry=()), params)
+    text = ts.sparse_step.trace(state, (tokens, tokens)).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    paths = re.findall(r'"([^"]*/ssd_\w+/pallas_call)"', text)
+    by_kernel = {}
+    for path in paths:
+        by_kernel.setdefault(path.split("/")[-2], set()).add(path)
+    # a block's forward pass and its recomputed one are both `ssd_fwd_kept`
+    # (JAX evaluates a `custom_vjp`'s forward rule under `checkpoint` too)
+    assert set(by_kernel) == {"ssd_fwd_kept", "ssd_bwd"}
+    for kernel, mine in by_kernel.items():
+        assert all("/ssm/ssm_scan/" in path for path in mine), kernel
+        for block in ("blocks_0", "blocks_1"):
+            assert any(f"/{block}/" in path for path in mine), (kernel, block)
+    from benchmarks import scope_tree
+    assert {scope_tree.parse(path)[1] for path in by_kernel["ssd_fwd_kept"]
+            } == {"forward", "recomputed"}
+    assert {scope_tree.parse(path)[1] for path in by_kernel["ssd_bwd"]
+            } == {"backward"}
+
+
 def test_an_unknown_pattern_is_refused():
     with pytest.raises(ValueError, match="nemotron_h"):
         get_model("nemotronh", "ptb")

@@ -558,6 +558,33 @@ def test_the_delta_rule_kernels_compile_at_the_cells_shape(v5e):
         v5e.devices[0], ("gdn_fwd", "gdn_fwd_kept", "gdn_bwd"))
 
 
+def test_the_scan_kernels_compile_at_the_cells_shape(v5e):
+    """`ops/ssd_scan.py`'s three kernels at `nemotronh_ssd_dp1`'s shape (2 x
+    8192 tokens, 64 heads of 64 in 8 groups, a state of 128, bfloat16, `[x |
+    B | C]` 6144 wide), eight chunks a grid step: Mosaic's own verdict on
+    the products whose left side is transposed (a float32 row's pieces
+    against zeros and ones), on the group's columns of `[x | B | C]` by the
+    index maps and on the VMEM the calls ask for (`vmem_limit_bytes` from
+    the blocks, under a core's 128 MiB), which neither lowering nor the
+    interpreter gives."""
+    from gaussiank_sgd_tpu.ops import ssd_scan
+
+    b, s, h, p, g, n = 2, 8192, 64, 64, 8, 128
+
+    def both(xbc, dt, a, dy, dstate):
+        def scan(*args):
+            return ssd_scan.ssd_scan(*args, g, n)
+        out, back = jax.vjp(scan, xbc, dt, a)
+        return scan(xbc, dt, a), out, back((dy, dstate))
+
+    assert ssd_scan.takes((b, s, h, p), (b, s, g, n))
+    _each_kernel_compiles_once(
+        both, (((b, s, h * p + 2 * g * n), jnp.bfloat16),
+               ((b, s, h), jnp.float32), ((h,), jnp.float32),
+               ((b, s, h * p), jnp.bfloat16), ((b, h, n, p), jnp.float32)),
+        v5e.devices[0], ("ssd_fwd", "ssd_fwd_kept", "ssd_bwd"))
+
+
 def test_the_prologue_kernels_compile_at_the_cells_shape(v5e):
     """`ops/delta_prologue.py`'s two kernels at `qwen3next_gdn_dp1`'s shape
     (2 x 8192 positions, the first 8192 of `qkvz`'s 12288 columns where they
